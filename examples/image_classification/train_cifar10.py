@@ -59,4 +59,5 @@ if __name__ == "__main__":
     net = models.get_model(args.network, num_classes=args.num_classes,
                            num_layers=args.num_layers,
                            image_shape="3,28,28")
+    mx.base.use_compile_cache()
     fit.fit(args, net, get_cifar_iter)

@@ -91,4 +91,5 @@ if __name__ == "__main__":
     net = models.get_model(args.network, num_classes=args.num_classes,
                            num_layers=args.num_layers,
                            image_shape=args.image_shape)
+    mx.base.use_compile_cache()
     fit.fit(args, net, get_rec_iter)

@@ -68,4 +68,5 @@ if __name__ == "__main__":
 
     net = models.get_model(args.network, num_classes=args.num_classes,
                            image_shape="1,28,28")
+    mx.base.use_compile_cache()
     fit.fit(args, net, get_mnist_iter)

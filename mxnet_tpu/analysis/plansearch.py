@@ -259,7 +259,7 @@ def predict_plan_wall(topo, entries, plan, node_shapes, model=None,
                 out = _out_shape(node_shapes, blk.terminal)
                 # apply_block's _relayout only transposes 4-d image
                 # activations — a non-4d block pays nothing
-                if x is not None and out is not None and pbw > 0 \
+                if x is not None and out is not None and pbw \
                         and len(x) == 4 and len(out) == 4:
                     # one transpose in, one out: read+write each
                     relayout_s = 2.0 * 4.0 * (_size(x) + _size(out)) \

@@ -2,9 +2,8 @@
 best (arXiv:1802.04799, adapted to the Pallas kernel surface).
 
 The harness owns ONE timing code path — :func:`measure` — with the cost
-database's semantics: synchronized dispatch (value fetch closes the
-async chain, which ``block_until_ready`` alone does not on relayed
-backends), **min-of-N** wall, compile excluded by an untimed warm-up
+database's semantics: synchronized dispatch (a value fetch closes
+the async chain), **min-of-N** wall, compile excluded by an untimed warm-up
 call, and optional in-program chaining (``chain=K`` scans K
 data-dependent applications inside one jitted program, dividing the
 wall by K — the same dispatch-overhead amortization ``bench.py`` and

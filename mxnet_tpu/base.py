@@ -12,12 +12,35 @@ import numpy as _np
 
 __all__ = [
     "MXNetError", "TShape", "DTYPE_TO_NP", "NP_TO_DTYPE", "dtype_np",
-    "dtype_id", "string_types", "numeric_types",
+    "dtype_id", "string_types", "numeric_types", "use_compile_cache",
 ]
 
 
 class MXNetError(RuntimeError):
     """Framework error type (reference: dmlc::Error surfaced via MXGetLastError)."""
+
+
+def use_compile_cache():
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Called by the entry points that compile real programs
+    (``chip_smoke.py``, ``bench.py``, ``python -m mxnet_tpu.serving``,
+    the image-classification ``fit``, ``tools/transformer_mfu.py``,
+    ``tools/xprof_top.py``) — never at package import, so tests and
+    AOT compiles for a described chip stay uncached.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this
+    sets nothing; otherwise the cache goes to ``.jax_cache`` in the
+    checkout: the path is part of the cache key, so it is fixed."""
+    import os
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 string_types = (str,)

@@ -111,6 +111,14 @@ def _accelerators():
     return [d for d in jax.local_devices() if d.platform != "cpu"] or []
 
 
+def on_tpu():
+    """True when jax's default backend is a TPU: the one platform probe
+    behind every Pallas-kernel-or-``jnp`` choice (``ops/fused.py``,
+    ``ops/pallas_kernels.py``)."""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
 def cpu(device_id=0):
     return Context("cpu", device_id)
 

@@ -26,12 +26,6 @@ from .symbol import eval_graph, _classify_vars
 __all__ = ["Executor"]
 
 
-def _as_jnp(v, dtype):
-    import numpy as np
-    import jax.numpy as jnp
-    return jnp.asarray(np.asarray(v), dtype=dtype)
-
-
 def _normalize(values, names, kind, default_ctor=None):
     """Accept list/tuple ordered by ``names`` or a dict; return dict."""
     if values is None:
@@ -420,9 +414,7 @@ class Executor:
             self.backward()
             return self._outputs
         for k, v in kwargs.items():
-            arr = self.arg_dict[k]
-            arr._set_data(v.data.astype(arr.dtype) if isinstance(v, NDArray)
-                          else _as_jnp(v, arr.dtype))
+            self.arg_dict[k]._write(v)
         from . import random as _random
         key = _random.take_key()
         self._last_key = key
@@ -456,16 +448,10 @@ class Executor:
             return self._forward(is_train, **kwargs)
 
     def _forward(self, is_train=False, **kwargs):
-        import numpy as np
         for k, v in kwargs.items():
             if k not in self.arg_dict:
                 raise MXNetError("unknown input %r" % k)
-            arr = self.arg_dict[k]
-            if isinstance(v, NDArray):
-                arr._set_data(v.data.astype(arr.dtype))
-            else:
-                import jax.numpy as jnp
-                arr._set_data(jnp.asarray(np.asarray(v), dtype=arr.dtype))
+            self.arg_dict[k]._write(v)
 
         from . import random as _random
         key = _random.take_key()
@@ -614,17 +600,13 @@ class Executor:
                          allow_extra_params=False):
         for k, v in arg_params.items():
             if k in self.arg_dict:
-                self.arg_dict[k]._set_data(
-                    v.data.astype(self.arg_dict[k].dtype)
-                    if isinstance(v, NDArray) else v)
+                self.arg_dict[k]._write(v)
             elif not allow_extra_params:
                 raise MXNetError("unknown argument %r" % k)
         if aux_params:
             for k, v in aux_params.items():
                 if k in self.aux_dict:
-                    self.aux_dict[k]._set_data(
-                        v.data.astype(self.aux_dict[k].dtype)
-                        if isinstance(v, NDArray) else v)
+                    self.aux_dict[k]._write(v)
                 elif not allow_extra_params:
                     raise MXNetError("unknown aux state %r" % k)
 

@@ -10,6 +10,7 @@ check :func:`available`.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import time
@@ -38,12 +39,17 @@ def _load():
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
-    if not os.path.exists(_LIB_PATH):
-        try:
-            subprocess.run(["make", "-C", _SRC_DIR], check=True,
-                           capture_output=True)
-        except (OSError, subprocess.CalledProcessError):
-            return None
+    # the library is not tracked by git: make builds it where it is
+    # missing and rebuilds it where a source is newer (a no-op
+    # otherwise), so a stale binary is never loaded.  One process at a
+    # time: test workers start together
+    try:
+        with open(os.path.join(_SRC_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            subprocess.run(["make", "-C", _SRC_DIR, "libmxtpu_io.so"],
+                           check=True, capture_output=True)
+    except (OSError, subprocess.CalledProcessError):
+        return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
     except OSError:

@@ -124,13 +124,18 @@ class KVStore:
         self._push_bytes.inc(sum(_nbytes(v) for v in vals))
         uniq, grouped = _group_kv_pairs(keys, vals)
         for k, group in zip(uniq, grouped):
+            # per-device values reduce on the first one's device, and
+            # the update runs where the stored weight lives (reference
+            # comm.h: copy to the merge buffer, then reduce)
             merged = group[0].copy()
             for other in group[1:]:
-                merged += other
+                merged += other.as_in_context(merged.context)
             if self._updater is not None:
                 if k not in self._store:
                     raise MXNetError("key %s has not been inited" % str(k))
-                self._updater(k, merged, self._store[k])
+                stored = self._store[k]
+                self._updater(k, merged.as_in_context(stored.context),
+                              stored)
             else:
                 self._store[k] = merged
 

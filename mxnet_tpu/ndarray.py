@@ -162,9 +162,7 @@ class NDArray:
             if other.shape != self.shape:
                 raise MXNetError(
                     f"copyto shape mismatch {self.shape} vs {other.shape}")
-            import jax
-            src = self.data.astype(other.dtype)
-            other._set_data(jax.device_put(src, other._target_device()))
+            other._write(self)
             return other
         if isinstance(other, Context):
             import jax
@@ -203,6 +201,7 @@ class NDArray:
         return NDArray(self.data[key])
 
     def __setitem__(self, key, value):
+        import jax
         if isinstance(key, NDArray):
             key = key.asnumpy()
         if isinstance(value, NDArray):
@@ -211,18 +210,37 @@ class NDArray:
             pass
         else:
             value = _np.asarray(value)
+        # a write lands on THIS array's device wherever the value lives
+        # (reference: an NDArray's context is fixed at creation): a host
+        # batch written into an executor's input must not drag the
+        # input, and with it the whole program, onto the host
+        root = self._view_of if self._view_of is not None else self
+        dev = root._target_device()
+        if isinstance(value, jax.Array):
+            value = jax.device_put(value, dev)
         if self._view_of is not None:
-            parent = self._view_of
-            sub = parent.data[self._index]
+            sub = root.data[self._index]
             sub = sub.at[key].set(value) if not _is_full_slice(key, sub.ndim) \
                 else _jnp().broadcast_to(_jnp().asarray(value, sub.dtype), sub.shape)
-            parent._set_data(parent.data.at[self._index].set(sub))
+            new = root.data.at[self._index].set(sub)
+        elif _is_full_slice(key, self.ndim):
+            new = _jnp().broadcast_to(
+                _jnp().asarray(value, self.dtype), self.shape).astype(self.dtype)
         else:
-            if _is_full_slice(key, self.ndim):
-                self._set_data(_jnp().broadcast_to(
-                    _jnp().asarray(value, self.dtype), self.shape).astype(self.dtype))
-            else:
-                self._set_data(self.data.at[key].set(value))
+            new = self.data.at[key].set(value)
+        root._set_data(jax.device_put(new, dev))
+
+    def _write(self, value):
+        """Rebind to ``value`` (NDArray, jax or host array) cast to this
+        array's dtype and moved to this array's device; unlike
+        ``self[:] = value`` the value's shape wins."""
+        import jax
+        if isinstance(value, NDArray):
+            value = value.data
+        elif not isinstance(value, jax.Array):
+            value = _np.asarray(value)
+        self._set_data(jax.device_put(
+            value, self._target_device()).astype(self.dtype))
 
     def slice(self, start, stop):
         return self[int(start):int(stop)]

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import context as _context
 from ..base import MXNetError
 
 
@@ -85,13 +86,6 @@ def _pick_bm(m):
     return None
 
 
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except (RuntimeError, IndexError):  # pragma: no cover
-        return False
-
-
 def _stats_kernel(x_ref, w_ref, c_ref, y_ref, s1_ref, s2_ref):
     from jax.experimental import pallas as pl
 
@@ -136,12 +130,25 @@ def matmul_stats(x2d, w2d, c, bm=None, interpret=False):
     through it); default consults the tuning cache, then the
     `_pick_bm` heuristic.  ``interpret`` runs the Pallas path in
     interpreter mode regardless of backend (CPU tuning/CI)."""
-    m, k = x2d.shape
-    n = w2d.shape[1]
+    from ..parallel import mesh as _mesh
+    full_m, k = x2d.shape
+    full_n = w2d.shape[1]
+    # under a multi-device mesh each device runs the kernel on its own
+    # (rows/data, columns/model) tile: m, n are the per-device sizes
+    mesh = _mesh.active_kernel_mesh()
+    row_axis = col_axis = None
+    m, n = full_m, full_n
+    if mesh is not None:
+        # columns split in whole 128-lane groups or not at all
+        row_axis, col_axis = _mesh.kernel_axes(mesh, full_m,
+                                               full_n // 128)
+        m = full_m // (mesh.shape[row_axis] if row_axis else 1)
+        n = full_n // (mesh.shape[col_axis] if col_axis else 1)
     # the cache is consulted (and hit/miss counted) ONLY when the
     # Pallas path is actually reachable — a jnp-fallback dispatch must
     # not report a tuned config it never used
-    eligible = (_on_tpu() or interpret) and n % 128 == 0 and k % 8 == 0
+    eligible = (_context.on_tpu() or interpret) \
+        and n % 128 == 0 and k % 8 == 0
     if eligible:
         if bm is None or m % bm:
             bm = _tuned_bm(m, k, n, x2d.dtype, w2d.dtype) \
@@ -149,9 +156,6 @@ def matmul_stats(x2d, w2d, c, bm=None, interpret=False):
     else:
         bm = None
     if eligible and bm is not None:
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-
         # label the chosen M block in the cost database so the block
         # choice is queryable by problem shape (telemetry.costdb;
         # note_kernel never raises into the trace)
@@ -165,46 +169,73 @@ def matmul_stats(x2d, w2d, c, bm=None, interpret=False):
                 + k * n * w2d.dtype.itemsize
                 + m * n * x2d.dtype.itemsize),
             block_config={"bm": int(bm), "grid_m": int(m // bm)})
+        c2d = c.reshape(1, full_n).astype(jnp.float32)
+        if mesh is None:
+            y, s1, s2 = _matmul_stats_call(x2d, w2d, c2d, bm, interpret)
+        else:
+            from jax.sharding import PartitionSpec as P
 
-        y, s1, s2 = pl.pallas_call(
-            _stats_kernel,
-            grid=(m // bm,),
-            in_specs=[
-                pl.BlockSpec((bm, k), lambda i: (i, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((k, n), lambda i: (0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, n), lambda i: (0, 0),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=[
-                pl.BlockSpec((bm, n), lambda i: (i, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, n), lambda i: (0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, n), lambda i: (0, 0),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((m, n), x2d.dtype),
-                jax.ShapeDtypeStruct((1, n), jnp.float32),
-                jax.ShapeDtypeStruct((1, n), jnp.float32),
-            ],
-            cost_estimate=pl.CostEstimate(
-                flops=2 * m * n * k,
-                bytes_accessed=m * k * x2d.dtype.itemsize
-                + k * n * w2d.dtype.itemsize + m * n * x2d.dtype.itemsize,
-                transcendentals=0),
-            interpret=interpret,
-        )(x2d, w2d, c.reshape(1, n).astype(jnp.float32))
+            def tile(x, w, cc):
+                y, s1, s2 = _matmul_stats_call(x, w, cc, bm, interpret)
+                if row_axis is not None:
+                    s1, s2 = lax.psum((s1, s2), row_axis)
+                return y, s1, s2
+
+            cols = P(None, col_axis)
+            y, s1, s2 = _mesh.shard_map_nocheck(
+                tile, mesh,
+                in_specs=(P(row_axis, None), cols, cols),
+                out_specs=(P(row_axis, col_axis), cols, cols),
+            )(x2d, w2d, c2d)
         return y, s1[0], s2[0]
     # fallback: plain dot + fused reduces (still correct, not fused)
     y = jnp.dot(x2d, w2d,
                 preferred_element_type=jnp.float32)
-    ys = y - c.reshape(1, n)
+    ys = y - c.reshape(1, full_n)
     s1 = jnp.sum(ys, axis=0)
     s2 = jnp.sum(ys * ys, axis=0)
     return y.astype(x2d.dtype), s1, s2
+
+
+def _matmul_stats_call(x2d, w2d, c2d, bm, interpret):
+    """The ``pallas_call`` of :func:`matmul_stats` on one device's
+    (M,K) @ (K,N) tile; ``c2d`` is (1,N) f32, sums come back (1,N)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = x2d.shape
+    n = w2d.shape[1]
+    return pl.pallas_call(
+        _stats_kernel,
+        grid=(m // bm,),
+        in_specs=[
+            pl.BlockSpec((bm, k), lambda i: (i, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((k, n), lambda i: (0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, n), lambda i: (0, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=[
+            pl.BlockSpec((bm, n), lambda i: (i, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, n), lambda i: (0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, n), lambda i: (0, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((m, n), x2d.dtype),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+        ],
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * n * k,
+            bytes_accessed=m * k * x2d.dtype.itemsize
+            + k * n * w2d.dtype.itemsize + m * n * x2d.dtype.itemsize,
+            transcendentals=0),
+        interpret=interpret,
+    )(x2d, w2d, c2d)
 
 
 # --------------------------------------------- fused conv1x1+BN (train)

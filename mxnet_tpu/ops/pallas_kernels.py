@@ -45,6 +45,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .. import context as _context
 from .registry import register
 
 _BLOCK_Q = 128
@@ -533,13 +534,6 @@ def _fa_bwd(causal, interpret, res, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
 @register("_contrib_FlashAttention", arg_names=("q", "k", "v"),
           params={"causal": False})
 def flash_attention_op(attrs, ctx, q, k, v):
@@ -551,8 +545,20 @@ def flash_attention_op(attrs, ctx, q, k, v):
     causal = bool(attrs["causal"])
     t = q.shape[1]
     block_q = min(_BLOCK_Q, t)
-    if _on_tpu() and t > 0 and t % block_q == 0 and k.shape[1] == t:
-        return flash_attention(q, k, v, causal)
+    if _context.on_tpu() and t > 0 and t % block_q == 0 \
+            and k.shape[1] == t:
+        from ..parallel import mesh as _mesh
+        mesh = _mesh.active_kernel_mesh()
+        if mesh is None:
+            return flash_attention(q, k, v, causal)
+        # each device runs the kernel on its (batch/data, heads/model)
+        # tile; attention mixes neither dim
+        from jax.sharding import PartitionSpec as P
+        b_axis, h_axis = _mesh.kernel_axes(mesh, q.shape[0], q.shape[2])
+        spec = P(b_axis, None, h_axis, None)
+        return _mesh.shard_map_nocheck(
+            lambda q_, k_, v_: flash_attention(q_, k_, v_, causal),
+            mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
     # ragged tails (seq not a multiple of the Q block) and cross-attention
     # (tk != tq) take the jnp path rather than failing; XLA still fuses it
     return _attention_jnp(q, k, v, causal)

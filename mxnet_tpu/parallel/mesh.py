@@ -78,21 +78,56 @@ def build_mesh_from_axes(axes, devices=None):
     return Mesh(arr, axis_names=tuple(axes))
 
 
+# trace-time routing for the Pallas kernels (ops/fused.py,
+# ops/pallas_kernels.py): GSPMD cannot partition a Mosaic kernel, so
+# under a mesh of more than one device each kernel call site wraps
+# itself in a shard_map over this mesh
+_KERNEL_MESH = None
+
+
+class kernel_mesh:
+    """Context manager naming the mesh that kernels traced within are
+    partitioned over (ShardedTrainer's step and forward traces set it);
+    a one-device mesh or ``None`` deactivates."""
+
+    def __init__(self, mesh):
+        self.mesh = mesh if mesh is not None and mesh.devices.size > 1 \
+            else None
+
+    def __enter__(self):
+        global _KERNEL_MESH
+        self._prev = _KERNEL_MESH
+        _KERNEL_MESH = self.mesh
+        return self
+
+    def __exit__(self, *exc):
+        global _KERNEL_MESH
+        _KERNEL_MESH = self._prev
+
+
+def active_kernel_mesh():
+    return _KERNEL_MESH
+
+
+def kernel_axes(mesh, rows, cols):
+    """(row_axis, col_axis) a kernel under ``mesh`` splits ``rows``
+    (batch-major, over the first mesh axis) and ``cols`` (output
+    channels or heads, over 'model') along; None where the mesh has no
+    such axis or the size does not divide."""
+    row_axis = mesh.axis_names[0]
+    if rows % mesh.shape[row_axis]:
+        row_axis = None
+    col_axis = "model" if "model" in mesh.axis_names else None
+    if col_axis is not None and cols % mesh.shape[col_axis]:
+        col_axis = None
+    return row_axis, col_axis
+
+
 def shard_map_nocheck(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions
-    (check_rep in <=0.7 / check_vma in >=0.8)."""
+    """shard_map with replication checking off."""
     import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def data_parallel_spec(mesh):

@@ -50,18 +50,9 @@ def world_size():
 
 
 def _distributed_initialized():
-    """True when this process already joined a jax.distributed job.
-    ``jax.distributed.is_initialized`` only exists on newer jax; fall
-    back to the runtime state object older versions expose."""
+    """True when this process already joined a jax.distributed job."""
     import jax
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:
-        from jax._src import distributed as _dist
-        return getattr(_dist.global_state, "client", None) is not None
-    except ImportError:  # pragma: no cover - very old jax
-        return False
+    return bool(jax.distributed.is_initialized())
 
 
 def ensure_initialized():

@@ -90,9 +90,8 @@ def pipeline_apply(stage_fn, params_stack, x, mesh, microbatches,
     of size n_stages.  x: (batch, ...), split into ``microbatches`` equal
     chunks.  Returns (batch, ...) outputs of the final stage, replicated.
     """
-    import jax
     from .. import telemetry
-    if not jax.core.trace_state_clean():
+    if _being_traced(params_stack, x):
         # caller is tracing (jit(pipeline_apply) is a supported
         # pattern): a span here would record one trace-time interval
         # and then nothing per execution — worse than no data
@@ -103,10 +102,16 @@ def pipeline_apply(stage_fn, params_stack, x, mesh, microbatches,
                                microbatches, remat)
 
 
+def _being_traced(*trees):
+    """Whether any array leaf of ``trees`` is a tracer, i.e. the caller
+    runs under jit/grad rather than on concrete arrays."""
+    import jax
+    return any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree_util.tree_leaves(trees))
+
+
 def _pipeline_apply(stage_fn, params_stack, x, mesh, microbatches, remat):
     import jax
-    import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.devices.size
@@ -118,12 +123,12 @@ def _pipeline_apply(stage_fn, params_stack, x, mesh, microbatches, remat):
 
     body = functools.partial(_stage_loop, stage_fn, axis_name="pipe",
                              remat=remat, n_stages=int(n))
-    out = shard_map(
+    out = jax.shard_map(
         lambda p, xs: jax.lax.psum(body(p, xs), "pipe"),
         mesh=mesh,
         in_specs=(P("pipe"), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(params_stack, x_stack)
     # only the last stage contributed nonzeros; psum replicates its result
     return out.reshape((b,) + out.shape[2:])
@@ -142,7 +147,7 @@ def pipeline_grad(loss_fn, stage_fn, params_stack, x, labels, mesh,
                             remat=remat)
         return loss_fn(y, labels)
 
-    if not jax.core.trace_state_clean():
+    if _being_traced(params_stack, x, labels):
         # under an outer trace a span records nothing per execution
         return jax.value_and_grad(full)(params_stack)
     with telemetry.span("pipeline.grad", category="trainer"):

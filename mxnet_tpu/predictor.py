@@ -95,7 +95,7 @@ class Predictor:
             if name in input_shapes:
                 args[name] = nd.zeros(shape, ctx=ctx)
             elif name in params:
-                args[name] = params[name]
+                args[name] = params[name].as_in_context(ctx)
             elif name.endswith("label"):
                 # deployment symbols keep their loss heads; label inputs
                 # are inert at inference.  NOTE: the reference
@@ -111,7 +111,7 @@ class Predictor:
         aux = {}
         for name, shape in zip(aux_names, aux_shapes):
             if name in params:
-                aux[name] = params[name]
+                aux[name] = params[name].as_in_context(ctx)
             else:
                 aux[name] = nd.zeros(shape, ctx=ctx)
         self._input_names = list(input_shapes)

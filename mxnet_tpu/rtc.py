@@ -68,10 +68,7 @@ class Rtc:
         import jax
         import jax.numpy as jnp
         from jax.experimental import pallas as pl
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-        except ImportError:  # pragma: no cover
-            pltpu = None
+        from jax.experimental.pallas import tpu as pltpu
         glb = {"jax": jax, "jnp": jnp, "pl": pl, "pltpu": pltpu,
                "np": np}
         try:

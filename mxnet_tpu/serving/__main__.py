@@ -76,6 +76,9 @@ def main(argv=None):
                              "largest rung")
     opts = parser.parse_args(argv)
 
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
+
     # deterministic replica identity: every restart serves the same net
     from mxnet_tpu import random as mx_random
     mx_random.seed(0)

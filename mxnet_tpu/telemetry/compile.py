@@ -20,22 +20,16 @@ from .registry import counter
 __all__ = ["install", "installed"]
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_installed = None   # None = not attempted, True/False = outcome
+_installed = False
 
 
 def install():
-    """Register the jax.monitoring duration listener once per process.
-    Returns True when listening, False when the API is unavailable
-    (report() then falls back to the step-time heuristic)."""
+    """Register the jax.monitoring duration listener once per process
+    (until then report() uses the step-time heuristic).  Returns True."""
     global _installed
-    if _installed is not None:
-        return _installed
-    try:
-        from jax import monitoring
-        register = monitoring.register_event_duration_secs_listener
-    except (ImportError, AttributeError):
-        _installed = False
-        return False
+    if _installed:
+        return True
+    from jax import monitoring
     c_total = counter("mxtpu_compile_total")
     c_secs = counter("mxtpu_compile_seconds_total")
 
@@ -46,12 +40,7 @@ def install():
             from . import flight
             flight.record("compile", duration_s=round(float(dur), 6))
 
-    try:
-        register(_on_duration)
-    except TypeError:
-        # listener signature changed under us: degrade to the heuristic
-        _installed = False
-        return False
+    monitoring.register_event_duration_secs_listener(_on_duration)
     _installed = True
     return True
 

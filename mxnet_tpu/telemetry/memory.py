@@ -282,7 +282,7 @@ def device_memory_stats(device=None):
             device = devs[0]
         stats = getattr(device, "memory_stats", None)
         stats = stats() if callable(stats) else None
-    except Exception:  # mxlint: allow-broad-except(memory_stats is backend-dependent and may raise on remote/relayed devices; live sampling degrades to None, never to a crash)
+    except Exception:  # mxlint: allow-broad-except(memory_stats is backend-dependent and may raise; live sampling degrades to None, never to a crash)
         return None
     return dict(stats) if stats else None
 

@@ -1,0 +1,80 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real widths.
+
+The chip's compiler is installed here and compiles for a chip that is
+described, not attached (on-chip-measurement guide, section 2): what it
+refuses costs no chip time.  Nothing runs, so these say nothing about
+results or speed — ``chip_smoke.py`` checks those on the chip.
+
+This is the only file that describes a topology, and it does so inside
+a fixture: only one process may load the TPU's library, so the call
+must not happen while any module is imported, and a second such file
+could land on another xdist worker where the fixture would skip.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu import context
+from mxnet_tpu.ops import fused
+from mxnet_tpu.ops import pallas_kernels as pk
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # mxlint: allow-broad-except(whatever keeps the chip's compiler from describing a v5e here — no libtpu, its lock held, an unknown topology name — means these tests cannot run, not that they failed)
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_for_chip(fn, one_chip, *shapes_dtypes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes_dtypes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# (batch, seq, heads, head_dim): the transformer bench's shape, its
+# longer-sequence cells (T=4096 takes the K/V-streaming kernels), d=128
+FLASH_SHAPES = [(8, 1024, 16, 64), (4, 2048, 16, 64), (2, 4096, 16, 64),
+                (2, 4096, 16, 128)]
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+def test_flash_forward_compiles_for_v5e(one_chip, shape):
+    _compile_for_chip(lambda q, k, v: pk.flash_attention(q, k, v, True),
+                      one_chip, *[(shape, jnp.bfloat16)] * 3)
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+    _compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                      *[(shape, jnp.bfloat16)] * 3)
+
+
+# ResNet-50 at batch 128: the 1x1 convs as (N*H*W, Cin) @ (Cin, Cout)
+MATMUL_STATS_SHAPES = [(401408, 64, 256), (100352, 512, 128),
+                       (25088, 1024, 256), (6272, 2048, 512),
+                       (6272, 512, 2048)]
+
+
+@pytest.mark.parametrize("m,k,n", MATMUL_STATS_SHAPES)
+def test_matmul_stats_compiles_for_v5e(one_chip, monkeypatch, m, k, n):
+    # jax.default_backend() is the CPU here; the probe is steered from
+    # the test so that matmul_stats takes its kernel branch
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    _compile_for_chip(fused.matmul_stats, one_chip,
+                      ((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16),
+                      ((n,), jnp.float32))

@@ -45,13 +45,11 @@ def main():
     sharding = NamedSharding(mesh, P("data", None))
     dev_arrays = [jax.device_put(a, sharding) for a in arrays]
 
-    from jax.experimental.shard_map import shard_map
-
     @jax.jit
     def allreduce(xs):
         def psum_all(*local):
             return tuple(jax.lax.psum(l, "data") for l in local)
-        f = shard_map(psum_all, mesh=mesh,
+        f = jax.shard_map(psum_all, mesh=mesh,
                       in_specs=tuple(P("data", None) for _ in xs),
                       out_specs=tuple(P(None, None) for _ in xs))
         return f(*xs)

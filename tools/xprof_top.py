@@ -199,8 +199,10 @@ def main():
 
     import jax
     from mxnet_tpu import models
+    from mxnet_tpu.base import use_compile_cache
     from mxnet_tpu.parallel import ShardedTrainer, build_mesh
 
+    use_compile_cache()
     rng = np.random.RandomState(0)
     if args.model == "transformer":
         sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
