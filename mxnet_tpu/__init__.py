@@ -9,6 +9,10 @@ Import convention mirrors the reference's ``import mxnet as mx``::
 """
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()   # the ``mxnet_tpu.import`` span's start
+
 __version__ = "0.1.0"
 
 from .base import MXNetError
@@ -85,3 +89,6 @@ try:
 except ImportError:  # pragma: no cover
     th = None
 
+# first to last line of this file, as a span record (telemetry.spans):
+# a benchmark's ``import_s``
+telemetry.spans.record("mxnet_tpu.import", _IMPORT_T0, _time.perf_counter())

@@ -86,6 +86,11 @@ def _pick_bm(m):
     return None
 
 
+#: the kernel's device name: the ``name=`` of its ``pallas_call``, which
+#: XLA makes the custom call's instruction name in a profiler trace
+MATMUL_STATS = "mxtpu_matmul_stats"
+
+
 def _stats_kernel(x_ref, w_ref, c_ref, y_ref, s1_ref, s2_ref):
     from jax.experimental import pallas as pl
 
@@ -235,6 +240,7 @@ def _matmul_stats_call(x2d, w2d, c2d, bm, interpret):
             + k * n * w2d.dtype.itemsize + m * n * x2d.dtype.itemsize,
             transcendentals=0),
         interpret=interpret,
+        name=MATMUL_STATS,
     )(x2d, w2d, c2d)
 
 
@@ -656,6 +662,14 @@ def _fused_fc_act_xla(act, flatten, has_bias):
     return f
 
 
+def _block_scope(kind):
+    """``jax.named_scope`` round one fused block's region (its kinds are
+    analysis.fusion's): every op of the block, forward and backward,
+    and the relayouts XLA puts beside it carry ``mxtpu.block.<kind>``
+    in their ``op_name``."""
+    return jax.named_scope("mxtpu.block." + kind)
+
+
 # mxlint: allow-dtype-widening(bn epilogue folds statistics in f32 by contract)
 def fused_block_conv_bn_act(conv_attrs, bn_attrs, layout, is_train, act,
                             pallas, x, w, b, gamma, beta, mm, mv,
@@ -676,15 +690,16 @@ def fused_block_conv_bn_act(conv_attrs, bn_attrs, layout, is_train, act,
     if pallas and train_stats and b is None and layout == "NHWC":
         f = _fused_conv_bn(eps, momentum, relu=(act == "relu"),
                            interpret=interpret)
-        out, _mean, _var, new_mm, new_mv = f(x, w, gamma, beta, mm32,
-                                             mv32)
+        args = (x, w, gamma, beta, mm32, mv32)
     else:
         f = _fused_conv_bn_act_xla(_conv_key(conv_attrs), layout, eps,
                                    momentum, train_stats, act,
                                    b is not None)
         args = (x, w) + ((b,) if b is not None else ()) + \
             (gamma, beta, mm32, mv32)
-        out, new_mm, new_mv = f(*args)
+    with _block_scope("conv_bn_act" if act else "conv_bn"):
+        # the Pallas leg also returns the batch mean and variance
+        out, *_, new_mm, new_mv = f(*args)
     return out, new_mm.astype(mm.dtype), new_mv.astype(mv.dtype)
 
 
@@ -699,8 +714,9 @@ def fused_block_bn_act(bn_attrs, ch, is_train, act, x, gamma, beta, mm,
     if bn_attrs.get("fix_gamma"):
         gamma = lax.stop_gradient(jnp.ones_like(gamma))
     f = _fused_bn_act_xla(eps, momentum, train_stats, ch, x.ndim, act)
-    out, new_mm, new_mv = f(x, gamma, beta, mm.astype(jnp.float32),
-                            mv.astype(jnp.float32))
+    with _block_scope("bn_act"):
+        out, new_mm, new_mv = f(x, gamma, beta, mm.astype(jnp.float32),
+                                mv.astype(jnp.float32))
     return out, new_mm.astype(mm.dtype), new_mv.astype(mv.dtype)
 
 
@@ -708,7 +724,8 @@ def fused_block_fc_act(fc_attrs, act, x, w, b):
     """Evaluate a planned FullyConnected(->act) block."""
     f = _fused_fc_act_xla(act, bool(fc_attrs.get("flatten", True)),
                           b is not None)
-    return f(x, w, b) if b is not None else f(x, w)
+    with _block_scope("fc_act"):
+        return f(x, w, b) if b is not None else f(x, w)
 
 
 # ---------------------------------------------------------- graph pass

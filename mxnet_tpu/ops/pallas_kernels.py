@@ -81,6 +81,12 @@ def _causal_mask(s, qi, ki, block_q, block_k):
     return jnp.where(row >= col, s, -jnp.inf)
 
 
+# Device names of the kernels: the ``name=`` of each ``pallas_call``
+# below, which XLA makes the custom call's instruction name — what a
+# profiler trace's "XLA Ops" line and its readers find them by.
+FLASH_FWD_PANEL = "mxtpu_flash_fwd_panel"
+
+
 def _flash_fwd_panel_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                   block_q):
     from jax.experimental import pallas as pl
@@ -107,6 +113,9 @@ def _flash_fwd_panel_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causa
     # keeps the block TPU-tileable): the backward kernel reconstitutes
     # the normalized p = exp(s - lse) without a second softmax pass
     lse_ref[0] = m + jnp.log(l)
+
+
+FLASH_FWD_STREAM = "mxtpu_flash_fwd_stream"
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -298,6 +307,7 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
                 jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32),
             ],
             interpret=interpret,
+            name=FLASH_FWD_PANEL,
         )(_fold_heads(q), _fold_heads(k), _fold_heads(v))
         return _unfold_heads(out, b, h), lse
     kernel = functools.partial(_flash_fwd_kernel, scale=scale,
@@ -325,8 +335,12 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=FLASH_FWD_STREAM,
     )(_fold_heads(q), _fold_heads(k), _fold_heads(v))
     return _unfold_heads(out, b, h), lse
+
+
+FLASH_BWD_PANEL = "mxtpu_flash_bwd_panel"
 
 
 def _flash_bwd_panel_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -369,6 +383,9 @@ def _flash_bwd_panel_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                     preferred_element_type=jnp.float32)
     dk_ref[0] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
+
+
+FLASH_BWD_STREAM = "mxtpu_flash_bwd_stream"
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -492,6 +509,7 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
             out_shape=[jax.ShapeDtypeStruct((b * h, t, d),
                                             jnp.float32)] * 3,
             interpret=interpret,
+            name=FLASH_BWD_PANEL,
         )(qt, kt, vt, dot, lse, delta)
     else:
         kernel = functools.partial(_flash_bwd_kernel, scale=scale,
@@ -508,6 +526,7 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
                             pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
             interpret=interpret,
+            name=FLASH_BWD_STREAM,
         )(qt, kt, vt, dot, lse, delta)
     return tuple(_unfold_heads(x, b, h).astype(q.dtype)
                  for x in (dq, dk, dv))

@@ -47,8 +47,13 @@ from ..symbol import eval_graph, _classify_vars
 from ..initializer import Xavier, InitDesc
 from ..ops.nn import image_layout
 from .. import optimizer as _opt_mod
+from ..telemetry.spans import span as _span
 
 __all__ = ["ShardedTrainer"]
+
+#: ``jax.named_scope`` names inside the step program: forward and loss,
+#: the vjp, the optimizer update
+SCOPE_FWD, SCOPE_BWD, SCOPE_OPT = "mxtpu.fwd", "mxtpu.bwd", "mxtpu.opt"
 
 
 def _make_update_rule(opt):
@@ -161,6 +166,7 @@ def _no_persistent_cache():
 
 
 class ShardedTrainer:
+    @_span("trainer.build", category="trainer")
     def __init__(self, symbol, mesh, data_shapes, label_shapes=(),
                  optimizer="sgd", optimizer_params=None, learning_rate=0.05,
                  momentum=0.9, weight_decay=0.0, initializer=None,
@@ -195,10 +201,11 @@ class ShardedTrainer:
             parallel config) triple before any compile and raise a
             descriptive MXNetError on findings.  None -> the
             ``MXNET_TPU_STRICT_BIND`` env default.
-        """
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
+        The constructor is a ``trainer.build`` span (telemetry.spans)
+        whose children are its phases in order: ``.graph``,
+        ``.init_params``, ``.place``, ``.plan``.
+        """
         from . import multihost
 
         self.symbol = symbol
@@ -334,25 +341,34 @@ class ShardedTrainer:
                         "not divisible by the %d sequence shards"
                         % (n, s[1], sp_size))
 
+        if strict is None:
+            from .. import config as _config
+            strict = _config.get_bool("MXNET_TPU_STRICT_BIND")
+        with _span("trainer.build.graph", category="trainer"):
+            self._analyse_graph(
+                symbol, mesh, data_shapes, label_shapes, optimizer,
+                optimizer_params, learning_rate, momentum, weight_decay,
+                tp_rules, strict)
+        with _span("trainer.build.init_params", category="trainer"):
+            host_params, host_aux = self._init_host_state(initializer)
+        with _span("trainer.build.place", category="trainer"):
+            self._place_state(host_params, host_aux, seed)
+        with _span("trainer.build.plan", category="trainer"):
+            self._plan_step(strict)
+
+    def _analyse_graph(self, symbol, mesh, data_shapes, label_shapes,
+                       optimizer, optimizer_params, learning_rate,
+                       momentum, weight_decay, tp_rules, strict):
+        """``trainer.build.graph``: everything the constructor works
+        out from the symbol and the mesh before any array exists —
+        variables and index inputs, shape inference, the optimizer's
+        rule, the native-layout weight set, tensor-parallel rules, the
+        SPMD verification pass, the shardings."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         self._topo = symbol._topo()
         if self._layout == "NHWC":
             self._check_nhwc_safe()
-        # plan-search decisions (analysis.plansearch): an ambient
-        # plan_decisions context wins; otherwise consult the committed
-        # graph_plan tuning-cache entry ONCE at construction — keyed by
-        # the graph's structural digest + trace layout + THIS mesh's
-        # axis sizes + backend — and activate it around every step
-        # trace, so a tuned plan is dispatched with zero search cost
-        # (greedy on miss, like kernel configs).  Pipeline stages never
-        # fuse (seeded partial topos), so the lookup is skipped there.
-        from ..analysis import fusion as _fusion_mod
-        self._plan_decisions = _fusion_mod.active_decisions()
-        if self._plan_decisions is None and self._fuse_blocks \
-                and self._pp <= 1:
-            from ..analysis import plansearch as _plansearch
-            self._plan_decisions = _plansearch.committed_decisions(
-                self._topo, symbol._entries, self._layout,
-                mesh=self._mesh_axis_sizes())
         arg_nodes, aux_nodes = _classify_vars(self._topo)
         self._arg_nodes, self._aux_nodes = arg_nodes, aux_nodes
         arg_names = [n.name for n in arg_nodes]
@@ -465,26 +481,6 @@ class ShardedTrainer:
             o, i, h, w = self._arg_shapes[n]
             self._store_shapes[n] = (h, w, i, o)
 
-        # ---- init params on host (f32 masters), device_put with shardings.
-        # Initializer errors propagate: a wrong-shape bug must not silently
-        # become a different init.
-        init = initializer or Xavier(rnd_type="gaussian", factor_type="in",
-                                     magnitude=2)
-        host_params = {}
-        for name in self._param_names:
-            arr = _HostArray(np.zeros(self._arg_shapes[name], np.float32))
-            init(InitDesc(name), arr)
-            host_params[name] = arr.data
-        for name in self._native_w:   # initializers see reference OIHW
-            host_params[name] = np.ascontiguousarray(
-                host_params[name].transpose(2, 3, 1, 0))
-        host_aux = {}
-        for name in self._aux_names:
-            v = np.zeros(self._aux_shapes[name], np.float32)
-            if name.endswith("moving_var"):
-                v[...] = 1.0
-            host_aux[name] = v
-
         tp_size = mesh.shape.get("model", 1)
         if tp_rules is None:
             if self._seq_parallel:
@@ -563,9 +559,6 @@ class ShardedTrainer:
         # BEFORE any compile — mismatched collectives, infeasible
         # stage/axis partitions and conflicting sharding specs raise a
         # node-level diagnostic here instead of hanging a fleet
-        if strict is None:
-            from .. import config as _config
-            strict = _config.get_bool("MXNET_TPU_STRICT_BIND")
         if strict:
             from ..analysis import spmd as _spmd
             _spmd.verify_trainer_config(
@@ -630,10 +623,36 @@ class ShardedTrainer:
             n: NamedSharding(mesh, batch_spec(n))
             for n in self._input_names}
 
+    def _init_host_state(self, initializer):
+        """``trainer.build.init_params``: the f32 masters and the aux
+        state drawn on the host.  Initializer errors propagate: a
+        wrong-shape bug must not silently become a different init."""
+        init = initializer or Xavier(rnd_type="gaussian", factor_type="in",
+                                     magnitude=2)
+        host_params = {}
+        for name in self._param_names:
+            arr = _HostArray(np.zeros(self._arg_shapes[name], np.float32))
+            init(InitDesc(name), arr)
+            host_params[name] = arr.data
+        for name in self._native_w:   # initializers see reference OIHW
+            host_params[name] = np.ascontiguousarray(
+                host_params[name].transpose(2, 3, 1, 0))
+        host_aux = {}
+        for name in self._aux_names:
+            v = np.zeros(self._aux_shapes[name], np.float32)
+            if name.endswith("moving_var"):
+                v[...] = 1.0
+            host_aux[name] = v
+        return host_params, host_aux
+
+    def _place_state(self, host_params, host_aux, seed):
+        """``trainer.build.place``: parameters, aux and zeroed
+        optimizer slots onto the mesh, and the step key."""
+        import jax
         # NB multi-host: every process runs this constructor with the
         # same seeds, so host_params are identical full values on every
         # rank; _put_state slices out each process's addressable shards
-        with mesh:
+        with self.mesh:
             self.params = {n: self._put_state(host_params[n],
                                               self._param_sharding[n])
                            for n in self._param_names}
@@ -641,7 +660,28 @@ class ShardedTrainer:
                                            self._aux_sharding[n])
                         for n in self._aux_names}
             self.opt_state = self._device_zero_slots()
+        self._key = jax.random.PRNGKey(seed)
 
+    def _plan_step(self, strict):
+        """``trainer.build.plan``: the fusion plan's decisions, the
+        step function that will trace under them (compiled here only
+        under ``auto_layouts``), and the dispatch bookkeeping."""
+        # plan-search decisions (analysis.plansearch): an ambient
+        # plan_decisions context wins; otherwise consult the committed
+        # graph_plan tuning-cache entry ONCE at construction — keyed by
+        # the graph's structural digest + trace layout + THIS mesh's
+        # axis sizes + backend — and activate it around every step
+        # trace, so a tuned plan is dispatched with zero search cost
+        # (greedy on miss, like kernel configs).  Pipeline stages never
+        # fuse (seeded partial topos), so the lookup is skipped there.
+        from ..analysis import fusion as _fusion_mod
+        self._plan_decisions = _fusion_mod.active_decisions()
+        if self._plan_decisions is None and self._fuse_blocks \
+                and self._pp <= 1:
+            from ..analysis import plansearch as _plansearch
+            self._plan_decisions = _plansearch.committed_decisions(
+                self._topo, self.symbol._entries, self._layout,
+                mesh=self._mesh_axis_sizes())
         self._step_fn = self._build_step()
         if strict:
             # MXG012 over the REAL step program: trace the un-jitted
@@ -676,7 +716,6 @@ class ShardedTrainer:
         # _step_count restarts at 0 after a resume, so anything deriving
         # a global step/epoch must add this offset
         self._resume_epoch = 0
-        self._key = jax.random.PRNGKey(seed)
         self._hyper_snapshot = self._hyper_state()
 
     def _verify_step_rank_divergence(self):
@@ -1182,6 +1221,7 @@ class ShardedTrainer:
                 return new_params, new_state, new_aux, loss, stats
             return new_params, new_state, new_aux, loss
 
+        step.__name__ = step.__qualname__ = "mxtpu_train_step"
         if collect_stats:
             self._py_step_stats = step
         else:
@@ -1260,19 +1300,24 @@ class ShardedTrainer:
                             key=key, batch_size=bsz)
                 return heads, (aux_upd, dict(sink) if sink else {})
 
+            # stable names on the device: every op of the step carries
+            # one of these scopes in its ``op_name``, whatever the compile
             from ..ops.nn import maybe_mirror
-            heads, vjp, (aux_upd, blk_stats) = jax.vjp(
-                maybe_mirror(fwd), params, has_aux=True)
-            cot = [jnp.ones_like(h) if il else jnp.zeros_like(h)
-                   for h, il in zip(heads, head_is_loss)]
-            (grads,) = vjp(list(cot))
+            with jax.named_scope(SCOPE_FWD):
+                heads, vjp, (aux_upd, blk_stats) = jax.vjp(
+                    maybe_mirror(fwd), params, has_aux=True)
+            with jax.named_scope(SCOPE_BWD):
+                cot = [jnp.ones_like(h) if il else jnp.zeros_like(h)
+                       for h, il in zip(heads, head_is_loss)]
+                (grads,) = vjp(list(cot))
 
             new_params, new_state = {}, {}
-            for k, w in params.items():
-                lr_mult, wd_eff = hyper[k]
-                g = grads[k].astype(jnp.float32) * rescale
-                new_params[k], new_state[k] = rule(
-                    w, g, opt_state[k], lr * lr_mult, wd_eff, t)
+            with jax.named_scope(SCOPE_OPT):
+                for k, w in params.items():
+                    lr_mult, wd_eff = hyper[k]
+                    g = grads[k].astype(jnp.float32) * rescale
+                    new_params[k], new_state[k] = rule(
+                        w, g, opt_state[k], lr * lr_mult, wd_eff, t)
 
             new_aux = {}
             for n in self._aux_nodes:
@@ -1307,6 +1352,8 @@ class ShardedTrainer:
                 return new_params, new_state, new_aux, loss, stats
             return new_params, new_state, new_aux, loss
 
+        # the module on a trace's "XLA Modules" line: jit_mxtpu_train_step
+        step.__name__ = step.__qualname__ = "mxtpu_train_step"
         if collect_stats:
             self._py_step_stats = step
         else:
@@ -1315,7 +1362,9 @@ class ShardedTrainer:
         state_sharding = {n: [self._param_sharding[n]] * self._n_slots
                           for n in self._param_names}
         if self._auto_layouts:
-            return self._compile_auto_layout(step, state_sharding)
+            return self._compile_auto_layout(
+                "trainer.step_stats" if collect_stats else "trainer.step",
+                step, state_sharding)
         in_shardings = (self._param_sharding, state_sharding,
                         self._aux_sharding, self._batch_sharding,
                         None, None, None)
@@ -1352,12 +1401,13 @@ class ShardedTrainer:
                 body, (params, opt_state, aux, key), (lrs, ts), length=k)
             return params, opt_state, aux, losses
 
+        multi.__name__ = multi.__qualname__ = "mxtpu_train_chain"
         state_sharding = {n: [self._param_sharding[n]] * self._n_slots
                           for n in self._param_names}
         if self._auto_layouts:
             import jax.numpy as jnp
             return self._compile_auto_layout(
-                multi, state_sharding,
+                "trainer.run_steps", multi, state_sharding,
                 lr_example=jnp.zeros((k,), jnp.float32),
                 t_example=jnp.ones((k,), jnp.float32),
                 migrate=False)
@@ -1370,8 +1420,9 @@ class ShardedTrainer:
                        out_shardings=out_shardings,
                        donate_argnums=(0, 1, 2))
 
-    def _compile_auto_layout(self, step, state_sharding, lr_example=None,
-                             t_example=None, migrate=True):
+    def _compile_auto_layout(self, program, step, state_sharding,
+                             lr_example=None, t_example=None,
+                             migrate=True):
         """Compile the step with XLA-chosen parameter/state layouts.
 
         jit pins donated I/O to default layouts, so every step pays
@@ -1427,7 +1478,10 @@ class ShardedTrainer:
                    as_spec(self.aux), zero_batch, jax.random.PRNGKey(0),
                    lr_example, t_example)
         with _no_persistent_cache():
-            compiled = jf.lower(*example).compile()
+            with _span("program.lower", program=program):
+                lowered = jf.lower(*example)
+            with _span("program.compile", program=program):
+                compiled = lowered.compile()
         fmts = compiled.input_formats[0]
         compiled._state_formats = (fmts[0], fmts[1], fmts[2])
         if migrate:
@@ -1648,9 +1702,15 @@ class ShardedTrainer:
         with GLOBAL batch dim (or a dict from :meth:`put_batch`).
         Returns the (device) loss scalar.
 
-        Telemetry: each call is a ``trainer.step`` span and one
-        ``step_end`` record (step time is host-side dispatch+staging —
-        on an async backend the device may still be computing).  The
+        Telemetry: each call is a ``trainer.step`` span over the whole
+        method, whose children say where a dispatch's host time goes —
+        ``trainer.step.prepare`` (everything before the executable is
+        called), ``<program>.launch`` (the executable call alone, up to
+        its return), ``trainer.step.account`` (everything after, with
+        ``<program>.sync`` inside it when the cost database blocks on
+        the output and ``telemetry.step_end``) — and one ``step_end``
+        record (step time is host-side dispatch+staging — on an async
+        backend the device may still be computing).  The
         step is split into compute / input-wait / collective-wait
         segments (``mxtpu_step_segment_seconds``, telemetry.distview):
         input-wait is the host->device staging time, and on a
@@ -1668,30 +1728,37 @@ class ShardedTrainer:
         from .. import telemetry
         from ..telemetry import flight as _flight, memory as _tmem
         from ..telemetry import tracing as _tracing
+        step_no = self._step_count + 1
         # one distributed trace per step: the existing distview
         # segments become its child spans, and flight events recorded
         # inside (step_begin, any error) carry the trace id
-        tr = _tracing.start_trace("trainer.step",
-                                  attrs={"step": self._step_count + 1})
-        with tr:
-            _flight.record("step_begin", program="trainer.step",
-                           step=self._step_count + 1)
-            self._seg = {"input_s": 0.0, "collective_s": 0.0,
-                         "skew": None}
-            t0 = _time.perf_counter()
-            ts0 = _time.time()
-            step_ctx = None
-            with telemetry.span("trainer.step", category="trainer"), \
-                    _flight.crash_guard("trainer.step"), \
-                    _tmem.annotate_oom("trainer.step"):
-                step_ctx = _tracing.current()
-                loss = self._step_impl(batch)
-            total = _time.perf_counter() - t0
-            if step_ctx is not None:
-                self._record_segment_spans(step_ctx, ts0, total)
-        telemetry.step_end(samples=self._batch_samples(batch),
-                           step_time=total,
-                           extra=self._segments_extra(total))
+        tr = _tracing.start_trace("trainer.step", attrs={"step": step_no})
+        with tr, _span("trainer.step", category="trainer", step=step_no), \
+                _flight.crash_guard("trainer.step"), \
+                _tmem.annotate_oom("trainer.step"):
+            step_ctx = _tracing.current()
+            with _span("trainer.step.prepare", category="trainer"):
+                t0 = _time.perf_counter()
+                _flight.record("step_begin", program="trainer.step",
+                               step=step_no)
+                self._seg = {"input_s": 0.0, "collective_s": 0.0,
+                             "skew": None}
+                program, fn, args = self._prepare_step(batch)
+                obs = self._begin_dispatch(program, fn)
+            out = self._launch(program, fn, args, obs)
+            with _span("trainer.step.account", category="trainer"):
+                self._end_dispatch(obs, out, args)
+                loss = self._finish_step(program, out, args)
+                # the donated state's handles (some hundreds of arrays)
+                # die here, inside the span, not as the method returns
+                del args, out
+                total = _time.perf_counter() - t0
+                if step_ctx is not None:
+                    self._record_segment_spans(
+                        step_ctx, _tracing.epoch_of(t0), total)
+                telemetry.step_end(samples=self._batch_samples(batch),
+                                   step_time=total,
+                                   extra=self._segments_extra(total))
         return loss
 
     def _record_segment_spans(self, ctx, ts0, total_s):
@@ -1760,43 +1827,56 @@ class ShardedTrainer:
         except (StopIteration, AttributeError, IndexError, TypeError):
             return 0
 
-    def _dispatch_planned(self, program, fn, args, steps=1):
-        """Dispatch through the AOT executable with the memory plan
-        registered + budget-checked on first use
-        (telemetry.memory.dispatch_planned).  Process-spanning meshes
-        keep the plain jit dispatch (AOT example staging is a
-        per-process choice) and skip the costdb sampling — a sampled
-        ``block_until_ready`` on one rank would skew the fleet.
-
-        Cost-database seam (telemetry.costdb): the fused blocks this
-        program's compile traced bind to it, and sampled dispatches
-        record synchronized wall time + flops/bytes + mesh shape as
-        persistent MFU/roofline records (:meth:`cost_summary`).
-        ``steps``: inner train steps one dispatch executes
-        (``run_steps`` passes its chain length so the per-step wall
-        meets the signatures' per-step flops)."""
-        from ..telemetry import costdb as _costdb, memory as _tmem
+    def _begin_dispatch(self, program, fn):
+        """Open the cost database's observation of one dispatch
+        (telemetry.costdb): the fused blocks this program's compile
+        traced bind to it, and sampled dispatches record synchronized
+        wall time + flops/bytes + mesh shape as persistent MFU/roofline
+        records (:meth:`cost_summary`).  None on a process-spanning
+        mesh, whose dispatches are never timed — a sampled
+        ``block_until_ready`` on one rank would skew the fleet."""
         if self._multiproc:
+            return None
+        from ..telemetry import costdb as _costdb
+        return _costdb.begin_dispatch(
+            program, key=(self._costdb_scope, id(fn)))
+
+    def _launch(self, program, fn, args, obs):
+        """Call the program: through its AOT executable, with the
+        memory plan registered + budget-checked on first use
+        (telemetry.memory.dispatch_planned, which holds the
+        ``<program>.launch`` span round the executable call and the
+        ``program.lower``/``program.compile`` spans of the first use).
+        Process-spanning meshes keep the plain jit dispatch (AOT
+        example staging is a per-process choice)."""
+        from ..telemetry import costdb as _costdb, memory as _tmem
+        if obs is None:
             # bind-only: the compile's traced block signatures must not
             # dangle (they would attach to the next single-proc program
-            # dispatched in this process); timing stays off — a sampled
-            # block_until_ready on one rank would skew the fleet
+            # dispatched in this process)
             try:
-                return fn(*args)
+                with _span(program + ".launch", category="trainer"):
+                    return fn(*args)
             finally:
                 _costdb.bind_pending(
                     program, key=(self._costdb_scope, id(fn)))
-        obs = _costdb.begin_dispatch(
-            program, key=(self._costdb_scope, id(fn)))
         try:
-            out = _tmem.dispatch_planned(self._aot_exes, program, fn,
-                                         args)
+            return _tmem.dispatch_planned(self._aot_exes, program, fn,
+                                          args)
         except BaseException:  # mxlint: allow-broad-except(re-raised unchanged — the handler only closes the costdb observation bind-only, so the compile's traced signatures cannot dangle and attach to the next program dispatched)
             _costdb.end_dispatch(obs, failed=True)
             raise
-        _costdb.end_dispatch(obs, out=out, args=args,
-                             mesh=self._mesh_axis_sizes(), steps=steps)
-        return out
+
+    def _end_dispatch(self, obs, out, args, steps=1):
+        """Close the observation :meth:`_begin_dispatch` opened.
+        ``steps``: inner train steps the one dispatch executed
+        (``run_steps`` passes its chain length so the per-step wall
+        meets the signatures' per-step flops)."""
+        if obs is not None:
+            from ..telemetry import costdb as _costdb
+            _costdb.end_dispatch(obs, out=out, args=args,
+                                 mesh=self._mesh_axis_sizes(),
+                                 steps=steps)
 
     def _mesh_axis_sizes(self):
         """{axis name: size} of the trainer's mesh — part of every
@@ -1841,11 +1921,12 @@ class ShardedTrainer:
             self._seg["collective_s"] += info["wait_s"]
             self._seg["skew"] = info
 
-    def _step_impl(self, batch):
+    def _prepare_step(self, batch):
+        """Everything of one :meth:`step` before the executable is
+        called; returns ``(program, fn, args)``."""
         import jax
         import jax.numpy as jnp
         from .. import resilience
-        from ..telemetry import numerics as _numerics
         resilience.fault_point("trainer.step")
         self._key, sub = jax.random.split(self._key)
         dev_batch = self._stage_timed(batch)
@@ -1858,43 +1939,44 @@ class ShardedTrainer:
                              + self._step_count)
         lr = (opt.lr_scheduler(opt.num_update)
               if opt.lr_scheduler is not None else opt.lr)
-        sampled = self._numerics_sampled()
-        if sampled:
+        program, fn = "trainer.step", self._step_fn
+        if self._numerics_sampled():
             # the numerics.nonfinite seam is evaluated ONLY on sampled
             # steps: an injected NaN must land where detection runs —
             # poisoning an unsampled (or auto_layouts-gated) step would
             # corrupt the run with zero anomaly signal
             dev_batch = self._maybe_poison_batch(dev_batch)
-        fn = self._step_fn
-        if sampled:
             if self._stats_step_fn is None:
                 self._stats_step_fn = self._build_step(collect_stats=True)
-            fn = self._stats_step_fn
+            program, fn = "trainer.step_stats", self._stats_step_fn
         self._ensure_state_formats(fn)
         args = (self.params, self.opt_state, self.aux, dev_batch, sub,
                 jnp.float32(lr), jnp.float32(opt.num_update))
         self._measure_collective_entry("trainer.step")
-        if sampled:
-            program = "trainer.step_stats"
-            self.params, self.opt_state, self.aux, loss, stats = \
-                self._dispatch_planned(program, fn, args)
-            # the stats fetch is the ONLY host sync numerics adds, and
-            # only on sampled steps; every rank samples the same step
-            # numbers, so a multi-process fleet syncs symmetrically
-            payload = _numerics.process_step(
-                stats, step=self._resume_epoch + self._step_count,
-                program="trainer.step",
-                provenance_fn=lambda: self._numerics_provenance(
-                    dev_batch, sub),
-                # instance-unique EWMA scope (rotated on rebuild): two
-                # trainers in one process must not share a grad_spike
-                # baseline — model A's small norms would false-trip B
-                scope=("trainer.step", self._costdb_scope))
-            if payload is not None:
-                self._seg["numerics"] = payload
-        else:
-            self.params, self.opt_state, self.aux, loss = \
-                self._dispatch_planned("trainer.step", fn, args)
+        return program, fn, args
+
+    def _finish_step(self, program, out, args):
+        """Take one :meth:`step`'s outputs; returns the loss."""
+        if program != "trainer.step_stats":
+            self.params, self.opt_state, self.aux, loss = out
+            return loss
+        from ..telemetry import numerics as _numerics
+        self.params, self.opt_state, self.aux, loss, stats = out
+        dev_batch, sub = args[3], args[4]
+        # the stats fetch is the ONLY host sync numerics adds, and
+        # only on sampled steps; every rank samples the same step
+        # numbers, so a multi-process fleet syncs symmetrically
+        payload = _numerics.process_step(
+            stats, step=self._resume_epoch + self._step_count,
+            program="trainer.step",
+            provenance_fn=lambda: self._numerics_provenance(
+                dev_batch, sub),
+            # instance-unique EWMA scope (rotated on rebuild): two
+            # trainers in one process must not share a grad_spike
+            # baseline — model A's small norms would false-trip B
+            scope=("trainer.step", self._costdb_scope))
+        if payload is not None:
+            self._seg["numerics"] = payload
         return loss
 
     def _numerics_sampled(self):
@@ -1926,7 +2008,7 @@ class ShardedTrainer:
         first float data input is poisoned with NaNs instead of raising,
         so the detection/provenance path is what gets exercised
         (tools/ci_check.py stage 11).  Called only on SAMPLED steps
-        (see ``_step_impl``), so the injection is always detectable."""
+        (see ``_prepare_step``), so the injection is always detectable."""
         from .. import resilience
         try:
             resilience.fault_point("numerics.nonfinite")
@@ -2005,28 +2087,44 @@ class ShardedTrainer:
         (benchmarks, synthetic-data soak runs); for distinct batches per
         step, stage the next batch with :meth:`put_batch` while the chip
         runs (double buffering) and call :meth:`step` per batch.
+
+        Telemetry: a ``trainer.run_steps`` span over the whole method
+        with the children of :meth:`step`'s (``.prepare``, ``.launch``,
+        ``.account`` with ``.sync`` inside it when the cost database
+        blocks on the output).
         """
         import time as _time
         from .. import telemetry
         from ..telemetry import flight as _flight, memory as _tmem
-        _flight.record("step_begin", program="trainer.run_steps",
-                       step=self._step_count + 1, count=num_steps)
-        self._seg = {"input_s": 0.0, "collective_s": 0.0, "skew": None}
-        t0 = _time.perf_counter()
-        with telemetry.span("trainer.run_steps", category="trainer"), \
-                _flight.crash_guard("trainer.run_steps"), \
-                _tmem.annotate_oom("trainer.run_steps"):
-            losses = self._run_steps_impl(batch, num_steps)
-        # the scan chain IS num_steps full optimizer updates observed
-        # once from the host: counters/percentiles advance per inner
-        # step, but the JSONL gets ONE record (count=num_steps) — per-
-        # record snapshots of an opaque chain would be byte-identical
-        total = _time.perf_counter() - t0
-        telemetry.step_end(
-            samples=self._batch_samples(batch),
-            step_time=total / max(1, num_steps),
-            count=num_steps,
-            extra=self._segments_extra(total, count=num_steps))
+        program = "trainer.run_steps"
+        with _span(program, category="trainer", steps=num_steps), \
+                _flight.crash_guard(program), _tmem.annotate_oom(program):
+            with _span("trainer.run_steps.prepare", category="trainer"):
+                t0 = _time.perf_counter()
+                _flight.record("step_begin", program=program,
+                               step=self._step_count + 1, count=num_steps)
+                self._seg = {"input_s": 0.0, "collective_s": 0.0,
+                             "skew": None}
+                fn, args = self._prepare_run_steps(batch, num_steps)
+                obs = self._begin_dispatch(program, fn)
+            out = self._launch(program, fn, args, obs)
+            with _span("trainer.run_steps.account", category="trainer"):
+                self._end_dispatch(obs, out, args, steps=num_steps)
+                self.params, self.opt_state, self.aux, losses = out
+                # the donated state's handles (some hundreds of arrays)
+                # die here, inside the span, not as the method returns
+                del args, out
+                # the scan chain IS num_steps full optimizer updates
+                # observed once from the host: counters/percentiles
+                # advance per inner step, but the JSONL gets ONE record
+                # (count=num_steps) — per-record snapshots of an opaque
+                # chain would be byte-identical
+                total = _time.perf_counter() - t0
+                telemetry.step_end(
+                    samples=self._batch_samples(batch),
+                    step_time=total / max(1, num_steps),
+                    count=num_steps,
+                    extra=self._segments_extra(total, count=num_steps))
         return losses
 
     def health(self):
@@ -2040,7 +2138,9 @@ class ShardedTrainer:
         from ..telemetry import slo
         return slo.health()
 
-    def _run_steps_impl(self, batch, num_steps):
+    def _prepare_run_steps(self, batch, num_steps):
+        """Everything of one :meth:`run_steps` before the executable is
+        called; returns ``(fn, args)``."""
         import jax
         import jax.numpy as jnp
         import numpy as _np
@@ -2078,10 +2178,7 @@ class ShardedTrainer:
                 jnp.asarray(_np.asarray(lrs, _np.float32)),
                 jnp.asarray(_np.asarray(ts, _np.float32)))
         self._measure_collective_entry("trainer.run_steps")
-        self.params, self.opt_state, self.aux, losses = \
-            self._dispatch_planned("trainer.run_steps", fn, args,
-                                   steps=num_steps)
-        return losses
+        return fn, args
 
     def forward(self, batch, is_train=False):
         """Jitted inference forward returning head arrays."""

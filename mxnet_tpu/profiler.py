@@ -23,7 +23,7 @@ import threading
 import time
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
-           "record_event", "is_running", "now_us"]
+           "record_event", "is_running", "now_us", "us_of"]
 
 _state = {
     "mode": "symbolic",      # 'symbolic' | 'all'
@@ -37,11 +37,17 @@ _lock = threading.Lock()
 _t0 = time.perf_counter()
 
 
+def us_of(t):
+    """A ``time.perf_counter()`` reading as microseconds on the
+    profiler's clock (trace-event timebase).  Public so the telemetry
+    span tracer puts its records on the same axis as the operator
+    events recorded here."""
+    return (t - _t0) * 1e6
+
+
 def now_us():
-    """Microseconds on the profiler's clock (trace-event timebase).
-    Public so the telemetry span tracer stamps its events on the same
-    axis as the operator events recorded here."""
-    return (time.perf_counter() - _t0) * 1e6
+    """Microseconds on the profiler's clock, now."""
+    return us_of(time.perf_counter())
 
 
 _now_us = now_us

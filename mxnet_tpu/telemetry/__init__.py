@@ -17,8 +17,11 @@ Three engines:
   undeclared name raises at the emit site;
 * **span tracer** (:mod:`.spans`) — ``telemetry.span("fwd")`` context
   manager/decorator recording wall time per phase, wired through the
-  executor, Module, both trainers, and the IO stack, and mirrored into
-  the Chrome trace when the profiler is running;
+  executor, Module, both trainers, and the IO stack; every finished
+  span is one record on ``time.perf_counter()`` in a bounded ring
+  (``telemetry.spans.records()``), mirrored into the Chrome trace when
+  the profiler is running and onto a ``jax.profiler`` trace's host
+  plane as ``mxtpu:<name>``;
 * **distributed tracing** (:mod:`.tracing`) — W3C-traceparent trace
   context (thread-local + explicitly attachable) giving every serving
   request and training step ONE causal trace: spans entered under an

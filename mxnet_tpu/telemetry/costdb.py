@@ -38,7 +38,7 @@ the dispatch it observes):
    :func:`note_kernel` (``ops/pallas_kernels.py``, ``ops/fused.py``)
    register *pending signatures* with shapes/dtypes/flops estimates;
 2. dispatch time: :func:`begin_dispatch`/:func:`end_dispatch` around
-   ``Executor._dispatch`` / ``ShardedTrainer._dispatch_planned`` bind
+   ``Executor._dispatch`` / ``ShardedTrainer._launch`` bind
    pending signatures to the program whose compile traced them, and on
    *sampled* dispatches (``MXNET_TPU_COSTDB_SAMPLE``, default every
    16th; the first post-compile dispatch is always sampled; ``0``
@@ -406,7 +406,11 @@ class CostDB:
         if t0 is None or failed:
             return
         import jax
-        jax.block_until_ready(out)
+        from .spans import span
+        # the one host sync this module adds to a dispatch, as a record
+        # of its own: counting ``<program>.sync`` records counts syncs
+        with span(program + ".sync", category="costdb"):
+            jax.block_until_ready(out)
         # per-step wall: a run_steps chain is `steps` full updates in
         # one dispatch, and the bound signatures carry ONE step's flops
         wall = (time.perf_counter() - t0) / max(1, int(steps))

@@ -32,6 +32,7 @@ from . import ioview as ioview_mod
 from . import memory as memory_mod
 from . import slo as slo_mod
 from . import tracing as tracing_mod
+from . import spans as spans_mod
 from .spans import drain_step_spans
 
 __all__ = ["step_end", "jsonl_event", "render_prom", "report",
@@ -104,6 +105,7 @@ def _jsonl_handle():
     return _jsonl["fh"]
 
 
+@spans_mod.span("telemetry.step_end", category="telemetry")
 def step_end(samples=None, step_time=None, extra=None, count=1):
     """Mark ``count`` training steps complete (1 for ordinary loops;
     ``ShardedTrainer.run_steps`` passes its scan length, since the
@@ -126,7 +128,6 @@ def step_end(samples=None, step_time=None, extra=None, count=1):
             h.observe(step_time)
         with _lock:
             _step_durs.extend([float(step_time)] * count)
-    spans = drain_step_spans()
     # live HBM sample at the step boundary (inert on backends without
     # memory_stats): the gauges land in the JSONL snapshot below and in
     # any later flight dump
@@ -144,6 +145,9 @@ def step_end(samples=None, step_time=None, extra=None, count=1):
     # on the MXNET_TPU_IOVIEW_EVERY cadence.  Runs even when the JSONL
     # is off — the call also ticks the window bottleneck classifier
     io_rec = ioview_mod.step_record()
+    # drained as late as the record allows: this call's own span and the
+    # trainer's (open round it) go in with the time they have run so far
+    spans = drain_step_spans()
     ev = {"step": step_no, "step_time_s": step_time, "samples": samples,
           "spans": spans, "counter_deltas": deltas}
     if count > 1:
@@ -459,11 +463,12 @@ def reset_steps():
 
 def reset():
     """Clear every sample, the percentile window, the per-step span
-    accumulator, the flight ring + memory-plan registry, and the
-    step-log handle (the env var is re-read on the next step).  Metric
-    objects and cached label children stay valid."""
+    accumulator and the span records, the flight ring + memory-plan
+    registry, and the step-log handle (the env var is re-read on the
+    next step).  Metric objects and cached label children stay valid."""
     REGISTRY.reset()
     drain_step_spans()
+    spans_mod.clear()
     flight.clear()
     ioview_mod.reset()
     memory_mod.clear_plans()

@@ -46,6 +46,7 @@ import threading
 from ..base import MXNetError
 from .registry import counter, gauge
 from . import flight
+from .spans import span
 
 __all__ = [
     "HbmOomError", "MemoryPlan",
@@ -424,7 +425,13 @@ def planned_executable(program, fn, args):
         if lower is None:
             return fn
         try:
-            compiled = lower(*args).compile()
+            # the one seam every program of the trainer and the executor
+            # compiles through: trace + lower (the graph passes run at
+            # trace time) apart from backend compile / cache load
+            with span("program.lower", program=program):
+                lowered = lower(*args)
+            with span("program.compile", program=program):
+                compiled = lowered.compile()
         except MXNetError:
             raise
         except Exception as e:  # mxlint: allow-broad-except(AOT lowering is an optimization for plan capture; any backend/tracing failure falls back to the ordinary jit dispatch path)
@@ -452,20 +459,26 @@ def dispatch_planned(cache, program, fn, args):
     partial tail batch), the entry is permanently downgraded to the jit
     wrapper for that fn — jax's own cache then serves every shape with
     no per-call raise/catch — and the registered plan keeps describing
-    the first-seen (steady-state) program."""
+    the first-seen (steady-state) program.
+
+    The call of the executable alone, up to its return, is a
+    ``<program>.launch`` span (telemetry.spans)."""
     key = (program, id(fn))
     exe = cache.get(key)
     if exe is None:
         exe = planned_executable(program, fn, args)
         cache[key] = exe
+    launch = span(program + ".launch")
     try:
-        return exe(*args)
+        with launch:
+            return exe(*args)
     except TypeError:
         if exe is fn:
             raise
         cache[key] = fn
         flight.record("plan_fallback", program=program)
-        return fn(*args)
+        with launch:
+            return fn(*args)
 
 
 # ----------------------------------------------------------- OOM forensics

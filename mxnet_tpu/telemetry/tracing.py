@@ -57,7 +57,7 @@ __all__ = [
     "TRACE_SCHEMA", "TraceContext", "Trace", "NULL_TRACE",
     "sample_rate", "enabled", "ring_capacity", "trace_dir", "slow_pct",
     "new_trace_id", "new_span_id", "parse_traceparent",
-    "current", "attach", "detach",
+    "current", "attach", "detach", "epoch_of",
     "start_trace", "record_span", "set_trace_status",
     "annotate", "take_annotations",
     "traces", "get_trace", "reset", "exemplar_for",
@@ -69,6 +69,11 @@ log = logging.getLogger(__name__)
 
 #: the per-trace JSONL export schema tag (one line per kept trace)
 TRACE_SCHEMA = "mxtpu-trace/1"
+
+#: epoch seconds less ``time.perf_counter()``, taken once: spans are timed
+#: on perf_counter (telemetry.spans), traces are merged across ranks and so
+#: keep epoch seconds — :func:`epoch_of` turns the one into the other
+_EPOCH_OFFSET = time.time() - time.perf_counter()
 
 _tls = threading.local()
 _lock = threading.Lock()
@@ -129,6 +134,12 @@ def _rank():
         return int(os.environ.get("MXNET_TPU_PROCESS_ID", "0") or 0)
     except ValueError:
         return 0
+
+
+def epoch_of(t):
+    """A ``time.perf_counter()`` reading as epoch seconds (a trace
+    span's ``ts``)."""
+    return t + _EPOCH_OFFSET
 
 
 # ------------------------------------------------------------ identities
@@ -265,14 +276,13 @@ class Trace:
     traces land in the ring and (``MXNET_TPU_TRACE_DIR``) the per-rank
     JSONL export."""
 
-    __slots__ = ("ctx", "name", "_attrs", "_prev", "_t0", "_p0")
+    __slots__ = ("ctx", "name", "_attrs", "_prev", "_p0")
 
     def __init__(self, name, ctx, attrs=None):
         self.name = name
         self.ctx = ctx
         self._attrs = dict(attrs) if attrs else {}
         self._prev = None
-        self._t0 = 0.0
         self._p0 = 0.0
 
     @property
@@ -281,10 +291,9 @@ class Trace:
 
     def __enter__(self):
         self._prev = attach(self.ctx)
-        self._t0 = time.time()
         self._p0 = time.perf_counter()
         doc = {"trace_id": self.ctx.trace_id, "root": self.name,
-               "rank": _rank(), "ts": round(self._t0, 6),
+               "rank": _rank(), "ts": round(epoch_of(self._p0), 6),
                "status": "ok", "attrs": self._attrs, "spans": []}
         with _lock:
             _active[self.ctx.trace_id] = doc

@@ -18,3 +18,18 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "chaos: fault-injection chaos test (tools/chaos_run.py harness)")
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _no_stale_resume_state():
+    """A test that loads a checkpoint stashes its iterator state for the
+    next ``fit`` (``io_resume.note_loaded_state``); one that never fits
+    would leave it for whatever test the worker runs next, whose own
+    iterator then refuses it.  Which file follows which on a worker
+    depends on timing, so clear it after every test."""
+    yield
+    from mxnet_tpu import io_resume
+    io_resume.clear_pending()

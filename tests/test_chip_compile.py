@@ -35,11 +35,19 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_for_chip(fn, one_chip, *shapes_dtypes):
+def _compile_for_chip(fn, one_chip, *shapes_dtypes, names):
+    """Compile ``fn`` for the described chip.  ``names``: what the
+    compiled program's custom calls must be called — the instruction's
+    name is what a device trace's "XLA Ops" line shows, and XLA makes
+    it from the ``name=`` of the ``pallas_call``."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes_dtypes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = [line.split("=")[0] for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert calls
+    for name in names:
+        assert any(name in c for c in calls), (name, calls)
     return compiled
 
 
@@ -52,7 +60,8 @@ FLASH_SHAPES = [(8, 1024, 16, 64), (4, 2048, 16, 64), (2, 4096, 16, 64),
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
 def test_flash_forward_compiles_for_v5e(one_chip, shape):
     _compile_for_chip(lambda q, k, v: pk.flash_attention(q, k, v, True),
-                      one_chip, *[(shape, jnp.bfloat16)] * 3)
+                      one_chip, *[(shape, jnp.bfloat16)] * 3,
+                      names=["mxtpu_flash_fwd_"])
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
@@ -61,7 +70,8 @@ def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
         return pk.flash_attention(q, k, v, True).astype(jnp.float32).sum()
 
     _compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
-                      *[(shape, jnp.bfloat16)] * 3)
+                      *[(shape, jnp.bfloat16)] * 3,
+                      names=["mxtpu_flash_fwd_", "mxtpu_flash_bwd_"])
 
 
 # ResNet-50 at batch 128: the 1x1 convs as (N*H*W, Cin) @ (Cin, Cout)
@@ -77,4 +87,4 @@ def test_matmul_stats_compiles_for_v5e(one_chip, monkeypatch, m, k, n):
     monkeypatch.setattr(context, "on_tpu", lambda: True)
     _compile_for_chip(fused.matmul_stats, one_chip,
                       ((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16),
-                      ((n,), jnp.float32))
+                      ((n,), jnp.float32), names=[fused.MATMUL_STATS])

@@ -493,3 +493,104 @@ def test_trainer_fusion_summary_and_metrics():
         kind="conv_bn_act").get() >= 2
     # unfused trainers surface no summary
     assert _make_trainer(False).fusion_summary() is None
+
+
+# ------------------------------------------- stable names on the device
+def _step_args(t):
+    import jax
+    import jax.numpy as jnp
+    spec = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    batch = {n: jax.ShapeDtypeStruct(tuple(s), jnp.float32)
+             for n, s in t._input_shapes.items()}
+    return (spec(t.params), spec(t.opt_state), spec(t.aux), batch,
+            jax.ShapeDtypeStruct((2,), jnp.uint32),
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.float32))
+
+
+def _opcodes(text):
+    import collections
+    import re
+    return collections.Counter(
+        re.findall(r"= \"?((?:stablehlo|func|chlo)\.[a-z_]+)", text))
+
+
+def _kernel_block_trainer():
+    """conv3x3+BN+relu -> a conv1x1+BN+relu wide enough (128 lanes) for
+    the matmul-with-stats kernel -> pooled FC+relu head."""
+    data = mx.sym.Variable("data")
+    net = mx.sym.Convolution(data, kernel=(3, 3), pad=(1, 1),
+                             num_filter=16, no_bias=True, name="conv0")
+    net = mx.sym.BatchNorm(net, name="bn0", fix_gamma=False)
+    net = mx.sym.Activation(net, act_type="relu", name="act0")
+    net = mx.sym.Convolution(net, kernel=(1, 1), num_filter=128,
+                             no_bias=True, name="conv1")
+    net = mx.sym.BatchNorm(net, name="bn1", fix_gamma=False)
+    net = mx.sym.Activation(net, act_type="relu", name="act1")
+    net = mx.sym.Pooling(net, global_pool=True, pool_type="avg")
+    net = mx.sym.FullyConnected(mx.sym.Flatten(net), num_hidden=16,
+                                name="fc0")
+    net = mx.sym.Activation(net, act_type="relu", name="fcact")
+    net = mx.sym.FullyConnected(net, num_hidden=10, name="fc1")
+    np.random.seed(7)
+    return ShardedTrainer(
+        mx.sym.SoftmaxOutput(net, name="softmax"), build_mesh(n_devices=1),
+        data_shapes={"data": (8, 3, 8, 8)},
+        label_shapes={"softmax_label": (8,)}, layout="NHWC", seed=3,
+        learning_rate=0.1, momentum=0.9, fuse_blocks=True)
+
+
+def test_train_step_names_its_phases_blocks_and_kernel(monkeypatch):
+    """The step program carries the names a device trace is read by:
+    the forward / backward / optimizer scopes, the fused block's kind,
+    the kernel's own ``name`` (lowered for the TPU from here, so the
+    block takes its Pallas leg), and fixed module names."""
+    import jax
+    from mxnet_tpu import context
+    from mxnet_tpu.ops import fused
+    from mxnet_tpu.parallel import trainer as trainer_mod
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    t = _kernel_block_trainer()
+    assert t._plan_decisions is None   # greedy plan: the 1x1 block is Pallas
+    text = jax.jit(t._py_step).trace(*_step_args(t)).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "module @jit_mxtpu_train_step" in text
+    for name in (trainer_mod.SCOPE_FWD, trainer_mod.SCOPE_BWD,
+                 trainer_mod.SCOPE_OPT, "mxtpu.block.conv_bn_act",
+                 "mxtpu.block.fc_act", fused.MATMUL_STATS):
+        assert name in text, name
+    assert fused.MATMUL_STATS == "mxtpu_matmul_stats"
+    # the kernel sits inside the forward, inside its block
+    assert ("mxtpu.fwd/jvp(mxtpu.block.conv_bn_act)/mxtpu_matmul_stats"
+            in text)
+    chain = t._build_multi_step(2)
+    assert chain.__wrapped__.__name__ == "mxtpu_train_chain"
+
+
+def test_names_change_no_arithmetic(monkeypatch):
+    """With ``jax.named_scope`` patched to a null context the same two
+    steps give bit-identical losses and parameters, and the lowered
+    text holds the same count of each opcode."""
+    import contextlib
+    import jax
+
+    def run():
+        t = _make_trainer(True)
+        text = jax.jit(t._py_step).lower(*_step_args(t)).as_text(
+            debug_info=True)
+        b = t.put_batch(_batch(0))
+        losses = [np.asarray(t.step(b)), np.asarray(t.step(b))]
+        return losses, {k: np.asarray(v) for k, v in t.params.items()}, text
+
+    named = run()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = run()
+    assert "mxtpu.fwd" in named[2] and "mxtpu.fwd" not in plain[2]
+    for a, b in zip(named[0], plain[0]):
+        assert a.tobytes() == b.tobytes()
+    for k in named[1]:
+        assert named[1][k].tobytes() == plain[1][k].tobytes(), k
+    counts = _opcodes(named[2])
+    assert counts and counts == _opcodes(plain[2])

@@ -113,3 +113,54 @@ def test_block_choice_cliff_shapes():
         assert t % bk == 0, t
         assert bk % bq == 0, t
         assert bk <= max(_BLOCK_K, bq), t
+
+
+def test_attention_step_names_its_kernels(monkeypatch):
+    """A tiny attention train step, lowered for the TPU from here: the
+    flash kernels carry their own names into the program (``name=`` of
+    the ``pallas_call``), forward under ``mxtpu.fwd`` and backward
+    under ``mxtpu.bwd`` — what a device trace's "XLA Ops" line shows
+    where it used to show ``transpose_jvp___``."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import context
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.parallel import ShardedTrainer, build_mesh
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    B, T, H, D = 2, 128, 2, 64
+    x = mx.sym.Variable("data")
+    qkv = mx.sym.FullyConnected(x, num_hidden=3 * H * D, flatten=False,
+                                name="qkv")
+    qkv = mx.sym.Reshape(qkv, shape=(0, 0, 3, H, -1))
+    q, k, v = (mx.sym.Reshape(
+        mx.sym.slice_axis(qkv, axis=2, begin=i, end=i + 1),
+        shape=(0, 0, -3, -2)) for i in range(3))
+    att = mx.sym._contrib_FlashAttention(q, k, v, causal=True, name="attn")
+    att = mx.sym.Reshape(att, shape=(-1, H * D))
+    net = mx.sym.SoftmaxOutput(
+        mx.sym.FullyConnected(att, num_hidden=16, name="head"),
+        label=mx.sym.Reshape(mx.sym.Variable("softmax_label"),
+                             shape=(-1,)), name="softmax")
+    t = ShardedTrainer(net, build_mesh(n_devices=1),
+                       data_shapes={"data": (B, T, 32)},
+                       label_shapes={"softmax_label": (B, T)},
+                       optimizer="adam", learning_rate=1e-4)
+    spec = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    args = (spec(t.params), spec(t.opt_state), spec(t.aux),
+            {"data": jax.ShapeDtypeStruct((B, T, 32), jnp.float32),
+             "softmax_label": jax.ShapeDtypeStruct((B, T), jnp.float32)},
+            jax.ShapeDtypeStruct((2,), jnp.uint32),
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.float32))
+    text = jax.jit(t._py_step).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert text.count("tpu_custom_call") >= 2
+    assert (pk.FLASH_FWD_PANEL, pk.FLASH_BWD_PANEL) == \
+        ("mxtpu_flash_fwd_panel", "mxtpu_flash_bwd_panel")
+    assert "mxtpu.fwd/jvp(mxtpu_flash_fwd_panel)/pallas_call" in text
+    assert "jvp(mxtpu_flash_bwd_panel)/pallas_call" in text
+    assert "mxtpu.bwd/" in text and "mxtpu.opt/" in text
+    # the streaming kernels' names are constants of their call sites too
+    assert (pk.FLASH_FWD_STREAM, pk.FLASH_BWD_STREAM) == \
+        ("mxtpu_flash_fwd_stream", "mxtpu_flash_bwd_stream")

@@ -24,6 +24,7 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.telemetry import flight
 from mxnet_tpu.telemetry import memory as tmem
+from mxnet_tpu.telemetry import spans
 
 
 def _load_tool(name):
@@ -225,6 +226,146 @@ def test_profiler_record_event_concurrent(tmp_path):
         json.load(open(mx.profiler.dump_profile()))["traceEvents"])
     assert not errors, errors
     assert total == n_threads * n_events
+
+
+# ----------------------------------------------------- the span record
+
+def test_span_record_nesting_carries_parent_ids():
+    with telemetry.span("outer"):
+        with telemetry.span("inner"):
+            pass
+        with telemetry.span("inner"):
+            pass
+    recs = spans.records()
+    assert [r.name for r in recs] == ["inner", "inner", "outer"]
+    a, b, outer = recs
+    assert outer.parent is None
+    assert a.parent == outer.id and b.parent == outer.id    # children
+    assert a.id != b.id and a.parent != b.id                # siblings
+    assert outer.start <= a.start <= a.end <= b.start <= b.end <= outer.end
+    assert a.thread == outer.thread == threading.get_ident()
+    # filters: by prefix, and by a window on time.perf_counter()
+    assert [r.name for r in spans.records(prefix="in")] == ["inner"] * 2
+    assert spans.records(since=b.start) == [b]
+    assert spans.records(until=a.end) == [a]
+
+
+def test_span_ring_is_bounded_and_keeps_the_newest():
+    assert spans._ring.maxlen == spans.RING_SIZE
+    for i in range(spans.RING_SIZE + 3):
+        spans.record("filler", float(i), float(i) + 0.5, i=i)
+    recs = spans.records()
+    assert len(recs) == spans.RING_SIZE
+    assert recs[0].attrs == {"i": 3}
+    assert recs[-1].attrs == {"i": spans.RING_SIZE + 2}
+
+
+def test_span_shared_across_threads_records_two_roots():
+    gate = threading.Barrier(2)
+
+    @telemetry.span("shared.op")
+    def work():
+        gate.wait(timeout=5)
+
+    with telemetry.span("main.outer"):
+        ts = [threading.Thread(target=work) for _ in range(2)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=5)
+            assert not t.is_alive()
+    ops = spans.records(prefix="shared.op")
+    assert len(ops) == 2
+    # the parent is what was open on the ENTERING thread: nothing
+    assert [r.parent for r in ops] == [None, None]
+    assert len({r.thread for r in ops}) == 2
+    assert len({r.id for r in ops}) == 2
+
+
+def test_span_self_time_on_a_hand_made_family():
+    R = spans.Record
+    parent = R("p", 10.0, 20.0, 1, None, 7, None)
+    family = [
+        parent,
+        R("a", 11.0, 13.0, 2, 1, 7, None),      # 2 s
+        R("b", 12.0, 15.0, 3, 1, 8, None),      # overlaps a: 2 s more
+        R("c", 18.0, 25.0, 4, 1, 7, None),      # cut at the parent's end
+        R("a.child", 11.0, 12.0, 5, 2, 7, None),    # a grandchild: not p's
+        R("other", 16.0, 17.0, 6, None, 7, None),   # no child of p
+    ]
+    assert spans.self_time(parent, family) == pytest.approx(10 - 4 - 2)
+    assert spans.self_time(family[1], family) == pytest.approx(1.0)
+    assert spans.self_time(family[5], family) == pytest.approx(1.0)
+
+
+def test_span_attributes_survive_and_exception_still_records():
+    with pytest.raises(ValueError):
+        with telemetry.span("program.compile", program="p", steps=3):
+            raise ValueError("boom")
+    (rec,) = spans.records()
+    assert rec.name == "program.compile"
+    assert rec.attrs == {"program": "p", "steps": 3}
+    assert rec.end >= rec.start
+    # the thread's stack of open spans is clean again
+    with telemetry.span("after"):
+        pass
+    assert spans.records(prefix="after")[0].parent is None
+
+
+def test_span_in_a_trace_has_one_timer_and_one_clock():
+    """A span recorded into a tracing trace carries the start of its
+    span record (one offset turns perf_counter into epoch seconds)."""
+    from mxnet_tpu.telemetry import tracing
+    with tracing.start_trace("root") as tr:
+        with telemetry.span("traced.op"):
+            pass
+    (rec,) = spans.records(prefix="traced.op")
+    doc = tracing.get_trace(tr.trace_id)
+    (sp,) = [s for s in doc["spans"] if s["name"] == "traced.op"]
+    assert sp["ts"] == round(tracing.epoch_of(rec.start), 6)
+    assert sp["dur_s"] == round(rec.end - rec.start, 6)
+    here = os.path.dirname(os.path.abspath(spans.__file__))
+    with open(os.path.join(here, "spans.py")) as f:
+        assert "time.time()" not in f.read()
+
+
+def test_span_open_round_step_end_lands_in_its_own_step(tmp_path,
+                                                        monkeypatch):
+    """``trainer.run_steps`` stays open round its own ``step_end``: its
+    sum goes into THAT step's JSONL record, not the next one's."""
+    path = str(tmp_path / "steps.jsonl")
+    monkeypatch.setenv("MXNET_TPU_TELEMETRY_JSONL", path)
+    for _ in range(2):
+        with telemetry.span("whole.step"):
+            telemetry.step_end(samples=1, step_time=0.01)
+    with open(path) as f:
+        recs = [json.loads(line) for line in f]
+    for rec in recs:
+        assert rec["spans"]["whole.step"]["count"] == 1
+        assert rec["spans"]["telemetry.step_end"]["count"] == 1
+    assert telemetry.step_span_totals() == {}
+
+
+def test_span_is_a_trace_annotation_under_the_jax_profiler(tmp_path):
+    """With a jax.profiler session open the span is also on the
+    profiler's host plane, as ``mxtpu:<name>``."""
+    import glob
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("annotated.outer"):
+            with telemetry.span("annotated.inner"):
+                jax.block_until_ready(jax.numpy.ones((8,)) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    names = {ev.name
+             for plane in jax.profiler.ProfileData.from_file(pb).planes
+             for line in plane.lines for ev in line.events}
+    if not any(n.startswith("mxtpu:") for n in names):
+        pytest.skip("this backend's profiler keeps no host annotations")
+    assert {"mxtpu:annotated.outer", "mxtpu:annotated.inner"} <= names
 
 
 # ------------------------------------------------------------ exporters
