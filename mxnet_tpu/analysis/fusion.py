@@ -5,8 +5,9 @@ the instrumentation of PRs 3-5 into throughput: conv->BN->ReLU and
 matmul->bias->activation chains — the blocks that dominate ResNet-style
 graphs — are pattern-matched over the Symbol DAG in topo order and each
 match is emitted as ONE fused region (`mxnet_tpu.ops.fused`
-``fused_block_*``: a Pallas matmul-with-stats kernel where eligible, a
-single custom-vjp XLA region otherwise).  Because every region carries
+``fused_block_*``: a single custom-vjp XLA region; the Pallas
+matmul-with-stats leg is dispatched for no block, see
+`_pallas_eligible`).  Because every region carries
 a hand-written backward, training keeps one fused dispatch per block in
 BOTH directions; the plan runs wherever :func:`mxnet_tpu.symbol.
 eval_graph` traces — forward, the executor's vjp backward, and the
@@ -285,16 +286,19 @@ def _conv_fusable(conv, layout, plan, claimed):
 
 
 def _pallas_eligible(blk, is_train):
-    """Pallas eligibility of a (possibly decision-transformed) block:
-    the matmul-with-stats kernel needs an eligible 1x1 conv head, NHWC
-    region layout, and train-mode BN statistics."""
-    if blk.conv is None or blk.bn is None \
-            or blk.kind not in ("conv_bn", "conv_bn_act"):
-        return False
-    from ..ops import fused as _fused
-    return bool(_fused._conv_eligible(blk.conv) and blk.layout == "NHWC"
-                and is_train
-                and not blk.bn.attrs.get("use_global_stats"))
+    """Whether a (possibly decision-transformed) block is lowered
+    through the matmul-with-stats kernel (``ops.fused._fused_conv_bn``)
+    and not as the XLA region every other conv block is: never.  The
+    kernel takes a 1x1 conv head on an NHWC activation flattened to
+    (N*H*W, C).  Measured on a v5e (PERF.md 6, PR 25): at ResNet-50's
+    widths the flatten is a ``reshape`` that moves the activation on
+    each side of the custom call, forward and backward (a third of the
+    step); where it is a bitcast (W of whole 8-row tiles, 128-lane
+    channels) the kernel's block is still 15% slower than the XLA
+    region alone in a chain and 65% slower between two convolutions,
+    which keep the batch in the sublanes where the kernel wants rows.
+    No shape was found where it wins, so no rule admits one."""
+    return False
 
 
 def _apply_decision(blk, cid, decisions, plan, is_train):

@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 from mxnet_tpu import context
 from mxnet_tpu.ops import fused
 from mxnet_tpu.ops import pallas_kernels as pk
+from test_fusion import one_by_one_block
 
 
 @pytest.fixture(scope="module")
@@ -45,7 +46,7 @@ def _compile_for_chip(fn, one_chip, *shapes_dtypes, names):
     compiled = jax.jit(fn).lower(*args).compile()
     calls = [line.split("=")[0] for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line]
-    assert calls
+    assert bool(calls) == bool(names), calls
     for name in names:
         assert any(name in c for c in calls), (name, calls)
     return compiled
@@ -88,3 +89,22 @@ def test_matmul_stats_compiles_for_v5e(one_chip, monkeypatch, m, k, n):
     _compile_for_chip(fused.matmul_stats, one_chip,
                       ((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16),
                       ((n,), jnp.float32), names=[fused.MATMUL_STATS])
+
+
+# stage 1 and stage 2 of ResNet-50 at batch 128 (the flatten round the
+# kernel moved the whole activation there), and the shape of PR 25's
+# A/B, whose flatten is a bitcast (the kernel lost there too)
+@pytest.mark.parametrize("x_shape,nout", [
+    ((128, 56, 56, 256), 64), ((128, 28, 28, 512), 128),
+    ((128, 32, 32, 128), 128)], ids=str)
+def test_bottleneck_block_holds_no_kernel_for_v5e(one_chip, monkeypatch,
+                                                  x_shape, nout):
+    """The block's compiled forward + backward holds no
+    ``mxtpu_matmul_stats`` and no ``reshape`` of the activation."""
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    fn, args = one_by_one_block(x_shape, nout, jnp.bfloat16)
+    compiled = _compile_for_chip(fn, one_chip, *args, names=[])
+    rows = "[%d," % (x_shape[0] * x_shape[1] * x_shape[2])
+    moved = [line for line in compiled.as_text().splitlines()
+             if " reshape(" in line and rows in line.split(" reshape(")[0]]
+    assert not moved, moved[:2]

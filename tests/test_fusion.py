@@ -48,8 +48,11 @@ def test_plan_resnet_style_blocks():
     plan = _plan(_resnet_style_net())
     s = plan.summary()
     assert s["kinds"] == {"conv_bn_act": 2, "fc_act": 1}
-    # conv1 is 1x1/s1/p0/no-bias under NHWC train stats -> Pallas
-    assert s["pallas_blocks"] == 1
+    # conv1 is 1x1/s1/p0/no-bias under NHWC train stats, the block the
+    # matmul-with-stats kernel was written for: it is an XLA region like
+    # conv0's (the kernel lost on every shape on the chip, PR 25)
+    assert s["pallas_blocks"] == 0
+    assert not any(b.pallas for b in plan.blocks.values())
     by_kind = {b.kind: b for b in plan.blocks.values()}
     assert by_kind["conv_bn_act"].terminal.name in ("act0", "act1")
     # interior edges: 2 per conv_bn_act, 1 per fc_act = 5; plus the
@@ -70,7 +73,7 @@ def test_plan_longest_chain_wins():
 def test_plan_eval_mode_disables_pallas():
     plan = _plan(_resnet_style_net(), is_train=False)
     s = plan.summary()
-    # same blocks, but the Pallas train-stats kernel is ineligible
+    # same blocks as the training plan, and no kernel here either
     assert s["kinds"] == {"conv_bn_act": 2, "fc_act": 1}
     assert s["pallas_blocks"] == 0
     assert not s["is_train"]
@@ -125,6 +128,80 @@ def test_plan_respects_exclusions():
     # conv1's chain degrades to bn_act; conv0's chain still fuses
     assert s["kinds"] == {"conv_bn_act": 1, "bn_act": 1, "fc_act": 1}
     assert s["fallbacks"] == {"claimed_by_other_pass": 1}
+
+
+# --------------------------- the lowering of the 1x1 conv->BN->ReLU block
+# (activation NHWC, filters, dtype): ResNet-50's four bottleneck shapes
+# at batch 128 (the flatten round the kernel is a ``reshape`` that moves
+# the activation: W of 28, 14, 7, and 64 filters in stage 1), the shapes
+# whose flatten is a bitcast in the compiled v5e text (W of whole 8-row
+# tiles, 128-lane channels: stage 2's first unit has them, and the A/B
+# of PR 25 ran the second), and float32
+_ONE_BY_ONE_CASES = [
+    ((128, 56, 56, 256), 64, "bfloat16"),
+    ((128, 28, 28, 512), 128, "bfloat16"),
+    ((128, 14, 14, 1024), 256, "bfloat16"),
+    ((128, 7, 7, 2048), 512, "bfloat16"),
+    ((128, 56, 56, 256), 128, "bfloat16"),
+    ((128, 32, 32, 128), 128, "bfloat16"),
+    ((8, 8, 8, 128), 128, "float32"),
+]
+
+
+def one_by_one_block(x_shape, nout, dtype):
+    """``(fn, [(shape, dtype), ...])``: the gradient of a 1x1
+    conv->BN->ReLU chain (ResNet's conv1->bn2->relu2) on an NHWC
+    activation, evaluated as ``eval_graph`` evaluates a training
+    step's — planned by the fusion pass, lowered by ``apply_block`` —
+    and the arguments it takes (tests/test_chip_compile.py compiles it
+    for the chip)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.nn import image_layout
+    from mxnet_tpu.symbol import eval_graph
+
+    net = mx.sym.Convolution(mx.sym.Variable("data"), kernel=(1, 1),
+                             num_filter=nout, no_bias=True, name="c")
+    net = mx.sym.BatchNorm(net, name="bn", fix_gamma=False)
+    out = mx.sym.Activation(net, act_type="relu", name="r")
+    topo = out._topo()
+    order = [n for n in topo if n.is_variable]
+    shapes = {"data": x_shape, "c_weight": (nout, x_shape[3], 1, 1)}
+
+    def loss(*values):
+        with block_fusion(True), image_layout("NHWC"):
+            heads, aux = eval_graph(
+                topo, out._entries,
+                {id(n): v for n, v in zip(order, values)}, is_train=True)
+        return heads[0].astype(jnp.float32).sum() \
+            + sum(a.sum() for a in aux.values())
+
+    return (jax.grad(loss, argnums=(0, 1, 2, 3)),
+            [(shapes.get(n.name, (nout,)),
+              jnp.dtype(dtype) if n.name in shapes else jnp.float32)
+             for n in order])
+
+
+@pytest.mark.parametrize("x_shape,nout,dtype", _ONE_BY_ONE_CASES, ids=str)
+def test_one_by_one_block_is_an_xla_region(monkeypatch, x_shape, nout,
+                                           dtype):
+    """A training trace of the 1x1 conv->BN->ReLU chain, taken as on
+    the TPU, is one fused block with no ``pallas_call`` and no 2-d
+    flatten of the activation in it, whatever the shape; the summary
+    counts no kernel and names no fallback."""
+    import jax
+    from mxnet_tpu import context
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    fn, args = one_by_one_block(x_shape, nout, dtype)
+    text = str(jax.make_jaxpr(fn)(
+        *[jax.ShapeDtypeStruct(sh, dt) for sh, dt in args]))
+    s = fusion.last_plan_summary()
+    assert s["kinds"] == {"conv_bn_act": 1} and s["fallbacks"] == {}
+    assert s["pallas_blocks"] == 0
+    assert "pallas_call" not in text
+    assert "conv_general_dilated" in text
+    rows = x_shape[0] * x_shape[1] * x_shape[2]
+    assert "[%d,%d]" % (rows, nout) not in text
 
 
 # ------------------------------------ relayout accounting (the matrix)
@@ -421,11 +498,11 @@ def test_executor_graceful_fallback_runs_unfused():
 
 
 # ---------------------------------------------------- trainer parity
-def _make_trainer(fuse, layout="NHWC", dtype="float32"):
+def _make_trainer(fuse, layout="NHWC", dtype="float32", hw=8):
     mesh = build_mesh(tp=1)
     np.random.seed(7)
     kwargs = dict(
-        data_shapes={"data": (8, 3, 8, 8)},
+        data_shapes={"data": (8, 3, hw, hw)},
         label_shapes={"softmax_label": (8,)},
         dtype=dtype, seed=3, learning_rate=0.1, momentum=0.9,
         fuse_blocks=fuse)
@@ -434,24 +511,27 @@ def _make_trainer(fuse, layout="NHWC", dtype="float32"):
     return ShardedTrainer(_resnet_style_net(), mesh, **kwargs)
 
 
-def _batch(seed=0):
+def _batch(seed=0, hw=8):
     rng = np.random.RandomState(seed)
     return {
-        "data": (rng.uniform(-1, 1, (8, 3, 8, 8)) * 2.0 + 0.25)
+        "data": (rng.uniform(-1, 1, (8, 3, hw, hw)) * 2.0 + 0.25)
         .astype(np.float32),
         "softmax_label": rng.randint(0, 10, 8).astype(np.float32),
     }
 
 
+@pytest.mark.parametrize("hw", [8, 7])
 @pytest.mark.parametrize("layout", ["NHWC", None])
-def test_trainer_step_parity(layout):
+def test_trainer_step_parity(layout, hw):
     """Two full fused-step training updates (fwd + custom-vjp bwd +
-    optimizer + BN aux) match the unfused trainer in either layout."""
-    t_ref = _make_trainer(False, layout=layout)
-    t_fused = _make_trainer(True, layout=layout)
+    optimizer + BN aux) match the unfused trainer in either layout, at
+    a width of whole tiles and at an odd one (ResNet-50's last stage:
+    the 1x1 block runs the XLA region there)."""
+    t_ref = _make_trainer(False, layout=layout, hw=hw)
+    t_fused = _make_trainer(True, layout=layout, hw=hw)
     losses = []
     for t in (t_ref, t_fused):
-        b = t.put_batch(_batch(0))
+        b = t.put_batch(_batch(0, hw))
         losses.append((float(t.step(b)), float(t.step(b))))
     np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5,
                                atol=1e-7)
@@ -517,11 +597,12 @@ def _opcodes(text):
 
 
 def _kernel_block_trainer():
-    """conv3x3+BN+relu -> a conv1x1+BN+relu wide enough (128 lanes) for
-    the matmul-with-stats kernel -> pooled FC+relu head."""
+    """conv3x3+BN+relu -> a conv1x1+BN+relu of the shapes the
+    matmul-with-stats kernel was written for (128 lanes in and out, W
+    of 8 a whole tile of rows) -> pooled FC+relu head."""
     data = mx.sym.Variable("data")
     net = mx.sym.Convolution(data, kernel=(3, 3), pad=(1, 1),
-                             num_filter=16, no_bias=True, name="conv0")
+                             num_filter=128, no_bias=True, name="conv0")
     net = mx.sym.BatchNorm(net, name="bn0", fix_gamma=False)
     net = mx.sym.Activation(net, act_type="relu", name="act0")
     net = mx.sym.Convolution(net, kernel=(1, 1), num_filter=128,
@@ -541,28 +622,31 @@ def _kernel_block_trainer():
         learning_rate=0.1, momentum=0.9, fuse_blocks=True)
 
 
-def test_train_step_names_its_phases_blocks_and_kernel(monkeypatch):
+def test_train_step_names_its_phases_and_blocks(monkeypatch):
     """The step program carries the names a device trace is read by:
-    the forward / backward / optimizer scopes, the fused block's kind,
-    the kernel's own ``name`` (lowered for the TPU from here, so the
-    block takes its Pallas leg), and fixed module names."""
+    the forward / backward / optimizer scopes, the fused block's kind
+    round the block's convolution, and fixed module names.  Lowered for
+    the TPU from here: the 1x1 block is an XLA region there too, so the
+    step holds no ``mxtpu_matmul_stats`` (tests/test_chip_compile.py
+    keeps the kernel's own name)."""
     import jax
     from mxnet_tpu import context
     from mxnet_tpu.ops import fused
     from mxnet_tpu.parallel import trainer as trainer_mod
     monkeypatch.setattr(context, "on_tpu", lambda: True)
     t = _kernel_block_trainer()
-    assert t._plan_decisions is None   # greedy plan: the 1x1 block is Pallas
+    assert t._plan_decisions is None   # greedy plan
     text = jax.jit(t._py_step).trace(*_step_args(t)).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert "module @jit_mxtpu_train_step" in text
     for name in (trainer_mod.SCOPE_FWD, trainer_mod.SCOPE_BWD,
                  trainer_mod.SCOPE_OPT, "mxtpu.block.conv_bn_act",
-                 "mxtpu.block.fc_act", fused.MATMUL_STATS):
+                 "mxtpu.block.fc_act"):
         assert name in text, name
-    assert fused.MATMUL_STATS == "mxtpu_matmul_stats"
-    # the kernel sits inside the forward, inside its block
-    assert ("mxtpu.fwd/jvp(mxtpu.block.conv_bn_act)/mxtpu_matmul_stats"
+    assert fused.MATMUL_STATS not in text and "tpu_custom_call" not in text
+    assert t.fusion_summary()["pallas_blocks"] == 0
+    # the block's convolution sits inside the forward, inside its block
+    assert ("mxtpu.fwd/jvp(mxtpu.block.conv_bn_act)/conv_general_dilated"
             in text)
     chain = t._build_multi_step(2)
     assert chain.__wrapped__.__name__ == "mxtpu_train_chain"

@@ -120,15 +120,16 @@ def test_decision_conv_bn_split():
         decisions={"chains": {cid: "conv_bn"}})
     blk = next(b for b in p.blocks.values() if b.kind == "conv_bn")
     assert blk.name == "b0" and blk.chain == cid and blk.act is None
-    # a split of the PALLAS-eligible 1x1 chain keeps the Pallas leg a
-    # naturally-matched conv_bn chain would get
+    # a split of the 1x1 chain gets the lowering a naturally-matched
+    # conv_bn chain would get: the XLA region (no block takes the
+    # matmul-with-stats kernel: fusion._pallas_eligible)
     g_nhwc = _greedy_plan(sym, layout="NHWC")
     cid1 = _chain_of(g_nhwc, "conv_bn_act", "r1")
     p2 = fusion.plan_block_fusion(
         sym._topo(), sym._entries, layout="NHWC", record=False,
         decisions={"chains": {cid1: "conv_bn"}})
     blk2 = next(b for b in p2.blocks.values() if b.kind == "conv_bn")
-    assert blk2.name == "b1" and blk2.pallas
+    assert blk2.name == "b1" and not blk2.pallas
 
 
 def test_decision_bn_act_split():
@@ -146,9 +147,8 @@ def test_decision_bn_act_split():
 
 def test_decision_layout_override_accounting_and_pallas():
     """A region pinned to a non-ambient layout pays 2 explicit
-    relayout edges, loses adjacency credit, and re-derives Pallas
-    eligibility from the REGION layout (an NHWC override in an NCHW
-    trace opens the 1x1 Pallas leg)."""
+    relayout edges and loses adjacency credit; an NHWC override of the
+    1x1 chain in an NCHW trace opens no Pallas leg."""
     sym = _conv_net()
     g = _greedy_plan(sym, layout="NCHW")
     assert all(not b.pallas for b in g.blocks.values())
@@ -158,7 +158,7 @@ def test_decision_layout_override_accounting_and_pallas():
         sym._topo(), sym._entries, layout="NCHW", record=False,
         decisions={"layouts": {cid: "NHWC"}})
     blk = next(b for b in p.blocks.values() if b.chain == cid)
-    assert blk.layout == "NHWC" and blk.pallas
+    assert blk.layout == "NHWC" and not blk.pallas
     assert p.relayout_edges_added == 2
     assert p.adjacent_edges == 0      # boundary layouts now differ
     s = p.summary()
@@ -166,16 +166,21 @@ def test_decision_layout_override_accounting_and_pallas():
 
 
 def test_decision_pallas_veto():
+    """No block is a Pallas block, so a committed veto (an entry from
+    before PR 25) overrides nothing and the search is offered none."""
     sym = _conv_net()
     g = _greedy_plan(sym, layout="NHWC")
     cid = _chain_of(g, "conv_bn_act", "r1")
     blk = next(b for b in g.blocks.values() if b.chain == cid)
-    assert blk.pallas
+    assert not blk.pallas
     p = fusion.plan_block_fusion(
         sym._topo(), sym._entries, layout="NHWC", record=False,
         decisions={"pallas": {cid: 0}})
     blk = next(b for b in p.blocks.values() if b.chain == cid)
-    assert not blk.pallas
+    assert not blk.pallas and p.overrides == 0
+    _greedy, moves = plansearch.chain_moves(
+        sym._topo(), sym._entries, layout="NHWC")
+    assert not [m for m in moves if m[0] == "pallas"]
 
 
 def test_stale_decisions_degrade_to_fuse():
