@@ -65,6 +65,9 @@ class Initializer:
             self._init_zero(desc, arr)
         elif name.endswith("moving_avg"):
             self._init_zero(desc, arr)
+        elif name.endswith("_load"):
+            # an expert layer's load statistics (_contrib_TopKMoE's aux)
+            self._init_zero(desc, arr)
         else:
             self._init_default(desc, arr)
 

@@ -367,3 +367,72 @@ def switch_moe_op(attrs, ctx, data, router_weight, expert1_weight,
                   expert2_weight, expert2_bias,
                   capacity_factor=float(attrs["capacity_factor"]))
     return y.reshape(shape), aux
+
+
+def _topk_moe_args(attrs):
+    names = ("data", "router_weight")
+    if attrs.get("use_expert_bias", True):
+        names += ("expert_bias",)
+    return names + ("w1_weight", "w3_weight", "w2_weight")
+
+
+@register("_contrib_TopKMoE", arg_names=_topk_moe_args,
+          aux_names=("load",),
+          params={"num_experts": 0, "experts_held": 0, "expert_offset": 0,
+                  "num_experts_per_tok": 1, "hidden_size": 0,
+                  "norm_topk_prob": True, "routed_scaling_factor": 1.0,
+                  "use_expert_bias": True, "router_trained": True},
+          aliases=("TopKMoE",))
+def topk_moe_op(attrs, ctx, data, router_weight, *rest):
+    """Token-choice top-k mixture-of-experts feed-forward over
+    (batch, seq, d) or (tokens, d), computing the share of the result
+    that the experts held here give.
+
+    ``num_experts`` is the router's width (all experts of the layer),
+    ``experts_held`` (0: all) and ``expert_offset`` say which of them
+    this layer holds; ``router_weight`` is ``(num_experts, d)``,
+    ``expert_bias`` ``(num_experts,)`` (selection only; absent with
+    ``use_expert_bias=False``), ``w1_weight``/``w3_weight``
+    ``(experts_held, d, hidden_size)`` and ``w2_weight``
+    ``(experts_held, hidden_size, d)``.  The partial results of shares
+    that together hold all the experts add up to the whole layer's.
+    So do their gradients for ``data`` and ``router_weight``: a share
+    returns its true part of both.  ``router_trained=False`` makes the
+    scores constants to the gradient instead (no gradient for
+    ``router_weight``, none through the gates), for a graph that is one
+    share and runs without the others; see ``topk_moe``.
+    The auxiliary state ``load`` (``experts_held + 1``) carries the held
+    experts' assignment counts of the last step and the tokens with no
+    held expert.
+
+    Symbol-level surface of :func:`mxnet_tpu.parallel.moe.topk_moe`.
+    """
+    from ..parallel import moe as _moe
+    load = rest[-1]
+    bias = rest[0] if attrs.get("use_expert_bias", True) else None
+    w1, w3, w2 = rest[-4:-1]
+    e, k = int(attrs["num_experts"]), int(attrs["num_experts_per_tok"])
+    held = int(attrs["experts_held"]) or e
+    off, ff = int(attrs["expert_offset"]), int(attrs["hidden_size"])
+    if not (0 < k <= e and 0 < held and 0 <= off and off + held <= e
+            and ff > 0):
+        raise MXNetError(
+            "_contrib_TopKMoE: num_experts=%d, experts_held=%d, "
+            "expert_offset=%d, num_experts_per_tok=%d, hidden_size=%d do "
+            "not describe a share of a layer" % (e, held, off, k, ff))
+    d = data.shape[-1]
+    for name, arr, shape in (("router_weight", router_weight, (e, d)),
+                             ("w1_weight", w1, (held, d, ff)),
+                             ("w3_weight", w3, (held, d, ff)),
+                             ("w2_weight", w2, (held, ff, d))):
+        if tuple(arr.shape) != shape:
+            raise MXNetError(
+                "_contrib_TopKMoE: %s is %s where the attributes ask for "
+                "%s" % (name, tuple(arr.shape), shape))
+    x = data.reshape(-1, d)
+    y, new_load = _moe.topk_moe(
+        x, router_weight, bias, w1, w3, w2, k, expert_offset=off,
+        norm_topk_prob=bool(attrs["norm_topk_prob"]),
+        routed_scaling_factor=float(attrs["routed_scaling_factor"]),
+        router_trained=bool(attrs["router_trained"]))
+    return y.reshape(data.shape), new_load.astype(load.dtype)

@@ -476,6 +476,86 @@ def layer_norm(attrs, ctx, data, gamma, beta):
     return out
 
 
+@register("RMSNorm", arg_names=("data", "gamma"),
+          params={"axis": -1, "eps": 1e-5}, aliases=("rms_norm",))
+# mxlint: allow-dtype-widening(normalization/softmax statistics accumulate in f32 by contract)
+def rms_norm(attrs, ctx, data, gamma):
+    """Root-mean-square normalization over ``axis``:
+    ``x * rsqrt(mean(x^2) + eps) * gamma`` with ``gamma`` of that axis'
+    length, no mean subtraction and no shift (Zhang & Sennrich,
+    arXiv:1910.07467).  Statistics in float32, output in the input's
+    dtype.  With a 4-d ``(batch, seq, heads, head_dim)`` input and the
+    default axis it is the per-head query/key norm."""
+    axis = int(attrs["axis"])
+    xf = data.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=axis, keepdims=True)
+                    + float(attrs["eps"]))
+    bshape = tuple(data.shape[axis] if i == (axis % data.ndim) else 1
+                   for i in range(data.ndim))
+    return (xf * inv * gamma.astype(jnp.float32).reshape(bshape)
+            ).astype(data.dtype)
+
+
+@register("_contrib_RotaryEmbedding",
+          params={"base": 10000.0, "offset": 0},
+          aliases=("RotaryEmbedding",))
+# mxlint: allow-dtype-widening(rotation angles need float32: position 8191 times a frequency has no bf16)
+def rotary_embedding(attrs, ctx, data):
+    """Rotary position embedding (Su et al., arXiv:2104.09864) over the
+    whole last axis of ``(batch, seq, heads, head_dim)``, rotate-half
+    convention: with ``x = [x1, x2]`` split in the middle of the head,
+    ``out = x * cos + [-x2, x1] * sin`` where the angle of position
+    ``p`` and pair ``i`` is ``(p + offset) * base^(-2i/head_dim)``, the
+    same for ``i`` and ``i + head_dim/2``.  Angles in float32 from an
+    iota (no table argument), output in the input's dtype."""
+    if data.ndim != 4 or data.shape[-1] % 2:
+        raise MXNetError(
+            "_contrib_RotaryEmbedding wants (batch, seq, heads, head_dim) "
+            "with an even head_dim; got %s" % (tuple(data.shape),))
+    t, d = data.shape[1], data.shape[3]
+    inv_freq = jnp.exp(jnp.arange(0, d, 2, dtype=jnp.float32)
+                       * (-jnp.log(jnp.float32(attrs["base"])) / d))
+    pos = jnp.arange(t, dtype=jnp.float32) + float(attrs["offset"])
+    ang = pos[:, None] * inv_freq[None, :]              # (t, d/2)
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    xf = data.astype(jnp.float32)
+    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(data.dtype)
+
+
+#: ``jax.named_scope`` of the short convolution's ops on the device
+SCOPE_SHORTCONV = "mxtpu.block.shortconv"
+
+
+@register("_contrib_CausalConv1D", arg_names=("data", "weight"),
+          params={"kernel": 3}, aliases=("CausalConv1D",))
+# mxlint: allow-dtype-widening(the taps' sum accumulates in f32 and is rounded once)
+def causal_conv1d(attrs, ctx, data, weight):
+    """Depthwise causal convolution along the sequence of
+    ``(batch, seq, channels)``: ``out[t] = sum_j w[:, j] * x[t - (K-1) + j]``
+    with zeros before position 0, one ``K``-tap filter a channel
+    (``weight`` is ``(channels, K)``), no bias.  The sequence stays the
+    second axis and the channels the last: no relayout to the
+    ``(batch, channels, width)`` that ``Convolution`` wants, and no
+    symmetric padding to cut off again.  It is ``K`` shifted
+    multiply-adds that XLA fuses into one pass over the activation."""
+    k = int(attrs["kernel"])
+    if data.ndim != 3 or weight.shape != (data.shape[2], k):
+        raise MXNetError(
+            "_contrib_CausalConv1D wants (batch, seq, channels) data and a "
+            "(channels, %d) weight; got data %s, weight %s"
+            % (k, tuple(data.shape), tuple(weight.shape)))
+    t = data.shape[1]
+    with jax.named_scope(SCOPE_SHORTCONV):
+        xp = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0)))
+        w = weight.astype(jnp.float32)
+        acc = sum(xp[:, j:j + t, :].astype(jnp.float32) * w[:, j]
+                  for j in range(k))
+        return acc.astype(data.dtype)
+
+
 @register("InstanceNorm", arg_names=("data", "gamma", "beta"),
           params={"eps": 1e-3})
 def instance_norm(attrs, ctx, data, gamma, beta):
@@ -526,21 +606,25 @@ def lrn(attrs, ctx, data):
 
 
 # ------------------------------------------------------------- activations
+#: ``act_type`` -> function: every activation that ``Activation`` computes
+_ACTIVATIONS = {
+    "relu": jax.nn.relu,
+    "sigmoid": jax.nn.sigmoid,
+    "tanh": jnp.tanh,
+    "softrelu": jax.nn.softplus,
+    "softsign": lambda x: x / (1 + jnp.abs(x)),
+    "silu": jax.nn.silu,
+}
+
+
 @register("Activation", params={"act_type": "relu"}, aliases=("activation",))
 def activation(attrs, ctx, data):
     """Reference: src/operator/activation-inl.h; functors mshadow_op.h."""
     t = attrs["act_type"]
-    if t == "relu":
-        return jax.nn.relu(data)
-    if t == "sigmoid":
-        return jax.nn.sigmoid(data)
-    if t == "tanh":
-        return jnp.tanh(data)
-    if t == "softrelu":
-        return jax.nn.softplus(data)
-    if t == "softsign":
-        return data / (1 + jnp.abs(data))
-    raise MXNetError(f"unknown act_type {t}")
+    if t not in _ACTIVATIONS:
+        raise MXNetError("unknown act_type %r on data of shape %s; known: %s"
+                         % (t, tuple(data.shape), ", ".join(_ACTIVATIONS)))
+    return _ACTIVATIONS[t](data)
 
 
 @register("LeakyReLU", arg_names=lambda a: ("data", "gamma")

@@ -46,13 +46,19 @@ import jax
 import jax.numpy as jnp
 
 from .. import context as _context
+from ..base import MXNetError
 from .registry import register
 
 _BLOCK_Q = 128
 
 
 def _attention_jnp(q, k, v, causal):
-    """Reference path (CPU / fallback / backward recompute)."""
+    """Reference path (CPU / fallback / backward recompute).  Fewer
+    key/value heads than query heads are repeated here, which only this
+    path does: the kernels index them (:func:`_fold_queries`)."""
+    group = _kv_group(q, k, v)
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
@@ -87,11 +93,20 @@ def _causal_mask(s, qi, ki, block_q, block_k):
 FLASH_FWD_PANEL = "mxtpu_flash_fwd_panel"
 
 
+def _q_block_pos(qi, n_q):
+    """Position of Q block ``qi`` inside its own head's sequence.  With
+    grouped queries the ``group`` query heads that read one key/value
+    head lie one after another along the kernel's Q axis (``n_q`` blocks
+    each); ``n_q`` None is one query head a key/value head, where the
+    block index is the position."""
+    return qi if n_q is None else jax.lax.rem(qi, n_q)
+
+
 def _flash_fwd_panel_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                  block_q):
+                  block_q, n_q=None):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
+    qi = _q_block_pos(pl.program_id(1), n_q)
     q = q_ref[0].astype(jnp.float32)        # (block_q, D)
     k = k_ref[0].astype(jnp.float32)        # (T, D)
     v = v_ref[0].astype(jnp.float32)        # (T, D)
@@ -120,7 +135,7 @@ FLASH_FWD_STREAM = "mxtpu_flash_fwd_stream"
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *, scale, causal,
-                      block_q, block_k, n_k):
+                      block_q, block_k, n_k, n_q=None):
     """Online-softmax forward: K/V stream through VMEM in blocks along
     the innermost grid axis; the running (m, l, acc) row statistics
     live in VMEM scratch.  Under ``causal`` the fully-masked upper-
@@ -130,7 +145,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     measured 10 MFU points SLOWER in round 4, docs/perf.md)."""
     from jax.experimental import pallas as pl
 
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    qi, ki = _q_block_pos(pl.program_id(1), n_q), pl.program_id(2)
     nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
@@ -184,6 +199,33 @@ def _fold_heads(x):
 def _unfold_heads(x, b, h):
     bh, t, d = x.shape
     return jnp.transpose(x.reshape(b, h, t, d), (0, 2, 1, 3))
+
+
+def _kv_group(q, k, v):
+    """How many query heads read one key/value head (1: as many
+    key/value heads as query heads).  Query head ``h`` reads key/value
+    head ``h // group``."""
+    hq, hk = q.shape[2], k.shape[2]
+    if k.shape != v.shape or hk <= 0 or hq % hk:
+        raise ValueError(
+            "flash attention: %d query heads cannot share %d key/value "
+            "heads (q %s, k %s, v %s); the query heads must be a whole "
+            "multiple of the key/value heads"
+            % (hq, hk, tuple(q.shape), tuple(k.shape), tuple(v.shape)))
+    return hq // hk
+
+
+def _fold_queries(x, group):
+    """(B, T, H, ...) -> (B*H/group, group*T, ...): the query heads of
+    one key/value head one after another along the kernel's Q axis.  A
+    reshape of :func:`_fold_heads`' result (head ``h`` is rows
+    ``(h % group) * T ...`` of key/value head ``h // group``), so the
+    kernels see K/V once a group and no copy of them is made."""
+    y = _fold_heads(x)
+    if group == 1:
+        return y
+    bh, t, d = y.shape
+    return y.reshape(bh // group, group * t, d)
 
 
 def _blocks(t):
@@ -276,10 +318,15 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
+    group = _kv_group(q, k, v)
+    bk = b * h // group                 # key/value heads over the batch
     scale = 1.0 / math.sqrt(d)
     block_q, block_k = blocks if blocks is not None else \
         _select_blocks("flash_attention_fwd", q, causal)
     assert t % block_q == 0, "seq length must be a multiple of the Q block"
+    # Q blocks of one key/value head: ``group`` query heads of t/block_q
+    n_q = t // block_q
+    grouped = dict(n_q=n_q) if group > 1 else {}
     # 2 matmuls (QK^T, PV) at 2*t*t*d MACs->flops each; traffic:
     # q, k, v read + o written (lse is negligible)
     _note_kernel_cost("flash_attention_fwd", q, block_q, block_k,
@@ -289,10 +336,11 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
         # T fits one VMEM panel: single-panel kernel (measured fastest
         # at these lengths; streaming costs 10-15%, docs/perf.md)
         kernel = functools.partial(_flash_fwd_panel_kernel, scale=scale,
-                                   causal=causal, block_q=block_q)
+                                   causal=causal, block_q=block_q,
+                                   **grouped)
         out, lse = pl.pallas_call(
             kernel,
-            grid=(b * h, t // block_q),
+            grid=(bk, group * n_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
                 pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
@@ -303,19 +351,21 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
                 pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-                jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32),
+                jax.ShapeDtypeStruct((bk, group * t, d), q.dtype),
+                jax.ShapeDtypeStruct((bk, group * t, 1), jnp.float32),
             ],
             interpret=interpret,
             name=FLASH_FWD_PANEL,
-        )(_fold_heads(q), _fold_heads(k), _fold_heads(v))
-        return _unfold_heads(out, b, h), lse
+        )(_fold_queries(q, group), _fold_heads(k), _fold_heads(v))
+        return (_unfold_heads(out.reshape(b * h, t, d), b, h),
+                lse.reshape(b * h, t, 1))
     kernel = functools.partial(_flash_fwd_kernel, scale=scale,
                                causal=causal, block_q=block_q,
-                               block_k=block_k, n_k=t // block_k)
+                               block_k=block_k, n_k=t // block_k,
+                               **grouped)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, t // block_q, t // block_k),
+        grid=(bk, group * n_q, t // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
@@ -326,8 +376,8 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
             pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bk, group * t, d), q.dtype),
+            jax.ShapeDtypeStruct((bk, group * t, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -336,17 +386,21 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
         ],
         interpret=interpret,
         name=FLASH_FWD_STREAM,
-    )(_fold_heads(q), _fold_heads(k), _fold_heads(v))
-    return _unfold_heads(out, b, h), lse
+    )(_fold_queries(q, group), _fold_heads(k), _fold_heads(v))
+    return (_unfold_heads(out.reshape(b * h, t, d), b, h),
+            lse.reshape(b * h, t, 1))
 
 
 FLASH_BWD_PANEL = "mxtpu_flash_bwd_panel"
 
 
 def _flash_bwd_panel_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, *, scale, causal, block_q):
+                      dq_ref, dk_ref, dv_ref, *, scale, causal, block_q,
+                      n_q=None):
     """One Q block against the full K/V panel; dK/dV accumulate across
-    the Q-block grid axis (their output block revisits per qi)."""
+    the Q-block grid axis (their output block revisits per qi), which
+    with grouped queries runs over every query head of the key/value
+    head: the sum over the group happens here."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -355,6 +409,8 @@ def _flash_bwd_panel_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
+
+    qi = _q_block_pos(qi, n_q)
 
     q = q_ref[0].astype(jnp.float32)        # (block_q, D)
     k = k_ref[0].astype(jnp.float32)        # (T, D)
@@ -387,10 +443,35 @@ def _flash_bwd_panel_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 FLASH_BWD_STREAM = "mxtpu_flash_bwd_stream"
 
+#: VMEM a kernel may use without asking (Mosaic's scoped default on v5e)
+_VMEM_DEFAULT = 16 * 2 ** 20
+
+
+def _grouped_stream_params(group, t, d, block_q, block_k):
+    """Extra ``pallas_call`` arguments of the streaming backward under
+    grouped queries: its dQ accumulator holds the whole group's rows
+    (``group * t`` rows of float32, lanes padded to 128), 16 MiB at 4
+    query heads a key/value head and 8192 positions, so the kernel asks
+    for the VMEM it needs.  Nothing for one query head a key/value head:
+    that call stays as it always was."""
+    if group == 1:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    lanes = -(-d // 128) * 128
+    need = 4 * lanes * (group * t                 # dq accumulator
+                        + 6 * block_k             # dk/dv scratch + outputs
+                        + 8 * block_q)            # q, dO, dq blocks
+    need += 4 * 4 * block_q * block_k             # s, p, dp, ds tiles
+    need += 2 * 2 * 2 * lanes * block_k           # k, v double-buffered
+    if need <= _VMEM_DEFAULT * 3 // 4:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(int(need * 1.5), 100 * 2 ** 20))}
+
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      scale, causal, block_q, block_k, n_k):
+                      scale, causal, block_q, block_k, n_k, n_q=None):
     """Single-pass streaming backward, grid (BH, ki, qi): one K/V block
     stays resident while Q/dO stream past it (inner axis).  dK/dV
     accumulate in per-ki scratch; dQ accumulates in a full-sequence
@@ -403,6 +484,11 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     ki, qi = pl.program_id(1), pl.program_id(2)
     nk, nq = pl.num_programs(1), pl.num_programs(2)
+    # with grouped queries the Q axis runs over every query head of the
+    # key/value head (dK/dV sum over the group in their scratch, dQ's
+    # accumulator holds the group's rows); the causal position is the
+    # block's place in its own head
+    qpos = _q_block_pos(qi, n_q)
 
     @pl.when(qi == 0)
     def _init_kv():
@@ -419,7 +505,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
+            s = _causal_mask(s, qpos, ki, block_q, block_k)
         p = jnp.exp(s - lse)                  # masked entries exp(-inf)=0
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -446,7 +532,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # only a multi-block causal sweep has fully-masked tiles to
         # skip; a pl.when around the hot body otherwise just impedes
         # the Mosaic pipeline (measured, docs/perf.md)
-        pl.when(_causal_live(qi, ki, block_q, block_k))(_step)
+        pl.when(_causal_live(qpos, ki, block_q, block_k))(_step)
     else:
         _step()
 
@@ -472,21 +558,28 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
+    group = _kv_group(q, k, v)
+    bk, hk = b * h // group, h // group
     scale = 1.0 / math.sqrt(d)
     block_q, block_k = blocks if blocks is not None else \
         _select_blocks("flash_attention_bwd", q, causal)
+    n_q = t // block_q
+    grouped = dict(n_q=n_q) if group > 1 else {}
     # 5 matmuls (dV, dP, dQ, dK, S recompute) at 2*t*t*d each;
     # traffic: q, k, v, o, dO read + dq, dk, dv written (lse/delta
     # rows are negligible)
     _note_kernel_cost("flash_attention_bwd", q, block_q, block_k,
                       causal, n_matmuls=10, n_tensors=8)
 
-    qt, kt, vt = _fold_heads(q), _fold_heads(k), _fold_heads(v)
-    dot = _fold_heads(g)
+    qt, kt, vt = _fold_queries(q, group), _fold_heads(k), _fold_heads(v)
+    dot = _fold_queries(g, group)
     # delta_i = sum_d(dO_i * O_i): rowwise, cheap — computed outside
     delta = jnp.sum(dot.astype(jnp.float32)
-                    * _fold_heads(o).astype(jnp.float32),
+                    * _fold_queries(o, group).astype(jnp.float32),
                     axis=-1, keepdims=True)
+    lse = lse.reshape(bk, group * t, 1)
+    dq_shape = jax.ShapeDtypeStruct((bk, group * t, d), jnp.float32)
+    dkv_shape = jax.ShapeDtypeStruct((bk, t, d), jnp.float32)
 
     qblock = pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0))
     kblock = pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0))
@@ -497,39 +590,40 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
         # the measured fastest formulation at these lengths (every
         # streaming variant paid 10-15%, docs/perf.md)
         kernel = functools.partial(_flash_bwd_panel_kernel, scale=scale,
-                                   causal=causal, block_q=block_q)
+                                   causal=causal, block_q=block_q,
+                                   **grouped)
         panel = pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0))
         qb2 = pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0))
         rows2 = pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0))
         dq, dk, dv = pl.pallas_call(
             kernel,
-            grid=(b * h, t // block_q),
+            grid=(bk, group * n_q),
             in_specs=[qb2, panel, panel, qb2, rows2, rows2],
             out_specs=[qb2, panel, panel],
-            out_shape=[jax.ShapeDtypeStruct((b * h, t, d),
-                                            jnp.float32)] * 3,
+            out_shape=[dq_shape, dkv_shape, dkv_shape],
             interpret=interpret,
             name=FLASH_BWD_PANEL,
         )(qt, kt, vt, dot, lse, delta)
     else:
         kernel = functools.partial(_flash_bwd_kernel, scale=scale,
                                    causal=causal, block_q=block_q,
-                                   block_k=block_k, n_k=n_k)
+                                   block_k=block_k, n_k=n_k, **grouped)
         dq, dk, dv = pl.pallas_call(
             kernel,
-            grid=(b * h, t // block_k, t // block_q),
+            grid=(bk, t // block_k, group * n_q),
             in_specs=[qblock, kblock, kblock, qblock, rows, rows],
             out_specs=[qblock, kblock, kblock],
-            out_shape=[jax.ShapeDtypeStruct((b * h, t, d),
-                                            jnp.float32)] * 3,
-            scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
+            out_shape=[dq_shape, dkv_shape, dkv_shape],
+            scratch_shapes=[pltpu.VMEM((group * t, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
             interpret=interpret,
             name=FLASH_BWD_STREAM,
+            **_grouped_stream_params(group, t, d, block_q, block_k),
         )(qt, kt, vt, dot, lse, delta)
-    return tuple(_unfold_heads(x, b, h).astype(q.dtype)
-                 for x in (dq, dk, dv))
+    return (_unfold_heads(dq.reshape(b * h, t, d), b, h).astype(q.dtype),
+            _unfold_heads(dk, b, hk).astype(k.dtype),
+            _unfold_heads(dv, b, hk).astype(v.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -558,10 +652,25 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 def flash_attention_op(attrs, ctx, q, k, v):
     """Attention over (batch, seq, heads, head_dim) inputs.
 
+    ``k`` and ``v`` may have fewer heads than ``q`` by a whole factor
+    (grouped-query attention): query head ``h`` reads key/value head
+    ``h // (q heads / kv heads)``.  The kernels index the shared head
+    in their block maps and sum ``dK``/``dV`` over the group; no copy of
+    ``K``/``V`` is repeated in HBM.
+
     New TPU-native capability (the reference era has no attention ops);
     Pallas kernel on TPU, jnp fallback elsewhere.
     """
     causal = bool(attrs["causal"])
+    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
+        raise MXNetError(
+            "_contrib_FlashAttention wants (batch, seq, heads, head_dim) "
+            "inputs; got q %s, k %s, v %s"
+            % (tuple(q.shape), tuple(k.shape), tuple(v.shape)))
+    try:
+        group = _kv_group(q, k, v)
+    except ValueError as e:
+        raise MXNetError("_contrib_FlashAttention: %s" % e) from None
     t = q.shape[1]
     block_q = min(_BLOCK_Q, t)
     if _context.on_tpu() and t > 0 and t % block_q == 0 \
@@ -573,7 +682,9 @@ def flash_attention_op(attrs, ctx, q, k, v):
         # each device runs the kernel on its (batch/data, heads/model)
         # tile; attention mixes neither dim
         from jax.sharding import PartitionSpec as P
-        b_axis, h_axis = _mesh.kernel_axes(mesh, q.shape[0], q.shape[2])
+        # heads shard by key/value head, so that a group stays whole
+        b_axis, h_axis = _mesh.kernel_axes(mesh, q.shape[0],
+                                           q.shape[2] // group)
         spec = P(b_axis, None, h_axis, None)
         return _mesh.shard_map_nocheck(
             lambda q_, k_, v_: flash_attention(q_, k_, v_, causal),

@@ -118,6 +118,22 @@ def _ln(attrs, known):
     return {"gamma": c, "beta": c}
 
 
+@register_param_shapes("RMSNorm")
+def _rms(attrs, known):
+    data = known.get("data")
+    if data is None:
+        return {}
+    return {"gamma": (int(data[int(attrs.get("axis", -1))]),)}
+
+
+@register_param_shapes("_contrib_CausalConv1D")
+def _causal_conv1d(attrs, known):
+    data = known.get("data")
+    if data is None:
+        return {}
+    return {"weight": (int(data[-1]), int(attrs["kernel"]))}
+
+
 @register_param_shapes("LeakyReLU")
 def _prelu(attrs, known):
     data = known.get("data")
@@ -221,3 +237,17 @@ def _switch_moe(attrs, known):
     return {"router_weight": (d, e), "expert1_weight": (e, d, ff),
             "expert1_bias": (e, ff), "expert2_weight": (e, ff, d),
             "expert2_bias": (e, d)}
+
+
+@register_param_shapes("_contrib_TopKMoE")
+def _topk_moe(attrs, known):
+    data = known.get("data")
+    if data is None:
+        return {}
+    d = int(data[-1])
+    e = int(attrs["num_experts"])
+    held = int(attrs["experts_held"]) or e
+    ff = int(attrs["hidden_size"])
+    return {"router_weight": (e, d), "expert_bias": (e,),
+            "w1_weight": (held, d, ff), "w3_weight": (held, d, ff),
+            "w2_weight": (held, ff, d), "load": (held + 1,)}

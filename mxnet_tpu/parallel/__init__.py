@@ -20,6 +20,6 @@ holds the TPU-native machinery:
 from . import multihost
 from . import reshard
 from .mesh import build_mesh, build_mesh_from_axes, data_parallel_spec
-from .moe import make_expert_mesh, switch_moe
+from .moe import make_expert_mesh, switch_moe, topk_moe
 from .pipeline import make_pipeline_mesh, pipeline_apply, pipeline_grad
 from .trainer import ShardedTrainer

@@ -1,4 +1,15 @@
-"""Expert parallelism: switch-routed mixture-of-experts FFN.
+"""Expert parallelism: mixture-of-experts feed-forward layers.
+
+Two layers live here.  :func:`switch_moe` is top-1 Switch routing through
+dense one-hot dispatch einsums with a capacity (overflow tokens dropped).
+:func:`topk_moe` is the token-choice top-k layer of current language
+models (sigmoid scores, a selection-only bias, gated experts): it is
+told which experts it holds, routes every token over all of them, and
+computes the held experts' part of the result by sorting the
+assignments and multiplying group by group; it drops nothing and builds
+no tensor of tokens x experts x capacity.  Its docstring has the rest.
+
+Switch routing, as it always was:
 
 Beyond-reference capability (the 0.10.1 reference predates MoE), built
 the TPU way: top-1 routing is expressed as dense one-hot dispatch
@@ -15,6 +26,7 @@ probability, and the standard load-balancing auxiliary loss.
 from __future__ import annotations
 
 import math
+import re
 
 import numpy as np
 
@@ -83,6 +95,251 @@ def switch_moe(x, router_w, w1, b1, w2, b2, capacity_factor=1.25,
     mean_p = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(frac * mean_p)
     return y, aux
+
+
+#: ``jax.named_scope`` of the expert layer's ops on the device
+SCOPE_MOE = "mxtpu.block.moe"
+
+#: what XLA:TPU names the Mosaic grouped-matmul custom calls it makes of
+#: ``jax.lax.ragged_dot`` (``%ragged-dot-none.3 = ... custom-call(``;
+#: their tile maps are ``%ragged-dot-metadata.*``): they visit only the
+#: row tiles the groups fill.  Where a backend has none it multiplies
+#: densely and masks, and no such instruction is in the program.
+_GROUPED_PRODUCT = re.compile(
+    r"^\s*%?ragged-dot-(?!metadata)[\w-]*(?:\.\d+)? = .*\bcustom-call\(",
+    re.M)
+#: grouped products of one trained expert layer: three forward, and
+#: each one's two backward products
+PRODUCTS_PER_TRAINED_LAYER = 9
+
+
+def _sorted_dispatch(k):
+    """``(dispatch, combine)`` for ``k`` assignments a token: the two
+    row permutations between token order and expert-sorted order, each
+    with a hand-written transpose so that both directions are gathers
+    (autodiff would scatter-add ``tokens * k`` rows)."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def dispatch(x, order, inv, held):
+        # row r of the sorted buffer is token order[r] // k
+        return x[order // k]
+
+    def dispatch_fwd(x, order, inv, held):
+        return dispatch(x, order, inv, held), (inv, held, x.shape[0])
+
+    def dispatch_bwd(res, g):
+        inv, held, t = res
+        # rows past the held assignments belong to no group: whatever a
+        # grouped product left there is not a gradient
+        gk = jnp.where(held.reshape(-1, 1), g[inv], 0)
+        return (gk.reshape(t, k, -1).sum(axis=1).astype(g.dtype),
+                None, None, None)
+
+    dispatch.defvjp(dispatch_fwd, dispatch_bwd)
+
+    @jax.custom_vjp
+    def combine(rows, order, inv):
+        return rows[inv]
+
+    def combine_fwd(rows, order, inv):
+        return rows[inv], order
+
+    def combine_bwd(order, g):
+        return g[order], None, None
+
+    combine.defvjp(combine_fwd, combine_bwd)
+    return dispatch, combine
+
+
+# mxlint: allow-dtype-widening(the router, its sigmoid and the gate normalisation run in float32 by the model's definition)
+def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
+             expert_offset=0, norm_topk_prob=True,
+             routed_scaling_factor=1.0, router_trained=True):
+    """Token-choice top-k expert layer over the experts held here.
+
+    x: (tokens, d).  router_w: (E, d), the router at its published
+    width.  expert_bias: (E,) or None, added to the scores for the
+    selection only.  w1, w3: (held, d, ff); w2: (held, ff, d): the
+    gated experts ``w2(silu(x w1) * (x w3))`` of the ``held`` experts
+    ``expert_offset .. expert_offset + held - 1``.
+
+    Every token is routed over all ``E`` experts: ``s = sigmoid(x
+    router_w^T)`` in float32, its ``top_k`` experts are the largest of
+    ``s + expert_bias``, its gates ``s`` at those, divided by their sum
+    (+1e-6) over all ``top_k`` if ``norm_topk_prob``, times
+    ``routed_scaling_factor``.  The result is ``sum_e gate_e *
+    Expert_e(x)`` over the chosen experts **held here**; a token none of
+    whose experts is here gets zero (the residual carries it).  Summed
+    over the shares that together hold all ``E``, the results are the
+    whole layer's.  There is no exchange and nothing stands in for the
+    absent experts.
+
+    The layer is exact about its share, gradients included: what it
+    returns for ``x`` and ``router_w`` is the held experts' part of
+    those gradients, and summed over the shares the parts are the whole
+    layer's (the exchange that sums the results sums them).  A graph
+    that is one share and runs without the others can say
+    ``router_trained=False``: the scores are then constants to the
+    gradient (none for ``router_w``, none through the gates), because
+    the held experts' part alone teaches the router, and through the
+    gates the layers below it, to prefer the experts held here.  When
+    to say so is the model builder's decision (``models.lfm2_moe``).
+
+    The held assignments are sorted by expert (one ``argsort`` of
+    ``tokens * top_k`` keys, the others last), the tokens gathered into
+    that order, and the three products done group by group
+    (``jax.lax.ragged_dot``; the backward's two products are grouped
+    too).  The buffer has ``tokens * top_k`` rows, the most that can be
+    held, so no assignment is ever dropped whatever the imbalance;
+    rows past the held ones belong to no group and cost no product.
+
+    Returns ``(y (tokens, d) in x's dtype, load)`` where ``load`` is
+    float32 ``(held + 1,)``: the held experts' assignment counts (the
+    group sizes the products were given) and the tokens with no held
+    expert.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    t, d = x.shape
+    held = w1.shape[0]
+    k = int(top_k)
+    with jax.named_scope(SCOPE_MOE):
+        logits = jnp.dot(x, router_w.T.astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        s = jax.nn.sigmoid(logits)                          # (t, E) f32
+        if not router_trained:
+            s = jax.lax.stop_gradient(s)
+        sel = s if expert_bias is None else \
+            s + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
+        _, idx = jax.lax.top_k(sel, k)                      # (t, k)
+        gates = jnp.take_along_axis(s, idx, axis=1)
+        if norm_topk_prob:
+            gates = gates / (jnp.sum(gates, axis=1, keepdims=True) + 1e-6)
+        gates = gates * float(routed_scaling_factor)
+
+        here = (idx >= expert_offset) & (idx < expert_offset + held)
+        local = jnp.where(here, idx - expert_offset, held).reshape(-1)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        counts = jnp.sum(
+            local[:, None] == jnp.arange(held, dtype=local.dtype)[None, :],
+            axis=0, dtype=jnp.int32)                        # (held,)
+
+        dispatch, combine = _sorted_dispatch(k)
+        xs = dispatch(x, order, inv, here)                  # (t*k, d)
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, w1.astype(x.dtype), counts)) \
+            * jax.lax.ragged_dot(xs, w3.astype(x.dtype), counts)
+        rows = jax.lax.ragged_dot(h, w2.astype(x.dtype), counts)
+        back = combine(rows, order, inv).reshape(t, k, d)
+        weight = jnp.where(here, gates, 0.0)[:, :, None]
+        y = jnp.sum(jnp.where(here[:, :, None],
+                              back.astype(jnp.float32), 0.0) * weight,
+                    axis=1).astype(x.dtype)
+
+        load = jnp.concatenate([
+            counts.astype(jnp.float32),
+            jnp.sum(~jnp.any(here, axis=1), dtype=jnp.float32)[None]])
+    note_layer(num_experts=router_w.shape[0], experts_held=held,
+               expert_offset=int(expert_offset), num_experts_per_tok=k,
+               hidden_size=w1.shape[2], buffer_rows=xs.shape[0])
+    return y, load
+
+
+# ---- what the last traced step's expert layers are, and what they carried
+_RECORDING = None
+_LAST_SUMMARY = None
+_LOAD_SAMPLES = []
+#: sampled dispatches whose loads are kept (the oldest go first)
+LOAD_SAMPLES_KEPT = 256
+
+
+class plan_recording:
+    """Collects what each expert layer of one traced step is; on a clean
+    exit with at least one layer the collection becomes
+    :func:`last_plan_summary`.  ``ShardedTrainer`` opens one round the
+    step's forward trace."""
+
+    def __enter__(self):
+        global _RECORDING
+        self._prev, _RECORDING = _RECORDING, []
+        return self
+
+    def __exit__(self, exc_type, *_exc):
+        global _RECORDING, _LAST_SUMMARY
+        layers, _RECORDING = _RECORDING, self._prev
+        if exc_type is None and layers:
+            _LAST_SUMMARY = {"expert_layers": len(layers), "layers": layers,
+                             "grouped_products": None,
+                             "grouped_layers": None}
+        return False
+
+
+def note_layer(**info):
+    """One expert layer's plan, from :func:`topk_moe` (no-op outside a
+    :class:`plan_recording`)."""
+    if _RECORDING is not None:
+        _RECORDING.append(info)
+
+
+def note_compiled(executable):
+    """Read from the compiled program of the step traced last what its
+    expert layers' products became: ``grouped_products`` counts the
+    grouped-matmul custom calls in its text, ``grouped_layers`` the
+    expert layers they cover at :data:`PRODUCTS_PER_TRAINED_LAYER` each
+    (a backend that multiplies densely and masks reads 0).  Both stay
+    None where the executable gives no text."""
+    if _LAST_SUMMARY is None or not hasattr(executable, "as_text"):
+        return
+    text = executable.as_text()
+    if text:
+        n = len(_GROUPED_PRODUCT.findall(text))
+        _LAST_SUMMARY["grouped_products"] = n
+        _LAST_SUMMARY["grouped_layers"] = min(
+            _LAST_SUMMARY["expert_layers"], n // PRODUCTS_PER_TRAINED_LAYER)
+
+
+def last_plan_summary():
+    """Summary of the expert layers of the step traced last in this
+    process (None before any): ``expert_layers``; per layer the router
+    width, experts held and offset, experts a token, the experts' width
+    and ``buffer_rows`` (rows of the sorted buffer its products run
+    over); and, once that step's program is compiled,
+    ``grouped_products`` and ``grouped_layers`` as
+    :func:`note_compiled` reads them from it.  As
+    ``analysis.fusion.last_plan_summary()``."""
+    return _LAST_SUMMARY
+
+
+def publish_load(loads):
+    """Publish the expert layers' loads of a step the host has already
+    waited for.  ``loads``: ``{layer: host array (held + 1,)}`` as
+    :func:`topk_moe` returns them.  Sets the ``mxtpu_moe_*`` gauges and
+    keeps the sample for :func:`load_samples`."""
+    import time
+    from ..telemetry.registry import gauge
+    sample = {}
+    for layer, load in loads.items():
+        load = np.asarray(load, np.float64)
+        counts = [float(c) for c in load[:-1]]
+        for i, c in enumerate(counts):
+            gauge("mxtpu_moe_expert_assignments").labels(
+                layer=layer, expert=str(i)).set(c)
+        gauge("mxtpu_moe_tokens_unrouted").labels(layer=layer).set(
+            float(load[-1]))
+        sample[layer] = {"assignments": counts,
+                         "tokens_unrouted": float(load[-1])}
+    _LOAD_SAMPLES.append((time.perf_counter(), sample))
+    del _LOAD_SAMPLES[:-LOAD_SAMPLES_KEPT]
+
+
+def load_samples():
+    """``[(perf_counter time, {layer: {"assignments": [...],
+    "tokens_unrouted"}})]`` of the dispatches whose loads were
+    published, oldest first."""
+    return list(_LOAD_SAMPLES)
 
 
 def init_moe_params(rng, d, ff, num_experts, scale=0.1):

@@ -33,6 +33,8 @@ _PASS_OPS = frozenset({
     "batch_dot", "elemwise_mul", "_mul", "_mul_scalar", "_div_scalar",
     "_plus_scalar", "_minus_scalar", "broadcast_mul", "negative",
     "clip", "expand_dims", "squeeze", "SwapAxis", "transpose",
+    # per channel / per head: a column-sharded activation stays sharded
+    "_contrib_CausalConv1D", "_contrib_RotaryEmbedding",
 })
 
 

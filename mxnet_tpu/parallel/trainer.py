@@ -378,6 +378,12 @@ class ShardedTrainer:
         self._param_names = [n for n in arg_names
                              if n not in self._input_names]
         self._aux_names = [n.name for n in aux_nodes]
+        # expert layers' load statistics, carried as aux state like a
+        # batch norm's moving statistics: {aux name: layer name}
+        self._moe_loads = {
+            node.inputs[-1][0].name: node.name for node in self._topo
+            if node.op is not None and node.op.name == "_contrib_TopKMoE"
+            and node.inputs[-1][0].is_variable}
 
         # data inputs consumed as integer indices (Embedding/take/...):
         # these must NOT be cast to a narrow compute dtype — bf16 rounds
@@ -1274,8 +1280,10 @@ class ShardedTrainer:
                 from ..analysis.fusion import plan_decisions
                 from .sequence import sequence_parallel as seq_ctx
                 from .mesh import kernel_mesh
+                from .moe import plan_recording
                 p = self._compute_view(p32, compute_dtype)
                 with image_layout(layout), kernel_mesh(self.mesh), \
+                        plan_recording(), \
                         conv_bn_fusion(self._fuse_conv_bn), \
                         block_fusion(self._fuse_blocks), \
                         plan_decisions(self._plan_decisions), \
@@ -1749,6 +1757,7 @@ class ShardedTrainer:
             with _span("trainer.step.account", category="trainer"):
                 self._end_dispatch(obs, out, args)
                 loss = self._finish_step(program, out, args)
+                self._publish_moe_loads(obs)
                 # the donated state's handles (some hundreds of arrays)
                 # die here, inside the span, not as the method returns
                 del args, out
@@ -1860,9 +1869,17 @@ class ShardedTrainer:
             finally:
                 _costdb.bind_pending(
                     program, key=(self._costdb_scope, id(fn)))
+        first = bool(self._moe_loads) and \
+            (program, id(fn)) not in self._aot_exes
         try:
-            return _tmem.dispatch_planned(self._aot_exes, program, fn,
-                                          args)
+            out = _tmem.dispatch_planned(self._aot_exes, program, fn,
+                                         args)
+            if first:
+                # what the expert layers' products became in the
+                # program just compiled, read from its text
+                from . import moe as _moe
+                _moe.note_compiled(self._aot_exes.get((program, id(fn))))
+            return out
         except BaseException:  # mxlint: allow-broad-except(re-raised unchanged — the handler only closes the costdb observation bind-only, so the compile's traced signatures cannot dangle and attach to the next program dispatched)
             _costdb.end_dispatch(obs, failed=True)
             raise
@@ -1877,6 +1894,19 @@ class ShardedTrainer:
             _costdb.end_dispatch(obs, out=out, args=args,
                                  mesh=self._mesh_axis_sizes(),
                                  steps=steps)
+
+    def _publish_moe_loads(self, obs):
+        """The expert layers' loads of the last step as gauges
+        (``parallel.moe.publish_load``), on the dispatches the cost
+        database has already blocked on and on no other: reading the
+        few floats then waits for nothing, and no dispatch gains a host
+        sync."""
+        from ..telemetry import costdb as _costdb
+        if not self._moe_loads or not _costdb.sampled(obs):
+            return
+        from . import moe as _moe
+        _moe.publish_load({layer: np.asarray(self.aux[aux])
+                           for aux, layer in self._moe_loads.items()})
 
     def _mesh_axis_sizes(self):
         """{axis name: size} of the trainer's mesh — part of every
@@ -2111,6 +2141,7 @@ class ShardedTrainer:
             with _span("trainer.run_steps.account", category="trainer"):
                 self._end_dispatch(obs, out, args, steps=num_steps)
                 self.params, self.opt_state, self.aux, losses = out
+                self._publish_moe_loads(obs)
                 # the donated state's handles (some hundreds of arrays)
                 # die here, inside the span, not as the method returns
                 del args, out

@@ -201,6 +201,16 @@ CATALOG = {
     "mxtpu_flight_dumps_total": (COUNTER, ("reason",),
                                  "flight-recorder black-box dumps "
                                  "written (MXNET_TPU_FLIGHT_DIR)"),
+    # ------------------------------- expert layers (parallel.moe)
+    "mxtpu_moe_expert_assignments": (
+        GAUGE, ("layer", "expert"),
+        "(token, expert) assignments a held expert of a top-k expert "
+        "layer computed in the last step the host waited for "
+        "(expert = its index among the held ones)"),
+    "mxtpu_moe_tokens_unrouted": (
+        GAUGE, ("layer",),
+        "tokens of that step none of whose chosen experts is held "
+        "here (the layer gives them zero; the residual carries them)"),
     # ------------------------------- block fusion (analysis.fusion)
     "mxtpu_fusion_plans_total": (COUNTER, (),
                                  "block-fusion plans computed (one per "

@@ -667,6 +667,13 @@ def begin_dispatch(program, key=None):
     return DB.begin_dispatch(program, key=key)
 
 
+def sampled(obs):
+    """Whether ``obs`` (what :func:`begin_dispatch` returned) is a
+    dispatch :func:`end_dispatch` synchronizes on: after it the
+    program's outputs are on hand and reading them waits for nothing."""
+    return obs is not None and obs[2] is not None
+
+
 def bind_pending(program, key=None):
     """Bind pending signatures only — :meth:`CostDB.bind_pending`.
     Never raises (multi-process dispatch paths call it from a

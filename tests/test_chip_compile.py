@@ -75,6 +75,46 @@ def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
                       names=["mxtpu_flash_fwd_", "mxtpu_flash_bwd_"])
 
 
+# (batch, seq, query heads, key/value heads, head_dim): LFM2-8B-A1B's
+# attention at the cell's 8192 positions (streaming kernels; the backward
+# asks for the VMEM of its 4-head dQ accumulator) and at one K/V panel
+GROUPED_SHAPES = [(1, 8192, 32, 8, 64), (4, 2048, 32, 8, 64)]
+
+
+@pytest.mark.parametrize("shape", GROUPED_SHAPES, ids=str)
+def test_grouped_query_flash_compiles_for_v5e(one_chip, shape):
+    b, t, hq, hk, d = shape
+
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+    compiled = _compile_for_chip(
+        jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+        ((b, t, hq, d), jnp.bfloat16), ((b, t, hk, d), jnp.bfloat16),
+        ((b, t, hk, d), jnp.bfloat16),
+        names=["mxtpu_flash_fwd_", "mxtpu_flash_bwd_"])
+    # K and V go to the kernels as they are: no repeated copy is made
+    assert "bf16[%d,%d,%d,%d]" % (b, t, hq, d) not in [
+        line.split("=")[1].split()[0] for line in compiled.as_text().splitlines()
+        if " broadcast(" in line and "=" in line]
+
+
+def test_grouped_matmul_of_the_expert_layer_compiles_for_v5e(one_chip):
+    """``jax.lax.ragged_dot`` at the cell's size, forward and both backward
+    products: three Mosaic grouped-matmul custom calls, no dense fall-back."""
+    def loss(x, w, sizes):
+        return jax.lax.ragged_dot(x, w, sizes).astype(jnp.float32).sum()
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((32768, 2048), jnp.bfloat16), ((8, 2048, 1792), jnp.bfloat16),
+        ((8,), jnp.int32))]
+    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(*args).compile().as_text()
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and "ragged-dot-metadata" not in l.split("=")[0]]
+    assert len(calls) == 3 and all("ragged-dot" in c.split("=")[0] for c in calls), \
+        [c.split("=")[0] for c in calls]
+
+
 # ResNet-50 at batch 128: the 1x1 convs as (N*H*W, Cin) @ (Cin, Cout)
 MATMUL_STATS_SHAPES = [(401408, 64, 256), (100352, 512, 128),
                        (25088, 1024, 256), (6272, 2048, 512),

@@ -212,6 +212,9 @@ CASES = {
     # one RNG stream in dict-literal order, so inserting mid-dict would
     # silently reroll every later case's data)
     "squeeze": ([_x(2, 1, 5)], {"axis": 1}),
+    "RMSNorm": ([_x(2, 6), _pos(6)], {}),
+    "_contrib_CausalConv1D": ([_x(2, 5, 4), _x(4, 3)], {"kernel": 3}),
+    "_contrib_RotaryEmbedding": ([_x(1, 4, 2, 6)], {"base": 100.0}),
 }
 
 # every other registered op must appear here, with the reason it has no
@@ -267,6 +270,9 @@ SKIP = {
     "RNN": "fused cell backward covered by tests/test_rnn.py parity",
     "_contrib_SwitchMoE": "router+dispatch grads covered by "
                           "tests/test_moe.py sharded-parity",
+    "_contrib_TopKMoE": "discrete top-k routing with an aux state; values "
+                        "and gradients against the plain reference in "
+                        "tests/test_lfm2_moe.py",
     "Custom": "user-defined python op",
     "BlockGrad": "gradient blocked by definition (backward is zero, "
                  "forward is identity)",

@@ -18,6 +18,10 @@ sys.path.insert(0, os.path.join(
 def test_monitor_weights_runs_and_learns():
     import mxnet_tpu as mx
     import monitor_weights
+    # the initializer and the iterator's shuffle draw from numpy's global
+    # generator, whose state is whatever the worker's last file left
+    np.random.seed(0)
+    mx.random.seed(0)
     model = monitor_weights.main(num_epoch=10)
     x, y = monitor_weights.synthetic_digits(200, seed=2)
     it = mx.io.NDArrayIter(x, y, batch_size=100,

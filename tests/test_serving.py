@@ -116,8 +116,17 @@ def test_batcher_single_request_takes_smallest_rung():
         bat.close()
 
 
+class SlowLadder(FakeLadder):
+    """A dispatch really takes its ``wall``: the queue backs up behind it
+    however slowly the machine starts the submitting threads."""
+
+    def dispatch(self, rung, feed):
+        time.sleep(self._wall)
+        return super().dispatch(rung, feed)
+
+
 def test_batcher_sheds_on_queue_full():
-    lad = FakeLadder(rungs=(1,), wall=0.2)   # slow: the queue backs up
+    lad = SlowLadder(rungs=(1,), wall=0.2)   # slow: the queue backs up
     bat = Batcher(lad, window_ms=1, queue_depth=2,
                   default_deadline_ms=10000)
     try:

@@ -55,6 +55,9 @@ MODULES = [
 # hand-written pages kept alongside the generated ones (never
 # overwritten here, only indexed): title -> filename
 HAND_WRITTEN = [
+    ("language-model ops (RMSNorm, rotary, short convolution, "
+     "grouped-query flash attention, top-k expert layer)",
+     "language_model_ops.md"),
     ("resilience", "resilience.md"),
     ("analysis (static verifier + mxlint)", "analysis.md"),
     ("telemetry (metrics, spans, run reports)", "telemetry.md"),
