@@ -23,12 +23,23 @@ and a full-sequence VMEM dQ accumulator (bwd).  VMEM then scales
 O(T*D) instead of the panel's O(T*D + block_q*T) working set with its
 (block_q, T) f32 score tiles, so S=4096+ trains; the dQ accumulator
 (T*D*4 bytes — 1 MB at T=4096, D=64) becomes the next wall around
-T~64k.  Causal tile-skipping on the
-streamed grid is applied only where fully-masked tiles exist
-(multi-block causal sweeps); round-4/5 measurements show every
-always-on skip formulation (dynamic fori_loop, two-pass grid,
-small-K-block grids) LOSES 10-15% on v5e — long MXU contractions beat
-the skipped FLOPs at these lengths (docs/perf.md).
+T~64k.
+
+Causal scores (PR 27): a Q block's products run over the static K/V
+prefix it can see, not over the tile and a mask's zeros.  The Q blocks
+on a K/V tile's diagonal (the panel route: all of a head's) are split
+into :data:`_CAUSAL_RANGES` static ranges, and a block runs the one
+long product of the whole-tile kernel over its range's columns: a
+static slice of the block that is in VMEM already, one copy of the
+body a range under ``pl.when`` on the static grid, same blocks, index
+maps and pipeline (:func:`_causal_plan`).  On the streamed grid tiles
+above the diagonal are skipped and tiles below it carry no mask.  At
+4 ranges the panel kernels compute 62.5% of the square and take 23%
+less time at (4, 2048, 32, 64); every formulation that gave up the
+long product to skip more (round 4's dynamic ``fori_loop``, round 5's
+two-pass grid and small-K-block grids) had LOST 10-15% on v5e, and a
+``pallas_call`` a range loses on the backward's partial dK/dV
+(docs/perf.md, PERF.md section 6).
 
 Block selection (ISSUE 9): both kernels consult the persistent tuning
 cache first (:mod:`mxnet_tpu.autotune`, ``MXNET_TPU_TUNE_CACHE``) and
@@ -79,12 +90,114 @@ def _causal_live(qi, ki, block_q, block_k):
     return ki * block_k <= qi * block_q + block_q - 1
 
 
-def _causal_mask(s, qi, ki, block_q, block_k):
-    row = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    col = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+def _causal_interior(qi, ki, block_q, block_k):
+    """No entry of this (qi, ki) tile can be masked: k_end <= q_start."""
+    return ki * block_k + block_k - 1 <= qi * block_q
+
+
+def _causal_mask(s, row0, col0=None):
+    """Scores ``s`` of rows ``row0 ...`` against columns ``col0 ...``
+    (None: from the first), with what lies above the diagonal at -inf."""
+    row = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if col0 is not None:
+        col = col0 + col
     return jnp.where(row >= col, s, -jnp.inf)
+
+
+#: Ranges a diagonal tile's Q blocks are split into under ``causal``
+#: (:func:`_causal_plan`).  Measured on the v5e at 1, 2, 4, 8, 16
+#: (``tools/flash_causal_bench.py``, PERF.md section 6): forward +
+#: backward 6.66 / 5.55 / 5.10 / 5.03 / 5.43 ms at (4, 2048, 32, 64) and
+#: 19.97 / 18.97 / 18.44 / 18.67 / 19.19 ms at (1, 8192, 32 over 8, 64):
+#: every range is one more copy of the body, and a Q block's fixed
+#: work does not shrink with its columns.
+_CAUSAL_RANGES = 4
+
+
+def _causal_plan(block_q, block_k, ranges=None):
+    """Under ``causal``, which columns of the K/V tile on its diagonal a
+    Q block multiplies: ``(m, ((lo, hi, cols), ...))``.  A K/V tile of
+    ``block_k`` columns has ``m = block_k // block_q`` Q blocks on its
+    diagonal (the panel route: all of a head's); the one at place ``j``
+    can see ``(j + 1) * block_q`` of its columns.  The places are split
+    into at most ``ranges`` static ranges (default
+    :data:`_CAUSAL_RANGES`), and a Q block at ``lo <= j < hi`` runs its
+    products over the first ``cols = hi * block_q`` columns: a static
+    slice of the tile in VMEM, so each range is the same long product
+    as the whole tile, only shorter.  Executed share of a diagonal
+    tile: ``(S + 1) / 2S`` at S even ranges; 1 range is the whole tile.
+    A ``block_k`` that ``block_q`` does not divide (a tuned pair may be
+    any two divisors of ``t``) keeps its diagonal tiles whole."""
+    if block_k % block_q:
+        return 1, ((0, 1, block_k),)
+    m = block_k // block_q
+    s = max(1, min(_CAUSAL_RANGES if ranges is None else int(ranges), m))
+    bounds = sorted({-(-i * m // s) for i in range(s + 1)})
+    return m, tuple((lo, hi, hi * block_q)
+                    for lo, hi in zip(bounds[:-1], bounds[1:]))
+
+
+def _scores_computed_pct(t, block_q, block_k, plan):
+    """Score elements the kernels compute under ``plan``, over ``t * t``
+    a head, in percent: interior tiles whole, diagonal tiles by their
+    range's columns, tiles above the diagonal not at all."""
+    m, ranges = plan
+    done = 0
+    for qi in range(t // block_q):
+        for ki in range(t // block_k):
+            if _causal_interior(qi, ki, block_q, block_k):
+                done += block_k
+            elif _causal_live(qi, ki, block_q, block_k):
+                j = qi % m
+                done += next(c for lo, hi, c in ranges if lo <= j < hi)
+    return 100.0 * done * block_q / (t * t)
+
+
+def _diagonal_tile(j, ranges, tile, on=None):
+    """Run ``tile(cols)`` for the static range of ``ranges`` that holds
+    ``j``, the Q block's place on its K/V tile's diagonal
+    (:func:`_causal_plan`), where ``on`` (None: everywhere) holds.  One
+    copy of the tile's body a range, under ``pl.when`` on the static
+    grid: the blocks, their index maps and so the pipeline are those of
+    the whole-tile kernel."""
+    from jax.experimental import pallas as pl
+
+    for lo, hi, cols in ranges:
+        conds = ([] if on is None else [on]) \
+            + ([j >= lo] if lo else []) \
+            + ([j < hi] if hi < ranges[-1][1] else [])
+        if conds:
+            pl.when(functools.reduce(jnp.logical_and, conds))(
+                functools.partial(tile, cols))
+        else:
+            tile(cols)
+
+
+def _causal_tiles(qpos, ki, block_q, block_k, plan, step):
+    """The streaming kernels' tile (``qpos``, ``ki``) under ``causal``:
+    ``step(False)`` where nothing in it can be masked, ``step(True,
+    cols)`` on the diagonal over the columns the Q block's range can
+    see, nothing above it."""
+    from jax.experimental import pallas as pl
+
+    m, ranges = plan
+    pl.when(_causal_interior(qpos, ki, block_q, block_k))(
+        functools.partial(step, False))
+    diagonal = jnp.logical_and(
+        _causal_live(qpos, ki, block_q, block_k),
+        jnp.logical_not(_causal_interior(qpos, ki, block_q, block_k)))
+    j = jax.lax.rem(qpos, m) if m > 1 else 0
+    _diagonal_tile(j, ranges, functools.partial(step, True), on=diagonal)
+
+
+def _prefix(ref, cols, *lead):
+    """Index of the first ``cols`` rows of ``ref`` under the leading
+    indices ``lead``; ``cols`` None or all of them: the index the
+    whole-tile kernels use."""
+    if cols is None or cols == ref.shape[len(lead)]:
+        return lead or Ellipsis
+    return lead + (slice(0, cols),)
 
 
 # Device names of the kernels: the ``name=`` of each ``pallas_call``
@@ -103,31 +216,37 @@ def _q_block_pos(qi, n_q):
 
 
 def _flash_fwd_panel_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                  block_q, n_q=None):
+                  block_q, n_q=None, plan=None):
+    """One Q block against the K/V panel; under ``causal`` against the
+    panel's prefix that its range can see (``plan``:
+    :func:`_causal_plan`'s, None where not causal)."""
     from jax.experimental import pallas as pl
 
     qi = _q_block_pos(pl.program_id(1), n_q)
-    q = q_ref[0].astype(jnp.float32)        # (block_q, D)
-    k = k_ref[0].astype(jnp.float32)        # (T, D)
-    v = v_ref[0].astype(jnp.float32)        # (T, D)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+
+    def tile(cols=None):
+        q = q_ref[0].astype(jnp.float32)             # (block_q, D)
+        k = k_ref[_prefix(k_ref, cols, 0)].astype(jnp.float32)   # (cols, D)
+        v = v_ref[_prefix(v_ref, cols, 0)].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if causal:
+            s = _causal_mask(s, qi * block_q)
+        m = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = p.sum(axis=-1, keepdims=True)
+        o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32) / l
+        o_ref[0] = o.astype(o_ref.dtype)
+        # log-sum-exp per query row ((block_q, 1) — the trailing unit dim
+        # keeps the block TPU-tileable): the backward kernel reconstitutes
+        # the normalized p = exp(s - lse) without a second softmax pass
+        lse_ref[0] = m + jnp.log(l)
+
     if causal:
-        t = k.shape[0]
-        row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, t), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (block_q, t), 1)
-        s = jnp.where(row >= col, s, -jnp.inf)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = p.sum(axis=-1, keepdims=True)
-    o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32) / l
-    o_ref[0] = o.astype(o_ref.dtype)
-    # log-sum-exp per query row ((block_q, 1) — the trailing unit dim
-    # keeps the block TPU-tileable): the backward kernel reconstitutes
-    # the normalized p = exp(s - lse) without a second softmax pass
-    lse_ref[0] = m + jnp.log(l)
+        _diagonal_tile(qi, plan[1], tile)
+    else:
+        tile()
 
 
 FLASH_FWD_STREAM = "mxtpu_flash_fwd_stream"
@@ -135,14 +254,17 @@ FLASH_FWD_STREAM = "mxtpu_flash_fwd_stream"
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *, scale, causal,
-                      block_q, block_k, n_k, n_q=None):
+                      block_q, block_k, n_q=None, plan=None):
     """Online-softmax forward: K/V stream through VMEM in blocks along
     the innermost grid axis; the running (m, l, acc) row statistics
-    live in VMEM scratch.  Under ``causal`` the fully-masked upper-
-    triangle tiles are skipped (~2x fewer MXU FLOPs for an LM) —
-    skipping happens on the STATIC grid via pl.when, which keeps the
-    Mosaic pipeline intact (a dynamic-trip-count fori_loop formulation
-    measured 10 MFU points SLOWER in round 4, docs/perf.md)."""
+    live in VMEM scratch.  Under ``causal`` a tile above the diagonal
+    is skipped, a tile below it runs with no mask (nothing in it can
+    be masked), and the tile on the diagonal runs over the prefix of
+    its columns that the Q block's range can see (``plan``:
+    :func:`_causal_plan`) — all on the STATIC grid via pl.when, which
+    keeps the Mosaic pipeline intact (a dynamic-trip-count fori_loop
+    formulation measured 10 MFU points SLOWER in round 4,
+    docs/perf.md)."""
     from jax.experimental import pallas as pl
 
     qi, ki = _q_block_pos(pl.program_id(1), n_q), pl.program_id(2)
@@ -154,14 +276,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _step():
-        q = q_ref[0].astype(jnp.float32)      # (bq, D)
-        k = k_ref[0].astype(jnp.float32)      # (bk, D)
-        v = v_ref[0].astype(jnp.float32)      # (bk, D)
+    def _step(masked, cols=None):
+        q = q_ref[0].astype(jnp.float32)             # (bq, D)
+        k = k_ref[_prefix(k_ref, cols, 0)].astype(jnp.float32)   # (cols, D)
+        v = v_ref[_prefix(v_ref, cols, 0)].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
+        if masked:
+            s = _causal_mask(s, qi * block_q, ki * block_k)
         m_prev = m_ref[...]
         l_prev = l_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -173,13 +295,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    if causal and n_k > 1:
-        # only a multi-block causal sweep has fully-masked tiles to
-        # skip; a pl.when around the hot body otherwise just impedes
-        # the Mosaic pipeline (measured, docs/perf.md)
-        pl.when(_causal_live(qi, ki, block_q, block_k))(_step)
+    if causal:
+        _causal_tiles(qi, ki, block_q, block_k, plan, _step)
     else:
-        _step()
+        _step(False)
 
     @pl.when(ki == nk - 1)
     def _done():
@@ -280,40 +399,116 @@ def _select_blocks(op, q, causal):
 
 
 def _note_kernel_cost(op, q, block_q, block_k, causal, n_matmuls,
-                      n_tensors):
+                      n_tensors, plan=None):
     """Label this kernel instantiation's chosen block shapes in the
     cost database (telemetry.costdb) so block-size cliffs — e.g. the
     2176-length 17-tiny-K-blocks fallback ADVICE flagged — become
     queryable by (op, shape).  ``n_tensors``: how many (B, T, H, D)
     sized tensors the kernel moves (HBM traffic estimate — the
-    backward touches twice the forward's).  Host-side, once per
-    compile; swallowed on failure (observability must not fail the
-    trace)."""
+    backward touches twice the forward's).  ``plan``: the kernel's
+    :func:`_causal_plan` (None where not causal); the record says into
+    how many static ranges a diagonal tile's Q blocks are split
+    (``causal_ranges``) and which share of a head's ``t * t`` scores
+    the kernel computes (``scores_computed_pct``), and a causal kernel
+    joins :func:`last_causal_plan`.  Host-side, once per compile;
+    swallowed on failure (observability must not fail the trace)."""
     try:
         from ..telemetry import costdb
         b, t, h, d = q.shape
         flops = float(n_matmuls) * b * h * t * t * d
         itemsize = jnp.dtype(q.dtype).itemsize
         bytes_ = float(n_tensors) * b * t * h * d * itemsize
+        config = {"block_q": int(block_q), "block_k": int(block_k),
+                  "n_k": int(t // block_k), "causal": bool(causal),
+                  "causal_ranges": len(plan[1]) if plan else 1,
+                  "scores_computed_pct": _scores_computed_pct(
+                      t, block_q, block_k, plan) if plan else 100.0}
+        if plan and _PLAN_RECORDING is not None:
+            _PLAN_RECORDING.append(dict(
+                config, kernel=op, shape=tuple(int(n) for n in q.shape)))
         costdb.note_kernel(
             op, [tuple(q.shape)], [str(q.dtype)], flops=flops,
-            bytes_accessed=bytes_,
-            block_config={"block_q": int(block_q),
-                          "block_k": int(block_k),
-                          "n_k": int(t // block_k),
-                          "causal": bool(causal)})
+            bytes_accessed=bytes_, block_config=config)
     except MemoryError:  # pragma: no cover - never mask resource exhaustion
         raise
     except Exception:  # mxlint: allow-broad-except(kernel labeling is observability inside a jit trace; any failure must not fail the compile)
         pass
 
 
+_PLAN_RECORDING = None    # causal flash kernels of the step being traced
+_LAST_CAUSAL_PLAN = None
+
+
+class causal_plan_recording:
+    """Collects the causal flash kernels of one traced step, forward and
+    backward; on a clean exit with at least one kernel the collection
+    becomes :func:`last_causal_plan`.  ``ShardedTrainer`` opens one
+    round the step's trace, as it does ``moe.plan_recording``."""
+
+    def __enter__(self):
+        global _PLAN_RECORDING
+        self._prev, _PLAN_RECORDING = _PLAN_RECORDING, []
+        return self
+
+    def __exit__(self, exc_type, *_exc):
+        global _PLAN_RECORDING, _LAST_CAUSAL_PLAN
+        kernels, _PLAN_RECORDING = _PLAN_RECORDING, self._prev
+        if exc_type is None and kernels:
+            _LAST_CAUSAL_PLAN = {
+                "kernels": kernels,
+                "causal_ranges": max(k["causal_ranges"] for k in kernels),
+                "scores_computed_pct": max(k["scores_computed_pct"]
+                                           for k in kernels)}
+        return False
+
+
+def last_causal_plan():
+    """What the causal flash kernels of the step traced last in this
+    process compute (None before any): per kernel its name, q shape,
+    blocks, ``causal_ranges`` and ``scores_computed_pct`` as its cost
+    database record has them (:func:`_note_kernel_cost`), and the
+    largest of each over the step's kernels.  50 plus half a Q block's
+    share is what the mask leaves; 100 is the whole square.  As
+    ``moe.last_plan_summary()``."""
+    return _LAST_CAUSAL_PLAN
+
+
 def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
-                                blocks=None):
+                                blocks=None, ranges=None):
     """q/k/v: (B, T, H, D) -> (o (B, T, H, D), lse (BH, T, 1) f32).
     ``blocks``: explicit (block_q, block_k) override (the autotuner
     measures candidates through it); default consults the tuning
-    cache, then the heuristic."""
+    cache, then the heuristic.  ``ranges``: explicit count of causal
+    ranges (measurements and tests; default :func:`_causal_plan`'s)."""
+    block_q, block_k = blocks if blocks is not None else \
+        _select_blocks("flash_attention_fwd", q, causal)
+    assert q.shape[1] % block_q == 0, \
+        "seq length must be a multiple of the Q block"
+    plan = _causal_plan(block_q, block_k, ranges) if causal else None
+    # 2 matmuls (QK^T, PV) at 2*t*t*d MACs->flops each; traffic:
+    # q, k, v read + o written (lse is negligible)
+    _note_kernel_cost("flash_attention_fwd", q, block_q, block_k,
+                      causal, n_matmuls=4, n_tensors=4, plan=plan)
+    return _flash_fwd_call(q, k, v, causal=bool(causal),
+                           interpret=bool(interpret), block_q=int(block_q),
+                           block_k=int(block_k), plan=plan)
+
+
+#: A kernel's call (arrays first, then static keywords) traced once a
+#: signature and inlined where it is called: the caller's jaxpr holds
+#: what an undecorated function would have put there, and a second call
+#: with the same shapes and keywords costs a cache lookup, not another
+#: trace of the kernel's body.  A symbol's shape inference evaluates
+#: each node's ancestors again: 232 traces of the forward kernel for 8
+#: attention layers, before the step itself is traced.
+_traced_once = functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("causal", "interpret", "block_q", "block_k", "plan"))
+
+
+@_traced_once
+def _flash_fwd_call(q, k, v, *, causal, interpret, block_q, block_k, plan):
+    """The forward kernel's call for blocks and plan already chosen."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -321,23 +516,16 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
     group = _kv_group(q, k, v)
     bk = b * h // group                 # key/value heads over the batch
     scale = 1.0 / math.sqrt(d)
-    block_q, block_k = blocks if blocks is not None else \
-        _select_blocks("flash_attention_fwd", q, causal)
-    assert t % block_q == 0, "seq length must be a multiple of the Q block"
     # Q blocks of one key/value head: ``group`` query heads of t/block_q
     n_q = t // block_q
     grouped = dict(n_q=n_q) if group > 1 else {}
-    # 2 matmuls (QK^T, PV) at 2*t*t*d MACs->flops each; traffic:
-    # q, k, v read + o written (lse is negligible)
-    _note_kernel_cost("flash_attention_fwd", q, block_q, block_k,
-                      causal, n_matmuls=4, n_tensors=4)
 
     if t // block_k == 1:
         # T fits one VMEM panel: single-panel kernel (measured fastest
         # at these lengths; streaming costs 10-15%, docs/perf.md)
         kernel = functools.partial(_flash_fwd_panel_kernel, scale=scale,
                                    causal=causal, block_q=block_q,
-                                   **grouped)
+                                   plan=plan, **grouped)
         out, lse = pl.pallas_call(
             kernel,
             grid=(bk, group * n_q),
@@ -361,8 +549,7 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
                 lse.reshape(b * h, t, 1))
     kernel = functools.partial(_flash_fwd_kernel, scale=scale,
                                causal=causal, block_q=block_q,
-                               block_k=block_k, n_k=t // block_k,
-                               **grouped)
+                               block_k=block_k, plan=plan, **grouped)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bk, group * n_q, t // block_k),
@@ -396,11 +583,13 @@ FLASH_BWD_PANEL = "mxtpu_flash_bwd_panel"
 
 def _flash_bwd_panel_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, *, scale, causal, block_q,
-                      n_q=None):
-    """One Q block against the full K/V panel; dK/dV accumulate across
-    the Q-block grid axis (their output block revisits per qi), which
-    with grouped queries runs over every query head of the key/value
-    head: the sum over the group happens here."""
+                      n_q=None, plan=None):
+    """One Q block against the K/V panel, under ``causal`` against the
+    panel's prefix that its range can see (``plan``:
+    :func:`_causal_plan`'s); dK/dV accumulate across the Q-block grid
+    axis (their output block revisits per qi), which with grouped
+    queries runs over every query head of the key/value head: the sum
+    over the group happens here."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -412,33 +601,37 @@ def _flash_bwd_panel_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     qi = _q_block_pos(qi, n_q)
 
-    q = q_ref[0].astype(jnp.float32)        # (block_q, D)
-    k = k_ref[0].astype(jnp.float32)        # (T, D)
-    v = v_ref[0].astype(jnp.float32)        # (T, D)
-    do = do_ref[0].astype(jnp.float32)      # (block_q, D)
-    lse = lse_ref[0]                        # (block_q, 1)
-    delta = delta_ref[0]                    # (block_q, 1) = rowsum(do*o)
+    def tile(cols=None):
+        q = q_ref[0].astype(jnp.float32)             # (block_q, D)
+        k = k_ref[_prefix(k_ref, cols, 0)].astype(jnp.float32)   # (cols, D)
+        v = v_ref[_prefix(v_ref, cols, 0)].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)           # (block_q, D)
+        lse = lse_ref[0]                             # (block_q, 1)
+        delta = delta_ref[0]                  # (block_q, 1) rowsum(do*o)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if causal:
+            s = _causal_mask(s, qi * block_q)
+        p = jnp.exp(s - lse)                    # masked entries exp(-inf)=0
+        # dV += P^T dO
+        dv_ref[_prefix(dv_ref, cols, 0)] += jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # dP = dO V^T ; dS = P o (dP - delta) * scale
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta) * scale
+        dq_ref[0] = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+        dk_ref[_prefix(dk_ref, cols, 0)] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
     if causal:
-        t = k.shape[0]
-        row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, t), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (block_q, t), 1)
-        s = jnp.where(row >= col, s, -jnp.inf)
-    p = jnp.exp(s - lse)                    # masked entries exp(-inf)=0
-    # dV += P^T dO
-    dv_ref[0] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-    # dP = dO V^T ; dS = P o (dP - delta) * scale
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * scale
-    dq_ref[0] = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-    dk_ref[0] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+        _diagonal_tile(qi, plan[1], tile)
+    else:
+        tile()
 
 
 FLASH_BWD_STREAM = "mxtpu_flash_bwd_stream"
@@ -471,15 +664,18 @@ def _grouped_stream_params(group, t, d, block_q, block_k):
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      scale, causal, block_q, block_k, n_k, n_q=None):
+                      scale, causal, block_q, block_k, n_q=None, plan=None):
     """Single-pass streaming backward, grid (BH, ki, qi): one K/V block
     stays resident while Q/dO stream past it (inner axis).  dK/dV
     accumulate in per-ki scratch; dQ accumulates in a full-sequence
     VMEM scratch (T*D f32 — 1 MB at T=4096) and each dQ block is
     emitted on the final ki sweep.  Same 5-matmul count as the old
     full-panel kernel, with only the O(T*D) dQ accumulator (not the
-    O(block_q*T) score tiles) scaling with sequence length; fully-
-    masked causal tiles are skipped on the static grid."""
+    O(block_q*T) score tiles) scaling with sequence length.  Under
+    ``causal``, on the static grid: tiles above the diagonal are
+    skipped, tiles below it carry no mask, the tile on the diagonal
+    runs over the prefix of its columns that the Q block's range can
+    see (``plan``: :func:`_causal_plan`)."""
     from jax.experimental import pallas as pl
 
     ki, qi = pl.program_id(1), pl.program_id(2)
@@ -495,25 +691,25 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _step():
-        q = q_ref[0].astype(jnp.float32)      # (bq, D)
-        k = k_ref[0].astype(jnp.float32)      # (bk, D)
-        v = v_ref[0].astype(jnp.float32)      # (bk, D)
-        do = do_ref[0].astype(jnp.float32)    # (bq, D)
-        lse = lse_ref[0]                      # (bq, 1)
-        delta = delta_ref[0]                  # (bq, 1)
+    def _step(masked, cols=None):
+        q = q_ref[0].astype(jnp.float32)             # (bq, D)
+        k = k_ref[_prefix(k_ref, cols, 0)].astype(jnp.float32)   # (cols, D)
+        v = v_ref[_prefix(v_ref, cols, 0)].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)           # (bq, D)
+        lse = lse_ref[0]                             # (bq, 1)
+        delta = delta_ref[0]                         # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qpos, ki, block_q, block_k)
+        if masked:
+            s = _causal_mask(s, qpos * block_q, ki * block_k)
         p = jnp.exp(s - lse)                  # masked entries exp(-inf)=0
-        dv_acc[...] += jax.lax.dot_general(
+        dv_acc[_prefix(dv_acc, cols)] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
-        dk_acc[...] += jax.lax.dot_general(
+        dk_acc[_prefix(dk_acc, cols)] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         contrib = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
@@ -528,13 +724,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         def _dq_add():
             dq_acc[sl, :] += contrib
 
-    if causal and n_k > 1:
-        # only a multi-block causal sweep has fully-masked tiles to
-        # skip; a pl.when around the hot body otherwise just impedes
-        # the Mosaic pipeline (measured, docs/perf.md)
-        pl.when(_causal_live(qpos, ki, block_q, block_k))(_step)
+    if causal:
+        _causal_tiles(qpos, ki, block_q, block_k, plan, _step)
     else:
-        _step()
+        _step(False)
 
     @pl.when(ki == nk - 1)
     def _emit_dq():
@@ -547,13 +740,31 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
-                                blocks=None):
+                                blocks=None, ranges=None):
     """Flash backward: P is reconstituted per tile from the forward\'s
     saved log-sum-exp, the (T, T) matrix never touches HBM, and no ref
     spans the full sequence — S=4096+ runs where the old full-panel
     kernel hit the VMEM wall (VERDICT r4 #2).  ``blocks``: explicit
     (block_q, block_k) override (autotuner); default is
-    cache-then-heuristic, keyed independently of the forward."""
+    cache-then-heuristic, keyed independently of the forward.
+    ``ranges``: as the forward's."""
+    block_q, block_k = blocks if blocks is not None else \
+        _select_blocks("flash_attention_bwd", q, causal)
+    plan = _causal_plan(block_q, block_k, ranges) if causal else None
+    # 5 matmuls (dV, dP, dQ, dK, S recompute) at 2*t*t*d each;
+    # traffic: q, k, v, o, dO read + dq, dk, dv written (lse/delta
+    # rows are negligible)
+    _note_kernel_cost("flash_attention_bwd", q, block_q, block_k,
+                      causal, n_matmuls=10, n_tensors=8, plan=plan)
+    return _flash_bwd_call(q, k, v, o, lse, g, causal=bool(causal),
+                           interpret=bool(interpret), block_q=int(block_q),
+                           block_k=int(block_k), plan=plan)
+
+
+@_traced_once
+def _flash_bwd_call(q, k, v, o, lse, g, *, causal, interpret, block_q,
+                    block_k, plan):
+    """The backward kernel's call for blocks and plan already chosen."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -561,15 +772,8 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
     group = _kv_group(q, k, v)
     bk, hk = b * h // group, h // group
     scale = 1.0 / math.sqrt(d)
-    block_q, block_k = blocks if blocks is not None else \
-        _select_blocks("flash_attention_bwd", q, causal)
     n_q = t // block_q
     grouped = dict(n_q=n_q) if group > 1 else {}
-    # 5 matmuls (dV, dP, dQ, dK, S recompute) at 2*t*t*d each;
-    # traffic: q, k, v, o, dO read + dq, dk, dv written (lse/delta
-    # rows are negligible)
-    _note_kernel_cost("flash_attention_bwd", q, block_q, block_k,
-                      causal, n_matmuls=10, n_tensors=8)
 
     qt, kt, vt = _fold_queries(q, group), _fold_heads(k), _fold_heads(v)
     dot = _fold_queries(g, group)
@@ -591,7 +795,7 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
         # streaming variant paid 10-15%, docs/perf.md)
         kernel = functools.partial(_flash_bwd_panel_kernel, scale=scale,
                                    causal=causal, block_q=block_q,
-                                   **grouped)
+                                   plan=plan, **grouped)
         panel = pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0))
         qb2 = pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0))
         rows2 = pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0))
@@ -607,7 +811,7 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
     else:
         kernel = functools.partial(_flash_bwd_kernel, scale=scale,
                                    causal=causal, block_q=block_q,
-                                   block_k=block_k, n_k=n_k, **grouped)
+                                   block_k=block_k, plan=plan, **grouped)
         dq, dk, dv = pl.pallas_call(
             kernel,
             grid=(bk, t // block_k, group * n_q),

@@ -1311,13 +1311,15 @@ class ShardedTrainer:
             # stable names on the device: every op of the step carries
             # one of these scopes in its ``op_name``, whatever the compile
             from ..ops.nn import maybe_mirror
-            with jax.named_scope(SCOPE_FWD):
-                heads, vjp, (aux_upd, blk_stats) = jax.vjp(
-                    maybe_mirror(fwd), params, has_aux=True)
-            with jax.named_scope(SCOPE_BWD):
-                cot = [jnp.ones_like(h) if il else jnp.zeros_like(h)
-                       for h, il in zip(heads, head_is_loss)]
-                (grads,) = vjp(list(cot))
+            from ..ops.pallas_kernels import causal_plan_recording
+            with causal_plan_recording():
+                with jax.named_scope(SCOPE_FWD):
+                    heads, vjp, (aux_upd, blk_stats) = jax.vjp(
+                        maybe_mirror(fwd), params, has_aux=True)
+                with jax.named_scope(SCOPE_BWD):
+                    cot = [jnp.ones_like(h) if il else jnp.zeros_like(h)
+                           for h, il in zip(heads, head_is_loss)]
+                    (grads,) = vjp(list(cot))
 
             new_params, new_state = {}, {}
             with jax.named_scope(SCOPE_OPT):

@@ -184,22 +184,32 @@ def test_grouped_query_flash_is_the_ungrouped_kernel_head_by_head(route):
 
 def test_factor_one_flash_calls_are_unchanged():
     """With as many key/value heads as query heads the kernels are called as
-    before this change: same grid, no group arithmetic in the kernel body, no
-    compiler parameters."""
-    q = jax.ShapeDtypeStruct((2, 256, 4, 32), jnp.float32)
+    before grouped queries came: same grid, no group arithmetic in the kernel
+    body, no compiler parameters.  Since the causal ranges (PR 27) a streaming
+    kernel takes one remainder for a Q block's place on its K/V tile's diagonal
+    where that tile holds several Q blocks; the block's place in its own head
+    is a second one, and only grouped queries take it."""
+    def text(blocks, kv_heads=4):
+        q = jax.ShapeDtypeStruct((2, 256, 4, 32), jnp.float32)
+        kv = jax.ShapeDtypeStruct((2, 256, kv_heads, 32), jnp.float32)
 
-    def text(blocks):
         def f(q, k, v, g):
             o, lse = pk._flash_attention_fwd_pallas(q, k, v, True, True,
                                                     blocks=blocks)
             return pk._flash_attention_bwd_pallas(q, k, v, o, lse, g, True,
                                                   True, blocks=blocks)
-        return str(jax.make_jaxpr(f)(q, q, q, q))
+        return str(jax.make_jaxpr(f)(q, kv, kv, q))
 
     for route, blocks in ROUTES.items():
         t = text(blocks)
         assert " rem " not in t and "vmem_limit" not in t, route
         assert t.count("grid=(8, %d" % (256 // blocks[route == "stream"]))
+        assert t.count("name=mxtpu_flash_fwd_%s" % route) == 1
+        assert t.count("name=mxtpu_flash_bwd_%s" % route) == 1
+    # two Q blocks on a streamed tile's diagonal: forward and backward take
+    # one remainder each, and the grouped kernels one more
+    assert text((64, 128)).count(" rem ") == 2
+    assert text((64, 128), kv_heads=1).count(" rem ") == 4
     assert pk._grouped_stream_params(1, 8192, 64, 128, 2048) == {}
     assert "compiler_params" in pk._grouped_stream_params(4, 8192, 64, 128, 2048)
 

@@ -164,3 +164,194 @@ def test_attention_step_names_its_kernels(monkeypatch):
     # the streaming kernels' names are constants of their call sites too
     assert (pk.FLASH_FWD_STREAM, pk.FLASH_BWD_STREAM) == \
         ("mxtpu_flash_fwd_stream", "mxtpu_flash_bwd_stream")
+    # the trainer's trace leaves the step's causal kernels, forward and
+    # backward, for last_causal_plan(): one Q block a head here, so the
+    # one diagonal tile is computed whole
+    plan = pk.last_causal_plan()
+    assert [(k["kernel"], k["shape"]) for k in plan["kernels"]] == \
+        [("flash_attention_fwd", (B, T, H, D)),
+         ("flash_attention_bwd", (B, T, H, D))]
+    assert (plan["causal_ranges"], plan["scores_computed_pct"]) == (1, 100.0)
+
+
+# ------------------------------------------- causal prefix ranges (PR 27)
+# (t, block_q, block_k): six Q blocks on a K/V tile's diagonal, which 4
+# ranges do not divide evenly; one tile (panel) and two (stream)
+CAUSAL_ROUTES = {"panel": (384, 64, 384), "stream": (768, 64, 384)}
+ONE_A_BLOCK = 6
+_single_range = {}
+
+
+def _causal_inputs(route, group):
+    t = CAUSAL_ROUTES[route][0]
+    rng = np.random.RandomState(3)
+    mk = lambda h: rng.normal(0, 1, (1, t, h, 32)).astype(np.float32)
+    return mk(4), mk(4 // group), mk(4 // group), mk(4)
+
+
+def _causal_run(route, group, ranges, inputs=None):
+    """(o, lse, dq, dk, dv) of the kernels at ``ranges`` static ranges."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    q, k, v, g = inputs or _causal_inputs(route, group)
+    blocks = CAUSAL_ROUTES[route][1:]
+    o, lse = pk._flash_attention_fwd_pallas(q, k, v, True, True,
+                                            blocks=blocks, ranges=ranges)
+    return (o, lse) + tuple(pk._flash_attention_bwd_pallas(
+        q, k, v, o, lse, g, True, True, blocks=blocks, ranges=ranges))
+
+
+@pytest.mark.parametrize("ranges", [1, 2, 4, ONE_A_BLOCK])
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("route", sorted(CAUSAL_ROUTES))
+def test_causal_ranges_change_no_number(route, group, ranges):
+    """A Q block that multiplies only the K/V prefix its range can see
+    gives what the whole tile gives: the columns left out enter every
+    sum as exp(-inf) = 0.  Against the single-range kernels to 1e-6
+    and against the dense float32 attention to this file's tolerance."""
+    import jax
+    from mxnet_tpu.ops import pallas_kernels as pk
+    t, block_q, block_k = CAUSAL_ROUTES[route]
+    m, plan = pk._causal_plan(block_q, block_k, ranges)
+    assert (m, len(plan)) == (ONE_A_BLOCK, ranges)
+    assert [hi - lo for lo, hi, _c in plan] == \
+        {1: [6], 2: [3, 3], 4: [2, 1, 2, 1], 6: [1] * 6}[ranges]
+    assert all(cols == hi * block_q for _lo, hi, cols in plan)
+    key = (route, group)
+    if key not in _single_range:
+        _single_range[key] = _causal_run(route, group, 1)
+    got = _causal_run(route, group, ranges)
+    for a, b in zip(got, _single_range[key]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=1e-6)
+    q, k, v, g = _causal_inputs(route, group)
+    want, vjp = jax.vjp(lambda *a: pk._attention_jnp(*a, True), q, k, v)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+    for a, b in zip(got[2:], vjp(g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=3e-5)
+
+
+@pytest.mark.parametrize("route", sorted(CAUSAL_ROUTES))
+def test_first_q_block_of_each_grouped_head_reads_block_q_columns(route):
+    """With one range a Q block, the first Q block of EVERY query head
+    of a group (its place in its own head, not on the kernel's Q axis)
+    reads the first ``block_q`` rows of K/V and no more: NaNs in V
+    below them do not reach it, and they reach the whole-tile kernel
+    (0 * NaN), so the test can tell."""
+    t, block_q, _bk = CAUSAL_ROUTES[route]
+    q, k, v, g = _causal_inputs(route, 4)
+    bad = v.copy()
+    bad[:, block_q:] = np.nan
+    clean = _causal_run(route, 4, ONE_A_BLOCK)
+    got = _causal_run(route, 4, ONE_A_BLOCK, (q, k, bad, g))
+    whole = _causal_run(route, 4, 1, (q, k, bad, g))
+    for name, i in (("o", 0), ("dq", 2)):
+        first = np.asarray(got[i])[:, :block_q]
+        assert np.isfinite(first).all(), name
+        np.testing.assert_array_equal(first,
+                                      np.asarray(clean[i])[:, :block_q])
+        assert np.isnan(np.asarray(whole[i])[:, :block_q]).any(), name
+        assert np.isnan(np.asarray(got[i])[:, block_q:]).any(), name
+
+
+# sha256[:16] of the jaxpr text of forward + backward at causal=False on
+# the commit before the ranges came (2fa84db), q (2, 256, 4, 32) float32
+NONCAUSAL_JAXPR = {("panel", 4): "3385563da8120ad7",
+                   ("panel", 1): "f392d7a587c082be",
+                   ("stream", 4): "1d115c3aaeaf854e",
+                   ("stream", 1): "75d30303bea0f05e"}
+
+
+@pytest.mark.parametrize("route,kv_heads", sorted(NONCAUSAL_JAXPR))
+def test_noncausal_calls_are_the_parents(route, kv_heads):
+    """``causal=False`` traces to the calls it always did, kernel bodies
+    included, with as many key/value heads as query heads and with
+    fewer: ring attention's blocks and every bidirectional model."""
+    import hashlib
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+    blocks = {"panel": (128, 256), "stream": (64, 64)}[route]
+    q = jax.ShapeDtypeStruct((2, 256, 4, 32), jnp.float32)
+    kv = jax.ShapeDtypeStruct((2, 256, kv_heads, 32), jnp.float32)
+
+    def f(q, k, v, g):
+        o, lse = pk._flash_attention_fwd_pallas(q, k, v, False, True,
+                                                blocks=blocks)
+        return pk._flash_attention_bwd_pallas(q, k, v, o, lse, g, False,
+                                              True, blocks=blocks)
+
+    text = str(jax.make_jaxpr(f)(q, kv, kv, q))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        NONCAUSAL_JAXPR[route, kv_heads]
+
+
+@pytest.mark.parametrize("t,block_k,ranges,want", [
+    # panel: S even ranges compute (S + 1) / 2S of the square
+    (2048, 2048, 1, 100.0), (2048, 2048, 2, 75.0), (2048, 2048, 4, 62.5),
+    (2048, 2048, 8, 56.25), (2048, 2048, 16, 53.125),
+    # stream, 4 x 4 tiles: 6 below the diagonal whole, 4 on it by ranges
+    (8192, 2048, 1, 100.0 * (6 + 4) / 16),
+    (8192, 2048, 4, 100.0 * (6 + 4 * 5 / 8) / 16),
+    (8192, 2048, 16, 100.0 * (6 + 4 * 17 / 32) / 16)])
+def test_last_causal_plan_counts_the_scores_computed(t, block_k, ranges,
+                                                     want):
+    """The kernels' records and ``last_causal_plan()`` say into how many
+    ranges a diagonal tile is split and which share of a head's t * t
+    scores is computed; only tracing happens here."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.telemetry import costdb
+    telemetry.reset()
+    q = jax.ShapeDtypeStruct((1, t, 2, 64), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((2, t, 1), jnp.float32)
+
+    def f(q, k, v, o, lse, g):
+        pk._flash_attention_fwd_pallas(q, k, v, True, True, ranges=ranges)
+        # a kernel that is not causal joins no plan
+        pk._flash_attention_fwd_pallas(q, k, v, False, True, ranges=ranges)
+        return pk._flash_attention_bwd_pallas(q, k, v, o, lse, g, True, True,
+                                              ranges=ranges)
+
+    with pk.causal_plan_recording():
+        jax.make_jaxpr(f)(q, q, q, q, lse, q)
+    plan = pk.last_causal_plan()
+    assert [k["kernel"] for k in plan["kernels"]] == \
+        ["flash_attention_fwd", "flash_attention_bwd"]
+    assert plan["causal_ranges"] == ranges
+    assert plan["scores_computed_pct"] == pytest.approx(want)
+    for kern in plan["kernels"]:
+        assert (kern["block_q"], kern["block_k"]) == (128, block_k)
+        assert kern["shape"] == (1, t, 2, 64)
+        assert kern["scores_computed_pct"] == pytest.approx(want)
+    with costdb.DB._lock:
+        configs = [s["block_config"] for s in costdb.DB._pending]
+    assert sorted((c["causal"], c["causal_ranges"],
+                   round(c["scores_computed_pct"], 3)) for c in configs) == \
+        sorted([(False, 1, 100.0)] + [(True, ranges, round(want, 3))] * 2)
+
+
+def test_default_causal_ranges_follow_the_blocks():
+    """The rule reads the blocks it is given: a tuned pair from the cache
+    gets ranges of its own Q blocks, and a ``block_k`` that ``block_q``
+    does not divide keeps whole diagonal tiles."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    s = pk._CAUSAL_RANGES
+    m, plan = pk._causal_plan(128, 2048)
+    assert m == 16 and len(plan) == min(s, 16)
+    assert plan[-1] == (plan[-1][0], 16, 2048)
+    assert pk._causal_plan(256, 2048)[0] == 8
+    assert pk._causal_plan(128, 128) == (1, ((0, 1, 128),))
+    assert pk._causal_plan(128, 192) == (1, ((0, 1, 192),))
+    # every Q block's place lies in exactly one range, which sees it whole
+    for block_q, block_k in ((128, 2048), (64, 384), (128, 640), (128, 1152)):
+        for ranges in (None, 1, 2, 3, 4, 5, 100):
+            m, plan = pk._causal_plan(block_q, block_k, ranges)
+            assert [lo for lo, _h, _c in plan] == \
+                [0] + [hi for _l, hi, _c in plan[:-1]]
+            assert plan[-1][1] == m
+            assert all(cols >= hi * block_q and cols <= block_k
+                       for _lo, hi, cols in plan)

@@ -1,0 +1,136 @@
+#!/usr/bin/env python
+"""Each flash attention kernel alone, causal, by the number of static
+ranges a diagonal tile's Q blocks are split into (``_causal_plan``).
+
+For every shape and every count of ranges the forward and the backward
+kernel are compiled with that count, run ``--reps`` times under one
+profiler session, and timed by their own device events (the custom
+call's ``name=``), so the transposes and the row sums round the kernels
+stay out of the number.  The share of the peak divides the operations
+the algorithm needs (the causal half of the square, no recomputation:
+2 products forward, 4 backward, the convention of the benchmark's
+``kernel_costs``) by the time, not the operations the kernel executes.
+
+Usage (on the TPU host; prints one JSON line a kernel and count):
+    python tools/flash_causal_bench.py \
+        --shapes 4x2048x32x32x64,1x8192x32x8x64 --ranges 1,2,4,8,16
+Shapes are ``B x T x query heads x key/value heads x head_dim``.
+"""
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import os
+import statistics
+import sys
+import tempfile
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+PEAK_FLOPS = 197e12          # TPU v5e, bf16 (benchmark/peaks.json)
+
+
+def kernel_events(trace_dir):
+    """``[(start_ns, duration_ns, name)]`` of the flash kernels' device
+    events in the newest trace under ``trace_dir``, by start."""
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                out += [(e.start_ns, e.duration_ns, e.name.split(" = ")[0])
+                        for e in line.events if "mxtpu_flash_" in e.name]
+    return sorted(out)
+
+
+def bench(pk, shape, ranges, reps, dtype, blocks=None):
+    """``[record]`` for one shape: forward and backward at each count
+    (``blocks``: a (block_q, block_k) other than the heuristic's)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    b, t, hq, hk, d = shape
+    rng = np.random.RandomState(0)
+    mk = lambda h: jnp.asarray(rng.normal(0, 1, (b, t, h, d)), dtype)
+    q, k, v, g = mk(hq), mk(hk), mk(hk), mk(hq)
+    kw = {} if blocks is None else {"blocks": tuple(blocks)}
+    blocks = blocks or pk._blocks(t)
+    variants = []
+    for s in ranges:
+        fwd = jax.jit(lambda q, k, v, s=s: pk._flash_attention_fwd_pallas(
+            q, k, v, True, False, ranges=s, **kw))
+        bwd = jax.jit(lambda q, k, v, o, lse, g, s=s:
+                      pk._flash_attention_bwd_pallas(
+                          q, k, v, o, lse, g, True, False, ranges=s, **kw))
+        o, lse = jax.block_until_ready(fwd(q, k, v))          # compiles
+        grads = jax.block_until_ready(bwd(q, k, v, o, lse, g))
+        variants.append((s, fwd, bwd, (o, lse), grads))
+    first = variants[0]
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for _s, fwd, bwd, (o, lse), _g in variants:
+            for _ in range(reps):
+                jax.block_until_ready(fwd(q, k, v))
+            for _ in range(reps):
+                jax.block_until_ready(bwd(q, k, v, o, lse, g))
+        jax.profiler.stop_trace()
+        events = kernel_events(trace_dir)
+    assert len(events) == 2 * reps * len(variants), len(events)
+    records = []
+    f32 = lambda x: np.asarray(x, np.float32)
+    for i, (s, _f, _b, outs, grads) in enumerate(variants):
+        plan = pk._causal_plan(blocks[0], blocks[1], s)
+        pct = pk._scores_computed_pct(t, blocks[0], blocks[1], plan)
+        for j, (which, products, got, ref) in enumerate((
+                ("fwd", 2, outs[:1], first[3][:1]),
+                ("bwd", 4, grads, first[4]))):
+            chunk = events[(2 * i + j) * reps:(2 * i + j + 1) * reps]
+            names = {n.rstrip(".0123456789") for _t0, _d, n in chunk}
+            assert len(names) == 1 and which in min(names), names
+            ms = statistics.median(dur for _t0, dur, _n in chunk) / 1e6
+            needed = products * b * hq * t * t * d      # causal half
+            records.append({
+                "kernel": min(names).lstrip("%"), "shape": list(shape),
+                "blocks": list(blocks), "ranges": len(plan[1]),
+                "scores_computed_pct": round(pct, 2), "ms": round(ms, 4),
+                "needed_gflop": round(needed / 1e9, 1),
+                "peak_pct_needed": round(
+                    100 * needed / (ms / 1e3) / PEAK_FLOPS, 2),
+                "max_diff_vs_first": max(
+                    float(np.abs(f32(a) - f32(r)).max())
+                    for a, r in zip(got, ref))})
+    return records
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shapes", default="4x2048x32x32x64,1x8192x32x8x64")
+    ap.add_argument("--ranges", default="1,2,4,8,16")
+    ap.add_argument("--blocks", default=None,
+                    help="block_q x block_k, e.g. 256x2048 (default: the "
+                         "heuristic's for each length)")
+    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--dtype", default="bfloat16")
+    args = ap.parse_args(argv)
+    import jax
+    from mxnet_tpu.ops import pallas_kernels as pk
+    if jax.default_backend() != "tpu":
+        sys.exit("flash_causal_bench: no TPU attached (backend %s); a time "
+                 "from another device is not these kernels' time"
+                 % jax.default_backend())
+    blocks = args.blocks and tuple(int(n) for n in args.blocks.split("x"))
+    for spec in args.shapes.split(","):
+        shape = tuple(int(n) for n in spec.split("x"))
+        for rec in bench(pk, shape, [int(s) for s in args.ranges.split(",")],
+                         args.reps, args.dtype, blocks):
+            print(json.dumps(rec), flush=True)
+
+
+if __name__ == "__main__":
+    main()
