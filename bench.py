@@ -191,10 +191,6 @@ def main():
         # exact 4x4/s1 space-to-depth rewrite of the 7x7/s2 stem
         # (ops/fused.py; ~+1%, parity-tested)
         stem_space_to_depth=os.environ.get("BENCH_STEM_S2D", "1") == "1",
-        # measured-off (docs/perf.md): phase-decomposed stride-2 backward
-        strided_bwd_phase=os.environ.get("BENCH_PHASE_BWD", "0") == "1",
-        # pointwise convs lowered as fusible dots (ops/fused.py)
-        conv1x1_as_dot=os.environ.get("BENCH_CONV1X1_DOT", "0") == "1",
         # block-granularity fusion + layout planning (analysis.fusion,
         # docs/api/fusion.md); BENCH_FUSE_BLOCKS=0 for the unfused A/B
         fuse_blocks=fuse_blocks)

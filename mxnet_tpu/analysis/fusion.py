@@ -5,13 +5,11 @@ the instrumentation of PRs 3-5 into throughput: conv->BN->ReLU and
 matmul->bias->activation chains — the blocks that dominate ResNet-style
 graphs — are pattern-matched over the Symbol DAG in topo order and each
 match is emitted as ONE fused region (`mxnet_tpu.ops.fused`
-``fused_block_*``: a single custom-vjp XLA region; the Pallas
-matmul-with-stats leg is dispatched for no block, see
-`_pallas_eligible`).  Because every region carries
-a hand-written backward, training keeps one fused dispatch per block in
-BOTH directions; the plan runs wherever :func:`mxnet_tpu.symbol.
-eval_graph` traces — forward, the executor's vjp backward, and the
-trainer's fused step.
+``fused_block_*``: a single custom-vjp XLA region).  Because every
+region carries a hand-written backward, training keeps one fused
+dispatch per block in BOTH directions; the plan runs wherever
+:func:`mxnet_tpu.symbol.eval_graph` traces — forward, the executor's
+vjp backward, and the trainer's fused step.
 
 **Layout planning.**  Each region boundary is pinned to an explicit
 activation layout (the trace-time ``image_layout``, NHWC on the TPU
@@ -43,8 +41,6 @@ unfused — the pass degrades, never refuses a graph.
 Enabled per-trace by ``ops.fused.block_fusion`` (the
 ``MXNET_FUSE_BLOCKS`` env default), wired through
 ``Executor`` (bind-time capture) and ``ShardedTrainer(fuse_blocks=...)``.
-When the older conv1x1-only pass (``MXNET_FUSE_CONV_BN``) is also
-active it keeps its claims; this pass fuses everything else.
 """
 from __future__ import annotations
 
@@ -140,11 +136,11 @@ def decisions_id(decisions):
 
 class FusedBlock:
     """One matched chain: the member nodes and how to emit them."""
-    __slots__ = ("kind", "terminal", "conv", "bn", "fc", "act", "pallas",
+    __slots__ = ("kind", "terminal", "conv", "bn", "fc", "act",
                  "layout", "chain", "graph", "plan_id")
 
     def __init__(self, kind, terminal, conv=None, bn=None, fc=None,
-                 act=None, pallas=False, layout="NCHW", chain=None,
+                 act=None, layout="NCHW", chain=None,
                  graph=None, plan_id=None):
         self.kind = kind
         self.terminal = terminal      # the node whose value the region yields
@@ -152,7 +148,6 @@ class FusedBlock:
         self.bn = bn
         self.fc = fc
         self.act = act                # act_type string or None
-        self.pallas = bool(pallas)
         self.layout = layout
         self.chain = chain            # stable chain id (greedy-terminal
         self.graph = graph            # topo index), graph digest, and
@@ -223,8 +218,9 @@ class FusionPlan:
             "is_train": self.is_train,
             "blocks": len(self.blocks),
             "kinds": kinds,
-            "pallas_blocks": sum(1 for b in self.blocks.values()
-                                 if b.pallas),
+            # no block lowers to a kernel; the benchmark's reader
+            # (layer_metrics/pallas_blocks.py) still asks for the key
+            "pallas_blocks": 0,
             "relayouts_eliminated": self.relayouts_eliminated,
             "relayout_edges_added": self.relayout_edges_added,
             "fallbacks": reasons,
@@ -285,28 +281,12 @@ def _conv_fusable(conv, layout, plan, claimed):
     return True
 
 
-def _pallas_eligible(blk, is_train):
-    """Whether a (possibly decision-transformed) block is lowered
-    through the matmul-with-stats kernel (``ops.fused._fused_conv_bn``)
-    and not as the XLA region every other conv block is: never.  The
-    kernel takes a 1x1 conv head on an NHWC activation flattened to
-    (N*H*W, C).  Measured on a v5e (PERF.md 6, PR 25): at ResNet-50's
-    widths the flatten is a ``reshape`` that moves the activation on
-    each side of the custom call, forward and backward (a third of the
-    step); where it is a bitcast (W of whole 8-row tiles, 128-lane
-    channels) the kernel's block is still 15% slower than the XLA
-    region alone in a chain and 65% slower between two convolutions,
-    which keep the batch in the sublanes where the kernel wants rows.
-    No shape was found where it wins, so no rule admits one."""
-    return False
-
-
-def _apply_decision(blk, cid, decisions, plan, is_train):
+def _apply_decision(blk, cid, decisions, plan):
     """Transform one greedy-matched block by the plan-search decision
-    vector (``decisions``): per-chain fuse/split/off, per-region
-    layout, and a per-block Pallas veto.  ``cid`` is the chain's
-    stable id (the GREEDY terminal's topo index, as a string) — the
-    key every committed ``graph_plan`` cache entry uses.  Returns the
+    vector (``decisions``): per-chain fuse/split/off and per-region
+    layout.  ``cid`` is the chain's stable id (the GREEDY terminal's
+    topo index, as a string) — the key every committed ``graph_plan``
+    cache entry uses.  Returns the
     block to plan (possibly a shorter chain) or None (chain unfused).
     Unknown/ineligible choices read as "fuse" — a stale entry must
     degrade, never break a trace."""
@@ -322,10 +302,6 @@ def _apply_decision(blk, cid, decisions, plan, is_train):
     if choice == "conv_bn" and blk.kind == "conv_bn_act":
         blk = FusedBlock("conv_bn", terminal=blk.bn, conv=blk.conv,
                          bn=blk.bn, act=None, layout=blk.layout)
-        # the split block keeps the Pallas leg a naturally-matched
-        # conv_bn chain would get — a split must not silently lose
-        # the kernel that is its main perf lever
-        blk.pallas = _pallas_eligible(blk, is_train)
         plan.overrides += 1
     elif choice == "bn_act" and blk.kind == "conv_bn_act":
         blk = FusedBlock("bn_act", terminal=blk.terminal, bn=blk.bn,
@@ -336,13 +312,6 @@ def _apply_decision(blk, cid, decisions, plan, is_train):
             and blk.kind != "fc_act":
         blk.layout = layout
         plan.overrides += 1
-        # eligibility follows the REGION layout (an NHWC override in
-        # an NCHW trace can open the Pallas leg; the reverse closes it)
-        blk.pallas = _pallas_eligible(blk, is_train)
-    veto = (decisions.get("pallas") or {}).get(cid)
-    if veto is not None and not veto and blk.pallas:
-        blk.pallas = False
-        plan.overrides += 1
     blk.chain = cid
     return blk
 
@@ -351,7 +320,7 @@ def plan_block_fusion(topo, entries, layout="NCHW", is_train=True,
                       exclude=(), record=True, decisions=None):
     """Match fusable chains over ``topo`` and return a
     :class:`FusionPlan`.  ``exclude``: node ids already claimed by
-    another trace-time pass (conv1x1+BN, stem s2d, dX elision) — chains
+    another trace-time pass (stem s2d, dX elision) — chains
     touching them fall back.  ``record`` emits the ``mxtpu_fusion_*``
     metrics and a ``fusion_plan`` flight event (one per trace).
     ``decisions``: plan-search overrides (analysis.plansearch; default:
@@ -378,13 +347,11 @@ def plan_block_fusion(topo, entries, layout="NCHW", is_train=True,
             return None
         if not _conv_fusable(src, layout, plan, claimed):
             return None
-        blk = FusedBlock("conv_bn_act" if act_node is not None
-                         else "conv_bn",
-                         terminal=act_node if act_node is not None
-                         else bn,
-                         conv=src, bn=bn, act=act_type, layout=layout)
-        blk.pallas = _pallas_eligible(blk, is_train)
-        return blk
+        return FusedBlock("conv_bn_act" if act_node is not None
+                          else "conv_bn",
+                          terminal=act_node if act_node is not None
+                          else bn,
+                          conv=src, bn=bn, act=act_type, layout=layout)
 
     for node in topo:
         if node.is_variable or node.op is None or id(node) in claimed:
@@ -431,7 +398,7 @@ def plan_block_fusion(topo, entries, layout="NCHW", is_train=True,
             # committed decision vector survives rebuilds whose auto-
             # generated node names differ
             blk = _apply_decision(blk, str(topo_index[id(node)]),
-                                  decisions, plan, is_train)
+                                  decisions, plan)
         if blk is not None:
             # a block's members must not collide with earlier claims
             members = blk.interior() + [blk.terminal]
@@ -541,14 +508,10 @@ def apply_block(blk, vals, is_train):
         mm, mv = val(bn, 3), val(bn, 4)
         if blk.layout != ambient:
             x = _relayout(x, blk.layout)
-        pallas = _tuned_pallas(blk, x, w)
         out, new_mm, new_mv = _fused.fused_block_conv_bn_act(
             conv.attrs, bn.attrs, blk.layout, is_train, blk.act,
-            pallas, x, w, b, gamma, beta, mm, mv)
-        # the costdb signature records the DISPATCHED lowering — a
-        # cache veto must be visible in the ground truth, not the
-        # planner's pre-veto choice
-        _note_block_cost(blk, out, x, w, pallas=pallas)
+            x, w, b, gamma, beta, mm, mv)
+        _note_block_cost(blk, out, x, w)
         _note_block_numerics(blk, out)
         if blk.layout != ambient:
             out = _relayout(out, ambient)
@@ -586,43 +549,14 @@ def _note_block_numerics(blk, out):
     _numerics.note_block(blk.name, out)
 
 
-def _tuned_pallas(blk, x, w):
-    """The block's Pallas-vs-XLA lowering choice, tuning cache first
-    (``mxnet_tpu.autotune.block_config``, keyed by kind + the traced
-    activation/weight shapes): a committed ``{"pallas": 0}`` from a
-    ``tools/autotune.py`` A/B turns the Pallas leg off for this shape.
-    The cache can only VETO the Pallas route, never force it onto an
-    ineligible block; the region's interior row-block split is tuned
-    separately under the ``matmul_stats`` key it dispatches.  Never
-    raises — any failure keeps the planner's choice."""
-    if not blk.pallas:
-        return False
-    try:
-        from .. import autotune
-        cfg = autotune.block_config(
-            blk.kind, [tuple(x.shape), tuple(w.shape)],
-            [str(x.dtype), str(w.dtype)],
-            extra={"layout": blk.layout, "act": blk.act or ""})
-        if cfg and not cfg.get("pallas", True):
-            return False
-    except MemoryError:  # pragma: no cover - never mask resource exhaustion
-        raise
-    except Exception:  # mxlint: allow-broad-except(the tuning-cache lookup is advisory trace-time observability; a failure keeps the planner's lowering choice)
-        pass
-    return True
-
-
-def _note_block_cost(blk, out, x, w, pallas=None):
+def _note_block_cost(blk, out, x, w):
     """Register the applied block as a pending cost-database signature
     (telemetry.costdb) with analytic flops/bytes estimates from the
     trace-time shapes — runs host-side inside the trace, once per
     compile.  The dispatch that owns this compile binds the signature
-    and attributes measured wall time to it.  ``pallas``: the
-    lowering actually dispatched (defaults to the planner's choice).
+    and attributes measured wall time to it.
     Observability: any failure is swallowed, the trace must never pay
     for it."""
-    if pallas is None:
-        pallas = blk.pallas
     try:
         from ..telemetry import costdb
         import numpy as _np
@@ -655,7 +589,7 @@ def _note_block_cost(blk, out, x, w, pallas=None):
         costdb.note_block(
             blk.name, blk.kind, shapes, dtypes, flops=flops,
             bytes_accessed=bytes_, layout=blk.layout,
-            pallas=pallas, graph=blk.graph, plan=blk.plan_id)
+            graph=blk.graph, plan=blk.plan_id)
     except MemoryError:  # pragma: no cover - never mask resource exhaustion
         raise
     except Exception:  # mxlint: allow-broad-except(cost-signature capture is observability inside a jit trace; any failure must not fail the compile)

@@ -70,10 +70,11 @@ def memlive_tolerance(default=0.25):
     """MXG018 relative drift tolerance (``MXNET_TPU_MEMLIVE_TOL``).
 
     The default is calibrated against the model zoo: forward-plan
-    drift vs ``memory_analysis`` measures within +-12% on every zoo
-    model (worst: resnext's grouped convs at -11.4%), so 25% flags
-    real formula regressions without tripping on XLA's temp-buffer
-    scheduling freedom."""
+    drift vs ``memory_analysis`` measures within +-7% on every zoo
+    model (worst: resnext's grouped convs at -6.7%; docs/api/
+    memlive.md has the table and what the gate takes out of XLA:CPU's
+    plan), so 25% flags real formula regressions without tripping on
+    XLA's temp-buffer scheduling freedom."""
     import os
     raw = os.environ.get("MXNET_TPU_MEMLIVE_TOL", "").strip()
     if not raw:

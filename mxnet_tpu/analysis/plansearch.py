@@ -1,8 +1,7 @@
 """Cost-model-guided whole-graph plan search (ROADMAP item 3).
 
 `analysis.fusion` is a greedy fixed-pattern matcher: the first longest
-chain wins, layout choice is purely local, and the Pallas-vs-XLA
-lowering is a per-block heuristic the tuning cache can only veto.  The
+chain wins and layout choice is purely local.  The
 costdb roofline (PR 7) *measures* the MFU gap those local choices leave
 behind but nothing acts on it globally.  This module closes the loop
 Relay/TVM-style (PAPERS.md: arXiv:1810.00952, arXiv:1802.04799):
@@ -10,12 +9,11 @@ Relay/TVM-style (PAPERS.md: arXiv:1810.00952, arXiv:1802.04799):
 * **search space** — one decision vector over the greedy plan's chain
   candidates: per-chain ``fuse``/``conv_bn``/``bn_act``/``off``
   (``fusion.CHAIN_CHOICES`` — splits the chains the greedy
-  longest-chain-wins rule forecloses), per-region layout
+  longest-chain-wins rule forecloses) and per-region layout
   (``NCHW``/``NHWC``, with the explicit boundary relayouts
-  ``fusion.apply_block`` inserts costed at peak bandwidth), and a
-  per-block Pallas veto.  Chains are keyed by the greedy terminal's
-  topo index, so a committed vector survives rebuilds whose auto-
-  generated node names differ;
+  ``fusion.apply_block`` inserts costed at peak bandwidth).  Chains
+  are keyed by the greedy terminal's topo index, so a committed vector
+  survives rebuilds whose auto-generated node names differ;
 * **objective** — predicted step wall from the learned cost model
   (:mod:`mxnet_tpu.autotune.model`, arXiv:2008.01040) over analytic
   flops/bytes per unit (the same formulas the trace-time costdb notes
@@ -186,10 +184,8 @@ def _block_cost(blk, node_shapes, itemsize=4):
     """Analytic (flops, bytes) of one fused block at shape-inference
     time — the same formulas ``fusion._note_block_cost`` feeds the
     costdb at trace time, so the objective and the measured ground
-    truth describe the same quantity.  A Pallas matmul-with-stats block
-    saves the separate forward stats pass over its output (the kernel's
-    whole point), so its traffic drops by one output read.  None when
-    shapes are unresolved."""
+    truth describe the same quantity.  None when shapes are
+    unresolved."""
     out = _out_shape(node_shapes, blk.terminal)
     if out is None:
         return None
@@ -209,8 +205,6 @@ def _block_cost(blk, node_shapes, itemsize=4):
                 or head.attrs.get("num_hidden") or w[0])
     flops = 2.0 * out_size * _size(w) / max(1, n_out) + 10.0 * out_size
     bytes_ = float(itemsize) * (_size(x) + _size(w) + out_size)
-    if blk.pallas:
-        bytes_ -= float(itemsize) * out_size
     return flops, max(bytes_, float(itemsize))
 
 
@@ -269,7 +263,7 @@ def predict_plan_wall(topo, entries, plan, node_shapes, model=None,
                 units.append({
                     "unit": "block", "name": blk.name,
                     "kind": blk.kind, "chain": blk.chain,
-                    "layout": blk.layout, "pallas": bool(blk.pallas),
+                    "layout": blk.layout,
                     "flops": flops, "bytes": bytes_,
                     "attainable_s": att, "predicted_s": pred,
                     "relayout_s": relayout_s,
@@ -311,7 +305,7 @@ def predict_plan_wall(topo, entries, plan, node_shapes, model=None,
             units.append({
                 "unit": "node", "name": node.name,
                 "kind": node.op.name, "chain": None,
-                "layout": None, "pallas": False,
+                "layout": None,
                 "flops": flops, "bytes": bytes_,
                 "attainable_s": att, "predicted_s": pred,
                 "relayout_s": 0.0,
@@ -325,8 +319,7 @@ def chain_moves(topo, entries, layout, is_train=True,
                 node_shapes=None):
     """The single-decision neighbor moves of this graph's search space,
     derived from the greedy plan: per chain the non-greedy
-    ``CHAIN_CHOICES``, a layout flip for image chains, and a Pallas
-    veto where the greedy plan chose the Pallas leg.  With
+    ``CHAIN_CHOICES`` and a layout flip for image chains.  With
     ``node_shapes``, layout flips are only offered for chains whose
     activation is actually 4-d (``apply_block`` transposes nothing
     else, so the move would be a no-op with phantom accounting).
@@ -348,8 +341,6 @@ def chain_moves(topo, entries, layout, is_train=True,
                 x = _in_shape(node_shapes, blk.conv or blk.bn, 0)
             if node_shapes is None or (x is not None and len(x) == 4):
                 moves.append(("layouts", cid, other))
-        if blk.pallas:
-            moves.append(("pallas", cid, 0))
     return greedy, moves
 
 

@@ -13,10 +13,9 @@ Three parts (see docs/api/autotune.md for the full contract):
 * **persistent tuning cache** (:mod:`.cache`) — JSONL schema
   ``mxtpu-tunecache/1`` under ``MXNET_TPU_TUNE_CACHE``, merged on load
   (best measured wall wins) so caches from multiple hosts/runs
-  compose.  Trace-time consumers — ``ops/pallas_kernels`` flash
-  fwd/bwd, ``ops/fused.matmul_stats``, ``analysis.fusion.apply_block``
-  — consult it first and fall back to the built-in heuristics on
-  miss, emitting ``mxtpu_tune_cache_{hit,miss}_total`` and a
+  compose.  The trace-time consumer — ``ops/pallas_kernels`` flash
+  fwd/bwd — consults it first and falls back to the built-in heuristic
+  on a miss, emitting ``mxtpu_tune_cache_{hit,miss}_total`` and a
   ``tune_lookup`` flight event; ``MXNET_TPU_AUTOTUNE=off|cache|search``
   gates the behavior (``search`` turns a miss into a bounded inline
   search);
@@ -33,22 +32,19 @@ Driver: ``tools/autotune.py`` (per-op tuning, zoo-model mode,
 from __future__ import annotations
 
 from .cache import (SCHEMA, TuneCache, CACHE, autotune_mode, cache_dir,
-                    key_sig, kernel_config, block_config, lookup, put,
+                    key_sig, kernel_config, lookup, put,
                     read_entries, reload_cache, summary, reset_stats)
 from .search import (measure, divisors, candidate_flash_configs,
-                     candidate_matmul_configs, tune_flash,
-                     tune_matmul_stats, tune_conv_block, inline_search,
-                     same_config)
+                     tune_flash, inline_search, same_config)
 from .model import (CostModel, FEATURES, featurize, fit_cost_model,
                     load_model)
 
 __all__ = [
     "SCHEMA", "TuneCache", "CACHE", "autotune_mode", "cache_dir",
-    "key_sig", "kernel_config", "block_config", "lookup", "put",
+    "key_sig", "kernel_config", "lookup", "put",
     "read_entries", "reload_cache", "summary", "reset_stats",
     "measure", "divisors", "candidate_flash_configs",
-    "candidate_matmul_configs", "tune_flash", "tune_matmul_stats",
-    "tune_conv_block", "inline_search", "same_config",
+    "tune_flash", "inline_search", "same_config",
     "CostModel", "FEATURES", "featurize", "fit_cost_model",
     "load_model",
 ]

@@ -1,8 +1,8 @@
 """Persistent Pallas tuning cache: measured-best block configs by key.
 
 The commit target of the search harness (:mod:`.search`) and the
-trace-time lookup the kernels consult (``ops/pallas_kernels._select_blocks``,
-``ops/fused.matmul_stats``, ``analysis.fusion.apply_block``).  One entry
+trace-time lookup the kernels consult (``ops/pallas_kernels._select_blocks``)
+and the plan search's commit target (``graph_plan`` entries).  One entry
 maps a tunable-kernel key — ``(op, shape signature, dtypes, mesh shape,
 backend, extra statics)``, hashed exactly like a costdb record key — to
 the block configuration that measured fastest, together with the walls
@@ -22,7 +22,7 @@ the lookup path never raises into a trace.
 ``off``     no lookups at all (heuristics only, zero overhead)
 ``cache``   lookup; on miss fall back to the heuristic (the default)
 ``search``  lookup; on miss run a *bounded* inline search for the ops
-            the harness knows (flash fwd/bwd, matmul_stats), commit
+            the harness knows (flash fwd/bwd), commit
             the winner, and use it
 ==========  ==========================================================
 
@@ -42,7 +42,7 @@ import time
 __all__ = [
     "SCHEMA", "TuneCache", "CACHE",
     "autotune_mode", "cache_dir", "key_sig",
-    "kernel_config", "block_config", "lookup", "put",
+    "kernel_config", "lookup", "put",
     "read_entries", "reload_cache", "summary", "reset_stats",
 ]
 
@@ -305,15 +305,14 @@ def reload_cache():
     CACHE.ensure_loaded()
 
 
-def kernel_config(op, shapes, dtypes, mesh=None, extra=None,
-                  searchable=True):
+def kernel_config(op, shapes, dtypes, mesh=None, extra=None):
     """The trace-time entry point: the tuned block config for this key,
     or None (use the heuristic).  Honors ``MXNET_TPU_AUTOTUNE``
     (``off`` skips the lookup entirely); emits the hit/miss metric and
-    a ``tune_lookup`` flight event; in ``search`` mode a miss on a
-    ``searchable`` op triggers a bounded inline search whose winner is
-    committed and returned.  Never raises — any failure reads as a
-    heuristic fallback."""
+    a ``tune_lookup`` flight event; in ``search`` mode a miss
+    triggers a bounded inline search whose winner is committed and
+    returned.  Never raises — any failure reads as a heuristic
+    fallback."""
     try:
         mode = autotune_mode()
         if mode == "off":
@@ -323,7 +322,7 @@ def kernel_config(op, shapes, dtypes, mesh=None, extra=None,
         entry = lookup(op, shapes, dtypes, mesh=mesh, extra=extra)
         hit = entry is not None
         searched = False
-        if entry is None and mode == "search" and searchable:
+        if entry is None and mode == "search":
             from . import search as _search
             entry = _search.inline_search(op, shapes, dtypes, mesh=mesh,
                                           extra=extra)
@@ -337,14 +336,6 @@ def kernel_config(op, shapes, dtypes, mesh=None, extra=None,
         raise
     except Exception:  # mxlint: allow-broad-except(the tuning-cache lookup runs inside jit traces; any failure must degrade to the built-in heuristic, never fail the compile)
         return None
-
-
-def block_config(kind, shapes, dtypes, mesh=None, extra=None):
-    """Tuned config for a fused-block region key (``analysis.fusion``
-    consults this from ``apply_block``).  Lookup-only: the inline
-    search does not know how to build arbitrary fused regions."""
-    return kernel_config("block:%s" % kind, shapes, dtypes, mesh=mesh,
-                         extra=extra, searchable=False)
 
 
 def summary():

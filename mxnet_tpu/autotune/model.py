@@ -16,9 +16,9 @@ linear model captures the multiplicative structure of a roofline):
 ``log_flops``       work term
 ``log_bytes``       traffic term
 ``log_ai``          arithmetic intensity (flops/byte)
-``log_bq``          row/Q block edge (``block_q`` | ``bm``)
+``log_bq``          Q block edge (``block_q``)
 ``log_bk``          K block edge (``block_k``)
-``log_grid``        inner grid length (``n_k`` | ``grid_m``) — the
+``log_grid``        inner grid length (``n_k``) — the
                     block-count cliff term (2176 -> 17 tiny K blocks)
 ``pad_waste``       padded-compute fraction when the config carries it
 =================  ===================================================
@@ -67,9 +67,9 @@ def featurize(flops=None, bytes_accessed=None, block_config=None,
     att = costdb._attainable_s(flops, bytes_ or None, pf, pbw) or _FLOOR
     ai = flops / bytes_ if bytes_ > 0 else 0.0
     cfg = dict(block_config or {})
-    bq = cfg.get("block_q") or cfg.get("bm") or 0
+    bq = cfg.get("block_q") or 0
     bk = cfg.get("block_k") or 0
-    grid = cfg.get("n_k") or cfg.get("grid_m") or 1
+    grid = cfg.get("n_k") or 1
     waste = float(cfg.get("pad_waste") or 0.0)
     return [1.0, _log(att), _log(flops), _log(bytes_ + 1.0),
             _log(ai + 1.0), _log(bq + 1.0), _log(bk + 1.0),

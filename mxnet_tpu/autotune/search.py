@@ -6,18 +6,16 @@ database's semantics: synchronized dispatch (a value fetch closes
 the async chain), **min-of-N** wall, compile excluded by an untimed warm-up
 call, and optional in-program chaining (``chain=K`` scans K
 data-dependent applications inside one jitted program, dividing the
-wall by K — the same dispatch-overhead amortization ``bench.py`` and
-the Pallas experiment tools use).  ``tools/pallas_block_experiment.py``
-and ``tools/pallas_matmul_stats_experiment.py`` reuse it instead of
-their old ad-hoc ``time.time`` loops.
+wall by K — the same dispatch-overhead amortization ``bench.py``
+uses).
 
-Tuners (``tune_flash``, ``tune_matmul_stats``, ``tune_conv_block``)
-enumerate a candidate space that ALWAYS contains the built-in
-heuristic, measure every candidate (``interpret=True`` keeps the real
-kernel code path exercisable on CPU CI), record each measurement into
+The tuner (``tune_flash``) enumerates a candidate space that ALWAYS
+contains the built-in heuristic, measures every candidate
+(``interpret=True`` keeps the real kernel code path exercisable on CPU
+CI), records each measurement into
 the cost database (kind=``kernel``, ``source="autotune"`` — the
 learned cost model's training data accumulates as a side effect), and
-commit the winner to the persistent tuning cache with the heuristic's
+commits the winner to the persistent tuning cache with the heuristic's
 wall alongside — so the A/B evidence (tuned <= heuristic on the
 measured run, by construction) persists with the entry.
 
@@ -34,9 +32,7 @@ import time
 
 __all__ = [
     "measure", "divisors",
-    "candidate_flash_configs", "candidate_matmul_configs",
-    "tune_flash", "tune_matmul_stats", "tune_conv_block",
-    "inline_search",
+    "candidate_flash_configs", "tune_flash", "inline_search",
 ]
 
 
@@ -135,35 +131,6 @@ def candidate_flash_configs(t, limit=8):
     return out[:max(2, int(limit))]
 
 
-def candidate_matmul_configs(m, limit=8):
-    """Row-block (``bm``) candidates for ``matmul_stats`` at M rows:
-    divisors of M in the VMEM-friendly range, heuristic first.  When
-    the MXU-aligned list has no divisor of M (the `_pick_bm` blind
-    spot — e.g. M = 98 at tiny batches), the raw divisor lattice of M
-    fills in, largest first, so every M stays tunable."""
-    from ..ops.fused import _pick_bm
-    heur = _pick_bm(m)
-    out, seen = [], set()
-
-    def add(bm):
-        if bm and m % bm == 0 and bm not in seen:
-            seen.add(bm)
-            out.append({"bm": int(bm), "grid_m": int(m // bm)})
-
-    add(heur)
-    for bm in (1024, 512, 448, 256, 128, 64, 32, 16, 8):
-        add(bm)
-    if len(out) < 2:
-        for bm in reversed(divisors(m, lo=2, hi=1024)):
-            add(bm)
-    if not out:
-        # prime M > 1024: the only divisors are 1 and M — one whole-M
-        # block is still a measurable (if VMEM-hungry) candidate, so
-        # "every M stays tunable" holds
-        add(m)
-    return out[:max(2, int(limit))]
-
-
 # ------------------------------------------------------------ tuners
 
 def _interpret_default(interpret):
@@ -214,7 +181,7 @@ def _finish(op, shapes, dtypes, extra, results, heur_cfg, commit,
 
 def same_config(a, b):
     """Loose config equality over the SHARED keys (a heuristic config
-    may omit derived fields like ``grid_m``/``n_k`` that a candidate
+    may omit derived fields like ``n_k`` that a candidate
     carries) — also the comparator ``tools/perf_top.py --suggest``
     uses to decide "already-tuned"."""
     if not a or not b:
@@ -301,117 +268,6 @@ def tune_flash(shape, dtype="float32", causal=False, which="fwd",
     return rep
 
 
-def tune_matmul_stats(m, k, n, dtype="float32", repeats=3,
-                      max_candidates=8, interpret=None, commit=True,
-                      cache=None, seed=0, source="search"):
-    """Tune the ``matmul_stats`` row block at GEMM shape (M, K, N).
-    The Pallas path needs ``n % 128 == 0 and k % 8 == 0`` (otherwise
-    the kernel itself falls back to jnp and there is nothing to tune —
-    raises ValueError)."""
-    import numpy as np
-    from ..ops import fused as _fused
-
-    if n % 128 or k % 8:
-        raise ValueError("matmul_stats pallas path needs N %% 128 == 0 "
-                         "and K %% 8 == 0 (got M=%d K=%d N=%d)"
-                         % (m, k, n))
-    interpret = _interpret_default(interpret)
-    rng = np.random.RandomState(seed)
-    x = rng.normal(0, 1, (m, k)).astype(dtype)
-    w = (rng.normal(0, 1, (k, n)) * 0.05).astype(dtype)
-    c = rng.normal(0, 1, (n,)).astype(np.float32)
-    heur_cfg = {"bm": _fused._pick_bm(m)}
-    op = "matmul_stats"
-    shapes = [(m, k), (k, n)]
-    dtypes = [str(np.dtype(dtype))] * 2
-    flops = 2.0 * m * n * k
-    itemsize = np.dtype(dtype).itemsize
-    bytes_ = float(m * k * itemsize + k * n * itemsize
-                   + m * n * itemsize)
-
-    results = []
-    for cfg in candidate_matmul_configs(m, limit=max_candidates):
-        fn = lambda x_, w_, c_: _fused.matmul_stats(
-            x_, w_, c_, bm=cfg["bm"], interpret=interpret)
-        try:
-            wall = measure(fn, (x, w, c), repeats=repeats)
-        except Exception as e:  # mxlint: allow-broad-except(a failing candidate is not a winner; the search continues)
-            results.append({"config": cfg, "wall_s": None,
-                            "error": str(e)[:200]})
-            continue
-        results.append({"config": cfg, "wall_s": wall})
-        _record_candidate(op, shapes, dtypes, cfg, wall, flops=flops,
-                          bytes_accessed=bytes_)
-    measured = [r for r in results if r["wall_s"] is not None]
-    if not measured:
-        raise RuntimeError("tune_matmul_stats: no candidate measured "
-                           "for (%d, %d, %d)" % (m, k, n))
-    rep = _finish(op, shapes, dtypes, None, measured, heur_cfg, commit,
-                  cache, source)
-    rep["candidates"] = results
-    return rep
-
-
-def tune_conv_block(x_shape, w_shape, kind="conv_bn_act", act="relu",
-                    layout="NHWC", dtype="float32", repeats=3,
-                    interpret=None, commit=True, cache=None, seed=0,
-                    source="search"):
-    """A/B the two lowerings of a pallas-eligible fused conv block
-    (``analysis.fusion`` conv_bn/conv_bn_act region): the Pallas
-    matmul-with-stats kernel vs the single XLA custom-vjp region.  The
-    winner persists as ``{"pallas": 0|1}`` under the block key
-    ``apply_block`` consults; the region's interior row-block split is
-    the ``matmul_stats`` ``bm`` — tune that key first (zoo mode does).
-
-    ``x_shape``: NHWC activations ``(N, H, W, C)``; ``w_shape``: OIHW
-    weight ``(O, C, 1, 1)`` (only the 1x1 case has a Pallas leg)."""
-    import numpy as np
-    from ..ops import fused as _fused
-
-    interpret = _interpret_default(interpret)
-    nb, hh, ww, cin = x_shape
-    nout = w_shape[0]
-    rng = np.random.RandomState(seed)
-    x = rng.uniform(-1, 1, x_shape).astype(dtype)
-    w = (rng.normal(0, 0.1, w_shape)).astype(dtype)
-    gamma = rng.uniform(0.5, 1.5, (nout,)).astype(np.float32)
-    beta = rng.uniform(-0.2, 0.2, (nout,)).astype(np.float32)
-    mm = np.zeros((nout,), np.float32)
-    mv = np.ones((nout,), np.float32)
-    conv_attrs = {"kernel": (1, 1), "stride": (1, 1), "pad": (0, 0),
-                  "dilate": (1, 1), "num_group": 1, "no_bias": True}
-    bn_attrs = {"eps": 1e-5, "momentum": 0.9, "fix_gamma": False}
-
-    def leg(pallas):
-        return lambda x_, w_: _fused.fused_block_conv_bn_act(
-            conv_attrs, bn_attrs, layout, True, act, pallas,
-            x_, w_, None, gamma, beta, mm, mv,
-            interpret=interpret)[0]
-
-    op = "block:%s" % kind
-    shapes = [tuple(x_shape), tuple(w_shape)]
-    dtypes = [str(np.dtype(dtype))] * 2
-    results = []
-    for pallas in (1, 0):
-        try:
-            wall = measure(leg(bool(pallas)), (x, w), repeats=repeats)
-        except Exception as e:  # mxlint: allow-broad-except(a failing leg is not a winner; the other lowering still measures)
-            results.append({"config": {"pallas": pallas},
-                            "wall_s": None, "error": str(e)[:200]})
-            continue
-        results.append({"config": {"pallas": pallas}, "wall_s": wall})
-    measured = [r for r in results if r["wall_s"] is not None]
-    if not measured:
-        raise RuntimeError("tune_conv_block: neither lowering measured "
-                           "for %r" % (x_shape,))
-    # the planner's default is the Pallas leg where eligible
-    rep = _finish(op, shapes, dtypes,
-                  {"layout": layout, "act": act or ""},
-                  measured, {"pallas": 1}, commit, cache, source)
-    rep["candidates"] = results
-    return rep
-
-
 # ------------------------------------------------------ inline search
 
 #: bounded inline-search budget (MXNET_TPU_AUTOTUNE=search on a miss)
@@ -438,13 +294,6 @@ def inline_search(op, shapes, dtypes, mesh=None, extra=None):
                              max_candidates=_INLINE_CANDIDATES,
                              key_shape=tuple(shapes[0]),
                              source="inline-search")
-            return rep["entry"]
-        if op == "matmul_stats":
-            (m, k), (_k2, n) = shapes[0], shapes[1]
-            rep = tune_matmul_stats(m, k, n, dtype=dtypes[0],
-                                    repeats=_INLINE_REPEATS,
-                                    max_candidates=_INLINE_CANDIDATES,
-                                    source="inline-search")
             return rep["entry"]
         return None
     except MemoryError:  # pragma: no cover - never mask resource exhaustion

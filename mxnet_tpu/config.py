@@ -41,9 +41,6 @@ _CATALOG = {
                                      "update_on_kvstore heuristic"),
     "MXNET_ENABLE_GPU_P2P": ("1", "inert", "ICI is always direct"),
     # profiler
-    "MXNET_FUSE_CONV_BN": ("0", "honored",
-        "Pallas conv1x1+BN stats fusion in ShardedTrainer (docs/perf.md: "
-        "measured slower on v5e; off by default)"),
     "MXNET_FUSE_BLOCKS": ("0", "honored",
         "block-granularity fusion pass (analysis.fusion): conv+BN+ReLU "
         "and FC+activation chains lowered as single fused regions with "
@@ -51,12 +48,6 @@ _CATALOG = {
         "default for Executor binds and ShardedTrainer(fuse_blocks=None)"),
     "MXNET_STEM_S2D": ("0", "honored",
         "space-to-depth rewrite of 7x7/s2 stem convs in ShardedTrainer"),
-    "MXNET_PHASE_BWD": ("0", "honored",
-        "phase-decomposed stride-2 conv backward-data (docs/perf.md: "
-        "measured slower on v5e; off by default)"),
-    "MXNET_CONV1X1_DOT": ("0", "honored",
-        "lower pointwise convs as dots (docs/perf.md: measured neutral "
-        "on v5e; off by default)"),
     "MXNET_PROFILER_AUTOSTART": ("0", "honored", "see profiler.py"),
     "MXNET_PROFILER_MODE": ("0", "honored", ""),
     "MXNET_PROFILER_FILENAME": ("profile.json", "honored", ""),

@@ -1,22 +1,15 @@
-"""Conv(1x1) + BatchNorm fusion: GEMM with a statistics epilogue.
+"""Fused-region math for the block pass, and the conv-only stem rewrites.
 
-Why: BN statistics are separate HBM passes over each conv's output —
-XLA cannot fuse a reduction into a conv/dot epilogue, and the stats
-bucket is ~18% of the ResNet-50 step (docs/perf.md).  ResNet-50's 40
-pointwise convs are GEMMs, so a Pallas kernel can produce
-``y = x @ w`` and the (shifted) per-channel ``sum`` / ``sum_sq`` of y in
-one pass, eliminating the forward stats read entirely for those layers.
-
-Scope: training-mode BatchNorm directly consuming an eligible
-Convolution (kernel 1x1, stride 1, pad 0, no bias, single consumer)
-under NHWC activations.  The graph pass (`plan_conv_bn_fusion`) runs at
-trace time inside :func:`mxnet_tpu.symbol.eval_graph` when enabled via
-``conv_bn_fusion(True)`` (ShardedTrainer(fuse_conv_bn=True)) or
-``MXNET_FUSE_CONV_BN=1``.
+`analysis.fusion` plans conv->BN(->act), BN(->act) and FC(->act) blocks;
+each lowers to ONE region here (`fused_block_*`): a ``jax.custom_vjp``
+whose BN/activation backward is hand-written, so XLA sees a single
+region boundary per block in each direction.  Beside them live the two
+exact rewrites of the input stem (space-to-depth conv, input-BN dX
+elision) that `symbol.eval_graph` plans under NHWC.
 
 Numerics match ``ops/nn.py _bn_core``: stats are shifted by the moving
 mean to avoid E[x²]-E[x]² cancellation; backward is the same two-pass
-formulation, with dX/dW as plain GEMMs.
+formulation.
 
 Reference roles: src/operator/batch_norm-inl.h (the BN kernel) and the
 reference's fused-op philosophy (optimizer_op.cc); the fusion itself is
@@ -31,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import context as _context
 from ..base import MXNetError
 
 
@@ -62,291 +54,14 @@ def _trace_flag(env_var, doc):
     return Ctx, enabled
 
 
-conv_bn_fusion, fusion_enabled = _trace_flag(
-    "MXNET_FUSE_CONV_BN",
-    "Context manager enabling/disabling the conv1x1+BN fusion during a "
-    "trace.")
-
 # Block-granularity fusion (ISSUE 6): the graph-level pass lives in
 # :mod:`mxnet_tpu.analysis.fusion`; the fused-region math it lowers to
-# lives below (`fused_block_*`).  When enabled it supersedes the
-# conv1x1-only pass above for every chain the old pass does not claim.
+# lives below (`fused_block_*`).
 block_fusion, block_fusion_enabled = _trace_flag(
     "MXNET_FUSE_BLOCKS",
     "Context manager enabling the block-granularity fusion pass "
     "(conv+BN+ReLU / FC+activation regions, analysis.fusion) during a "
     "trace.")
-
-
-# ------------------------------------------------------------ the kernel
-def _pick_bm(m):
-    for bm in (512, 448, 256, 128, 64, 32, 16, 8):
-        if m % bm == 0:
-            return bm
-    return None
-
-
-#: the kernel's device name: the ``name=`` of its ``pallas_call``, which
-#: XLA makes the custom call's instruction name in a profiler trace
-MATMUL_STATS = "mxtpu_matmul_stats"
-
-
-def _stats_kernel(x_ref, w_ref, c_ref, y_ref, s1_ref, s2_ref):
-    from jax.experimental import pallas as pl
-
-    i = pl.program_id(0)
-    y = jnp.dot(x_ref[:], w_ref[:], preferred_element_type=jnp.float32)
-    y_ref[:] = y.astype(y_ref.dtype)
-    ys = y - c_ref[:]
-
-    @pl.when(i == 0)
-    def _init():
-        s1_ref[:] = jnp.zeros_like(s1_ref)
-        s2_ref[:] = jnp.zeros_like(s2_ref)
-
-    s1_ref[:] += jnp.sum(ys, axis=0, keepdims=True)
-    s2_ref[:] += jnp.sum(ys * ys, axis=0, keepdims=True)
-
-
-def _tuned_bm(m, k, n, x_dtype, w_dtype):
-    """Tuning-cache row block for this GEMM shape (None on miss/off/
-    invalid; emits the cache hit/miss metrics) — the ``bm`` the
-    autotuner measured fastest wins over the `_pick_bm` heuristic."""
-    try:
-        from .. import autotune
-        cfg = autotune.kernel_config(
-            "matmul_stats", [(m, k), (k, n)],
-            [str(x_dtype), str(w_dtype)])
-        if cfg:
-            bm = int(cfg.get("bm", 0))
-            if bm > 0 and m % bm == 0:
-                return bm
-    except MemoryError:  # pragma: no cover - never mask resource exhaustion
-        raise
-    except Exception:  # mxlint: allow-broad-except(the tuning-cache lookup is advisory; any failure degrades to the heuristic block pick)
-        pass
-    return None
-
-
-def matmul_stats(x2d, w2d, c, bm=None, interpret=False):
-    """(M,K)@(K,N) -> y (M,N) in x's dtype, plus f32 (N,) sums of
-    (y - c) and (y - c)^2.  Pallas on TPU, jnp elsewhere.  ``bm``:
-    explicit row-block override (the autotuner measures candidates
-    through it); default consults the tuning cache, then the
-    `_pick_bm` heuristic.  ``interpret`` runs the Pallas path in
-    interpreter mode regardless of backend (CPU tuning/CI)."""
-    from ..parallel import mesh as _mesh
-    full_m, k = x2d.shape
-    full_n = w2d.shape[1]
-    # under a multi-device mesh each device runs the kernel on its own
-    # (rows/data, columns/model) tile: m, n are the per-device sizes
-    mesh = _mesh.active_kernel_mesh()
-    row_axis = col_axis = None
-    m, n = full_m, full_n
-    if mesh is not None:
-        # columns split in whole 128-lane groups or not at all
-        row_axis, col_axis = _mesh.kernel_axes(mesh, full_m,
-                                               full_n // 128)
-        m = full_m // (mesh.shape[row_axis] if row_axis else 1)
-        n = full_n // (mesh.shape[col_axis] if col_axis else 1)
-    # the cache is consulted (and hit/miss counted) ONLY when the
-    # Pallas path is actually reachable — a jnp-fallback dispatch must
-    # not report a tuned config it never used
-    eligible = (_context.on_tpu() or interpret) \
-        and n % 128 == 0 and k % 8 == 0
-    if eligible:
-        if bm is None or m % bm:
-            bm = _tuned_bm(m, k, n, x2d.dtype, w2d.dtype) \
-                or _pick_bm(m)
-    else:
-        bm = None
-    if eligible and bm is not None:
-        # label the chosen M block in the cost database so the block
-        # choice is queryable by problem shape (telemetry.costdb;
-        # note_kernel never raises into the trace)
-        from ..telemetry import costdb
-        costdb.note_kernel(
-            "matmul_stats", [(m, k), (k, n)],
-            [str(x2d.dtype), str(w2d.dtype)],
-            flops=2.0 * m * n * k,
-            bytes_accessed=float(
-                m * k * x2d.dtype.itemsize
-                + k * n * w2d.dtype.itemsize
-                + m * n * x2d.dtype.itemsize),
-            block_config={"bm": int(bm), "grid_m": int(m // bm)})
-        c2d = c.reshape(1, full_n).astype(jnp.float32)
-        if mesh is None:
-            y, s1, s2 = _matmul_stats_call(x2d, w2d, c2d, bm, interpret)
-        else:
-            from jax.sharding import PartitionSpec as P
-
-            def tile(x, w, cc):
-                y, s1, s2 = _matmul_stats_call(x, w, cc, bm, interpret)
-                if row_axis is not None:
-                    s1, s2 = lax.psum((s1, s2), row_axis)
-                return y, s1, s2
-
-            cols = P(None, col_axis)
-            y, s1, s2 = _mesh.shard_map_nocheck(
-                tile, mesh,
-                in_specs=(P(row_axis, None), cols, cols),
-                out_specs=(P(row_axis, col_axis), cols, cols),
-            )(x2d, w2d, c2d)
-        return y, s1[0], s2[0]
-    # fallback: plain dot + fused reduces (still correct, not fused)
-    y = jnp.dot(x2d, w2d,
-                preferred_element_type=jnp.float32)
-    ys = y - c.reshape(1, full_n)
-    s1 = jnp.sum(ys, axis=0)
-    s2 = jnp.sum(ys * ys, axis=0)
-    return y.astype(x2d.dtype), s1, s2
-
-
-def _matmul_stats_call(x2d, w2d, c2d, bm, interpret):
-    """The ``pallas_call`` of :func:`matmul_stats` on one device's
-    (M,K) @ (K,N) tile; ``c2d`` is (1,N) f32, sums come back (1,N)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    m, k = x2d.shape
-    n = w2d.shape[1]
-    return pl.pallas_call(
-        _stats_kernel,
-        grid=(m // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, k), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, n), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, n), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, n), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, n), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, n), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, n), x2d.dtype),
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-        ],
-        cost_estimate=pl.CostEstimate(
-            flops=2 * m * n * k,
-            bytes_accessed=m * k * x2d.dtype.itemsize
-            + k * n * w2d.dtype.itemsize + m * n * x2d.dtype.itemsize,
-            transcendentals=0),
-        interpret=interpret,
-        name=MATMUL_STATS,
-    )(x2d, w2d, c2d)
-
-
-# --------------------------------------------- fused conv1x1+BN (train)
-@functools.lru_cache(maxsize=None)
-def _fused_conv_bn(eps, momentum, relu=False, interpret=False):
-    """custom_vjp: NHWC x (N,H,W,K) + OIHW w (N_out,K,1,1) + BN params
-    -> (out, mean, var, new_mm, new_mv), _bn_core numerics.  With
-    ``relu`` the activation folds into the same region (forward epilogue
-    + mask in the hand-written backward) — the conv+BN+ReLU block stays
-    one fused dispatch each way (analysis.fusion).  ``interpret`` runs
-    the Pallas GEMM in interpreter mode (autotuner A/B on CPU)."""
-
-    # mxlint: allow-dtype-widening(bn epilogue folds statistics in f32 by contract)
-    def fwd_math(x, w, gamma, beta, mm, mv):
-        nb, h, wd, k = x.shape
-        nout = w.shape[0]
-        m = nb * h * wd
-        x2d = x.reshape(m, k)
-        w2d = jnp.transpose(w.reshape(nout, k)).astype(x.dtype)
-        c = lax.stop_gradient(mm.astype(jnp.float32))
-        y2d, s1, s2 = matmul_stats(x2d, w2d, c, interpret=interpret)
-        meanc = s1 / m
-        var = jnp.maximum(s2 / m - jnp.square(meanc), 0.0)
-        mean = meanc + c
-        new_mm = mm * momentum + mean * (1 - momentum)
-        new_mv = mv * momentum + var * (1 - momentum)
-        inv = lax.rsqrt(var + eps)
-        scale = gamma.astype(jnp.float32) * inv
-        shift = beta.astype(jnp.float32) - mean * scale
-        out2d = y2d.astype(jnp.float32) * scale + shift
-        if relu:
-            out2d = jnp.maximum(out2d, 0.0)
-        out = out2d.astype(x.dtype).reshape(nb, h, wd, nout)
-        return ((out, mean, var, new_mm, new_mv),
-                (x, w, y2d, gamma, beta, mean, inv, c))
-
-    @jax.custom_vjp
-    def f(x, w, gamma, beta, mm, mv):
-        return fwd_math(x, w, gamma, beta, mm, mv)[0]
-
-    def f_fwd(x, w, gamma, beta, mm, mv):
-        return fwd_math(x, w, gamma, beta, mm, mv)
-
-    def f_bwd(res, cots):
-        x, w, y2d, gamma, beta, mean, inv, c = res
-        dout, dmean_o, dvar_o, dmm_o, dmv_o = cots
-        nb, h, wd, k = x.shape
-        nout = w.shape[0]
-        m = nb * h * wd
-        x2d = x.reshape(m, k)
-        w2d = jnp.transpose(w.reshape(nout, k)).astype(x.dtype)
-        dyf = dout.reshape(m, nout).astype(jnp.float32)
-        if relu:
-            # mask from the recomputed pre-activation (saving it would
-            # cost an extra (M, Nout) residual; scale/shift are vectors)
-            scale = gamma.astype(jnp.float32) * inv
-            shift = beta.astype(jnp.float32) - mean * scale
-            pre = y2d.astype(jnp.float32) * scale + shift
-            dyf = jnp.where(pre > 0, dyf, 0.0)
-        ys = y2d.astype(jnp.float32) - c
-        meanc = mean - c
-        dbeta = jnp.sum(dyf, axis=0)
-        sdyxs = jnp.sum(dyf * ys, axis=0)
-        dgamma = (sdyxs - meanc * dbeta) * inv
-        a = gamma.astype(jnp.float32) * inv
-        dmean = dmean_o + (1 - momentum) * dmm_o
-        dvar = dvar_o + (1 - momentum) * dmv_o
-        kk = (-a * inv * dgamma + 2.0 * dvar) * (1.0 / m)
-        d = -kk * meanc - a * dbeta * (1.0 / m) + dmean * (1.0 / m)
-        dY = dyf * a + ys * kk + d                  # (M, Nout) f32
-        dYc = dY.astype(x.dtype)
-        dx2d = jnp.dot(dYc, jnp.transpose(w2d),
-                       preferred_element_type=jnp.float32)
-        dw2d = jnp.dot(jnp.transpose(x2d), dYc,
-                       preferred_element_type=jnp.float32)
-        dx = dx2d.astype(x.dtype).reshape(x.shape)
-        # w2d is (K, Nout) = w.reshape(Nout, K).T
-        dw = jnp.transpose(dw2d).reshape(w.shape).astype(w.dtype)
-        dmm = momentum * dmm_o
-        dmv = momentum * dmv_o
-        return (dx, dw, dgamma.astype(gamma.dtype),
-                dbeta.astype(gamma.dtype), dmm, dmv)
-
-    f.defvjp(f_fwd, f_bwd)
-    return f
-
-
-# mxlint: allow-dtype-widening(bn epilogue folds statistics in f32 by contract)
-def fused_conv_bn_apply(conv_attrs, bn_attrs, is_train, x, w, gamma,
-                        beta, mm, mv):
-    """Evaluate the fused pair; returns BatchNorm-op-shaped outputs
-    (out[, mean, var], new_mm, new_mv)."""
-    eps = float(bn_attrs["eps"])
-    momentum = float(bn_attrs["momentum"])
-    if bn_attrs["fix_gamma"]:
-        gamma = lax.stop_gradient(jnp.ones_like(gamma))
-    f = _fused_conv_bn(eps, momentum)
-    out, mean, var, new_mm, new_mv = f(
-        x, w, gamma, beta, mm.astype(jnp.float32),
-        mv.astype(jnp.float32))
-    new_mm = new_mm.astype(mm.dtype)
-    new_mv = new_mv.astype(mv.dtype)
-    if bn_attrs.get("output_mean_var"):
-        return out, mean, var, new_mm, new_mv
-    return out, new_mm, new_mv
 
 
 # ------------------------------------------- block-granularity regions
@@ -672,14 +387,9 @@ def _block_scope(kind):
 
 # mxlint: allow-dtype-widening(bn epilogue folds statistics in f32 by contract)
 def fused_block_conv_bn_act(conv_attrs, bn_attrs, layout, is_train, act,
-                            pallas, x, w, b, gamma, beta, mm, mv,
-                            interpret=False):
+                            x, w, b, gamma, beta, mm, mv):
     """Evaluate a planned conv->BN(->act) block; returns
-    (out, new_mm, new_mv).  ``pallas`` routes the eligible 1x1 case
-    through the matmul-with-stats-epilogue kernel (`matmul_stats`);
-    everything else runs the general single-region custom_vjp.
-    ``interpret`` runs the Pallas leg in interpreter mode (the
-    autotuner's CPU A/B; never set on the training path)."""
+    (out, new_mm, new_mv)."""
     eps = float(bn_attrs["eps"])
     momentum = float(bn_attrs["momentum"])
     train_stats = bool(is_train and not bn_attrs.get("use_global_stats"))
@@ -687,19 +397,12 @@ def fused_block_conv_bn_act(conv_attrs, bn_attrs, layout, is_train, act,
         gamma = lax.stop_gradient(jnp.ones_like(gamma))
     mm32 = mm.astype(jnp.float32)
     mv32 = mv.astype(jnp.float32)
-    if pallas and train_stats and b is None and layout == "NHWC":
-        f = _fused_conv_bn(eps, momentum, relu=(act == "relu"),
-                           interpret=interpret)
-        args = (x, w, gamma, beta, mm32, mv32)
-    else:
-        f = _fused_conv_bn_act_xla(_conv_key(conv_attrs), layout, eps,
-                                   momentum, train_stats, act,
-                                   b is not None)
-        args = (x, w) + ((b,) if b is not None else ()) + \
-            (gamma, beta, mm32, mv32)
+    f = _fused_conv_bn_act_xla(_conv_key(conv_attrs), layout, eps,
+                               momentum, train_stats, act, b is not None)
+    args = (x, w) + ((b,) if b is not None else ()) + \
+        (gamma, beta, mm32, mv32)
     with _block_scope("conv_bn_act" if act else "conv_bn"):
-        # the Pallas leg also returns the batch mean and variance
-        out, *_, new_mm, new_mv = f(*args)
+        out, new_mm, new_mv = f(*args)
     return out, new_mm.astype(mm.dtype), new_mv.astype(mv.dtype)
 
 
@@ -726,183 +429,6 @@ def fused_block_fc_act(fc_attrs, act, x, w, b):
                           b is not None)
     with _block_scope("fc_act"):
         return f(x, w, b) if b is not None else f(x, w)
-
-
-# ---------------------------------------------------------- graph pass
-def _conv_eligible(node):
-    a = node.attrs
-    kernel = tuple(a.get("kernel") or ())
-    stride = tuple(a.get("stride") or ()) or (1,) * len(kernel)
-    pad = tuple(a.get("pad") or ()) or (0,) * len(kernel)
-    dilate = tuple(a.get("dilate") or ()) or (1,) * len(kernel)
-    return (kernel == (1, 1) and stride == (1, 1) and pad == (0, 0)
-            and dilate == (1, 1) and int(a.get("num_group", 1)) == 1
-            and bool(a.get("no_bias")))
-
-
-def plan_conv_bn_fusion(topo, entries=()):
-    """id(BatchNorm node) -> Convolution node for fusable pairs; plus the
-    set of conv-node ids to skip.  A conv is fusable when it feeds
-    EXACTLY its BatchNorm and nothing else (graph heads count as uses)."""
-    uses = {}
-    for node in topo:
-        for (src, _i) in node.inputs:
-            uses[id(src)] = uses.get(id(src), 0) + 1
-    for (node, _i) in entries:
-        uses[id(node)] = uses.get(id(node), 0) + 1
-    plan, skip = {}, set()
-    for node in topo:
-        if node.is_variable or node.op is None:
-            continue
-        if node.op.name != "BatchNorm":
-            continue
-        if node.attrs.get("use_global_stats"):
-            continue
-        if int(node.attrs.get("axis", 1)) != 1:
-            continue
-        src, idx = node.inputs[0]
-        if (src.is_variable or src.op is None
-                or src.op.name != "Convolution" or idx != 0):
-            continue
-        if uses.get(id(src), 0) != 1 or not _conv_eligible(src):
-            continue
-        plan[id(node)] = src
-        skip.add(id(src))
-    return plan, skip
-
-
-# ------------------------------------------- pointwise conv as a dot
-# A 1x1/s1/p0 conv IS a GEMM over flattened spatial positions.  XLA:TPU
-# lowers convolutions through the conv library (opaque to fusion) but
-# dots through the standard MXU emitter, which CAN fuse elementwise
-# producers/consumers — the BN normalize/ReLU passes around ResNet's 40
-# pointwise convs could fold into the GEMM's operand reads.
-conv1x1_dot, conv1x1_dot_enabled = _trace_flag(
-    "MXNET_CONV1X1_DOT",
-    "Context manager lowering eligible pointwise convs as dots.")
-
-
-def conv1x1_as_dot(x, w_hwio):
-    """x NHWC, w (1, 1, I, O) -> conv output via a flattened dot."""
-    nb, h, wd, cin = x.shape
-    nout = w_hwio.shape[3]
-    y = jnp.dot(x.reshape(nb * h * wd, cin),
-                w_hwio.reshape(cin, nout))
-    return y.reshape(nb, h, wd, nout).astype(x.dtype)
-
-
-# --------------------------------- phase-decomposed stride-2 backward
-# XLA computes backward-data of a strided conv as a conv over the
-# lhs-dilated cotangent: for stride 2, ~3/4 of the MACs multiply
-# inserted zeros.  The exact phase decomposition removes every wasted
-# MAC: output positions of parity (r_h, r_w) only receive kernel taps of
-# matching parity, so dX splits into 4 dense stride-1 convs of dY with
-# the parity sub-kernels, interleaved back (depth-to-space).  Derivation
-# (per dim, stride 2, pad P, kernel k):
-#
-#   dX[i] = sum_{a ≡ (i+P) mod 2} dY[(i+P-a)/2] * W[a]
-#         = sum_u dY[q-u] * W[r+2u],  q = floor((i+P)/2), r = (i+P) mod 2
-#
-# — a correlation of dY with the reversed parity-r sub-kernel, offset so
-# q' = q - ku + 1 (left pad ku-1-q_lo, right pad q_max-Ho+1; negative
-# pads crop).  Mathematically exact; bitwise it differs from the dilated
-# form only in f32 accumulation order.  Enabled per-trace by the
-# ``phase_bwd`` context (ShardedTrainer strided_bwd_phase=True).
-phase_bwd, phase_bwd_enabled = _trace_flag(
-    "MXNET_PHASE_BWD",
-    "Context manager enabling the stride-2 backward decomposition.")
-
-
-def _phase_ranges(k, pad, h_in, h_out):
-    """Per-parity (ku, q_lo, pad_l, pad_r, i0) for one spatial dim."""
-    out = []
-    for r in (0, 1):
-        ku = max(0, (k - r + 1) // 2)          # taps a = r, r+2, ... < k
-        # i = 2q + r - pad ranges over [0, h_in): q in [q_lo, q_lo + h/2)
-        q_lo = max(0, (pad - r + 1) // 2)
-        i0 = 2 * q_lo + r - pad
-        n = h_in // 2
-        q_max = q_lo + n - 1
-        pad_l = ku - 1 - q_lo
-        pad_r = q_max - h_out + 1
-        out.append((ku, q_lo, pad_l, pad_r, i0))
-    return out
-
-
-def _phase_bwd_dx(dy, w_hwio, pads, x_shape):
-    """Exact dX of a stride-2 NHWC/HWIO conv via phase decomposition."""
-    kh, kw = w_hwio.shape[0], w_hwio.shape[1]
-    nb, h, wd, cin = x_shape
-    ho, wo = dy.shape[1], dy.shape[2]
-    wt = jnp.transpose(w_hwio, (0, 1, 3, 2))     # contraction over cout
-    rows = _phase_ranges(kh, pads[0][0], h, ho)
-    cols = _phase_ranges(kw, pads[1][0], wd, wo)
-    # phases keyed by output-row parity i0 (each is 0 or 1 exactly once)
-    zs = {}
-    for (kuh, _qh, plh, prh, i0h) in rows:
-        for (kuw, _qw, plw, prw, i0w) in cols:
-            rh = (i0h + pads[0][0]) % 2
-            rw = (i0w + pads[1][0]) % 2
-            if kuh == 0 or kuw == 0:
-                zs[(i0h, i0w)] = jnp.zeros(
-                    (nb, h // 2, wd // 2, cin), dy.dtype)
-                continue
-            sub = wt[rh::2, rw::2]               # (kuh, kuw, cout, cin)
-            sub = sub[::-1, ::-1]                # reversed correlation
-            dn = lax.conv_dimension_numbers(dy.shape, sub.shape,
-                                            ("NHWC", "HWIO", "NHWC"))
-            zs[(i0h, i0w)] = lax.conv_general_dilated(
-                dy, sub, window_strides=(1, 1),
-                padding=((plh, prh), (plw, prw)),
-                dimension_numbers=dn)
-    # interleave: dX[:, 2q+i0h, 2p+i0w, :] = zs[(i0h, i0w)][:, q, p, :]
-    w_even = jnp.stack([zs[(0, 0)], zs[(0, 1)]], axis=3)
-    w_odd = jnp.stack([zs[(1, 0)], zs[(1, 1)]], axis=3)
-    row_even = w_even.reshape(nb, h // 2, wd, cin)
-    row_odd = w_odd.reshape(nb, h // 2, wd, cin)
-    full = jnp.stack([row_even, row_odd], axis=2)
-    return full.reshape(nb, h, wd, cin)
-
-
-@functools.lru_cache(maxsize=None)
-def _phase_bwd_conv(pads):
-    """Stride-2 NHWC x HWIO conv whose backward-data uses the phase
-    decomposition (backward-filter unchanged)."""
-
-    def conv(x, w):
-        dn = lax.conv_dimension_numbers(x.shape, w.shape,
-                                        ("NHWC", "HWIO", "NHWC"))
-        return lax.conv_general_dilated(
-            x, w, window_strides=(2, 2), padding=pads,
-            dimension_numbers=dn)
-
-    @jax.custom_vjp
-    def f(x, w):
-        return conv(x, w)
-
-    def f_fwd(x, w):
-        return conv(x, w), (x, w)
-
-    def f_bwd(res, dy):
-        x, w = res
-        _, wvjp = jax.vjp(lambda ww: conv(x, ww), w)
-        (dw,) = wvjp(dy)
-        dx = _phase_bwd_dx(dy, w, pads, x.shape)
-        return dx.astype(x.dtype), dw
-
-    f.defvjp(f_fwd, f_bwd)
-    return f
-
-
-def phase_bwd_eligible(x_shape, kernel, stride, pad, dilate, num_group):
-    return (len(kernel) == 2 and tuple(stride) == (2, 2)
-            and tuple(dilate) == (1, 1) and int(num_group) == 1
-            and x_shape[1] % 2 == 0 and x_shape[2] % 2 == 0)
-
-
-def phase_bwd_conv_nhwc(x, w_hwio, pads):
-    """Entry point for ops/nn.py: stride-2 conv with decomposed bwd."""
-    return _phase_bwd_conv(tuple(pads))(x, w_hwio)
 
 
 # ------------------------------------------- space-to-depth stem conv

@@ -158,27 +158,11 @@ def convolution(attrs, ctx, data, weight, bias=None):
             data.shape, weight.shape[2:] + weight.shape[1:2] + weight.shape[:1],
             ("NHWC", "HWIO", "NHWC"))
         w = jnp.transpose(weight, (2, 3, 1, 0))
-        from .fused import (phase_bwd_enabled, phase_bwd_eligible,
-                            phase_bwd_conv_nhwc, conv1x1_dot_enabled,
-                            conv1x1_as_dot)
-        if conv1x1_dot_enabled() and kernel == (1, 1) \
-                and stride == (1, 1) and tuple(pad) == (0, 0) \
-                and dilate == (1, 1) and int(attrs["num_group"]) == 1:
-            # pointwise conv lowered as a fusible dot (ops/fused.py)
-            y = conv1x1_as_dot(data, w)
-        elif phase_bwd_enabled() and phase_bwd_eligible(
-                data.shape, kernel, stride, pad, dilate,
-                attrs["num_group"]):
-            # stride-2 conv with phase-decomposed backward-data
-            # (ops/fused.py — removes the 4x lhs-dilation MAC waste)
-            y = phase_bwd_conv_nhwc(data, w,
-                                    tuple((p, p) for p in pad))
-        else:
-            y = lax.conv_general_dilated(
-                data, w, window_strides=stride,
-                padding=[(p, p) for p in pad], rhs_dilation=dilate,
-                dimension_numbers=dn,
-                feature_group_count=int(attrs["num_group"]))
+        y = lax.conv_general_dilated(
+            data, w, window_strides=stride,
+            padding=[(p, p) for p in pad], rhs_dilation=dilate,
+            dimension_numbers=dn,
+            feature_group_count=int(attrs["num_group"]))
         if bias is not None:
             y = y + bias
         return _mxu_out(y.astype(data.dtype))

@@ -171,11 +171,10 @@ class ShardedTrainer:
                  optimizer="sgd", optimizer_params=None, learning_rate=0.05,
                  momentum=0.9, weight_decay=0.0, initializer=None,
                  dtype="float32", tp_rules=None, seed=0, layout=None,
-                 auto_layouts=False, fuse_conv_bn=None, fuse_blocks=None,
+                 auto_layouts=False, fuse_blocks=None,
                  stem_space_to_depth=None, elide_input_bn_grad=True,
-                 strided_bwd_phase=None, pipeline_stages=1,
-                 pipeline_microbatches=None, sequence_parallel=False,
-                 input_mean=None, input_std=None, conv1x1_as_dot=None,
+                 pipeline_stages=1, pipeline_microbatches=None,
+                 sequence_parallel=False, input_mean=None, input_std=None,
                  native_weight_layout=None, strict=None):
         """
         symbol: loss-headed Symbol (e.g. SoftmaxOutput net).
@@ -240,12 +239,6 @@ class ShardedTrainer:
         if layout not in (None, "NCHW", "NHWC"):
             raise MXNetError("unsupported layout %r" % (layout,))
         self._layout = layout or "NCHW"
-        # fuse_conv_bn: conv1x1+BN GEMM-with-stats-epilogue fusion
-        # (ops/fused.py); None -> MXNET_FUSE_CONV_BN env default
-        if fuse_conv_bn is None:
-            from ..ops import fused as _fused_mod
-            fuse_conv_bn = _fused_mod.fusion_enabled()
-        self._fuse_conv_bn = bool(fuse_conv_bn) and self._layout == "NHWC"
         # fuse_blocks: block-granularity fusion pass (analysis.fusion) —
         # conv+BN+ReLU / FC+activation chains emitted as single
         # custom-vjp regions with a pinned layout per boundary, on both
@@ -266,24 +259,6 @@ class ShardedTrainer:
         # an input-BN beta grad (ops/fused.py).  Always sound here: the
         # trainer's vjp differentiates params only, never batch inputs.
         self._elide_input_grads = bool(elide_input_bn_grad)
-        # strided_bwd_phase: phase-decomposed backward-data for stride-2
-        # convs (ops/fused.py) — exact, NHWC only.  None -> the
-        # MXNET_PHASE_BWD env default (off: measured 6% SLOWER end-to-end
-        # on ResNet-50/v5e — XLA:TPU's dilated backward already skips the
-        # inserted zeros; the 4 small sub-convs + interleave cost more
-        # than they save, docs/perf.md)
-        if strided_bwd_phase is None:
-            from ..ops import fused as _fused_mod
-            strided_bwd_phase = _fused_mod.phase_bwd_enabled()
-        self._phase_bwd = bool(strided_bwd_phase) and \
-            self._layout == "NHWC"
-        # conv1x1_as_dot: lower pointwise convs as fusible dots
-        # (ops/fused.py); None -> MXNET_CONV1X1_DOT env default
-        if conv1x1_as_dot is None:
-            from ..ops import fused as _fused_mod
-            conv1x1_as_dot = _fused_mod.conv1x1_dot_enabled()
-        self._conv1x1_dot = bool(conv1x1_as_dot) and \
-            self._layout == "NHWC"
         # native_weight_layout: store conv-weight MASTERS physically as
         # HWIO (f32) so the default/canonical layout IS the layout the
         # TPU conv wants.  jit's Layout.AUTO cannot reach lax.scan loop
@@ -1274,9 +1249,8 @@ class ShardedTrainer:
                 # compute-precision copies of the f32 masters (the astype
                 # vjp returns f32 grads automatically); native-layout
                 # weights arrive HWIO and grads flow back HWIO
-                from ..ops.fused import (conv_bn_fusion, stem_s2d,
-                                         elide_input_grads, phase_bwd,
-                                         conv1x1_dot, block_fusion)
+                from ..ops.fused import (stem_s2d, elide_input_grads,
+                                         block_fusion)
                 from ..analysis.fusion import plan_decisions
                 from .sequence import sequence_parallel as seq_ctx
                 from .mesh import kernel_mesh
@@ -1284,12 +1258,9 @@ class ShardedTrainer:
                 p = self._compute_view(p32, compute_dtype)
                 with image_layout(layout), kernel_mesh(self.mesh), \
                         plan_recording(), \
-                        conv_bn_fusion(self._fuse_conv_bn), \
                         block_fusion(self._fuse_blocks), \
                         plan_decisions(self._plan_decisions), \
                         stem_s2d(self._stem_s2d), \
-                        phase_bwd(self._phase_bwd), \
-                        conv1x1_dot(self._conv1x1_dot), \
                         seq_ctx(self.mesh if self._seq_parallel
                                 else None), \
                         elide_input_grads(

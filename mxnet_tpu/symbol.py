@@ -128,18 +128,13 @@ def eval_graph(topo, entries, var_values, is_train=False, key=None,
     aux_updates = {}
     device_map = device_map or {}
 
-    # optional conv1x1+BN fusion (ops/fused.py): deferred convs carry
-    # their input values to the consuming BatchNorm node
-    fuse_plan, fuse_skip = {}, set()
+    # conv-only rewrites of the stem (ops/fused.py)
     stem_plan = set()
     elide_plan = set()
     if is_train and not device_map:
         from .ops import fused as _fused
         from .ops.nn import current_image_layout
         if current_image_layout() == "NHWC":
-            if _fused.fusion_enabled():
-                fuse_plan, fuse_skip = _fused.plan_conv_bn_fusion(
-                    topo, entries)
             if _fused.stem_s2d_enabled():
                 stem_plan = _fused.plan_stem_s2d(topo)
             if _fused.elide_names():
@@ -164,8 +159,7 @@ def eval_graph(topo, entries, var_values, is_train=False, key=None,
             block_plan = _fusion.plan_block_fusion(
                 topo, entries, layout=current_image_layout(),
                 is_train=is_train,
-                exclude=(set(fuse_skip) | set(fuse_plan) | stem_plan
-                         | elide_plan))
+                exclude=stem_plan | elide_plan)
             if not block_plan.blocks:
                 block_plan = None
 
@@ -175,31 +169,6 @@ def eval_graph(topo, entries, var_values, is_train=False, key=None,
                 vals[id(node)] = (var_values[id(node)],)
             except KeyError:
                 raise MXNetError("no value bound for variable %r" % node.name)
-            continue
-        if id(node) in fuse_skip:
-            # conv deferred into its BatchNorm consumer
-            vals[id(node)] = (tuple(vals[id(src)][idx]
-                                    for (src, idx) in node.inputs),)
-            continue
-        if id(node) in fuse_plan:
-            from .ops import fused as _fused
-            conv_node = fuse_plan[id(node)]
-            conv_ins = vals[id(conv_node)][0]
-            bn_ins = [vals[id(src)][idx]
-                      for (src, idx) in node.inputs[1:]]
-            outs = _fused.fused_conv_bn_apply(
-                conv_node.attrs, node.attrs, is_train,
-                conv_ins[0], conv_ins[1], *bn_ins)
-            n_vis = node.num_outputs()
-            n_aux = len(node.inputs) - node.num_args
-            vals[id(node)] = outs[:n_vis]
-            for (src, _), upd in zip(node.inputs[node.num_args:],
-                                     outs[n_vis:n_vis + n_aux]):
-                if src.is_variable:
-                    aux_updates[id(src)] = upd
-            if monitor is not None:
-                for oname, val in zip(node.output_names(), outs[:n_vis]):
-                    monitor(oname, val)
             continue
         if id(node) in stem_plan:
             from .ops import fused as _fused

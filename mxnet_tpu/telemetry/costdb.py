@@ -16,7 +16,7 @@ three existing-but-disconnected signals into durable records:
   analytic shape-derived estimates for fused-block / Pallas-kernel
   records (registered at trace time, when the shapes are in hand);
 * **block identity** — the PR 6 ``FusionPlan`` block kind plus the
-  Pallas block configuration (``block_q``/``block_k``/``bm``), so the
+  Pallas block configuration (``block_q``/``block_k``), so the
   2176-style block-shape cliffs become queryable by (op, shape).
 
 Each record derives **MFU** (``flops / wall_s / peak_flops``) and
@@ -262,7 +262,7 @@ class CostDB:
     # ------------------------------------------------ trace-time notes
     def note_block(self, name, block_kind, shapes, dtypes, flops=None,
                    bytes_accessed=None, block_config=None, layout=None,
-                   pallas=False, graph=None, plan=None):
+                   graph=None, plan=None):
         """Register a fused block traced right now (pending until the
         surrounding program's dispatch binds it).  Called from
         ``analysis.fusion.apply_block`` with trace-time shapes.
@@ -283,7 +283,7 @@ class CostDB:
                 else float(bytes_accessed),
                 "block_config": dict(block_config) if block_config
                 else None,
-                "layout": layout, "pallas": bool(pallas),
+                "layout": layout, "pallas": False,
                 "graph": graph, "plan": plan,
             })
         except MemoryError:  # pragma: no cover - never mask resource exhaustion

@@ -3,9 +3,8 @@
 Covers the contracts in docs/api/autotune.md: the measurement runner's
 min-wall semantics, candidate spaces over the divisor lattice, the
 persistent tuning cache (merge-on-load, corrupt-file degradation,
-best-wall-wins), trace-time lookup in the flash kernels /
-matmul_stats / fused blocks with the tuned entry winning over the
-heuristic, the `_blocks()` heuristic across the full divisor lattice
+best-wall-wins), trace-time lookup in the flash kernels with the
+tuned entry winning over the heuristic, the `_blocks()` heuristic across the full divisor lattice
 (ADVICE cliff shapes included), the learned cost model
 (fit/predict/save/load/calibration) and analysis rule MXG010, and the
 perf_top --suggest / tools/autotune.py CLI surfaces.
@@ -13,7 +12,6 @@ perf_top --suggest / tools/autotune.py CLI surfaces.
 import importlib.util
 import json
 import os
-import types
 
 import numpy as np
 import pytest
@@ -21,7 +19,6 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import autotune, telemetry
 from mxnet_tpu.ops import pallas_kernels as pk
-from mxnet_tpu.ops import fused as fused_mod
 from mxnet_tpu.telemetry import costdb
 
 
@@ -155,23 +152,25 @@ def test_autotune_off_mode_skips_lookup(monkeypatch, tmp_path):
 
 def test_lookup_emits_metrics_and_flight_event(monkeypatch, tmp_path):
     monkeypatch.setenv("MXNET_TPU_TUNE_CACHE", str(tmp_path))
-    autotune.put("matmul_stats", [(256, 64), (64, 128)],
-                 ["float32", "float32"], {"bm": 64}, wall_s=1e-3)
+    autotune.put("flash_attention_fwd", [(2, 256, 2, 32)], ["float32"],
+                 {"block_q": 64, "block_k": 128}, wall_s=1e-3)
     from mxnet_tpu.telemetry import flight
     flight.RECORDER.clear()
-    assert autotune.kernel_config("matmul_stats", [(256, 64), (64, 128)],
-                                  ["float32", "float32"]) == {"bm": 64}
-    assert autotune.kernel_config("matmul_stats", [(512, 64), (64, 128)],
-                                  ["float32", "float32"]) is None
+    assert autotune.kernel_config(
+        "flash_attention_fwd", [(2, 256, 2, 32)], ["float32"]) \
+        == {"block_q": 64, "block_k": 128}
+    assert autotune.kernel_config(
+        "flash_attention_fwd", [(2, 512, 2, 32)], ["float32"]) is None
     hits = telemetry.counter("mxtpu_tune_cache_hit_total").labels(
-        op="matmul_stats").get()
+        op="flash_attention_fwd").get()
     misses = telemetry.counter("mxtpu_tune_cache_miss_total").labels(
-        op="matmul_stats").get()
+        op="flash_attention_fwd").get()
     assert hits == 1 and misses == 1
     evs = [e for e in flight.RECORDER.events()
            if e["kind"] == "tune_lookup"]
     assert len(evs) == 2
-    assert evs[0]["hit"] is True and evs[0]["config"] == {"bm": 64}
+    assert evs[0]["hit"] is True \
+        and evs[0]["config"] == {"block_q": 64, "block_k": 128}
     assert evs[1]["hit"] is False
 
 
@@ -179,9 +178,10 @@ def test_lookup_emits_metrics_and_flight_event(monkeypatch, tmp_path):
 
 def test_cache_put_persist_merge_roundtrip(monkeypatch, tmp_path):
     monkeypatch.setenv("MXNET_TPU_TUNE_CACHE", str(tmp_path))
-    autotune.put("matmul_stats", [(256, 64), (64, 128)],
-                 ["float32", "float32"], {"bm": 64}, wall_s=2e-3,
-                 heuristic_config={"bm": 256}, heuristic_wall_s=3e-3)
+    autotune.put("flash_attention_fwd", [(2, 256, 2, 32)], ["float32"],
+                 {"block_q": 64}, wall_s=2e-3,
+                 heuristic_config={"block_q": 128},
+                 heuristic_wall_s=3e-3)
     files = [f for f in os.listdir(tmp_path)
              if f.startswith("tunecache")]
     assert len(files) == 1
@@ -189,32 +189,32 @@ def test_cache_put_persist_merge_roundtrip(monkeypatch, tmp_path):
                                              strict=True)
     assert skipped == 0 and len(entries) == 1
     e = entries[0]
-    assert e["config"] == {"bm": 64} and e["wall_s"] == 2e-3
+    assert e["config"] == {"block_q": 64} and e["wall_s"] == 2e-3
     assert e["heuristic_wall_s"] == 3e-3
 
 
 def test_cache_merge_best_measured_wall_wins(tmp_path):
     """Multi-host/run composition: two files with the same key keep
     the better-measured config."""
-    sig, payload = autotune.key_sig("matmul_stats",
-                                    [(256, 64), (64, 128)],
-                                    ["float32", "float32"],
+    sig, payload = autotune.key_sig("flash_attention_fwd",
+                                    [(2, 256, 2, 32)], ["float32"],
                                     backend="cpu")
-    base = {"schema": autotune.SCHEMA, "sig": sig, "op": "matmul_stats",
+    base = {"schema": autotune.SCHEMA, "sig": sig,
+            "op": "flash_attention_fwd",
             "shapes": payload["shapes"], "dtypes": payload["dtypes"],
             "mesh": None, "backend": "cpu", "extra": None}
     (tmp_path / "tunecache-hostA.jsonl").write_text(json.dumps(
-        dict(base, config={"bm": 256}, wall_s=5e-3, ts=2.0)) + "\n")
+        dict(base, config={"block_q": 128}, wall_s=5e-3, ts=2.0)) + "\n")
     (tmp_path / "tunecache-hostB.jsonl").write_text(json.dumps(
-        dict(base, config={"bm": 64}, wall_s=1e-3, ts=1.0)) + "\n")
+        dict(base, config={"block_q": 64}, wall_s=1e-3, ts=1.0)) + "\n")
     entries, _ = autotune.read_entries(str(tmp_path))
     assert len(entries) == 1
-    assert entries[0]["config"] == {"bm": 64}   # faster, though older
+    assert entries[0]["config"] == {"block_q": 64}   # faster, though older
     c = autotune.TuneCache()
     c.load(str(tmp_path))
-    got = c.lookup("matmul_stats", [(256, 64), (64, 128)],
-                   ["float32", "float32"], backend="cpu")
-    assert got["config"] == {"bm": 64}
+    got = c.lookup("flash_attention_fwd", [(2, 256, 2, 32)], ["float32"],
+                   backend="cpu")
+    assert got["config"] == {"block_q": 64}
 
 
 def test_full_shape_entry_displaces_proxy(tmp_path):
@@ -258,23 +258,6 @@ def test_inline_search_commits_proxy_entry(monkeypatch, tmp_path):
     assert e["proxy"] is True and e["source"] == "inline-search"
 
 
-def test_matmul_stats_no_lookup_on_ineligible_path(monkeypatch,
-                                                   tmp_path):
-    """Review fix: a dispatch that takes the jnp fallback (no Pallas
-    path reachable) must not consult the cache or count hits — the
-    BENCH 'tuned configs dispatched' evidence must mean dispatched."""
-    monkeypatch.setenv("MXNET_TPU_TUNE_CACHE", str(tmp_path))
-    autotune.put("matmul_stats", [(256, 64), (64, 100)],
-                 ["float32", "float32"], {"bm": 64}, wall_s=1e-3)
-    rng = np.random.RandomState(0)
-    x = rng.normal(0, 1, (256, 64)).astype(np.float32)
-    w = rng.normal(0, 1, (64, 100)).astype(np.float32)  # N%128 != 0
-    c = np.zeros((100,), np.float32)
-    fused_mod.matmul_stats(x, w, c)          # CPU, not interpret
-    s = autotune.summary()
-    assert s["hits"] == 0 and s["misses"] == 0
-
-
 # ------------------------------------------------ measurement runner
 
 def test_measure_min_wall_and_chain():
@@ -295,28 +278,6 @@ def test_candidate_spaces_contain_heuristic():
                    and c["block_k"] == heur["block_k"] for c in cands)
         for c in cands:
             assert t % c["block_q"] == 0 and t % c["block_k"] == 0
-    for m in (256, 25088, 98):
-        cands = autotune.candidate_matmul_configs(m)
-        assert len(cands) >= 2
-        for c in cands:
-            assert m % c["bm"] == 0
-
-
-def test_tune_matmul_stats_commits_and_feeds_costdb(monkeypatch,
-                                                    tmp_path):
-    monkeypatch.setenv("MXNET_TPU_TUNE_CACHE", str(tmp_path))
-    rep = autotune.tune_matmul_stats(256, 64, 128, repeats=1,
-                                     max_candidates=3, interpret=True)
-    assert rep["best"]["wall_s"] <= rep["heuristic"]["wall_s"]
-    assert rep["entry"]["heuristic_wall_s"] is not None
-    entries, _ = autotune.read_entries(str(tmp_path), strict=True)
-    assert len(entries) == 1
-    # candidate measurements became costdb kernel records
-    recs = [r for r in costdb.records()
-            if r["kind"] == "kernel" and r["name"] == "matmul_stats"
-            and r["source"] == "autotune"]
-    assert len(recs) >= 2
-    assert all(r["wall_s"] and r["flops"] for r in recs)
 
 
 def test_tune_flash_fwd_and_bwd_interpret(monkeypatch, tmp_path):
@@ -361,75 +322,18 @@ def test_flash_attention_correct_under_tuned_config(monkeypatch,
     assert autotune.summary()["hits"] >= 2
 
 
-def test_matmul_stats_tuned_bm(monkeypatch, tmp_path):
-    monkeypatch.setenv("MXNET_TPU_TUNE_CACHE", str(tmp_path))
-    autotune.put("matmul_stats", [(256, 64), (64, 128)],
-                 ["float32", "float32"], {"bm": 64}, wall_s=1e-3)
-    assert fused_mod._tuned_bm(256, 64, 128, np.float32(0).dtype,
-                               np.float32(0).dtype) == 64
-    # a bm that does not divide M degrades to None (heuristic)
-    autotune.put("matmul_stats", [(300, 64), (64, 128)],
-                 ["float32", "float32"], {"bm": 64}, wall_s=1e-3)
-    assert fused_mod._tuned_bm(300, 64, 128, np.float32(0).dtype,
-                               np.float32(0).dtype) is None
-    # correctness under the tuned bm (interpret pallas path)
-    rng = np.random.RandomState(1)
-    x = rng.normal(0, 1, (256, 64)).astype(np.float32)
-    w = rng.normal(0, 1, (64, 128)).astype(np.float32) * 0.05
-    c = rng.normal(0, 1, (128,)).astype(np.float32)
-    y, s1, s2 = fused_mod.matmul_stats(x, w, c, interpret=True)
-    yref = x @ w
-    np.testing.assert_allclose(np.asarray(y), yref, rtol=2e-4,
-                               atol=2e-4)
-    np.testing.assert_allclose(np.asarray(s1),
-                               (yref - c).sum(0), rtol=1e-3)
-
-
-def test_fusion_block_pallas_veto(monkeypatch, tmp_path):
-    """A committed {"pallas": 0} vetoes the Pallas leg for that shape;
-    the cache can never force Pallas onto an ineligible block."""
-    from mxnet_tpu.analysis import fusion
-    monkeypatch.setenv("MXNET_TPU_TUNE_CACHE", str(tmp_path))
-    blk = types.SimpleNamespace(pallas=True, kind="conv_bn_act",
-                                layout="NHWC", act="relu")
-    import jax.numpy as jnp
-    x = jnp.zeros((2, 8, 8, 16), jnp.float32)
-    w = jnp.zeros((32, 16, 1, 1), jnp.float32)
-    assert fusion._tuned_pallas(blk, x, w) is True      # miss: keep
-    autotune.put("block:conv_bn_act",
-                 [(2, 8, 8, 16), (32, 16, 1, 1)],
-                 ["float32", "float32"], {"pallas": 0}, wall_s=1e-3,
-                 extra={"layout": "NHWC", "act": "relu"})
-    assert fusion._tuned_pallas(blk, x, w) is False     # veto
-    blk2 = types.SimpleNamespace(pallas=False, kind="conv_bn_act",
-                                 layout="NHWC", act="relu")
-    assert fusion._tuned_pallas(blk2, x, w) is False    # never forced
-
-
-def test_tune_conv_block_ab(monkeypatch, tmp_path):
-    monkeypatch.setenv("MXNET_TPU_TUNE_CACHE", str(tmp_path))
-    rep = autotune.tune_conv_block((2, 8, 8, 16), (32, 16, 1, 1),
-                                   repeats=1, interpret=True)
-    assert rep["best"]["config"]["pallas"] in (0, 1)
-    assert len(rep["candidates"]) == 2
-    entries, _ = autotune.read_entries(str(tmp_path), strict=True)
-    assert entries[0]["op"] == "block:conv_bn_act"
-
-
 # --------------------------------------------------- inline search
 
 def test_search_mode_inline_commits_on_miss(monkeypatch, tmp_path):
     monkeypatch.setenv("MXNET_TPU_TUNE_CACHE", str(tmp_path))
     monkeypatch.setenv("MXNET_TPU_AUTOTUNE", "search")
-    cfg = autotune.kernel_config("matmul_stats", [(256, 64), (64, 128)],
-                                 ["float32", "float32"])
-    assert cfg is not None and "bm" in cfg
+    key = ("flash_attention_bwd", [(2, 256, 2, 32)], ["float32"])
+    cfg = autotune.kernel_config(*key, extra={"causal": True})
+    assert cfg is not None and "block_q" in cfg
     s = autotune.summary()
     assert s["misses"] == 1 and s["searches"] == 1
     # committed: the next lookup is a plain hit
-    cfg2 = autotune.kernel_config("matmul_stats",
-                                  [(256, 64), (64, 128)],
-                                  ["float32", "float32"])
+    cfg2 = autotune.kernel_config(*key, extra={"causal": True})
     assert cfg2 == cfg
     assert autotune.summary()["hits"] == 1
 
@@ -504,13 +408,6 @@ def test_cost_model_geometry_means_for_configless_predict(tmp_path):
         == pytest.approx(without)
 
 
-def test_candidate_matmul_prime_m_stays_tunable():
-    """Review fix: prime M > 1024 has no lattice divisor besides 1 and
-    M — the whole-M block must remain as a candidate."""
-    cands = autotune.candidate_matmul_configs(1031)
-    assert cands == [{"bm": 1031, "grid_m": 1}]
-
-
 def test_mxg010_flags_predicted_slow_and_discriminates():
     from mxnet_tpu.analysis import verify_model
     slow = autotune.CostModel().fit(_synthetic_records(100.0))
@@ -546,17 +443,17 @@ def test_perf_top_suggest(monkeypatch, tmp_path):
     db.mkdir()
     monkeypatch.setenv("MXNET_TPU_PEAK_FLOPS", "1e12")
     monkeypatch.setenv("MXNET_TPU_PEAK_BW", "1e11")
-    costdb.record("kernel", "matmul_stats", wall_s=5e-3, flops=1e9,
-                  bytes_accessed=1e6, shapes=[(256, 64), (64, 128)],
-                  dtypes=["float32", "float32"],
-                  block_config={"bm": 256}, backend="cpu")
+    costdb.record("kernel", "flash_attention_bwd", wall_s=5e-3,
+                  flops=1e9, bytes_accessed=1e6,
+                  shapes=[(2, 256, 2, 32)], dtypes=["float32"],
+                  block_config={"block_q": 128}, backend="cpu")
     costdb.flush(str(db))
     cache = tmp_path / "cache"
     autotune.CACHE.clear()
     monkeypatch.setenv("MXNET_TPU_TUNE_CACHE", str(cache))
-    autotune.put("matmul_stats", [(256, 64), (64, 128)],
-                 ["float32", "float32"], {"bm": 64}, wall_s=1e-3,
-                 heuristic_config={"bm": 256}, heuristic_wall_s=5e-3,
+    autotune.put("flash_attention_bwd", [(2, 256, 2, 32)], ["float32"],
+                 {"block_q": 64}, wall_s=1e-3,
+                 heuristic_config={"block_q": 128}, heuristic_wall_s=5e-3,
                  backend="cpu")
     records, _ = costdb.read_records(str(db))
     ranked = ptop.rank(records)
@@ -565,7 +462,7 @@ def test_perf_top_suggest(monkeypatch, tmp_path):
     assert len(rows) == 1
     r = rows[0]
     assert r["status"] == "better-available"
-    assert r["tuned_config"] == {"bm": 64}
+    assert r["tuned_config"] == {"block_q": 64}
     assert r["expected_delta_frac"] == pytest.approx(0.8)
     # an untuned record reports the miss, not a crash
     costdb.record("kernel", "flash_attention_fwd", wall_s=1e-3,
@@ -580,7 +477,7 @@ def test_autotune_cli_tune_then_all_hits(monkeypatch, tmp_path):
     at = _load_tool("autotune")
     cache = str(tmp_path / "cache")
     db = str(tmp_path / "db")
-    argv = ["--op", "matmul_stats", "--shapes", "256x64x128",
+    argv = ["--op", "flash_fwd", "--shapes", "1x256x1x32",
             "--repeats", "1", "--max-candidates", "2", "--interpret",
             "--cache", cache, "--costdb", db, "--json"]
     assert at.main(argv) == 0

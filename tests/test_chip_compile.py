@@ -16,7 +16,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from mxnet_tpu import context
-from mxnet_tpu.ops import fused
 from mxnet_tpu.ops import pallas_kernels as pk
 from test_fusion import one_by_one_block
 
@@ -115,25 +114,9 @@ def test_grouped_matmul_of_the_expert_layer_compiles_for_v5e(one_chip):
         [c.split("=")[0] for c in calls]
 
 
-# ResNet-50 at batch 128: the 1x1 convs as (N*H*W, Cin) @ (Cin, Cout)
-MATMUL_STATS_SHAPES = [(401408, 64, 256), (100352, 512, 128),
-                       (25088, 1024, 256), (6272, 2048, 512),
-                       (6272, 512, 2048)]
-
-
-@pytest.mark.parametrize("m,k,n", MATMUL_STATS_SHAPES)
-def test_matmul_stats_compiles_for_v5e(one_chip, monkeypatch, m, k, n):
-    # jax.default_backend() is the CPU here; the probe is steered from
-    # the test so that matmul_stats takes its kernel branch
-    monkeypatch.setattr(context, "on_tpu", lambda: True)
-    _compile_for_chip(fused.matmul_stats, one_chip,
-                      ((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16),
-                      ((n,), jnp.float32), names=[fused.MATMUL_STATS])
-
-
 # stage 1 and stage 2 of ResNet-50 at batch 128 (the flatten round the
-# kernel moved the whole activation there), and the shape of PR 25's
-# A/B, whose flatten is a bitcast (the kernel lost there too)
+# removed kernel moved the whole activation there), and the shape of
+# PR 25's A/B, whose flatten is a bitcast (the kernel lost there too)
 @pytest.mark.parametrize("x_shape,nout", [
     ((128, 56, 56, 256), 64), ((128, 28, 28, 512), 128),
     ((128, 32, 32, 128), 128)], ids=str)

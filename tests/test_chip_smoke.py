@@ -18,7 +18,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import mxnet_tpu as mx
 from mxnet_tpu import base, context
-from mxnet_tpu.ops import fused
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.parallel import build_mesh
 from mxnet_tpu.parallel.mesh import kernel_mesh
@@ -131,28 +130,6 @@ def test_ndarray_write_keeps_the_array_on_its_device():
 
 
 # ------------------------------------------- kernels under a mesh
-
-def test_matmul_stats_partitions_itself_under_a_mesh():
-    mesh = build_mesh(n_devices=4, tp=2)
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(64, 16), jnp.float32)
-    w = jnp.asarray(rng.randn(16, 256), jnp.float32)
-    c = jnp.asarray(rng.randn(256), jnp.float32)
-    want = fused.matmul_stats(x, w, c, interpret=True)
-
-    def under_mesh(x, w, c):
-        with kernel_mesh(mesh):
-            return fused.matmul_stats(x, w, c, interpret=True)
-
-    sh = lambda *spec: NamedSharding(mesh, P(*spec))
-    got = jax.jit(under_mesh, in_shardings=(
-        sh("data", None), sh(None, "model"), sh("model")))(x, w, c)
-    assert "shard_map" in str(jax.make_jaxpr(under_mesh)(x, w, c))
-    assert got[0].sharding.spec == P("data", "model")
-    for g, r in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
-                                   rtol=1e-5, atol=1e-4)
-
 
 def test_flash_op_partitions_itself_under_a_mesh(monkeypatch):
     mesh = build_mesh(n_devices=4, tp=2)

@@ -515,10 +515,10 @@ def _seed_db(tmp_path, monkeypatch):
               bytes_accessed=1e6, shapes=[(8, 8)], dtypes=["float32"],
               backend="cpu", block_kind="fc_act",
               program="trainer.step")
-    db.record("kernel", "matmul_stats", wall_s=0.002, flops=5e8,
-              bytes_accessed=2e6, shapes=[(128, 64)],
+    db.record("kernel", "flash_attention_fwd", wall_s=0.002, flops=5e8,
+              bytes_accessed=2e6, shapes=[(1, 256, 1, 32)],
               dtypes=["float32"], backend="cpu",
-              block_config={"bm": 128, "grid_m": 4})
+              block_config={"block_q": 128, "block_k": 256})
     db.record("program", "trainer.step", wall_s=0.013, flops=1.5e9,
               bytes_accessed=1.03e8, shapes=[(8, 8)],
               dtypes=["float32"], backend="cpu")
@@ -543,7 +543,7 @@ def test_perf_top_ranks_worst_first(tmp_path, monkeypatch, capsys):
     assert perf_top.main([str(tmp_path)]) == 0
     out = capsys.readouterr().out
     assert "worst MFU: slow_block" in out
-    assert "bm=128" in out                 # block config is visible
+    assert "block_q=128" in out            # block config is visible
 
 
 def test_perf_top_kind_filter_and_missing_path(tmp_path, monkeypatch,
@@ -553,8 +553,8 @@ def test_perf_top_kind_filter_and_missing_path(tmp_path, monkeypatch,
     assert perf_top.main([str(tmp_path), "--json", "--kind",
                           "kernel"]) == 0
     doc = json.loads(capsys.readouterr().out)
-    assert [e["name"] for e in doc["entries"]] == ["matmul_stats"]
-    assert doc["entries"][0]["block_config"]["bm"] == 128
+    assert [e["name"] for e in doc["entries"]] == ["flash_attention_fwd"]
+    assert doc["entries"][0]["block_config"]["block_q"] == 128
     assert perf_top.main([str(tmp_path / "nope")]) == 2
     capsys.readouterr()
 

@@ -21,7 +21,7 @@ def _plan(sym, layout="NHWC", is_train=True):
 
 
 def _resnet_style_net(num_classes=10, act="relu", bn_kwargs=None):
-    """conv3x3+BN+act trunk -> pallas-eligible conv1x1+BN+act ->
+    """conv3x3+BN+act trunk -> conv1x1+BN+act ->
     residual add (the trunk terminal has two consumers) -> FC+relu head."""
     bn_kwargs = bn_kwargs or {}
     data = mx.sym.Variable("data")
@@ -48,11 +48,8 @@ def test_plan_resnet_style_blocks():
     plan = _plan(_resnet_style_net())
     s = plan.summary()
     assert s["kinds"] == {"conv_bn_act": 2, "fc_act": 1}
-    # conv1 is 1x1/s1/p0/no-bias under NHWC train stats, the block the
-    # matmul-with-stats kernel was written for: it is an XLA region like
-    # conv0's (the kernel lost on every shape on the chip, PR 25)
+    # no block lowers to a kernel; the benchmark reads the key
     assert s["pallas_blocks"] == 0
-    assert not any(b.pallas for b in plan.blocks.values())
     by_kind = {b.kind: b for b in plan.blocks.values()}
     assert by_kind["conv_bn_act"].terminal.name in ("act0", "act1")
     # interior edges: 2 per conv_bn_act, 1 per fc_act = 5; plus the
@@ -68,15 +65,6 @@ def test_plan_longest_chain_wins():
     plan = _plan(_resnet_style_net())
     kinds = {b.kind for b in plan.blocks.values()}
     assert "bn_act" not in kinds
-
-
-def test_plan_eval_mode_disables_pallas():
-    plan = _plan(_resnet_style_net(), is_train=False)
-    s = plan.summary()
-    # same blocks as the training plan, and no kernel here either
-    assert s["kinds"] == {"conv_bn_act": 2, "fc_act": 1}
-    assert s["pallas_blocks"] == 0
-    assert not s["is_train"]
 
 
 def test_plan_conv_multi_consumer_falls_back_to_bn_act():
@@ -433,7 +421,7 @@ def test_fused_conv_bn_region_bf16_biased_grads():
 
     def loss(x, w, b):
         out, _mm, _mv = F.fused_block_conv_bn_act(
-            conv_attrs, bn_attrs, "NHWC", True, "relu", False,
+            conv_attrs, bn_attrs, "NHWC", True, "relu",
             x, w, b, gamma, beta, mm, mv)
         return jnp.sum(out.astype(jnp.float32))
 
@@ -627,11 +615,9 @@ def test_train_step_names_its_phases_and_blocks(monkeypatch):
     the forward / backward / optimizer scopes, the fused block's kind
     round the block's convolution, and fixed module names.  Lowered for
     the TPU from here: the 1x1 block is an XLA region there too, so the
-    step holds no ``mxtpu_matmul_stats`` (tests/test_chip_compile.py
-    keeps the kernel's own name)."""
+    step holds no custom call, by the removed kernel's name or any."""
     import jax
     from mxnet_tpu import context
-    from mxnet_tpu.ops import fused
     from mxnet_tpu.parallel import trainer as trainer_mod
     monkeypatch.setattr(context, "on_tpu", lambda: True)
     t = _kernel_block_trainer()
@@ -643,7 +629,8 @@ def test_train_step_names_its_phases_and_blocks(monkeypatch):
                  trainer_mod.SCOPE_OPT, "mxtpu.block.conv_bn_act",
                  "mxtpu.block.fc_act"):
         assert name in text, name
-    assert fused.MATMUL_STATS not in text and "tpu_custom_call" not in text
+    assert "mxtpu_matmul_stats" not in text \
+        and "tpu_custom_call" not in text
     assert t.fusion_summary()["pallas_blocks"] == 0
     # the block's convolution sits inside the forward, inside its block
     assert ("mxtpu.fwd/jvp(mxtpu.block.conv_bn_act)/conv_general_dilated"

@@ -175,102 +175,73 @@ def test_run_steps_lr_schedule_advances_per_inner_step():
             rtol=1e-5, atol=1e-6, err_msg=name)
 
 
-# ------------------------------------- phase-decomposed stride-2 bwd
-@pytest.mark.parametrize("cfg", [
-    # (H, W, Cin, Cout, kernel, pad)
-    (56, 56, 8, 16, (3, 3), (1, 1)),    # resnet stage-transition conv
-    (28, 28, 8, 16, (1, 1), (0, 0)),    # downsample shortcut
-    (16, 16, 4, 8, (7, 7), (3, 3)),     # stem form
-    (12, 10, 3, 4, (5, 3), (2, 0)),     # mixed kernel/pad
-    (8, 8, 3, 4, (2, 2), (0, 0)),       # even kernel
-])
-def test_phase_bwd_dx_exact(cfg):
-    """Phase-decomposed backward-data of a stride-2 conv equals the
-    dilated-conv transpose, elementwise."""
-    h, w, cin, cout, kernel, pad = cfg
+# ------------------------------------------- one lowering of a conv
+@pytest.mark.parametrize("kernel,stride,pad", [
+    ((1, 1), (1, 1), (0, 0)),    # pointwise: no dot in its place
+    ((3, 3), (2, 2), (1, 1)),    # stride 2: no phase-split backward
+], ids=["1x1", "3x3s2"])
+def test_nhwc_convolution_is_one_conv_call(kernel, stride, pad):
+    """Under NHWC the Convolution op is one ``conv_general_dilated``
+    forward and XLA's own two transposes of it backward, whatever the
+    kernel and stride: no rewrite stands between the op and the call."""
+    from mxnet_tpu.ops.nn import image_layout
+    from mxnet_tpu.ops.registry import apply_op, get_op, OpContext
+    op = get_op("Convolution")
+    attrs = op.parse_attrs({"kernel": kernel, "stride": stride, "pad": pad,
+                            "num_filter": 16, "no_bias": True})
+
+    def loss(x, w):
+        with image_layout("NHWC"):
+            (y,) = apply_op(op, attrs, OpContext(is_train=True), x, w)
+        return jnp.sum(y * y)
+
+    x = jax.ShapeDtypeStruct((2, 8, 8, 4), jnp.float32)
+    w = jax.ShapeDtypeStruct((16, 4) + kernel, jnp.float32)
+    fwd = str(jax.make_jaxpr(loss)(x, w))
+    both = str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(x, w))
+    assert fwd.count("conv_general_dilated") == 1
+    assert both.count("conv_general_dilated") == 3
+    assert "dot_general" not in both and "custom_vjp" not in both
+
+
+# ------------------------------------------- space-to-depth stem conv
+def test_stem_space_to_depth_matches():
+    """The 4x4/s1 space-to-depth rewrite of the 7x7/s2 stem trains
+    identically to the direct conv (f32); the stem reads the data
+    variable directly, with no input BatchNorm."""
+    data = mx.sym.Variable("data")
+    net = mx.sym.Convolution(data, kernel=(7, 7), stride=(2, 2),
+                             pad=(3, 3), num_filter=8, no_bias=True,
+                             name="conv0")
+    net = mx.sym.BatchNorm(net, name="bn0", fix_gamma=False)
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.Pooling(net, global_pool=True, pool_type="avg")
+    net = mx.sym.Flatten(net)
+    net = mx.sym.FullyConnected(net, num_hidden=10, name="fc")
+    sym = mx.sym.SoftmaxOutput(net, name="softmax")
+
+    def make(stem):
+        mesh = build_mesh(tp=1)
+        np.random.seed(11)
+        return ShardedTrainer(
+            sym, mesh,
+            data_shapes={"data": (8, 3, 16, 16)},
+            label_shapes={"softmax_label": (8,)},
+            layout="NHWC", dtype="float32", seed=5, learning_rate=0.1,
+            momentum=0.9, stem_space_to_depth=stem)
+
+    t_ref, t_s2d = make(False), make(True)
     rng = np.random.RandomState(3)
-    x = jnp.asarray(rng.randn(2, h, w, cin).astype(np.float32))
-    wt = jnp.asarray(
-        rng.randn(kernel[0], kernel[1], cin, cout).astype(np.float32))
-    pads = tuple((p, p) for p in pad)
-
-    def conv(xx, ww):
-        dn = jax.lax.conv_dimension_numbers(xx.shape, ww.shape,
-                                            ("NHWC", "HWIO", "NHWC"))
-        return jax.lax.conv_general_dilated(
-            xx, ww, window_strides=(2, 2), padding=pads,
-            dimension_numbers=dn)
-
-    y, vjp = jax.vjp(conv, x, wt)
-    dy = jnp.asarray(rng.randn(*y.shape).astype(np.float32))
-    dx_true, dw_true = vjp(dy)
-
-    f = fused._phase_bwd_conv(pads)
-    y2, vjp2 = jax.vjp(f, x, wt)
-    dx_ph, dw_ph = vjp2(dy)
-
-    np.testing.assert_allclose(np.asarray(y2), np.asarray(y), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(dw_ph), np.asarray(dw_true),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(dx_ph), np.asarray(dx_true),
-                               rtol=1e-4, atol=1e-5)
-
-
-def test_phase_bwd_trainer_parity():
-    """ResNet-18 (real stride-2 sites) trains identically with and
-    without the phase-decomposed backward."""
-    from mxnet_tpu import models
-    mesh = build_mesh(tp=1)
-
-    def make(enable):
-        np.random.seed(23)
-        net = models.get_model("resnet18", num_classes=10,
-                               image_shape="3,32,32")
-        return ShardedTrainer(
-            net, mesh, data_shapes={"data": (8, 3, 32, 32)},
-            label_shapes={"softmax_label": (8,)},
-            layout="NHWC", seed=5, learning_rate=0.1, momentum=0.9,
-            strided_bwd_phase=enable)
-
-    a, b = make(False), make(True)
-    rng = np.random.RandomState(0)
-    batch = {"data": rng.uniform(-1, 1, (8, 3, 32, 32)).astype("f"),
+    batch = {"data": rng.randn(8, 3, 16, 16).astype("f"),
              "softmax_label": rng.randint(0, 10, 8).astype("f")}
-    for _ in range(2):
-        la, lb = float(a.step(batch)), float(b.step(batch))
-        assert np.isclose(la, lb, rtol=1e-4)
-    for name in a.params:
+    for t in (t_ref, t_s2d):
+        b = t.put_batch(batch)
+        t.step(b)
+        t.step(b)
+    for k in t_ref.params:
         np.testing.assert_allclose(
-            np.asarray(a.params[name]), np.asarray(b.params[name]),
-            rtol=5e-4, atol=5e-5, err_msg=name)
-
-
-def test_conv1x1_as_dot_parity():
-    """Pointwise convs lowered as dots train identically to the conv
-    lowering (ResNet-50's bottleneck blocks are mostly 1x1 convs)."""
-    from mxnet_tpu import models
-    mesh = build_mesh(tp=1)
-
-    def make(enable):
-        np.random.seed(53)
-        net = models.get_model("resnet50", num_classes=10,
-                               image_shape="3,64,64")
-        return ShardedTrainer(
-            net, mesh, data_shapes={"data": (8, 3, 64, 64)},
-            label_shapes={"softmax_label": (8,)},
-            layout="NHWC", seed=5, learning_rate=0.1, momentum=0.9,
-            conv1x1_as_dot=enable)
-
-    a, b = make(False), make(True)
-    rng = np.random.RandomState(0)
-    batch = {"data": rng.uniform(-1, 1, (8, 3, 64, 64)).astype("f"),
-             "softmax_label": rng.randint(0, 10, 8).astype("f")}
-    la, lb = float(a.step(batch)), float(b.step(batch))
-    assert np.isclose(la, lb, rtol=5e-4)
-    for name in a.params:
-        np.testing.assert_allclose(
-            np.asarray(a.params[name]), np.asarray(b.params[name]),
-            rtol=5e-4, atol=5e-5, err_msg=name)
+            np.asarray(t_s2d.params[k]), np.asarray(t_ref.params[k]),
+            rtol=1e-4, atol=1e-5, err_msg=k)
 
 
 # ------------------------------------------- raw-uint8 device ingest

@@ -34,7 +34,7 @@ def _isolated_plan_cache():
 
 
 def _conv_net(num_classes=10):
-    """conv3x3+BN+relu -> pallas-eligible conv1x1+BN+relu -> FC+relu
+    """conv3x3+BN+relu -> conv1x1+BN+relu -> FC+relu
     -> FC head: one chain of every matchable kind but bn_act."""
     d = mx.sym.Variable("data")
     n = mx.sym.Convolution(d, kernel=(3, 3), pad=(1, 1), num_filter=8,
@@ -120,16 +120,14 @@ def test_decision_conv_bn_split():
         decisions={"chains": {cid: "conv_bn"}})
     blk = next(b for b in p.blocks.values() if b.kind == "conv_bn")
     assert blk.name == "b0" and blk.chain == cid and blk.act is None
-    # a split of the 1x1 chain gets the lowering a naturally-matched
-    # conv_bn chain would get: the XLA region (no block takes the
-    # matmul-with-stats kernel: fusion._pallas_eligible)
+    # a split of the 1x1 chain under NHWC is a conv_bn block too
     g_nhwc = _greedy_plan(sym, layout="NHWC")
     cid1 = _chain_of(g_nhwc, "conv_bn_act", "r1")
     p2 = fusion.plan_block_fusion(
         sym._topo(), sym._entries, layout="NHWC", record=False,
         decisions={"chains": {cid1: "conv_bn"}})
     blk2 = next(b for b in p2.blocks.values() if b.kind == "conv_bn")
-    assert blk2.name == "b1" and not blk2.pallas
+    assert blk2.name == "b1" and blk2.chain == cid1
 
 
 def test_decision_bn_act_split():
@@ -147,53 +145,39 @@ def test_decision_bn_act_split():
 
 def test_decision_layout_override_accounting_and_pallas():
     """A region pinned to a non-ambient layout pays 2 explicit
-    relayout edges and loses adjacency credit; an NHWC override of the
-    1x1 chain in an NCHW trace opens no Pallas leg."""
+    relayout edges and loses adjacency credit; the summary still
+    counts no kernel block."""
     sym = _conv_net()
     g = _greedy_plan(sym, layout="NCHW")
-    assert all(not b.pallas for b in g.blocks.values())
     assert g.adjacent_edges == 1 and g.relayout_edges_added == 0
     cid = _chain_of(g, "conv_bn_act", "r1")   # the 1x1 chain
     p = fusion.plan_block_fusion(
         sym._topo(), sym._entries, layout="NCHW", record=False,
         decisions={"layouts": {cid: "NHWC"}})
     blk = next(b for b in p.blocks.values() if b.chain == cid)
-    assert blk.layout == "NHWC" and not blk.pallas
+    assert blk.layout == "NHWC"
     assert p.relayout_edges_added == 2
     assert p.adjacent_edges == 0      # boundary layouts now differ
     s = p.summary()
     assert s["relayout_edges_added"] == 2 and s["searched"]
-
-
-def test_decision_pallas_veto():
-    """No block is a Pallas block, so a committed veto (an entry from
-    before PR 25) overrides nothing and the search is offered none."""
-    sym = _conv_net()
-    g = _greedy_plan(sym, layout="NHWC")
-    cid = _chain_of(g, "conv_bn_act", "r1")
-    blk = next(b for b in g.blocks.values() if b.chain == cid)
-    assert not blk.pallas
-    p = fusion.plan_block_fusion(
-        sym._topo(), sym._entries, layout="NHWC", record=False,
-        decisions={"pallas": {cid: 0}})
-    blk = next(b for b in p.blocks.values() if b.chain == cid)
-    assert not blk.pallas and p.overrides == 0
-    _greedy, moves = plansearch.chain_moves(
-        sym._topo(), sym._entries, layout="NHWC")
-    assert not [m for m in moves if m[0] == "pallas"]
+    assert s["pallas_blocks"] == 0
 
 
 def test_stale_decisions_degrade_to_fuse():
-    """Unknown chain ids and ineligible choices read as greedy — a
-    stale committed entry must never break a plan."""
+    """Unknown chain ids, ineligible choices and a decision category
+    this tree no longer has (an entry committed before PR 28 may carry
+    ``pallas``) read as greedy — a stale committed entry must never
+    break a plan."""
     sym = _conv_net()
     g = _greedy_plan(sym)
     fc_cid = _chain_of(g, "fc_act")
     p = fusion.plan_block_fusion(
         sym._topo(), sym._entries, record=False,
-        decisions={"chains": {"9999": "off", fc_cid: "conv_bn"}})
+        decisions={"chains": {"9999": "off", fc_cid: "conv_bn"},
+                   "pallas": {_chain_of(g, "conv_bn_act", "r1"): 0}})
     assert sorted(b.kind for b in p.blocks.values()) == \
         sorted(b.kind for b in g.blocks.values())
+    assert p.overrides == 0
 
 
 def test_adjacent_overridden_regions_claim_no_elimination():
@@ -396,7 +380,7 @@ def test_executor_bind_picks_up_committed_plan(tmp_path, monkeypatch):
 
 def test_executor_searched_vs_greedy_parity():
     """Forward + backward parity of a decision-transformed plan (chain
-    split + per-region layout override + pallas veto) against greedy —
+    split + per-region layout override) against greedy —
     the plan search may only change WHERE the math runs, never what it
     computes."""
     sym = _conv_net()
@@ -495,8 +479,8 @@ def test_perf_top_suggest_plan_untuned_row(tmp_path):
     _write_costdb(db)
     # a cache with SOME entry (not graph_plan) so --cache is readable
     c = autotune.TuneCache()
-    c.put("matmul_stats", [(8, 8), (8, 8)], ["float32"] * 2,
-          {"bm": 8}, wall_s=1e-4, persist=False)
+    c.put("flash_attention_fwd", [(1, 256, 1, 32)], ["float32"],
+          {"block_q": 64}, wall_s=1e-4, persist=False)
     with open(cache / "tunecache-1.jsonl", "w") as f:
         f.write(json.dumps(c.entries()[0], default=repr) + "\n")
     res = _perf_top([str(db), "--suggest", "--cache", str(cache),
@@ -585,7 +569,7 @@ def test_perf_top_suggest_bad_cache_is_usage_error(tmp_path):
 def _tiny_cost_model():
     recs = [{"wall_s": 10.0 ** (-6 + i % 3), "flops": 10.0 ** (6 + i),
              "bytes_accessed": 10.0 ** (5 + i),
-             "block_config": {"bm": 2 ** (3 + i % 4)}}
+             "block_config": {"block_q": 2 ** (3 + i % 4)}}
             for i in range(12)]
     return autotune.CostModel().fit(recs)
 

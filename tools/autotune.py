@@ -4,24 +4,17 @@ fit the learned cost model over the costdb ground truth.
 
 The driver for :mod:`mxnet_tpu.autotune` (ROADMAP item 2).  Modes:
 
-**Per-op tuning** — enumerate + measure candidates for explicit keys::
+**Tuning** — enumerate + measure candidates for explicit keys::
 
     python tools/autotune.py --op flash_fwd  --shapes 2x2176x8x64,2x3200x8x64
     python tools/autotune.py --op flash_bwd  --shapes 2x2176x8x64 --causal
-    python tools/autotune.py --op matmul_stats --shapes 25088x64x256
 
-Shapes are ``BxTxHxD`` for flash, ``MxKxN`` for matmul_stats.  Winners
+Shapes are ``BxTxHxD``.  Winners
 commit to the persistent tuning cache (``--cache`` or
 ``MXNET_TPU_TUNE_CACHE``); every candidate measurement also lands in
 the cost database (``--costdb`` or ``MXNET_TPU_COSTDB``) as the cost
 model's training data.  Keys already cached are skipped (all-hit
 second runs are the CI contract) unless ``--force``.
-
-**Zoo-model mode** — tune every tunable kernel a model's fusion plan
-instantiates (the Pallas conv-block GEMMs and, where present,
-attention kernels), at the exact shapes the trace will dispatch::
-
-    python tools/autotune.py --model resnet50 --batch 32
 
 **Cost model** — fit/report::
 
@@ -53,14 +46,13 @@ DEFAULT_FLASH_SHAPES = ((1, 2048, 2, 64), (1, 2176, 2, 64),
                         (1, 3200, 2, 64))
 
 
-def _parse_shapes(spec, rank, what):
+def _parse_shapes(spec):
     out = []
     for part in spec.split(","):
         dims = tuple(int(x) for x in part.lower().split("x") if x)
-        if len(dims) != rank:
-            raise ValueError("%s shape %r must have %d dims (%s)"
-                             % (what, part, rank,
-                                "BxTxHxD" if rank == 4 else "MxKxN"))
+        if len(dims) != 4:
+            raise ValueError("flash shape %r must have 4 dims (BxTxHxD)"
+                             % (part,))
         out.append(dims)
     return out
 
@@ -71,8 +63,7 @@ def _cached(op, shapes, dtypes, extra=None):
 
 
 def _runner(args, say, results, skipped, failed):
-    """The shared probe-cache / skip / tune / report-failure step —
-    ONE implementation serving the per-op and zoo sweeps."""
+    """The probe-cache / skip / tune / report-failure step."""
     def run(label, probe, fn):
         entry = None if args.force else probe()
         if entry is not None:
@@ -106,128 +97,22 @@ def tune_keys(args, say):
     results, skipped, failed = [], [], []
     run = _runner(args, say, results, skipped, failed)
 
-    if args.op in ("flash_fwd", "flash_bwd"):
-        which = args.op.rsplit("_", 1)[1]
-        shapes = (_parse_shapes(args.shapes, 4, "flash") if args.shapes
-                  else list(DEFAULT_FLASH_SHAPES))
-        for shp in shapes:
-            op = "flash_attention_%s" % which
-            label = "%s %s causal=%d" % (op, "x".join(map(str, shp)),
-                                         args.causal)
-            run(label,
-                lambda shp=shp, op=op: _cached(
-                    op, [shp], [args.dtype],
-                    extra={"causal": bool(args.causal)}),
-                lambda shp=shp: autotune.tune_flash(
-                    shp, dtype=args.dtype, causal=args.causal,
-                    which=which, repeats=args.repeats,
-                    max_candidates=args.max_candidates,
-                    interpret=args.interpret))
-    elif args.op == "matmul_stats":
-        for (m, k, n) in _parse_shapes(args.shapes, 3, "matmul"):
-            label = "matmul_stats %dx%dx%d" % (m, k, n)
-            run(label,
-                lambda m=m, k=k, n=n: _cached(
-                    "matmul_stats", [(m, k), (k, n)],
-                    [args.dtype, args.dtype]),
-                lambda m=m, k=k, n=n: autotune.tune_matmul_stats(
-                    m, k, n, dtype=args.dtype, repeats=args.repeats,
-                    max_candidates=args.max_candidates,
-                    interpret=args.interpret))
-    return results, skipped, failed
-
-
-def tune_model(args, say):
-    """Zoo-model mode: tune every tunable kernel the model's fusion
-    plan instantiates, at the exact trace-time shapes."""
-    from mxnet_tpu import autotune, models
-    from mxnet_tpu.analysis import fusion, infer_node_shapes
-
-    net = models.get_model(args.model, num_classes=args.num_classes)
-    data_shape = {"mlp": (args.batch, 784),
-                  "lenet": (args.batch, 1, 28, 28)}.get(
-        args.model, (args.batch, 3, 224, 224))
-    topo, node_shapes = infer_node_shapes(
-        net, {"data": data_shape, "softmax_label": (args.batch,)})
-    plan = fusion.plan_block_fusion(topo, net._entries,
-                                    layout=args.layout, record=False)
-    results, skipped, failed = [], [], []
-    run = _runner(args, say, results, skipped, failed)
-
-    gemms, blocks, flashes = [], [], []
-    for blk in plan.blocks.values():
-        if not blk.pallas or blk.conv is None:
-            continue
-        src, idx = blk.conv.inputs[0]
-        in_sh = node_shapes.get(id(src))
-        if not in_sh or len(in_sh) <= idx:
-            continue
-        nb, c, h, w = in_sh[idx]          # reference NCHW inference
-        nout = int(blk.conv.attrs.get("num_filter"))
-        if args.layout == "NHWC":
-            x_shape = (nb, h, w, c)
-        else:
-            continue                      # only the NHWC leg has Pallas
-        gemms.append((nb * h * w, c, nout))
-        blocks.append((blk.kind, blk.act, x_shape, (nout, c, 1, 1)))
-    for node in topo:
-        if node.is_variable or node.op is None:
-            continue
-        if node.op.name in ("_contrib_FlashAttention",
-                            "_contrib_RingAttention"):
-            src, idx = node.inputs[0]
-            sh = node_shapes.get(id(src))
-            if sh and len(sh) > idx and len(sh[idx]) == 4:
-                # the NODE's causal attr, not the CLI flag: the cache
-                # key must match what the trace will look up
-                flashes.append((tuple(sh[idx]),
-                                bool(node.attrs.get("causal", False))))
-
-    say("autotune: model %s -> %d conv-block GEMM(s), %d fused "
-        "block(s), %d attention shape(s)"
-        % (args.model, len(set(gemms)), len(blocks),
-           len(set(flashes))))
-
-    for (m, k, n) in sorted(set(gemms)):
-        label = "matmul_stats %dx%dx%d" % (m, k, n)
-        if n % 128 or k % 8:
-            say("autotune: %-44s skipped (no pallas path)" % label)
-            continue
+    which = args.op.rsplit("_", 1)[1]
+    shapes = (_parse_shapes(args.shapes) if args.shapes
+              else list(DEFAULT_FLASH_SHAPES))
+    for shp in shapes:
+        op = "flash_attention_%s" % which
+        label = "%s %s causal=%d" % (op, "x".join(map(str, shp)),
+                                     args.causal)
         run(label,
-            lambda m=m, k=k, n=n: _cached(
-                "matmul_stats", [(m, k), (k, n)],
-                [args.dtype, args.dtype]),
-            lambda m=m, k=k, n=n: autotune.tune_matmul_stats(
-                m, k, n, dtype=args.dtype, repeats=args.repeats,
+            lambda shp=shp, op=op: _cached(
+                op, [shp], [args.dtype],
+                extra={"causal": bool(args.causal)}),
+            lambda shp=shp: autotune.tune_flash(
+                shp, dtype=args.dtype, causal=args.causal,
+                which=which, repeats=args.repeats,
                 max_candidates=args.max_candidates,
                 interpret=args.interpret))
-    for (kind, act, x_shape, w_shape) in sorted(set(blocks)):
-        label = "block:%s %s" % (kind, "x".join(map(str, x_shape)))
-        run(label,
-            lambda kind=kind, act=act, x_shape=x_shape,
-            w_shape=w_shape: _cached(
-                "block:%s" % kind, [x_shape, w_shape],
-                [args.dtype, args.dtype],
-                extra={"layout": args.layout, "act": act or ""}),
-            lambda kind=kind, act=act, x_shape=x_shape,
-            w_shape=w_shape: autotune.tune_conv_block(
-                x_shape, w_shape, kind=kind, act=act,
-                layout=args.layout, dtype=args.dtype,
-                repeats=args.repeats, interpret=args.interpret))
-    for (shp, causal) in sorted(set(flashes)):
-        for which in ("fwd", "bwd"):
-            label = "flash_attention_%s %s causal=%d" % (
-                which, "x".join(map(str, shp)), causal)
-            run(label,
-                lambda shp=shp, which=which, causal=causal: _cached(
-                    "flash_attention_%s" % which, [shp], [args.dtype],
-                    extra={"causal": causal}),
-                lambda shp=shp, which=which, causal=causal:
-                autotune.tune_flash(
-                    shp, dtype=args.dtype, causal=causal,
-                    which=which, repeats=args.repeats,
-                    max_candidates=args.max_candidates,
-                    interpret=args.interpret))
     return results, skipped, failed
 
 
@@ -313,19 +198,11 @@ def main(argv=None):
         prog="autotune",
         description="tune Pallas block configs; fit/report the "
                     "learned cost model")
-    ap.add_argument("--op", choices=("flash_fwd", "flash_bwd",
-                                     "matmul_stats"))
+    ap.add_argument("--op", choices=("flash_fwd", "flash_bwd"))
     ap.add_argument("--shapes", default=None,
-                    help="comma-separated BxTxHxD (flash) or MxKxN "
-                         "(matmul_stats); flash defaults to the "
+                    help="comma-separated BxTxHxD; defaults to the "
                          "bench + ADVICE-cliff set")
     ap.add_argument("--causal", action="store_true")
-    ap.add_argument("--model", default=None,
-                    help="zoo-model mode: tune every tunable kernel "
-                         "this model's fusion plan instantiates")
-    ap.add_argument("--batch", type=int, default=8)
-    ap.add_argument("--num-classes", type=int, default=10)
-    ap.add_argument("--layout", default="NHWC")
     ap.add_argument("--dtype", default="float32")
     ap.add_argument("--repeats", type=int, default=3,
                     help="min-of-N timing repeats per candidate")
@@ -353,13 +230,9 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true", dest="as_json")
     args = ap.parse_args(argv)
 
-    if not (args.op or args.model or args.fit_model or args.report):
+    if not (args.op or args.fit_model or args.report):
         # argparse.error raises SystemExit(2)
-        ap.error("nothing to do: give --op, --model, --fit-model or "
-                 "--report")
-    if args.op == "matmul_stats" and not args.shapes:
-        ap.error("--op matmul_stats needs --shapes MxKxN")
-
+        ap.error("nothing to do: give --op, --fit-model or --report")
     if args.cache:
         os.environ["MXNET_TPU_TUNE_CACHE"] = args.cache
     if args.costdb:
@@ -375,11 +248,8 @@ def main(argv=None):
     doc = {"schema": "mxtpu-autotune/1", "tuned": 0, "cached": 0,
            "failed": 0, "keys": []}
     ok = True
-    if args.op or args.model:
-        if args.model:
-            results, skipped, failed = tune_model(args, say)
-        else:
-            results, skipped, failed = tune_keys(args, say)
+    if args.op:
+        results, skipped, failed = tune_keys(args, say)
         doc["tuned"] = len(results)
         doc["cached"] = len(skipped)
         doc["failed"] = len(failed)

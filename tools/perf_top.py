@@ -129,17 +129,12 @@ def _cache_entries(cache_path):
 
 def _match_entry(rec, entries):
     """The tuning-cache entry for a costdb block/kernel record's key:
-    op name + shapes + dtypes must agree (block records match their
-    ``block:<kind>`` key by traced shapes)."""
+    op name + shapes + dtypes must agree."""
     name = str(rec.get("name"))
-    kind = rec.get("kind")
     shapes = json.dumps(rec.get("shapes") or [])
     dtypes = json.dumps([str(d) for d in (rec.get("dtypes") or [])])
-    want_ops = {name}
-    if kind == "block" and rec.get("block_kind"):
-        want_ops.add("block:%s" % rec["block_kind"])
     for e in entries:
-        if e["op"] in want_ops \
+        if e["op"] == name \
                 and json.dumps(e.get("shapes") or []) == shapes \
                 and json.dumps([str(d) for d in
                                 (e.get("dtypes") or [])]) == dtypes:
