@@ -187,7 +187,6 @@ def bench_trainer(mesh, batch=128):
 def phase_fused_train(dev, batch=128, scan=10, steps=20):
     import jax
     from mxnet_tpu.parallel import build_mesh
-    np.random.seed(0)
     t0 = time.perf_counter()
     trainer = bench_trainer(build_mesh(n_devices=1), batch)
     build_s = time.perf_counter() - t0
@@ -267,7 +266,6 @@ def flash_compare(shape, causal=True):
 def phase_flash(dev, layers=4, steps=3):
     sys.path.insert(0, os.path.join(ROOT, "examples", "transformer"))
     from train_lm import build_bench_trainer
-    np.random.seed(0)
     t0 = time.perf_counter()
     trainer, staged = build_bench_trainer(layers=layers)
     losses = [float(trainer.step(staged)) for _ in range(steps)]
@@ -345,7 +343,6 @@ def phase_four_chips(steps=10, batch=128):
     x, y = image_batch(1, batch)
 
     def run(mesh):
-        np.random.seed(0)
         trainer = bench_trainer(mesh, batch)
         staged = trainer.put_batch({"data": x, "softmax_label": y})
         losses = [float(trainer.step(staged)) for _ in range(steps)]

@@ -3,6 +3,13 @@
 Reference: ``python/mxnet/initializer.py`` (660 L) — registry of initializers
 dispatched by parameter-name pattern; ``InitDesc`` carries the name + attrs
 (``__init__`` override per variable).
+
+One dispatch, two places to draw.  ``init(desc, arr)`` fills a host array
+(``Module``, the executor): numpy's global generator, float64 cast to the
+array's dtype, the reference's numbers.  ``rule_for(init, desc)`` names the
+rule without running it, and ``draw(rule, desc, shape, key)`` runs a rule
+marked ``traceable`` on a ``jax.random`` key instead, float32 and under a
+trace: ``ShardedTrainer`` draws its initial state that way, on the device.
 """
 from __future__ import annotations
 
@@ -30,6 +37,73 @@ class InitDesc(str):
         return ret
 
 
+def traceable(rule):
+    """Mark a slot rule as one that can be drawn under a trace: it
+    assigns ``arr[:]`` once, from constants and from ``_rng(arr)``, and
+    reads nothing of ``arr`` but its shape and nothing of ``desc`` (one
+    trace serves every parameter of that rule and shape).  ``draw``
+    runs such a rule on a jax key; a rule without the mark (a user's
+    ``_init_weight`` written against ``np.random``, an SVD, a loop
+    over elements) only ever fills a host array."""
+    rule.traceable = True
+    return rule
+
+
+class _Traced:
+    """What a ``traceable`` rule fills inside ``draw``: a shape, the
+    float32 value the rule assigned, and ``np.random``'s ``uniform`` /
+    ``normal`` over one ``jax.random`` key (a rule draws once, so the
+    key is used once)."""
+
+    def __init__(self, shape, key):
+        self.shape = tuple(shape)
+        self._key = key
+        self.value = None
+
+    def __setitem__(self, index, value):
+        import jax.numpy as jnp
+        if index != slice(None):
+            raise MXNetError("a traceable rule assigns arr[:] whole")
+        self.value = jnp.broadcast_to(
+            jnp.asarray(value, jnp.float32), self.shape)
+
+    def uniform(self, low, high, size):
+        import jax
+        return jax.random.uniform(self._key, size, "float32",
+                                  float(low), float(high))
+
+    def normal(self, loc, scale, size):
+        import jax
+        return float(loc) + float(scale) * jax.random.normal(
+            self._key, size, "float32")
+
+
+def _rng(arr):
+    """Where a rule draws for ``arr``: the traced value's own key, else
+    numpy's global generator (float64, as the reference)."""
+    return arr if isinstance(arr, _Traced) else np.random
+
+
+def rule_for(init, desc):
+    """The callable ``(desc, arr)`` that ``init(desc, arr)`` runs for
+    this name, without running it: an ``Initializer``'s slot rule, the
+    rule of the ``Mixed`` pattern that matches, or ``init`` itself
+    where its call is its own code (``Load``, a subclass that
+    overrides ``__call__``, any callable)."""
+    if type(init).__call__ in (Initializer.__call__, Mixed.__call__):
+        return init.rule(desc)
+    return init
+
+
+def draw(rule, desc, shape, key):
+    """The float32 value a ``traceable`` ``rule`` gives a parameter of
+    ``shape``, as a function of a ``jax.random`` key: the same rule
+    that fills a host array, traced."""
+    arr = _Traced(shape, key)
+    rule(desc, arr)
+    return arr.value
+
+
 class Initializer:
     """Base initializer; callable on (InitDesc/name, NDArray)."""
 
@@ -40,36 +114,36 @@ class Initializer:
         return json.dumps([self.__class__.__name__.lower(), self._kwargs])
 
     def __call__(self, desc, arr):
+        self.rule(desc)(desc, arr)
+
+    def rule(self, desc):
+        """The slot rule this name gets (a bound method taking
+        ``(desc, arr)``): the one place the suffix rules live."""
         if not isinstance(desc, str):
             raise TypeError("desc must be a string or InitDesc")
         if isinstance(desc, InitDesc) and desc.attrs.get("__init__"):
-            create(desc.attrs["__init__"])._init_weight(desc, arr)
-            return
+            return create(desc.attrs["__init__"])._init_weight
         name = desc.lower()
         # name-pattern dispatch, matching the reference's suffix rules
         if name.endswith("upsampling"):
-            self._init_bilinear(desc, arr)
-        elif name.endswith("bias"):
-            self._init_bias(desc, arr)
-        elif name.endswith("gamma"):
-            self._init_gamma(desc, arr)
-        elif name.endswith("beta"):
-            self._init_beta(desc, arr)
-        elif name.endswith("weight"):
-            self._init_weight(desc, arr)
-        elif name.endswith("moving_mean") or name.endswith("running_mean"):
-            self._init_zero(desc, arr)
-        elif name.endswith("moving_var") or name.endswith("running_var"):
-            self._init_one(desc, arr)
-        elif name.endswith("moving_inv_var"):
-            self._init_zero(desc, arr)
-        elif name.endswith("moving_avg"):
-            self._init_zero(desc, arr)
-        elif name.endswith("_load"):
-            # an expert layer's load statistics (_contrib_TopKMoE's aux)
-            self._init_zero(desc, arr)
-        else:
-            self._init_default(desc, arr)
+            return self._init_bilinear
+        if name.endswith("bias"):
+            return self._init_bias
+        if name.endswith("gamma"):
+            return self._init_gamma
+        if name.endswith("beta"):
+            return self._init_beta
+        if name.endswith("weight"):
+            return self._init_weight
+        if name.endswith(("moving_var", "running_var")):
+            return self._init_one
+        if name.endswith(("moving_mean", "running_mean", "moving_inv_var",
+                          "moving_avg",
+                          # an expert layer's load statistics
+                          # (_contrib_TopKMoE's aux)
+                          "_load")):
+            return self._init_zero
+        return self._init_default
 
     # ---- slot initializers
     def _init_bilinear(self, _, arr):
@@ -83,18 +157,23 @@ class Initializer:
             weight[i] = (1 - abs(x / f - c)) * (1 - abs(y / f - c))
         arr[:] = weight.reshape(shape)
 
+    @traceable
     def _init_zero(self, _, arr):
         arr[:] = 0.0
 
+    @traceable
     def _init_one(self, _, arr):
         arr[:] = 1.0
 
+    @traceable
     def _init_bias(self, _, arr):
         arr[:] = 0.0
 
+    @traceable
     def _init_gamma(self, _, arr):
         arr[:] = 1.0
 
+    @traceable
     def _init_beta(self, _, arr):
         arr[:] = 0.0
 
@@ -157,10 +236,13 @@ class Mixed:
         self.map = list(zip([re.compile(p) for p in patterns], initializers))
 
     def __call__(self, name, arr):
+        self.rule(name)(name, arr)
+
+    def rule(self, name):
+        """The rule of the first pattern that matches ``name``."""
         for prog, init in self.map:
             if prog.match(name):
-                init(name, arr)
-                return
+                return rule_for(init, name)
         raise MXNetError(
             "Parameter name %s did not match any pattern. Consider adding a "
             "\".*\" pattern at the end with default Initializer." % name)
@@ -168,6 +250,7 @@ class Mixed:
 
 @register
 class Zero(Initializer):
+    @traceable
     def _init_weight(self, _, arr):
         arr[:] = 0.0
 
@@ -177,6 +260,7 @@ alias("zeros")(Zero)
 
 @register
 class One(Initializer):
+    @traceable
     def _init_weight(self, _, arr):
         arr[:] = 1.0
 
@@ -190,6 +274,7 @@ class Constant(Initializer):
         super().__init__(value=value)
         self.value = value
 
+    @traceable
     def _init_weight(self, _, arr):
         arr[:] = self.value
 
@@ -202,8 +287,9 @@ class Uniform(Initializer):
         super().__init__(scale=scale)
         self.scale = scale
 
+    @traceable
     def _init_weight(self, _, arr):
-        arr[:] = np.random.uniform(-self.scale, self.scale, arr.shape)
+        arr[:] = _rng(arr).uniform(-self.scale, self.scale, arr.shape)
 
 
 @register
@@ -212,8 +298,9 @@ class Normal(Initializer):
         super().__init__(sigma=sigma)
         self.sigma = sigma
 
+    @traceable
     def _init_weight(self, _, arr):
-        arr[:] = np.random.normal(0, self.sigma, arr.shape)
+        arr[:] = _rng(arr).normal(0, self.sigma, arr.shape)
 
 
 @register
@@ -246,6 +333,7 @@ class Xavier(Initializer):
         self.factor_type = factor_type
         self.magnitude = float(magnitude)
 
+    @traceable
     def _init_weight(self, name, arr):
         shape = arr.shape
         hw_scale = 1.0
@@ -267,9 +355,9 @@ class Xavier(Initializer):
             raise ValueError("Incorrect factor type")
         scale = np.sqrt(self.magnitude / factor)
         if self.rnd_type == "uniform":
-            arr[:] = np.random.uniform(-scale, scale, arr.shape)
+            arr[:] = _rng(arr).uniform(-scale, scale, arr.shape)
         elif self.rnd_type == "gaussian":
-            arr[:] = np.random.normal(0, scale, arr.shape)
+            arr[:] = _rng(arr).normal(0, scale, arr.shape)
         else:
             raise ValueError("Unknown random type")
 

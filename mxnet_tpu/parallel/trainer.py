@@ -44,7 +44,7 @@ import numpy as np
 
 from ..base import MXNetError
 from ..symbol import eval_graph, _classify_vars
-from ..initializer import Xavier, InitDesc
+from ..initializer import Xavier, InitDesc, rule_for, draw
 from ..ops.nn import image_layout
 from .. import optimizer as _opt_mod
 from ..telemetry.spans import span as _span
@@ -54,6 +54,11 @@ __all__ = ["ShardedTrainer"]
 #: ``jax.named_scope`` names inside the step program: forward and loss,
 #: the vjp, the optimizer update
 SCOPE_FWD, SCOPE_BWD, SCOPE_OPT = "mxtpu.fwd", "mxtpu.bwd", "mxtpu.opt"
+
+#: folded into ``PRNGKey(seed)`` for the initial draw, so that it shares
+#: no stream with the step's key, which is ``PRNGKey(seed)`` split once
+#: a dispatch; each parameter then folds in its index in ``_param_names``
+_INIT_STREAM = 0x696e6974   # "init"
 
 
 def _make_update_rule(opt):
@@ -185,6 +190,27 @@ class ShardedTrainer:
             `_make_update_rule` for the fused set.  ``learning_rate`` /
             ``momentum`` / ``weight_decay`` are convenience defaults merged
             into ``optimizer_params``.
+        initializer: what the float32 masters start from; default
+            ``Xavier(rnd_type="gaussian", factor_type="in", magnitude=2)``.
+            Which rule a name gets is the initializer's own dispatch
+            (suffix rules, a ``Variable``'s ``init=``, ``Mixed``'s
+            patterns).  Where that rule can be traced (the built-in
+            ``Zero``/``One``/``Constant``/``Uniform``/``Normal``/
+            ``Xavier``/``MSRAPrelu`` and the bias/gamma/beta/aux
+            constants) the parameter is drawn ON THE DEVICE, in float32
+            from ``jax.random``, by one jitted program under the
+            parameters' shardings, keyed by ``seed``: the host rule's
+            distribution (fans from the reference OIHW shape), not its
+            numbers, and ``np.random.seed`` does not move it.  Any other
+            rule (a subclass whose ``_init_weight`` is numpy code,
+            ``Load``, ``Orthogonal``, ``Bilinear``, ``LSTMBias``,
+            ``FusedRNN``) fills a host array from numpy's global
+            generator as it always did, parameter by parameter.
+        seed: two trainers of one graph and one ``seed`` start equal, on
+            one device or sharded over a mesh.  The initial draw uses
+            ``fold_in(PRNGKey(seed), "init")`` and then one ``fold_in``
+            a parameter by its index; the step's own key (dropout) is
+            ``PRNGKey(seed)`` split once a dispatch: no shared stream.
         dtype: compute dtype for activations/grads (master weights stay f32).
         tp_rules: {param_name: axis_index} — weight dims to shard over the
             'model' axis.  Default: classifier-style FullyConnected weights
@@ -203,7 +229,10 @@ class ShardedTrainer:
 
         The constructor is a ``trainer.build`` span (telemetry.spans)
         whose children are its phases in order: ``.graph``,
-        ``.init_params``, ``.place``, ``.plan``.
+        ``.init_params``, ``.place``, ``.plan``.  ``.init_params``'s
+        record carries what was drawn where: ``device_params`` /
+        ``device_bytes``, ``host_params`` / ``host_bytes`` and
+        ``host_names`` (at most eight).
         """
         from . import multihost
 
@@ -324,10 +353,10 @@ class ShardedTrainer:
                 symbol, mesh, data_shapes, label_shapes, optimizer,
                 optimizer_params, learning_rate, momentum, weight_decay,
                 tp_rules, strict)
-        with _span("trainer.build.init_params", category="trainer"):
-            host_params, host_aux = self._init_host_state(initializer)
+        with _span("trainer.build.init_params", category="trainer") as sp:
+            host_params, sp.attrs = self._init_state(initializer, seed)
         with _span("trainer.build.place", category="trainer"):
-            self._place_state(host_params, host_aux, seed)
+            self._place_state(host_params, seed)
         with _span("trainer.build.plan", category="trainer"):
             self._plan_step(strict)
 
@@ -604,42 +633,100 @@ class ShardedTrainer:
             n: NamedSharding(mesh, batch_spec(n))
             for n in self._input_names}
 
-    def _init_host_state(self, initializer):
+    def _init_state(self, initializer, seed):
         """``trainer.build.init_params``: the f32 masters and the aux
-        state drawn on the host.  Initializer errors propagate: a
-        wrong-shape bug must not silently become a different init."""
+        state.  Every parameter whose rule is ``traceable``
+        (``initializer.rule_for``) and all of the aux state come out of
+        ONE jitted program under their own shardings, so each device
+        (each process of a multi-host job) materialises its shards and
+        nothing whole exists on the host.  A rule that only fills a host
+        array (a user's ``_init_weight`` over ``np.random``, ``Load``,
+        an SVD) is run here on numpy, as it always was.  Returns those
+        host-drawn values and the span's attributes.  Initializer
+        errors propagate: a wrong-shape bug must not silently become a
+        different init."""
+        import jax
+        import jax.numpy as jnp
         init = initializer or Xavier(rnd_type="gaussian", factor_type="in",
                                      magnitude=2)
-        host_params = {}
-        for name in self._param_names:
+        attrs = self.symbol.attr_dict()
+        traced, host_params = {}, {}
+        for index, name in enumerate(self._param_names):
+            desc = InitDesc(name, attrs.get(name))
+            rule = rule_for(init, desc)
+            if getattr(rule, "traceable", False):
+                traced[name] = (rule, desc, index)
+                continue
             arr = _HostArray(np.zeros(self._arg_shapes[name], np.float32))
-            init(InitDesc(name), arr)
+            rule(desc, arr)
             host_params[name] = arr.data
-        for name in self._native_w:   # initializers see reference OIHW
-            host_params[name] = np.ascontiguousarray(
-                host_params[name].transpose(2, 3, 1, 0))
-        host_aux = {}
-        for name in self._aux_names:
-            v = np.zeros(self._aux_shapes[name], np.float32)
-            if name.endswith("moving_var"):
-                v[...] = 1.0
-            host_aux[name] = v
-        return host_params, host_aux
 
-    def _place_state(self, host_params, host_aux, seed):
-        """``trainer.build.place``: parameters, aux and zeroed
-        optimizer slots onto the mesh, and the step key."""
+        def to_store(name, value):   # rules see reference OIHW
+            return value.transpose(2, 3, 1, 0) \
+                if name in self._native_w else value
+
+        # parameters of one rule and one shape share ONE traced and
+        # lowered function, called once a parameter with its own key:
+        # a model of many small layers costs a trace and a lowering per
+        # distinct shape, not per parameter.  (Not one vmapped draw a
+        # shape: XLA compiles eight stacked draws of 2048 x 2048 in 7 s
+        # where one takes 0.3 s.)
+        drawers = {}
+        fold_in = jax.jit(jax.random.fold_in)
+
+        def drawn(name, key):
+            rule, desc, _ = traced[name]
+            shape = self._arg_shapes[name]
+            at = (rule, shape, name in self._native_w)
+            if at not in drawers:
+                drawers[at] = jax.jit(
+                    lambda k: to_store(name, draw(rule, desc, shape, k)))
+            return drawers[at](key)
+
+        def program(key):
+            key = jax.random.fold_in(key, _INIT_STREAM)
+            params = {name: drawn(name, fold_in(key, index))
+                      for name, (_, _, index) in traced.items()}
+            aux = {name: jnp.full(
+                       self._aux_shapes[name],
+                       1.0 if name.endswith("moving_var") else 0.0,
+                       jnp.float32)
+                   for name in self._aux_names}
+            return params, aux
+
+        # a numpy key: every process of a multi-host job hands the
+        # program the same replicated value
+        key = np.asarray(jax.random.PRNGKey(seed))
+        shardings = ({n: self._param_sharding[n] for n in traced},
+                     self._aux_sharding)
+        with self.mesh:
+            self.params, self.aux = jax.jit(
+                program, out_shardings=shardings)(key)
+
+        def nbytes(names):
+            return sum(4 * int(np.prod(self._arg_shapes[n])) for n in names)
+        return ({n: np.ascontiguousarray(to_store(n, v))
+                 for n, v in host_params.items()},
+                {"device_params": len(traced),
+                 "device_bytes": nbytes(traced),
+                 "host_params": len(host_params),
+                 "host_bytes": nbytes(host_params),
+                 "host_names": sorted(host_params)[:8]})
+
+    def _place_state(self, host_params, seed):
+        """``trainer.build.place``: the host-drawn parameters and the
+        zeroed optimizer slots onto the mesh, and the step key."""
         import jax
         # NB multi-host: every process runs this constructor with the
-        # same seeds, so host_params are identical full values on every
-        # rank; _put_state slices out each process's addressable shards
+        # same seeds.  The device-drawn state (_init_state) is one
+        # global program, each rank holding its shards only; a
+        # HOST-drawn parameter is the identical full value on every
+        # rank, of which _put_state slices out the addressable shards
         with self.mesh:
-            self.params = {n: self._put_state(host_params[n],
-                                              self._param_sharding[n])
-                           for n in self._param_names}
-            self.aux = {n: self._put_state(host_aux[n],
-                                           self._aux_sharding[n])
-                        for n in self._aux_names}
+            for n, value in host_params.items():
+                self.params[n] = self._put_state(
+                    value, self._param_sharding[n])
+            self.params = {n: self.params[n] for n in self._param_names}
             self.opt_state = self._device_zero_slots()
         self._key = jax.random.PRNGKey(seed)
 

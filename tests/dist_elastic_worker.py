@@ -63,7 +63,6 @@ def main():
     rank, nproc = jax.process_index(), jax.process_count()
     mesh = build_mesh(devices=jax.devices(),
                       axis_names=("data", "model"), tp=1)
-    np.random.seed(11)
     trainer = ShardedTrainer(
         _mlp(), mesh,
         data_shapes={"data": (GBATCH, 64)},

@@ -58,7 +58,6 @@ def _global_batch(step):
 
 
 def _build_trainer(mesh):
-    np.random.seed(7)           # identical init on every process
     return ShardedTrainer(
         _mlp(), mesh,
         data_shapes={"data": (GBATCH, 64)},

@@ -24,6 +24,7 @@ import json
 import os
 import signal
 import sys
+import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -58,6 +59,35 @@ def _mlp():
     return mx.sym.SoftmaxOutput(net, name="softmax")
 
 
+def _await_fleet(idlog, world, steps, timeout=120.0):
+    """A fleet death is every rank dying at the same step, and
+    ``launch.py`` kills the survivors of the first death.  The ranks
+    share no collective here (each trains on a mesh of its own), so
+    nothing orders them: hold this rank's kill until every rank's id
+    log holds ``steps`` lines (rank 0's last checkpoint is then on
+    disk: it saves before it logs the next step).  Otherwise a rank
+    that saves nothing reaches its kill before rank 0 has saved once
+    ("leg A left no complete checkpoint"), or a late rank loses steps
+    that the checkpoint's cursor counts as consumed.  A fleet that
+    never assembles is an error of its own, not a death."""
+    deadline = time.monotonic() + timeout
+    for r in range(world):
+        while True:
+            try:
+                with open("%s.rank%d" % (idlog, r)) as f:
+                    if sum(1 for _ in f) >= steps:
+                        break
+            except FileNotFoundError:
+                pass
+            if time.monotonic() > deadline:
+                sys.stderr.write("ioresume worker: FLEET NEVER ASSEMBLED: "
+                                 "rank %d logged fewer than %d steps in "
+                                 "%.0f s\n" % (r, steps, timeout))
+                sys.stderr.flush()
+                sys.exit(3)
+            time.sleep(0.02)
+
+
 def main():
     phase = os.environ.get("IORESUME_PHASE", "train")
     prefix = os.environ["IORESUME_CKPT"]
@@ -73,7 +103,6 @@ def main():
     # the tracked iterator's state() rides every checkpoint manifest
     ioview.track(it)
 
-    np.random.seed(11)
     trainer = ShardedTrainer(
         _mlp(), build_mesh(n_devices=1),
         data_shapes={"data": (BATCH, 16)},
@@ -117,6 +146,7 @@ def main():
                              % (rank, world, step))
             sys.stderr.flush()
             log.close()
+            _await_fleet(idlog, world, kill_step)
             os.kill(os.getpid(), signal.SIGKILL)
     log.close()
     print("ioresume worker %d/%d OK phase=%s start=%d end=%d"

@@ -46,7 +46,6 @@ def _batch(step):
 
 
 def _build(mesh):
-    np.random.seed(11)
     return ShardedTrainer(
         _mlp(), mesh,
         data_shapes={"data": (GBATCH, 64)},

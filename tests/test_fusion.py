@@ -488,7 +488,6 @@ def test_executor_graceful_fallback_runs_unfused():
 # ---------------------------------------------------- trainer parity
 def _make_trainer(fuse, layout="NHWC", dtype="float32", hw=8):
     mesh = build_mesh(tp=1)
-    np.random.seed(7)
     kwargs = dict(
         data_shapes={"data": (8, 3, hw, hw)},
         label_shapes={"softmax_label": (8,)},
@@ -602,7 +601,6 @@ def _kernel_block_trainer():
                                 name="fc0")
     net = mx.sym.Activation(net, act_type="relu", name="fcact")
     net = mx.sym.FullyConnected(net, num_hidden=10, name="fc1")
-    np.random.seed(7)
     return ShardedTrainer(
         mx.sym.SoftmaxOutput(net, name="softmax"), build_mesh(n_devices=1),
         data_shapes={"data": (8, 3, 8, 8)},

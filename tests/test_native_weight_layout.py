@@ -29,8 +29,6 @@ def _net():
 
 
 def _trainer(native, **kw):
-    mx.random.seed(7)
-    np.random.seed(7)
     return ShardedTrainer(
         _net(), build_mesh(tp=1),
         data_shapes={"data": (8, 3, 16, 16)},
@@ -114,8 +112,6 @@ def test_native_layout_shared_weight_excluded():
                                 name="fc")
     net = mx.sym.SoftmaxOutput(out + 0.0 * mx.sym.reshape(reg, shape=(1,)),
                                name="softmax")
-    mx.random.seed(3)
-    np.random.seed(3)
     tr = ShardedTrainer(
         net, build_mesh(tp=1),
         data_shapes={"data": (4, 2, 8, 8)},

@@ -50,7 +50,6 @@ def _fresh(monkeypatch):
 
 
 def _mlp_trainer(**kw):
-    np.random.seed(11)   # Xavier init draws from numpy's global RNG
     net = models.get_model("mlp", num_classes=10)
     kw.setdefault("dtype", "float32")
     kw.setdefault("seed", 0)

@@ -114,13 +114,11 @@ def test_pipeline_trainer_matches_plain(pp, dp, micro):
     sym_a, sym_b = _mlp_tower(), _mlp_tower()
     bsz = 16
 
-    np.random.seed(3)
     plain = ShardedTrainer(
         sym_a, build_mesh(n_devices=1, tp=1),
         data_shapes={"data": (bsz, 12)},
         label_shapes={"softmax_label": (bsz,)},
         learning_rate=0.1, momentum=0.9, seed=7)
-    np.random.seed(3)
     piped = ShardedTrainer(
         sym_b, build_mesh(n_devices=dp * pp, pp=pp),
         data_shapes={"data": (bsz, 12)},
@@ -146,7 +144,6 @@ def test_pipeline_transformer_trains():
     seq, vocab = 8, 16
     bsz = 16
     sym = _tiny_transformer(seq=seq, vocab=vocab)
-    np.random.seed(5)
     tr = ShardedTrainer(
         sym, build_mesh(n_devices=8, pp=4),
         data_shapes={"data": (bsz, seq)},
@@ -169,14 +166,12 @@ def test_pipeline_transformer_trains():
 def test_pipeline_transformer_matches_plain():
     """Transformer gradients through the pipeline match the plain path."""
     seq, vocab, bsz = 8, 16, 8
-    np.random.seed(9)
     plain = ShardedTrainer(
         _tiny_transformer(seq=seq, vocab=vocab),
         build_mesh(n_devices=1, tp=1),
         data_shapes={"data": (bsz, seq)},
         label_shapes={"softmax_label": (bsz * seq,)},
         learning_rate=0.2, momentum=0.9, seed=4)
-    np.random.seed(9)
     piped = ShardedTrainer(
         _tiny_transformer(seq=seq, vocab=vocab),
         build_mesh(n_devices=2, pp=2),
@@ -227,7 +222,6 @@ def test_pipeline_run_steps_matches_step_loop():
     bsz = 16
 
     def make(sym):
-        np.random.seed(47)
         return ShardedTrainer(
             sym, build_mesh(n_devices=4, pp=2),
             data_shapes={"data": (bsz, 12)},

@@ -36,7 +36,6 @@ def _mlp():
 
 
 def _make(mesh):
-    np.random.seed(3)
     return ShardedTrainer(
         _mlp(), mesh,
         data_shapes={"data": (GBATCH, 64)},

@@ -252,7 +252,6 @@ def test_truncated_params_named_not_unpickle_error(tmp_path):
 # ------------------------------------------------ trainer checkpoint wiring
 
 def _trainer(seed=5):
-    np.random.seed(11)
     mesh = build_mesh(tp=1)
     return ShardedTrainer(
         _mlp_sym(), mesh,

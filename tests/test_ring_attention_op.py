@@ -63,7 +63,6 @@ def test_sequence_parallel_trainer_matches_single_device():
     bsz, seq, vocab = 4, 16, 16
 
     def make(sp):
-        np.random.seed(41)
         return ShardedTrainer(
             _ring_lm(seq, vocab), build_mesh(n_devices=sp, tp=sp),
             data_shapes={"data": (bsz, seq)},

@@ -42,7 +42,6 @@ def _batch(batch=8, image=8, classes=10, seed=0):
 def _make(layout=None, **kw):
     mesh = build_mesh(tp=1)
     kw.setdefault("learning_rate", 0.1)
-    np.random.seed(7)  # initializers draw from the global numpy RNG
     return ShardedTrainer(
         _small_convnet(), mesh,
         data_shapes={"data": (8, 3, 8, 8)},
@@ -178,7 +177,6 @@ def test_nhwc_guard_rejects_axis_ops():
 
 def test_nhwc_deconv_builds():
     """Deconvolution shape hook must resolve channels under NHWC."""
-    np.random.seed(0)
     data = mx.sym.Variable("data")
     net = mx.sym.Convolution(data, kernel=(3, 3), pad=(1, 1), num_filter=4,
                              name="c1")
@@ -239,10 +237,7 @@ def test_bench_script_cpu_smoke(monkeypatch, capsys):
 def test_auto_layouts_matches_default():
     """auto_layouts=True (XLA-chosen persistent param layouts) trains
     identically to the default-layout step."""
-    np.random.seed(0)
-
-    def build(auto):
-        np.random.seed(11)  # identical initializer draws for both builds
+    def build(auto):   # one seed: identical initial weights in both
         data = mx.sym.Variable("data")
         net = mx.sym.Convolution(data, kernel=(3, 3), pad=(1, 1),
                                  num_filter=4, name="c1")
@@ -336,7 +331,6 @@ def _tiny_mlp_trainer():
     net = mx.sym.Activation(net, act_type="relu")
     net = mx.sym.FullyConnected(net, num_hidden=4, name="fc2")
     net = mx.sym.SoftmaxOutput(net, name="softmax")
-    np.random.seed(11)
     t = ShardedTrainer(net, build_mesh(tp=1), data_shapes={"data": (8, 12)},
                        label_shapes={"softmax_label": (8,)},
                        learning_rate=0.1, seed=5)

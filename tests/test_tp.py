@@ -115,7 +115,6 @@ def test_transformer_tp_parity(tp):
         # mathematically zero (softmax is shift-invariant per query), so
         # adam would amplify tp-reduction-order noise on it into
         # arbitrary-sign updates
-        np.random.seed(17)
         return ShardedTrainer(
             _transformer(seq=seq, vocab=vocab),
             build_mesh(n_devices=max(tp_, 1), tp=tp_),
@@ -140,7 +139,6 @@ def test_resnet_tp_parity():
     from mxnet_tpu import models
 
     def make(tp_):
-        np.random.seed(29)
         net = models.get_model("resnet18", num_classes=10,
                                image_shape="3,32,32")
         return ShardedTrainer(
@@ -167,7 +165,6 @@ def test_resnet_tp_parity():
 def test_dp_tp_composition():
     """dp=2 x tp=4 on the transformer: auto rules + batch sharding."""
     bsz, seq, vocab = 16, 8, 16
-    np.random.seed(31)
     tr = ShardedTrainer(
         _transformer(seq=seq, vocab=vocab),
         build_mesh(n_devices=8, tp=4),

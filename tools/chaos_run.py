@@ -107,7 +107,6 @@ def main():
     w.close()
 
     def make_trainer(mesh=None):
-        np.random.seed(11)
         net = mx.sym.Variable("data")
         net = mx.sym.FullyConnected(net, name="fc1", num_hidden=16)
         net = mx.sym.Activation(net, act_type="relu")

@@ -977,7 +977,6 @@ def numerics_check(repo_root=_ROOT):
         """One deterministic tiny-MLP run appending to ``ledger``."""
         os.environ["MXNET_TPU_NUMERICS_LEDGER"] = ledger
         telemetry.numerics.reset()
-        np.random.seed(11)      # Xavier init draws from numpy's RNG
         net = models.get_model("mlp", num_classes=10)
         trainer = ShardedTrainer(
             net, build_mesh(tp=1), data_shapes={"data": (8, 64)},
@@ -1613,6 +1612,10 @@ def io_resume_check(repo_root=_ROOT):
         if res.returncode == 0:
             problems.append("leg A fleet was SIGKILLed mid-epoch but "
                             "launch.py exited 0")
+            return problems
+        if "FLEET NEVER ASSEMBLED" in res.stderr:
+            problems.append("leg A: a rank waited in vain for the others "
+                            "to reach the kill step: %s" % res.stderr[-600:])
             return problems
         eps = find_checkpoints(prefix)
         if not eps:

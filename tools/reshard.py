@@ -266,7 +266,6 @@ def selfcheck():
                                     multihost)
 
     def make(axes):
-        np.random.seed(3)
         data = mx.sym.Variable("data")
         net = mx.sym.FullyConnected(data, name="fc1", num_hidden=32)
         net = mx.sym.Activation(net, act_type="relu")
