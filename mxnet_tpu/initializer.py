@@ -25,7 +25,8 @@ from .registry import get_register_func, get_create_func, get_alias_func
 
 __all__ = ["InitDesc", "Initializer", "Uniform", "Normal", "Zero", "One",
            "Constant", "Orthogonal", "Xavier", "MSRAPrelu", "Bilinear",
-           "LSTMBias", "Load", "Mixed", "register"]
+           "LSTMBias", "Load", "Mixed", "LogUniform",
+           "InverseSoftplusLogUniform", "register"]
 
 
 class InitDesc(str):
@@ -301,6 +302,48 @@ class Normal(Initializer):
     @traceable
     def _init_weight(self, _, arr):
         arr[:] = _rng(arr).normal(0, self.sigma, arr.shape)
+
+
+def _math(arr):
+    """``numpy``'s elementwise functions for what ``_rng(arr)`` drew:
+    ``jax.numpy`` under a trace."""
+    if isinstance(arr, _Traced):
+        import jax.numpy as jnp
+        return jnp
+    return np
+
+
+@register
+class LogUniform(Initializer):
+    """``log(U(low, high))``: the log of a rate drawn evenly between two
+    bounds (a gated delta-rule layer's ``A_log``: ``fla.layers.kda``)."""
+
+    def __init__(self, low=1.0, high=16.0):
+        super().__init__(low=low, high=high)
+        self.low, self.high = float(low), float(high)
+
+    @traceable
+    def _init_weight(self, _, arr):
+        arr[:] = _math(arr).log(
+            _rng(arr).uniform(self.low, self.high, arr.shape))
+
+
+@register
+class InverseSoftplusLogUniform(Initializer):
+    """``softplus^-1(exp(U(log low, log high)))``: a bias under a softplus
+    whose output starts log-uniform between ``low`` and ``high`` (a
+    gated delta-rule layer's ``dt_bias``: ``fla.layers.kda``)."""
+
+    def __init__(self, low=0.001, high=0.1):
+        super().__init__(low=low, high=high)
+        self.low, self.high = float(low), float(high)
+
+    @traceable
+    def _init_weight(self, _, arr):
+        xp = _math(arr)
+        dt = xp.exp(_rng(arr).uniform(np.log(self.low), np.log(self.high),
+                                      arr.shape))
+        arr[:] = dt + xp.log(-xp.expm1(-dt))
 
 
 @register

@@ -64,19 +64,10 @@ from __future__ import annotations
 from .. import symbol as sym
 from ..base import MXNetError
 from ..telemetry.spans import span
+from .decoder_blocks import gated_mlp as _gated_mlp, linear as _linear, \
+    topk_experts
 
 CONV, ATTENTION = "conv", "full_attention"
-
-
-def _linear(x, n_out, name, weight=None):
-    kw = {} if weight is None else {"weight": weight}
-    return sym.FullyConnected(x, num_hidden=n_out, flatten=False,
-                              no_bias=True, name=name, **kw)
-
-
-def _gated_mlp(x, width, d, prefix):
-    gate = sym.Activation(_linear(x, width, prefix + "w1"), act_type="silu")
-    return _linear(gate * _linear(x, width, prefix + "w3"), d, prefix + "w2")
 
 
 def _short_conv(x, cfg, prefix):
@@ -112,16 +103,8 @@ def _attention(x, cfg, prefix):
 
 
 def _experts(x, cfg, prefix):
-    held = int(cfg["num_experts"])
-    return sym._contrib_TopKMoE(
-        x, num_experts=int(cfg.get("router_num_experts", held)),
-        router_trained=bool(cfg.get("router_trained", True)),
-        experts_held=held, expert_offset=int(cfg.get("expert_offset", 0)),
-        num_experts_per_tok=int(cfg["num_experts_per_tok"]),
-        hidden_size=int(cfg["moe_intermediate_size"]),
-        norm_topk_prob=bool(cfg["norm_topk_prob"]),
-        routed_scaling_factor=float(cfg["routed_scaling_factor"]),
-        use_expert_bias=bool(cfg["use_expert_bias"]), name=prefix + "moe")
+    return topk_experts(x, cfg, prefix + "moe", cfg["num_experts_per_tok"],
+                        cfg["norm_topk_prob"], cfg["use_expert_bias"])
 
 
 def get_symbol(cfg, seq_len):

@@ -436,3 +436,71 @@ def topk_moe_op(attrs, ctx, data, router_weight, *rest):
         routed_scaling_factor=float(attrs["routed_scaling_factor"]),
         router_trained=bool(attrs["router_trained"]))
     return y.reshape(data.shape), new_load.astype(load.dtype)
+
+
+@register("_contrib_KDAGate", arg_names=("data", "a_log", "dt_bias"),
+          params={"num_heads": 1}, aliases=("KDAGate",))
+# mxlint: allow-dtype-widening(the log-decay is float32 by the op's definition: its running sums reach the hundreds inside a chunk)
+def kda_gate(attrs, ctx, data, a_log, dt_bias):
+    """The per-channel log-decay of a gated delta-rule layer, in
+    float32: ``g = -exp(a_log) * softplus(data + dt_bias)`` over
+    ``(batch, seq, num_heads * head_dim)``, with ``a_log``
+    ``(num_heads,)`` one scalar a head and ``dt_bias`` ``(num_heads *
+    head_dim,)``; returns ``(batch, seq, num_heads, head_dim)``,
+    ``<= 0``, so that ``exp(g)`` lies in ``(0, 1]``."""
+    h = int(attrs["num_heads"])
+    if data.ndim != 3 or h <= 0 or data.shape[2] % h \
+            or a_log.shape != (h,) or dt_bias.shape != data.shape[2:]:
+        raise MXNetError(
+            "_contrib_KDAGate wants (batch, seq, num_heads * head_dim) data, "
+            "a_log (num_heads,) and dt_bias (num_heads * head_dim,); got "
+            "data %s, a_log %s, dt_bias %s with num_heads=%d"
+            % (tuple(data.shape), tuple(a_log.shape), tuple(dt_bias.shape), h))
+    f32 = jnp.float32
+
+    # rematerialised: the backward keeps the op's inputs, not its float32
+    # softplus
+    @jax.checkpoint
+    def gate(data, a_log, dt_bias):
+        soft = jax.nn.softplus(data.astype(f32) + dt_bias.astype(f32))
+        return soft.reshape(data.shape[:2] + (h, -1)) \
+            * -jnp.exp(a_log.astype(f32))[:, None]
+
+    return gate(data, a_log, dt_bias)
+
+
+@register("_contrib_GatedDeltaRule",
+          arg_names=("q", "k", "v", "g", "beta"),
+          params={"chunk_size": 64, "qk_l2norm": False, "scale": 1.0},
+          aliases=("GatedDeltaRule",))
+def gated_delta_rule_op(attrs, ctx, q, k, v, g, beta):
+    """Linear attention by the gated delta rule with a per-channel decay
+    (Kimi Delta Attention): ``q, k`` ``(batch, seq, heads, dk)``, ``v``
+    ``(batch, seq, heads, dv)``, the log-decay ``g`` ``(batch, seq,
+    heads, dk)`` (float32, ``<= 0``: ``_contrib_KDAGate``'s) and the
+    write strength ``beta`` ``(batch, seq, heads)``; returns ``(batch,
+    seq, heads, dv)``.  A head carries a ``dk x dv`` state ``S``
+    (``S_0 = 0``) along the sequence::
+
+        S'  = Diag(exp(g_t)) S_{t-1}
+        S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+        o_t = S_t^T q_t
+
+    computed as a scan over chunks of ``chunk_size`` positions (matrix
+    products inside a chunk, one state a head carried between chunks and
+    kept for the backward): :mod:`mxnet_tpu.ops.delta_rule`.  With
+    ``qk_l2norm`` each head of ``q`` and of ``k`` is first normalised (``x
+    * rsqrt(sum x^2 + 1e-6)``); ``q`` is then multiplied by ``scale``."""
+    from . import delta_rule
+    shapes = (tuple(q.shape), tuple(k.shape), tuple(v.shape),
+              tuple(g.shape), tuple(beta.shape))
+    if q.ndim != 4 or k.shape != q.shape or g.shape != q.shape \
+            or v.shape[:3] != q.shape[:3] or v.ndim != 4 \
+            or beta.shape != q.shape[:3] or int(attrs["chunk_size"]) <= 0:
+        raise MXNetError(
+            "_contrib_GatedDeltaRule wants q, k, g (batch, seq, heads, dk), "
+            "v (batch, seq, heads, dv) and beta (batch, seq, heads); got "
+            "q %s, k %s, v %s, g %s, beta %s" % shapes)
+    return delta_rule.gated_delta_rule(
+        q, k, v, g, beta, chunk=int(attrs["chunk_size"]),
+        qk_l2norm=bool(attrs["qk_l2norm"]), scale=float(attrs["scale"]))

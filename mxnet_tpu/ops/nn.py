@@ -480,6 +480,39 @@ def rms_norm(attrs, ctx, data, gamma):
             ).astype(data.dtype)
 
 
+@register("_contrib_GatedRMSNorm", arg_names=("data", "gate", "gamma"),
+          params={"eps": 1e-5}, aliases=("GatedRMSNorm",))
+# mxlint: allow-dtype-widening(normalization/softmax statistics accumulate in f32 by contract)
+def gated_rms_norm(attrs, ctx, data, gate, gamma):
+    """Sigmoid-gated RMS normalization over the last axis:
+    ``x * rsqrt(mean(x^2) + eps) * gamma * sigmoid(gate)`` with ``gate``
+    of ``data``'s shape and ``gamma`` of the last axis' length: the
+    output norm of a linear-attention layer, over each head of
+    ``(batch, seq, heads, head_dim)`` with one gain a channel of the
+    head.  Statistics and the gate in float32, rounded once to the
+    input's dtype."""
+    if gate.shape != data.shape or gamma.shape != data.shape[-1:]:
+        raise MXNetError(
+            "_contrib_GatedRMSNorm wants a gate of the data's shape and a "
+            "gamma of its last axis; got data %s, gate %s, gamma %s"
+            % (tuple(data.shape), tuple(gate.shape), tuple(gamma.shape)))
+    eps = float(attrs["eps"])
+
+    # rematerialised: the backward keeps the op's inputs, not their
+    # float32 copies
+    @jax.checkpoint
+    # mxlint: allow-dtype-widening(normalization/softmax statistics accumulate in f32 by contract)
+    def norm(data, gate, gamma):
+        xf = data.astype(jnp.float32)
+        inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                        + eps)
+        return (xf * inv * gamma.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))
+                ).astype(data.dtype)
+
+    return norm(data, gate, gamma)
+
+
 @register("_contrib_RotaryEmbedding",
           params={"base": 10000.0, "offset": 0},
           aliases=("RotaryEmbedding",))
@@ -514,7 +547,7 @@ SCOPE_SHORTCONV = "mxtpu.block.shortconv"
 
 
 @register("_contrib_CausalConv1D", arg_names=("data", "weight"),
-          params={"kernel": 3}, aliases=("CausalConv1D",))
+          params={"kernel": 3, "act_type": ""}, aliases=("CausalConv1D",))
 # mxlint: allow-dtype-widening(the taps' sum accumulates in f32 and is rounded once)
 def causal_conv1d(attrs, ctx, data, weight):
     """Depthwise causal convolution along the sequence of
@@ -524,7 +557,10 @@ def causal_conv1d(attrs, ctx, data, weight):
     second axis and the channels the last: no relayout to the
     ``(batch, channels, width)`` that ``Convolution`` wants, and no
     symmetric padding to cut off again.  It is ``K`` shifted
-    multiply-adds that XLA fuses into one pass over the activation."""
+    multiply-adds that XLA fuses into one pass over the activation.
+    With ``act_type`` (an ``Activation`` type, ``silu`` say) the
+    activation of the sum is returned, rounded once, and the pair is
+    rematerialised: the backward keeps ``data`` and not the sum."""
     k = int(attrs["kernel"])
     if data.ndim != 3 or weight.shape != (data.shape[2], k):
         raise MXNetError(
@@ -532,12 +568,20 @@ def causal_conv1d(attrs, ctx, data, weight):
             "(channels, %d) weight; got data %s, weight %s"
             % (k, tuple(data.shape), tuple(weight.shape)))
     t = data.shape[1]
-    with jax.named_scope(SCOPE_SHORTCONV):
+
+    # mxlint: allow-dtype-widening(the taps' sum accumulates in f32 and is rounded once)
+    def taps(data, weight):
         xp = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0)))
         w = weight.astype(jnp.float32)
-        acc = sum(xp[:, j:j + t, :].astype(jnp.float32) * w[:, j]
-                  for j in range(k))
-        return acc.astype(data.dtype)
+        return sum(xp[:, j:j + t, :].astype(jnp.float32) * w[:, j]
+                   for j in range(k))
+
+    with jax.named_scope(SCOPE_SHORTCONV):
+        if not attrs["act_type"]:
+            return taps(data, weight).astype(data.dtype)
+        act = _activation_fn(attrs["act_type"], data)
+        return jax.checkpoint(
+            lambda d, w: act(taps(d, w)).astype(d.dtype))(data, weight)
 
 
 @register("InstanceNorm", arg_names=("data", "gamma", "beta"),
@@ -604,11 +648,16 @@ _ACTIVATIONS = {
 @register("Activation", params={"act_type": "relu"}, aliases=("activation",))
 def activation(attrs, ctx, data):
     """Reference: src/operator/activation-inl.h; functors mshadow_op.h."""
-    t = attrs["act_type"]
+    return _activation_fn(attrs["act_type"], data)(data)
+
+
+def _activation_fn(t, data):
+    """The elementwise function of ``act_type`` ``t`` (``data``: what it
+    is meant for, for the error)."""
     if t not in _ACTIVATIONS:
         raise MXNetError("unknown act_type %r on data of shape %s; known: %s"
                          % (t, tuple(data.shape), ", ".join(_ACTIVATIONS)))
-    return _ACTIVATIONS[t](data)
+    return _ACTIVATIONS[t]
 
 
 @register("LeakyReLU", arg_names=lambda a: ("data", "gamma")

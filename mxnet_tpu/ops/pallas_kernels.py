@@ -323,14 +323,26 @@ def _unfold_heads(x, b, h):
 def _kv_group(q, k, v):
     """How many query heads read one key/value head (1: as many
     key/value heads as query heads).  Query head ``h`` reads key/value
-    head ``h // group``."""
+    head ``h // group``.  ``q`` and ``k`` share one head width, which
+    ``v`` need not have (latent attention: scores over 192, values of
+    128); the error says which of head count and widths is at fault."""
     hq, hk = q.shape[2], k.shape[2]
-    if k.shape != v.shape or hk <= 0 or hq % hk:
+    shapes = "(q %s, k %s, v %s)" % (tuple(q.shape), tuple(k.shape),
+                                     tuple(v.shape))
+    if q.shape[-1] != k.shape[-1]:
+        raise ValueError(
+            "flash attention: query heads are %d wide and key heads %d "
+            "%s; the score needs one width" % (q.shape[-1], k.shape[-1],
+                                               shapes))
+    if k.shape[:-1] != v.shape[:-1]:
+        raise ValueError(
+            "flash attention: keys and values differ in more than their "
+            "head width %s; they share batch, positions and heads" % shapes)
+    if hk <= 0 or hq % hk:
         raise ValueError(
             "flash attention: %d query heads cannot share %d key/value "
-            "heads (q %s, k %s, v %s); the query heads must be a whole "
-            "multiple of the key/value heads"
-            % (hq, hk, tuple(q.shape), tuple(k.shape), tuple(v.shape)))
+            "heads %s; the query heads must be a whole multiple of the "
+            "key/value heads" % (hq, hk, shapes))
     return hq // hk
 
 
@@ -399,13 +411,17 @@ def _select_blocks(op, q, causal):
 
 
 def _note_kernel_cost(op, q, block_q, block_k, causal, n_matmuls,
-                      n_tensors, plan=None):
+                      n_tensors, plan=None, v=None):
     """Label this kernel instantiation's chosen block shapes in the
     cost database (telemetry.costdb) so block-size cliffs — e.g. the
     2176-length 17-tiny-K-blocks fallback ADVICE flagged — become
     queryable by (op, shape).  ``n_tensors``: how many (B, T, H, D)
     sized tensors the kernel moves (HBM traffic estimate — the
-    backward touches twice the forward's).  ``plan``: the kernel's
+    backward touches twice the forward's).  With ``v`` (values of a
+    width of their own) ``n_matmuls`` and ``n_tensors`` are pairs,
+    ``(over the query/key width, over the value width)``: the forward's
+    score product and Q, K run over ``dk``, its value product and V, O
+    over ``dv``.  ``plan``: the kernel's
     :func:`_causal_plan` (None where not causal); the record says into
     how many static ranges a diagonal tile's Q blocks are split
     (``causal_ranges``) and which share of a head's ``t * t`` scores
@@ -414,10 +430,15 @@ def _note_kernel_cost(op, q, block_q, block_k, causal, n_matmuls,
     swallowed on failure (observability must not fail the trace)."""
     try:
         from ..telemetry import costdb
-        b, t, h, d = q.shape
-        flops = float(n_matmuls) * b * h * t * t * d
+        b, t, h, dk = q.shape
+        dv = dk if v is None else v.shape[-1]
+
+        def over_widths(n):
+            return n * dk if v is None else n[0] * dk + n[1] * dv
+
+        flops = float(over_widths(n_matmuls)) * b * h * t * t
         itemsize = jnp.dtype(q.dtype).itemsize
-        bytes_ = float(n_tensors) * b * t * h * d * itemsize
+        bytes_ = float(over_widths(n_tensors)) * b * t * h * itemsize
         config = {"block_q": int(block_q), "block_k": int(block_k),
                   "n_k": int(t // block_k), "causal": bool(causal),
                   "causal_ranges": len(plan[1]) if plan else 1,
@@ -425,7 +446,8 @@ def _note_kernel_cost(op, q, block_q, block_k, causal, n_matmuls,
                       t, block_q, block_k, plan) if plan else 100.0}
         if plan and _PLAN_RECORDING is not None:
             _PLAN_RECORDING.append(dict(
-                config, kernel=op, shape=tuple(int(n) for n in q.shape)))
+                config, kernel=op, shape=tuple(int(n) for n in q.shape),
+                dk=int(dk), dv=int(dv)))
         costdb.note_kernel(
             op, [tuple(q.shape)], [str(q.dtype)], flops=flops,
             bytes_accessed=bytes_, block_config=config)
@@ -475,7 +497,8 @@ def last_causal_plan():
 
 def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
                                 blocks=None, ranges=None):
-    """q/k/v: (B, T, H, D) -> (o (B, T, H, D), lse (BH, T, 1) f32).
+    """q/k: (B, T, H, D), v: (B, T, Hk, Dv) -> (o (B, T, H, Dv), lse
+    (BH, T, 1) f32); the scale is ``D ** -0.5``.
     ``blocks``: explicit (block_q, block_k) override (the autotuner
     measures candidates through it); default consults the tuning
     cache, then the heuristic.  ``ranges``: explicit count of causal
@@ -485,10 +508,11 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
     assert q.shape[1] % block_q == 0, \
         "seq length must be a multiple of the Q block"
     plan = _causal_plan(block_q, block_k, ranges) if causal else None
-    # 2 matmuls (QK^T, PV) at 2*t*t*d MACs->flops each; traffic:
-    # q, k, v read + o written (lse is negligible)
-    _note_kernel_cost("flash_attention_fwd", q, block_q, block_k,
-                      causal, n_matmuls=4, n_tensors=4, plan=plan)
+    # 2 matmuls at 2*t*t*width flops each: QK^T over the query/key
+    # width, PV over the value width; traffic: q, k, v read + o written
+    # (lse is negligible)
+    _note_kernel_cost("flash_attention_fwd", q, block_q, block_k, causal,
+                      n_matmuls=(2, 2), n_tensors=(2, 2), plan=plan, v=v)
     return _flash_fwd_call(q, k, v, causal=bool(causal),
                            interpret=bool(interpret), block_q=int(block_q),
                            block_k=int(block_k), plan=plan)
@@ -508,11 +532,13 @@ _traced_once = functools.partial(
 
 @_traced_once
 def _flash_fwd_call(q, k, v, *, causal, interpret, block_q, block_k, plan):
-    """The forward kernel's call for blocks and plan already chosen."""
+    """The forward kernel's call for blocks and plan already chosen.
+    ``q`` and ``k`` are ``d`` wide, ``v`` and the result ``dv``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     group = _kv_group(q, k, v)
     bk = b * h // group                 # key/value heads over the batch
     scale = 1.0 / math.sqrt(d)
@@ -532,20 +558,20 @@ def _flash_fwd_call(q, k, v, *, causal, interpret, block_q, block_k, plan):
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
                 pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
-                pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
+                pl.BlockSpec((1, t, dv), lambda bh, qi: (bh, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
+                pl.BlockSpec((1, block_q, dv), lambda bh, qi: (bh, qi, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((bk, group * t, d), q.dtype),
+                jax.ShapeDtypeStruct((bk, group * t, dv), q.dtype),
                 jax.ShapeDtypeStruct((bk, group * t, 1), jnp.float32),
             ],
             interpret=interpret,
             name=FLASH_FWD_PANEL,
         )(_fold_queries(q, group), _fold_heads(k), _fold_heads(v))
-        return (_unfold_heads(out.reshape(b * h, t, d), b, h),
+        return (_unfold_heads(out.reshape(b * h, t, dv), b, h),
                 lse.reshape(b * h, t, 1))
     kernel = functools.partial(_flash_fwd_kernel, scale=scale,
                                causal=causal, block_q=block_q,
@@ -556,25 +582,25 @@ def _flash_fwd_call(q, k, v, *, causal, interpret, block_q, block_k, plan):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda bh, qi, ki: (bh, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bk, group * t, d), q.dtype),
+            jax.ShapeDtypeStruct((bk, group * t, dv), q.dtype),
             jax.ShapeDtypeStruct((bk, group * t, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
         name=FLASH_FWD_STREAM,
     )(_fold_queries(q, group), _fold_heads(k), _fold_heads(v))
-    return (_unfold_heads(out.reshape(b * h, t, d), b, h),
+    return (_unfold_heads(out.reshape(b * h, t, dv), b, h),
             lse.reshape(b * h, t, 1))
 
 
@@ -645,12 +671,14 @@ def _grouped_stream_params(group, t, d, block_q, block_k):
     grouped queries: its dQ accumulator holds the whole group's rows
     (``group * t`` rows of float32, lanes padded to 128), 16 MiB at 4
     query heads a key/value head and 8192 positions, so the kernel asks
-    for the VMEM it needs.  Nothing for one query head a key/value head:
-    that call stays as it always was."""
-    if group == 1:
+    for the VMEM it needs; so does a head wider than the 128 lanes
+    (latent attention's 192 takes two lane tiles a row).  Nothing for
+    one query head a key/value head of at most 128: that call stays as
+    it always was."""
+    lanes = -(-d // 128) * 128
+    if group == 1 and lanes == 128:
         return {}
     from jax.experimental.pallas import tpu as pltpu
-    lanes = -(-d // 128) * 128
     need = 4 * lanes * (group * t                 # dq accumulator
                         + 6 * block_k             # dk/dv scratch + outputs
                         + 8 * block_q)            # q, dO, dq blocks
@@ -751,11 +779,12 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
     block_q, block_k = blocks if blocks is not None else \
         _select_blocks("flash_attention_bwd", q, causal)
     plan = _causal_plan(block_q, block_k, ranges) if causal else None
-    # 5 matmuls (dV, dP, dQ, dK, S recompute) at 2*t*t*d each;
-    # traffic: q, k, v, o, dO read + dq, dk, dv written (lse/delta
-    # rows are negligible)
-    _note_kernel_cost("flash_attention_bwd", q, block_q, block_k,
-                      causal, n_matmuls=10, n_tensors=8, plan=plan)
+    # 5 matmuls at 2*t*t*width each: dQ, dK and the recomputed S over
+    # the query/key width, dV and dP over the value width; traffic:
+    # q, k read + dq, dk written (dk wide), v, o, dO read + dv written
+    # (dv wide; lse/delta rows are negligible)
+    _note_kernel_cost("flash_attention_bwd", q, block_q, block_k, causal,
+                      n_matmuls=(6, 4), n_tensors=(4, 4), plan=plan, v=v)
     return _flash_bwd_call(q, k, v, o, lse, g, causal=bool(causal),
                            interpret=bool(interpret), block_q=int(block_q),
                            block_k=int(block_k), plan=plan)
@@ -764,11 +793,14 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
 @_traced_once
 def _flash_bwd_call(q, k, v, o, lse, g, *, causal, interpret, block_q,
                     block_k, plan):
-    """The backward kernel's call for blocks and plan already chosen."""
+    """The backward kernel's call for blocks and plan already chosen.
+    ``q``, ``k`` and their gradients are ``d`` wide; ``v``, ``o``, ``g``
+    and ``dV`` ``dv``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     group = _kv_group(q, k, v)
     bk, hk = b * h // group, h // group
     scale = 1.0 / math.sqrt(d)
@@ -783,10 +815,13 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal, interpret, block_q,
                     axis=-1, keepdims=True)
     lse = lse.reshape(bk, group * t, 1)
     dq_shape = jax.ShapeDtypeStruct((bk, group * t, d), jnp.float32)
-    dkv_shape = jax.ShapeDtypeStruct((bk, t, d), jnp.float32)
+    dk_shape = jax.ShapeDtypeStruct((bk, t, d), jnp.float32)
+    dv_shape = jax.ShapeDtypeStruct((bk, t, dv), jnp.float32)
 
     qblock = pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0))
     kblock = pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0))
+    doblock = pl.BlockSpec((1, block_q, dv), lambda bh, ki, qi: (bh, qi, 0))
+    vblock = pl.BlockSpec((1, block_k, dv), lambda bh, ki, qi: (bh, ki, 0))
     rows = pl.BlockSpec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0))
     n_k = t // block_k
     if n_k == 1:
@@ -798,13 +833,15 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal, interpret, block_q,
                                    plan=plan, **grouped)
         panel = pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0))
         qb2 = pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0))
+        vpanel = pl.BlockSpec((1, t, dv), lambda bh, qi: (bh, 0, 0))
+        dob2 = pl.BlockSpec((1, block_q, dv), lambda bh, qi: (bh, qi, 0))
         rows2 = pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0))
-        dq, dk, dv = pl.pallas_call(
+        dq, dk_, dv_ = pl.pallas_call(
             kernel,
             grid=(bk, group * n_q),
-            in_specs=[qb2, panel, panel, qb2, rows2, rows2],
-            out_specs=[qb2, panel, panel],
-            out_shape=[dq_shape, dkv_shape, dkv_shape],
+            in_specs=[qb2, panel, vpanel, dob2, rows2, rows2],
+            out_specs=[qb2, panel, vpanel],
+            out_shape=[dq_shape, dk_shape, dv_shape],
             interpret=interpret,
             name=FLASH_BWD_PANEL,
         )(qt, kt, vt, dot, lse, delta)
@@ -812,22 +849,23 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal, interpret, block_q,
         kernel = functools.partial(_flash_bwd_kernel, scale=scale,
                                    causal=causal, block_q=block_q,
                                    block_k=block_k, plan=plan, **grouped)
-        dq, dk, dv = pl.pallas_call(
+        dq, dk_, dv_ = pl.pallas_call(
             kernel,
             grid=(bk, t // block_k, group * n_q),
-            in_specs=[qblock, kblock, kblock, qblock, rows, rows],
-            out_specs=[qblock, kblock, kblock],
-            out_shape=[dq_shape, dkv_shape, dkv_shape],
+            in_specs=[qblock, kblock, vblock, doblock, rows, rows],
+            out_specs=[qblock, kblock, vblock],
+            out_shape=[dq_shape, dk_shape, dv_shape],
             scratch_shapes=[pltpu.VMEM((group * t, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+                            pltpu.VMEM((block_k, dv), jnp.float32)],
             interpret=interpret,
             name=FLASH_BWD_STREAM,
-            **_grouped_stream_params(group, t, d, block_q, block_k),
+            **_grouped_stream_params(group, t, max(d, dv), block_q,
+                                     block_k),
         )(qt, kt, vt, dot, lse, delta)
     return (_unfold_heads(dq.reshape(b * h, t, d), b, h).astype(q.dtype),
-            _unfold_heads(dk, b, hk).astype(k.dtype),
-            _unfold_heads(dv, b, hk).astype(v.dtype))
+            _unfold_heads(dk_, b, hk).astype(k.dtype),
+            _unfold_heads(dv_, b, hk).astype(v.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -851,10 +889,20 @@ def _fa_bwd(causal, interpret, res, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
+#: ``jax.named_scope`` of an attention call whose value heads have a
+#: width of their own (latent attention)
+SCOPE_MLA = "mxtpu.block.mla"
+
+
 @register("_contrib_FlashAttention", arg_names=("q", "k", "v"),
           params={"causal": False})
 def flash_attention_op(attrs, ctx, q, k, v):
     """Attention over (batch, seq, heads, head_dim) inputs.
+
+    ``v`` may have a head width of its own (latent attention: ``q``, ``k``
+    of 192, ``v`` of 128): the scores run over ``q``'s width and are
+    scaled by its ``** -0.5``, the result and ``dV`` have ``v``'s, in the
+    same kernels; such a call carries the scope ``mxtpu.block.mla``.
 
     ``k`` and ``v`` may have fewer heads than ``q`` by a whole factor
     (grouped-query attention): query head ``h`` reads key/value head
@@ -865,6 +913,13 @@ def flash_attention_op(attrs, ctx, q, k, v):
     New TPU-native capability (the reference era has no attention ops);
     Pallas kernel on TPU, jnp fallback elsewhere.
     """
+    if q.ndim == 4 and v.ndim == 4 and q.shape[-1] != v.shape[-1]:
+        with jax.named_scope(SCOPE_MLA):
+            return _flash_attention_op(attrs, q, k, v)
+    return _flash_attention_op(attrs, q, k, v)
+
+
+def _flash_attention_op(attrs, q, k, v):
     causal = bool(attrs["causal"])
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise MXNetError(

@@ -126,6 +126,22 @@ def _rms(attrs, known):
     return {"gamma": (int(data[int(attrs.get("axis", -1))]),)}
 
 
+@register_param_shapes("_contrib_GatedRMSNorm")
+def _gated_rms(attrs, known):
+    data = known.get("data")
+    if data is None:
+        return {}
+    return {"gamma": (int(data[-1]),)}
+
+
+@register_param_shapes("_contrib_KDAGate")
+def _kda_gate(attrs, known):
+    data = known.get("data")
+    if data is None:
+        return {}
+    return {"a_log": (int(attrs["num_heads"]),), "dt_bias": (int(data[-1]),)}
+
+
 @register_param_shapes("_contrib_CausalConv1D")
 def _causal_conv1d(attrs, known):
     data = known.get("data")

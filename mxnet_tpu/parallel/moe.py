@@ -6,8 +6,10 @@ dense one-hot dispatch einsums with a capacity (overflow tokens dropped).
 models (sigmoid scores, a selection-only bias, gated experts): it is
 told which experts it holds, routes every token over all of them, and
 computes the held experts' part of the result by sorting the
-assignments and multiplying group by group; it drops nothing and builds
-no tensor of tokens x experts x capacity.  Its docstring has the rest.
+assignments and multiplying group by group; it builds no tensor of
+tokens x experts x capacity, and its sorted buffer follows the share of
+the experts it holds (:func:`buffer_rows`): nothing can be dropped
+where a quarter or more is held.  Its docstring has the rest.
 
 Switch routing, as it always was:
 
@@ -153,6 +155,53 @@ def _sorted_dispatch(k):
     return dispatch, combine
 
 
+def _bounded_products(x, w1, w3, w2, gates, order, counts, n_rows):
+    """The held experts' part of the result over a sorted buffer of
+    ``n_rows < tokens * top_k`` rows (:func:`buffer_rows`): the buffer is
+    the head of the sorted order, the groups end where it does, and the
+    gather into it, the three products and the sum back into token order
+    all run over its rows alone (autodiff's transposes too: a gather of
+    ``n_rows`` rows for the scatter-add and the reverse).  An assignment
+    past the buffer has no row and adds nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    t, k = gates.shape
+    order = order[:n_rows]
+    token = order // k
+    sizes = jnp.diff(jnp.minimum(jnp.cumsum(counts), n_rows), prepend=0)
+    # rows past the held assignments belong to no group: what a grouped
+    # product leaves there is neither a result nor a gradient
+    filled = jnp.arange(n_rows) < jnp.sum(sizes)
+    weight = jnp.where(filled, gates.reshape(-1)[order], 0.0)
+    xs = jnp.where(filled[:, None], x[token], 0)            # (n_rows, d)
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, w1.astype(x.dtype), sizes)) \
+        * jax.lax.ragged_dot(xs, w3.astype(x.dtype), sizes)
+    rows = jax.lax.ragged_dot(h, w2.astype(x.dtype), sizes)
+    rows = jnp.where(filled[:, None], rows.astype(jnp.float32), 0.0) \
+        * weight[:, None]
+    y = jnp.zeros((t, x.shape[1]), jnp.float32).at[token].add(rows)
+    return y.astype(x.dtype)
+
+
+#: rows the sorted buffer is rounded up to (a sublane tile)
+ROW_TILE = 8
+#: how many times the even load of the held experts the buffer takes
+BUFFER_OVER_EVEN = 4
+
+
+def buffer_rows(tokens, top_k, held, num_experts):
+    """Rows of :func:`topk_moe`'s sorted buffer: ``min(tokens * top_k,
+    BUFFER_OVER_EVEN * tokens * top_k * held / num_experts)``, the
+    second rounded up to :data:`ROW_TILE`.  With a quarter or more of
+    the experts held that is every assignment there can be; with 8 of
+    256 it is ``tokens * top_k / 8``, four times what even routing
+    sends here."""
+    every = int(tokens) * int(top_k)
+    share = -(-BUFFER_OVER_EVEN * every * int(held) // int(num_experts))
+    return min(every, -(-share // ROW_TILE) * ROW_TILE)
+
+
 # mxlint: allow-dtype-widening(the router, its sigmoid and the gate normalisation run in float32 by the model's definition)
 def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
              expert_offset=0, norm_topk_prob=True,
@@ -191,14 +240,24 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
     ``tokens * top_k`` keys, the others last), the tokens gathered into
     that order, and the three products done group by group
     (``jax.lax.ragged_dot``; the backward's two products are grouped
-    too).  The buffer has ``tokens * top_k`` rows, the most that can be
-    held, so no assignment is ever dropped whatever the imbalance;
-    rows past the held ones belong to no group and cost no product.
+    too).  The buffer has :func:`buffer_rows` rows, a rule from the
+    shapes alone.  Where ``held / E >= 1/4`` that is ``tokens * top_k``,
+    the most that can be held: no assignment is dropped whatever the
+    imbalance.  Below a quarter it is four times the even load of the
+    held experts (a layer that holds 8 of 256 would else gather and
+    multiply over 32 times its expected rows): the first
+    ``buffer_rows`` held assignments in expert order are computed and
+    the rest contribute nothing, which happens only when the held
+    experts together draw more than four times their even share;
+    ``load`` still counts them, so a reader sees ``sum(load[:-1]) -
+    buffer_rows`` assignments left out.  Gathers, products and the
+    combine run over the buffer's rows only; rows past the held
+    assignments belong to no group and cost no product.
 
     Returns ``(y (tokens, d) in x's dtype, load)`` where ``load`` is
-    float32 ``(held + 1,)``: the held experts' assignment counts (the
-    group sizes the products were given) and the tokens with no held
-    expert.
+    float32 ``(held + 1,)``: the held experts' assignment counts as the
+    routing made them (the group sizes of the products, before the
+    buffer's bound) and the tokens with no held expert.
     """
     import jax
     import jax.numpy as jnp
@@ -228,23 +287,30 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
             local[:, None] == jnp.arange(held, dtype=local.dtype)[None, :],
             axis=0, dtype=jnp.int32)                        # (held,)
 
-        dispatch, combine = _sorted_dispatch(k)
-        xs = dispatch(x, order, inv, here)                  # (t*k, d)
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, w1.astype(x.dtype), counts)) \
-            * jax.lax.ragged_dot(xs, w3.astype(x.dtype), counts)
-        rows = jax.lax.ragged_dot(h, w2.astype(x.dtype), counts)
-        back = combine(rows, order, inv).reshape(t, k, d)
-        weight = jnp.where(here, gates, 0.0)[:, :, None]
-        y = jnp.sum(jnp.where(here[:, :, None],
-                              back.astype(jnp.float32), 0.0) * weight,
-                    axis=1).astype(x.dtype)
+        n_rows = buffer_rows(t, k, held, router_w.shape[0])
+        if n_rows < t * k:
+            y = _bounded_products(x, w1, w3, w2, gates, order, counts,
+                                  n_rows)
+        else:
+            dispatch, combine = _sorted_dispatch(k)
+            xs = dispatch(x, order, inv, here)              # (t*k, d)
+            h = jax.nn.silu(
+                jax.lax.ragged_dot(xs, w1.astype(x.dtype), counts)) \
+                * jax.lax.ragged_dot(xs, w3.astype(x.dtype), counts)
+            rows = jax.lax.ragged_dot(h, w2.astype(x.dtype), counts)
+            back = combine(rows, order, inv).reshape(t, k, d)
+            weight = jnp.where(here, gates, 0.0)[:, :, None]
+            y = jnp.sum(jnp.where(here[:, :, None],
+                                  back.astype(jnp.float32), 0.0) * weight,
+                        axis=1).astype(x.dtype)
 
         load = jnp.concatenate([
             counts.astype(jnp.float32),
             jnp.sum(~jnp.any(here, axis=1), dtype=jnp.float32)[None]])
     note_layer(num_experts=router_w.shape[0], experts_held=held,
                expert_offset=int(expert_offset), num_experts_per_tok=k,
-               hidden_size=w1.shape[2], buffer_rows=xs.shape[0])
+               hidden_size=w1.shape[2], buffer_rows=n_rows,
+               even_rows=t * k * held / router_w.shape[0])
     return y, load
 
 
@@ -304,9 +370,10 @@ def note_compiled(executable):
 def last_plan_summary():
     """Summary of the expert layers of the step traced last in this
     process (None before any): ``expert_layers``; per layer the router
-    width, experts held and offset, experts a token, the experts' width
-    and ``buffer_rows`` (rows of the sorted buffer its products run
-    over); and, once that step's program is compiled,
+    width, experts held and offset, experts a token, the experts' width,
+    ``buffer_rows`` (rows of the sorted buffer its products run over,
+    :func:`buffer_rows`) and ``even_rows`` (the assignments even routing
+    sends to the held experts); and, once that step's program is compiled,
     ``grouped_products`` and ``grouped_layers`` as
     :func:`note_compiled` reads them from it.  As
     ``analysis.fusion.last_plan_summary()``."""

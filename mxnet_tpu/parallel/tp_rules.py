@@ -35,6 +35,7 @@ _PASS_OPS = frozenset({
     "clip", "expand_dims", "squeeze", "SwapAxis", "transpose",
     # per channel / per head: a column-sharded activation stays sharded
     "_contrib_CausalConv1D", "_contrib_RotaryEmbedding",
+    "_contrib_GatedRMSNorm", "_contrib_GatedDeltaRule",
 })
 
 

@@ -98,6 +98,46 @@ def test_grouped_query_flash_compiles_for_v5e(one_chip, shape):
         if " broadcast(" in line and "=" in line]
 
 
+# (batch, seq, heads, query/key width, value width): Kimi Linear's latent
+# attention at the cell's 8192 positions (streaming kernels; a 192-wide
+# head takes two lane tiles a row, so the backward asks for the VMEM of its
+# dQ accumulator) and at one K/V panel
+LATENT_SHAPES = [(1, 8192, 32, 192, 128), (1, 2048, 32, 192, 128)]
+
+
+@pytest.mark.parametrize("shape", LATENT_SHAPES, ids=str)
+def test_latent_width_flash_compiles_for_v5e(one_chip, shape):
+    b, t, h, dk, dv = shape
+
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+    _compile_for_chip(
+        jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+        ((b, t, h, dk), jnp.bfloat16), ((b, t, h, dk), jnp.bfloat16),
+        ((b, t, h, dv), jnp.bfloat16),
+        names=["mxtpu_flash_fwd_", "mxtpu_flash_bwd_"])
+
+
+def test_gated_delta_rule_scan_compiles_for_v5e(one_chip):
+    """The chunked scan at Kimi Linear's heads and widths, forward and
+    backward, two groups of chunks: plain XLA ops (no custom call), and the
+    plan fits a small share of the chip."""
+    from mxnet_tpu.ops import delta_rule
+    b, t, h, d = 1, 1024, 32, 128
+
+    def loss(q, k, v, g, beta):
+        return delta_rule.gated_delta_rule(
+            q, k, v, g, beta, qk_l2norm=True,
+            scale=d ** -0.5).astype(jnp.float32).sum()
+
+    wide = ((b, t, h, d), jnp.bfloat16)
+    compiled = _compile_for_chip(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip, wide, wide, wide,
+        ((b, t, h, d), jnp.float32), ((b, t, h), jnp.bfloat16), names=[])
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
 def test_grouped_matmul_of_the_expert_layer_compiles_for_v5e(one_chip):
     """``jax.lax.ragged_dot`` at the cell's size, forward and both backward
     products: three Mosaic grouped-matmul custom calls, no dense fall-back."""

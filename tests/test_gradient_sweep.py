@@ -215,6 +215,8 @@ CASES = {
     "RMSNorm": ([_x(2, 6), _pos(6)], {}),
     "_contrib_CausalConv1D": ([_x(2, 5, 4), _x(4, 3)], {"kernel": 3}),
     "_contrib_RotaryEmbedding": ([_x(1, 4, 2, 6)], {"base": 100.0}),
+    "_contrib_GatedRMSNorm": ([_x(2, 3, 2, 4), _x(2, 3, 2, 4), _pos(4)], {}),
+    "_contrib_KDAGate": ([_x(2, 3, 8), _x(2), _x(8)], {"num_heads": 2}),
 }
 
 # every other registered op must appear here, with the reason it has no
@@ -273,6 +275,9 @@ SKIP = {
     "_contrib_TopKMoE": "discrete top-k routing with an aux state; values "
                         "and gradients against the plain reference in "
                         "tests/test_lfm2_moe.py",
+    "_contrib_GatedDeltaRule": "a scan with a custom backward; values and all "
+                               "five gradients against the token-by-token "
+                               "recurrence in tests/test_kimi_linear.py",
     "Custom": "user-defined python op",
     "BlockGrad": "gradient blocked by definition (backward is zero, "
                  "forward is identity)",

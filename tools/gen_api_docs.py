@@ -56,7 +56,8 @@ MODULES = [
 # overwritten here, only indexed): title -> filename
 HAND_WRITTEN = [
     ("language-model ops (RMSNorm, rotary, short convolution, "
-     "grouped-query flash attention, top-k expert layer)",
+     "grouped-query and latent flash attention, gated delta rule, "
+     "top-k expert layer)",
      "language_model_ops.md"),
     ("resilience", "resilience.md"),
     ("analysis (static verifier + mxlint)", "analysis.md"),
@@ -179,7 +180,11 @@ SEE_ALSO = {
                  "resync count) restored by `restore_iterator` for "
                  "exact mid-epoch resume, chaos-gated through the "
                  "`io.resume` seam"],
-    "parallel": ["[resilience](resilience.md) — multihost init/barrier "
+    "parallel": ["[language-model ops](language_model_ops.md) — "
+                 "`topk_moe` as `_contrib_TopKMoE`: the share it holds, "
+                 "its buffer rule (`parallel.moe.buffer_rows`) and when "
+                 "no assignment can be dropped",
+                 "[resilience](resilience.md) — multihost init/barrier "
                  "timeouts, watchdog restarts, preemption handler",
                  "[analysis](analysis.md) — MXG007 sharding-coverage "
                  "verification against tp_rules, and the "
