@@ -46,6 +46,27 @@ walks the groups in reverse; for each it makes the state-free part again,
 runs the group's chunks forward once more for the state each was handed,
 then walks them in reverse.
 
+Two lowerings of that algebra, chosen by what the code observes
+(:func:`_lowering_for`: the backend and the shapes; no switch).  On a TPU,
+at the chunk of 64 and head widths of whole lane tiles, the scan is two
+Pallas kernels, :data:`KDA_FWD` and :data:`KDA_BWD`: grid (batch x heads,
+groups), a group of :data:`GROUP` positions a step, the head's float32
+state (backward: its cotangent) in VMEM scratch across the groups.  A
+step makes the group's state-free part in VMEM (:func:`_tiles_state_free`:
+the same tree, the same substitution inside blocks of :data:`SUB` and the
+same block formula, on tiles: no reshape across the tiling, no ``stack``,
+no row set in place) and walks the
+chunks through their products with the state; the backward takes the
+cotangents of the state-free part by ``jax.vjp`` of that function while the
+kernel body is traced, so that Mosaic sees dots, elementwise ops, ``iota``
+masks and row rolls.  Nothing of ``(chunks, heads, chunk, width)`` float32
+is written to HBM.  Anywhere else (the CPU, a toy width, another chunk) the
+same chunks run as ``jax.numpy`` ops under ``lax.scan`` (:func:`_forward`,
+:func:`_backward`): the tests' oracle beside the recurrence, as
+``_attention_jnp`` is for the flash kernels.  Under a mesh of more than one
+device the kernels' calls wrap themselves in a ``shard_map`` over (batch,
+heads).
+
 ``q`` and ``k`` may come raw: with ``qk_l2norm`` each head of both is
 normalised (``x * rsqrt(sum x^2 + 1e-6)``, float32) and ``q`` scaled inside
 the state-free part, so that the backward keeps the raw heads alone
@@ -57,6 +78,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from .. import context as _context
 
 #: ``jax.named_scope`` of the op on the device
 SCOPE_KDA = "mxtpu.block.kda"
@@ -235,9 +258,291 @@ def _unchunks(x):
 
 def _group_free(args, how):
     """The state-free parts of every chunk of one group, chunk-major.
-    ``how``: the static ``(chunk, sub, group, l2norm, scale)``."""
-    chunk, sub, _group, l2norm, scale = how
+    ``how``: the static ``(chunk, sub, group, l2norm, scale, lowering)``."""
+    chunk, sub, _group, l2norm, scale, _lowering = how
     return _state_free(*(_chunks(a, chunk) for a in args), sub, l2norm, scale)
+
+
+# ---- the same chunk algebra on two-dimensional tiles, for the kernels
+#: ``name=`` of the two ``pallas_call``s: what a device trace shows
+KDA_FWD = "mxtpu_kda_fwd"
+KDA_BWD = "mxtpu_kda_bwd"
+#: VMEM the kernels ask for: a group's state-free part and, backward, what
+#: its cotangents are pulled through stay there
+_VMEM_FWD = 48 * 2 ** 20
+_VMEM_BWD = 100 * 2 ** 20
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _bmm(a, b, contract, precision=None):
+    """Batched over the leading axis: ``a``'s and ``b``'s axes ``contract``
+    summed, float32 result."""
+    return jax.lax.dot_general(
+        a, b, ((contract[:1], contract[1:]), ((0,), (0,))),
+        precision=precision, preferred_element_type=_F32)
+
+
+def _dot(a, b, contract, dtype):
+    """Two-dimensional: operands in ``dtype``, float32 result."""
+    return jax.lax.dot_general(
+        a.astype(dtype), b.astype(dtype),
+        ((contract[:1], contract[1:]), ((), ())),
+        preferred_element_type=_F32)
+
+
+def _sibling_mask(c, b):
+    """``(c, c)``: ``j``'s block of ``b`` positions (a power of two) is the
+    left sibling of ``i``'s."""
+    by = b.bit_length() - 1
+    bi, bj = _iota((c, c), 0) >> by, _iota((c, c), 1) >> by
+    return (bi == bj + 1) & ((bi & 1) == 1)
+
+
+def _tiles_inverse(m, chunk, sub):
+    """:func:`_unit_lower_inverse` for ``(n, chunk, chunk)`` tiles: forward
+    substitution row by row inside the diagonal blocks of ``sub`` positions
+    (elementwise), then the same block formula at the same precision: with
+    ``x`` the inverse of the diagonal blocks of ``b`` positions and ``low``
+    the blocks under them that join two siblings, ``x - x low x`` is the
+    inverse at ``2 b``.  No row is set in place and no block is cut out:
+    row ``r`` of every block is made at once from the transposed blocks
+    (their column ``r``, summed along the lanes, is what scales the rows
+    solved so far), summed over each block's rows and chosen by a mask."""
+    n = m.shape[0]
+    row, lane = _iota((chunk, chunk), 0), _iota((chunk, chunk), 1)
+    eye = jnp.where(row == lane, 1.0, 0.0).astype(_F32)
+    by = sub.bit_length() - 1
+    blocks = jnp.where((row >> by) == (lane >> by), m, 0.0)
+    across = jnp.swapaxes(blocks, 1, 2)
+    x = jnp.broadcast_to(eye, m.shape)
+    for r in range(1, sub):
+        scale = jnp.sum(jnp.where((lane & (sub - 1)) == r, across, 0.0),
+                        axis=2, keepdims=True)
+        solved = jnp.sum((scale * x).reshape(n, chunk // sub, sub, chunk),
+                         axis=2, keepdims=True)
+        solved = jnp.broadcast_to(solved, (n, chunk // sub, sub, chunk))
+        x = jnp.where((row & (sub - 1)) == r, eye - solved.reshape(m.shape), x)
+    b = sub
+    while b < chunk:
+        low = jnp.where(_sibling_mask(chunk, b), m, 0.0)
+        x = x - _bmm(_bmm(x, low, (2, 1), _HI), x, (2, 1), _HI)
+        b *= 2
+    return x
+
+
+def _tiles_state_free(q, k, v, g, beta, *, chunk, sub, l2norm, scale, roll):
+    """:func:`_state_free` for the ``n`` chunks of one group at once, on
+    tiles: ``q, k, v`` ``(n * chunk, d)`` in the compute dtype, ``g``
+    float32, ``beta`` ``(n, chunk)`` float32 (a chunk a row: lane-dense in
+    HBM; turned into a column here).  Returns ``(Q exp(G), W, U', K
+    exp(G_last - G))`` as ``(n * chunk, d)``, ``exp(G_last)`` ``(n, 1, dk)``
+    and ``tril(B)`` ``(n, chunk, chunk)``.
+
+    What differs from the ``jax.numpy`` form is where things live, not what
+    is computed: the running sums are one product with a triangle of ones
+    (float32, highest precision); the running sum at a block's boundary is
+    found by ``roll``ing rows (``pltpu.roll``), level by level, and carries
+    no gradient (it cancels between the two factors it scales); the two
+    factors of a level share one ``exp`` (rows of a right sibling take
+    ``exp(G - start)``, rows of a left sibling ``exp(end - G)``: the only
+    rows the level keeps); the substitution makes a row of every block at
+    once by masks and sums (:func:`_tiles_inverse`); ``beta`` scales the
+    rows of the operands of ``(I + Diag(beta) tril(A, -1))^-1`` instead of
+    its columns; masks are made a chunk's tile at a time and shared by the
+    group's chunks."""
+    dtype = q.dtype
+    rows, dk = k.shape
+    n = rows // chunk
+
+    def tiles(x):
+        return x.reshape((n, chunk) + x.shape[1:])
+
+    def rolled(x, by):          # rows move down by ``by``, round the group
+        return tiles(roll(x.reshape(rows, dk), by % rows))
+
+    q32, k32 = tiles(q).astype(_F32), tiles(k).astype(_F32)
+    if l2norm:
+        q32, k32 = _l2(q32), _l2(k32)
+    q32 = q32 * scale
+    eye = _iota((chunk, chunk), 0) == _iota((chunk, chunk), 1)
+    # (n, chunk) -> (n, chunk, 1) by masks and sums alone: chunk c's row,
+    # then its entries down the diagonal of a tile, summed along the lanes
+    chunk_of = _iota(beta.shape, 0)
+    beta = tiles(jnp.concatenate([
+        jnp.sum(jnp.where(eye, jnp.sum(jnp.where(chunk_of == c, beta, 0.0),
+                                       axis=0, keepdims=True), 0.0),
+                axis=1, keepdims=True) for c in range(n)], axis=0))
+    ones = jnp.where(_iota((chunk, chunk), 0) >= _iota((chunk, chunk), 1),
+                     1.0, 0.0).astype(_F32)
+    big_g = _bmm(jnp.broadcast_to(ones, (n, chunk, chunk)),
+                 tiles(g.astype(_F32)), (2, 1), _HI)
+    pos = _iota((chunk, dk), 0)                # place inside the chunk
+    ends = jax.lax.stop_gradient(big_g)        # of blocks of one position
+    strict_k = strict_q = 0.0
+    b = 1
+    while b < chunk:
+        # rows of a right sibling look back to where their block starts
+        # (the left sibling's end), rows of a left sibling ahead to where
+        # theirs ends; a chunk's first block is a left sibling, so that what
+        # rolls in from the chunk before is never looked at
+        right_sibling = (pos & b) != 0
+        e = jnp.exp(jnp.where(right_sibling, big_g - rolled(ends, b),
+                              ends - big_g))
+        ke, qe = (k32 * e).astype(dtype), (q32 * e).astype(dtype)
+        sel = _sibling_mask(chunk, b)
+        strict_k = strict_k + jnp.where(sel, _bmm(ke, ke, (2, 2)), 0.0)
+        strict_q = strict_q + jnp.where(sel, _bmm(qe, ke, (2, 2)), 0.0)
+        ends = jnp.where(right_sibling, ends, rolled(ends, -b))
+        b *= 2
+    own = jnp.sum(q32 * k32, axis=-1, keepdims=True)
+    incl = strict_q + jnp.where(eye, own, 0.0)
+    inv = _tiles_inverse(beta * strict_k, chunk, sub).astype(dtype)
+    decay = jnp.exp(big_g)
+    last = jnp.sum(jnp.where(pos == chunk - 1, big_g, 0.0),
+                   axis=1, keepdims=True)                     # (n, 1, dk)
+    w = _bmm(inv, (beta * k32 * decay).astype(dtype), (2, 1))
+    u = _bmm(inv, (beta * tiles(v).astype(_F32)).astype(dtype), (2, 1))
+    kd = k32 * jnp.exp(last - big_g)
+
+    def flat(x):
+        return x.reshape(rows, x.shape[-1]).astype(dtype)
+
+    return (flat(q32 * decay), flat(w), flat(u), flat(kd), jnp.exp(last),
+            incl.astype(dtype))
+
+
+def _tile_fwd(state, free):
+    """:func:`_chunk_fwd` on one chunk's tiles; the state is held
+    transposed, ``(dv, dk)``, so that a decay a key channel scales its
+    lanes."""
+    qg, w, u0, kd, gamma, b = free
+    dtype = qg.dtype
+    u = u0.astype(_F32) - _dot(w, state, (1, 1), dtype)
+    o = _dot(qg, state, (1, 1), dtype) + _dot(b, u, (1, 0), dtype)
+    return gamma * state + _dot(u, kd, (0, 0), dtype), o
+
+
+def _tile_bwd(d_state, free, state, d_o):
+    """:func:`_chunk_bwd` on one chunk's tiles, states transposed."""
+    qg, w, u0, kd, gamma, b = free
+    dtype = qg.dtype
+    u = u0.astype(_F32) - _dot(w, state, (1, 1), dtype)
+    d_u = _dot(b, d_o, (0, 0), dtype) + _dot(kd, d_state, (1, 1), dtype)
+    d_free = (_dot(d_o, state, (1, 0), dtype),
+              -_dot(d_u, state, (1, 0), dtype),
+              d_u,
+              _dot(u, d_state, (1, 0), dtype),
+              jnp.sum(d_state * state, axis=0, keepdims=True),
+              _dot(d_o, u, (1, 1), dtype))
+    d_prev = _dot(d_o, qg, (0, 0), dtype) - _dot(d_u, w, (0, 0), dtype) \
+        + gamma * d_state
+    return d_prev, d_free
+
+
+def _tile(free, c, chunk):
+    """Chunk ``c``'s part of a group's state-free tiles."""
+    rows = slice(c * chunk, (c + 1) * chunk)
+    return tuple(x[rows] for x in free[:4]) + (free[4][c], free[5][c])
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, starts_ref,
+                    state, *, free_of, chunk):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first_group():
+        state[...] = jnp.zeros_like(state)
+
+    s = state[...]
+    starts_ref[0, 0] = s
+    free = free_of(q_ref[0], k_ref[0], v_ref[0], g_ref[0], beta_ref[0, 0])
+    for c in range(q_ref.shape[1] // chunk):
+        s, o = _tile_fwd(s, _tile(free, c, chunk))
+        o_ref[0, c * chunk:(c + 1) * chunk, :] = o.astype(o_ref.dtype)
+    state[...] = s
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref,
+                    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_state, *,
+                    free_of, chunk):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) == 0)
+    def _last_group():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    n = q_ref.shape[1] // chunk
+    # autodiff runs while this body is traced: what reaches Mosaic is the
+    # state-free part's own ops and their transposes
+    free, pull = jax.vjp(free_of, q_ref[0], k_ref[0], v_ref[0], g_ref[0],
+                         beta_ref[0, 0])
+    s, handed = starts_ref[0, 0], []
+    for c in range(n):
+        handed.append(s)
+        if c + 1 < n:
+            s = _tile_fwd(s, _tile(free, c, chunk))[0]
+    ds, d_free = d_state[...], [None] * n
+    for c in reversed(range(n)):
+        ds, d_free[c] = _tile_bwd(ds, _tile(free, c, chunk), handed[c],
+                                  do_ref[0, c * chunk:(c + 1) * chunk, :])
+    d_state[...] = ds
+    cot = tuple(jnp.concatenate([d[i] for d in d_free], axis=0).astype(f.dtype)
+                for i, f in enumerate(free[:4])) \
+        + tuple(jnp.stack([d[i] for d in d_free]).astype(f.dtype)
+                for i, f in zip((4, 5), free[4:]))
+    grads = pull(cot)
+    for ref, grad in zip((dq_ref, dk_ref, dv_ref, dg_ref), grads):
+        ref[0] = grad.astype(ref.dtype)
+    dbeta_ref[0, 0] = grads[4]
+
+
+def _kernel_parts(q, how, reverse=False):
+    """What both kernels' calls share: ``(the state-free part bound to its
+    statics, block specs by width, the call's keywords by VMEM asked for, the
+    arguments as the kernels take them)``.  ``reverse``: the grid walks the
+    groups from the last."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    chunk, sub, group, l2norm, scale, lowering = how
+    b, h, t = q.shape[:3]
+    last = t // group - 1
+    free_of = functools.partial(
+        _tiles_state_free, chunk=chunk, sub=sub, l2norm=l2norm, scale=scale,
+        roll=lambda x, by: pltpu.roll(x, by, 0))
+
+    def group_of(gi):
+        return last - gi if reverse else gi
+
+    def rows(width):
+        """A group's rows of a ``(heads, T, width)`` array; ``width`` None:
+        of ``beta`` as ``(heads, groups, group / chunk, chunk)``, a chunk a
+        row; a ``(dv, dk)`` pair: the group's state of ``(groups, heads, dv,
+        dk)``."""
+        if width is None:
+            return pl.BlockSpec((1, 1, group // chunk, chunk),
+                                lambda bh, gi: (bh, group_of(gi), 0, 0))
+        if isinstance(width, tuple):
+            return pl.BlockSpec((1, 1) + width,
+                                lambda bh, gi: (group_of(gi), bh, 0, 0))
+        return pl.BlockSpec((1, group, width),
+                            lambda bh, gi: (bh, group_of(gi), 0))
+
+    def keywords(vmem):
+        if lowering == "interpret":
+            return {"interpret": True}
+        return {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem)}
+
+    def flat(*wide, beta):
+        return [x.reshape((b * h, t) + x.shape[3:]) for x in wide] \
+            + [beta.astype(_F32).reshape(b * h, t // group, group // chunk,
+                                         chunk)]
+
+    return free_of, rows, keywords, flat
 
 
 #: traced once a signature and inlined where it is called, as the flash
@@ -298,6 +603,53 @@ def _backward(q, k, v, g, beta, starts, d_o, *, how):
     return tuple(_unchunks(x) for x in grads)
 
 
+@_traced_once
+def _forward_kernel(q, k, v, g, beta, *, how):
+    """:func:`_forward` as one ``pallas_call``: grid (batch x heads, groups),
+    a group a step, the head's float32 state in VMEM across the groups.  The
+    states come back transposed, ``(T / group, B, H, dv, dk)``: they are
+    :func:`_backward_kernel`'s alone."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    free_of, rows, keywords, flat = _kernel_parts(q, how)
+    (b, h, t, dk), dv = q.shape, v.shape[-1]
+    n_groups = t // how[2]
+    o, starts = pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, free_of=free_of, chunk=how[0]),
+        grid=(b * h, n_groups),
+        in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), rows(None)],
+        out_specs=[rows(dv), rows((dv, dk))],
+        out_shape=[jax.ShapeDtypeStruct((b * h, t, dv), v.dtype),
+                   jax.ShapeDtypeStruct((n_groups, b * h, dv, dk), _F32)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
+        name=KDA_FWD, **keywords(_VMEM_FWD),
+    )(*flat(q, k, v, g, beta=beta))
+    return o.reshape(b, h, t, dv), starts.reshape(n_groups, b, h, dv, dk)
+
+
+@_traced_once
+def _backward_kernel(q, k, v, g, beta, starts, d_o, *, how):
+    """:func:`_backward` as one ``pallas_call`` that walks the groups in
+    reverse, the cotangent of the state in VMEM across them."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    free_of, rows, keywords, flat = _kernel_parts(q, how, reverse=True)
+    (b, h, t, dk), dv = q.shape, v.shape[-1]
+    args = flat(q, k, v, g, beta=beta)
+    wide = [rows(dk), rows(dk), rows(dv), rows(dk), rows(None)]
+    grads = pl.pallas_call(
+        functools.partial(_kda_bwd_kernel, free_of=free_of, chunk=how[0]),
+        grid=(b * h, t // how[2]),
+        in_specs=wide + [rows((dv, dk)), rows(dv)],
+        out_specs=wide,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args],
+        scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
+        name=KDA_BWD, **keywords(_VMEM_BWD),
+    )(*args, starts.reshape((-1, b * h, dv, dk)), d_o.reshape(b * h, t, dv))
+    return tuple(x.reshape(like.shape).astype(like.dtype)
+                 for x, like in zip(grads, (q, k, v, g, beta)))
+
+
 def _sizes(t, chunk, group):
     """``(padded length, group)`` for ``t`` positions: whole chunks, and
     whole groups of chunks once there is more than one group."""
@@ -319,21 +671,55 @@ def _head_major(x, padded):
     return x
 
 
+def _lowerings(how):
+    """``(forward, backward)`` of the lowering ``how`` names."""
+    return (_forward, _backward) if how[5] == "xla" \
+        else (_forward_kernel, _backward_kernel)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _scan(q, k, v, g, beta, how):
-    return _forward(q, k, v, g, beta, how=how)[0]
+    return _lowerings(how)[0](q, k, v, g, beta, how=how)[0]
 
 
 def _scan_fwd(q, k, v, g, beta, how):
-    o, starts = _forward(q, k, v, g, beta, how=how)
+    o, starts = _lowerings(how)[0](q, k, v, g, beta, how=how)
     return o, (q, k, v, g, beta, starts)
 
 
 def _scan_bwd(how, res, d_o):
-    return _backward(*res, d_o, how=how)
+    return _lowerings(how)[1](*res, d_o, how=how)
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)
+
+
+def _lowering_for(chunk, dk, dv):
+    """``"pallas"`` where the two kernels run (a TPU, the chunk of 64 and
+    widths of whole lane tiles), else ``"xla"``: the ``jax.numpy`` form.
+    (``"interpret"``, the kernels under Pallas's interpreter at any width,
+    is the tests' to ask for.)"""
+    if _context.on_tpu() and chunk == CHUNK and dk % 128 == 0 \
+            and dv % 128 == 0:
+        return "pallas"
+    return "xla"
+
+
+def _on_the_mesh(args, how):
+    """``_scan`` over head-major ``args``; the kernels under a mesh of more
+    than one device wrapped in a ``shard_map`` over (batch, heads), as the
+    flash kernels are: the state mixes neither axis."""
+    from ..parallel import mesh as _mesh
+    mesh = None if how[5] == "xla" else _mesh.active_kernel_mesh()
+    if mesh is None:
+        return _scan(*args, how)
+    from jax.sharding import PartitionSpec as P
+    b_axis, h_axis = _mesh.kernel_axes(mesh, args[0].shape[0],
+                                       args[0].shape[1])
+    wide, narrow = P(b_axis, h_axis, None, None), P(b_axis, h_axis, None)
+    return _mesh.shard_map_nocheck(
+        lambda *a: _scan(*a, how), mesh,
+        in_specs=(wide, wide, wide, wide, narrow), out_specs=wide)(*args)
 
 
 # mxlint: allow-dtype-widening(the log-decay, its running sums and the state are float32 by the op's definition)
@@ -353,15 +739,17 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, sub=SUB, group=GROUP,
                          % chunk)
     sub = min(int(sub), chunk)
     padded, group = _sizes(t, chunk, int(group))
-    how = (chunk, sub, group, bool(qk_l2norm), float(scale))
+    lowering = _lowering_for(chunk, int(q.shape[3]), int(v.shape[3]))
+    how = (chunk, sub, group, bool(qk_l2norm), float(scale), lowering)
     with jax.named_scope(SCOPE_KDA):
         args = [_head_major(x, padded)
                 for x in (q, k, v.astype(q.dtype), g.astype(_F32),
                           beta.astype(q.dtype))]
-        o = _scan(*args, how)
+        o = _on_the_mesh(args, how)
         o = jnp.moveaxis(o[:, :, :t], 1, 2).astype(v.dtype)
     note_layer(heads=int(q.shape[2]), dk=int(q.shape[3]), dv=int(v.shape[3]),
                positions=int(t), chunk=chunk, group=group, form="chunked",
+               lowering="xla" if lowering == "xla" else "pallas",
                state_bytes=4 * int(q.shape[0]) * int(q.shape[2])
                * int(q.shape[3]) * int(v.shape[3]) * (padded // group))
     return o
@@ -391,6 +779,8 @@ class plan_recording:
                 "layers": layers,
                 "chunked_layers": sum(1 for x in layers
                                       if x["form"] == "chunked"),
+                "kernel_layers": sum(1 for x in layers
+                                     if x["lowering"] == "pallas"),
                 "state_bytes": sum(x["state_bytes"] for x in layers)}
         return False
 
@@ -406,7 +796,8 @@ def last_plan_summary():
     """Summary of the linear-attention layers of the step traced last in
     this process (None before any): per layer its heads, widths, positions,
     chunk and group lengths, the form it lowered to (``chunked``: this
-    module's scan) and the bytes of state its backward keeps (one state a
-    head and group); ``chunked_layers`` and
+    module's scan), its ``lowering`` (``pallas``: the two kernels; ``xla``:
+    ``jax.numpy`` ops) and the bytes of state its backward keeps (one state
+    a head and group); ``chunked_layers``, ``kernel_layers`` and
     ``state_bytes`` over all of them.  As ``moe.last_plan_summary()``."""
     return _LAST_SUMMARY

@@ -119,11 +119,15 @@ def test_latent_width_flash_compiles_for_v5e(one_chip, shape):
         names=["mxtpu_flash_fwd_", "mxtpu_flash_bwd_"])
 
 
-def test_gated_delta_rule_scan_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("names", [[], ["mxtpu_kda_fwd", "mxtpu_kda_bwd"]],
+                         ids=["jax_numpy_form", "kernels"])
+def test_gated_delta_rule_scan_compiles_for_v5e(one_chip, names, monkeypatch):
     """The chunked scan at Kimi Linear's heads and widths, forward and
-    backward, two groups of chunks: plain XLA ops (no custom call), and the
-    plan fits a small share of the chip."""
+    backward, two groups of chunks: as plain XLA ops (no custom call: what
+    any backend but the TPU runs) and, the platform probe patched true, as
+    the two kernels; either plan fits a small share of the chip."""
     from mxnet_tpu.ops import delta_rule
+    monkeypatch.setattr(context, "on_tpu", lambda: bool(names))
     b, t, h, d = 1, 1024, 32, 128
 
     def loss(q, k, v, g, beta):
@@ -134,7 +138,7 @@ def test_gated_delta_rule_scan_compiles_for_v5e(one_chip):
     wide = ((b, t, h, d), jnp.bfloat16)
     compiled = _compile_for_chip(
         jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip, wide, wide, wide,
-        ((b, t, h, d), jnp.float32), ((b, t, h), jnp.bfloat16), names=[])
+        ((b, t, h, d), jnp.float32), ((b, t, h), jnp.bfloat16), names=names)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 

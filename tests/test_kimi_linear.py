@@ -4,6 +4,7 @@ their own, the expert layer's share-sized buffer, the shared expert, and the
 five-layer model through ``ShardedTrainer`` against the plain reference
 (``benchmark/references/kimi-linear-48b-a3b.py``), all at toy size on the CPU.
 """
+import functools
 import hashlib
 import json
 import os
@@ -99,15 +100,46 @@ KDA_CASES = {
 }
 
 
+LOWERINGS = ["xla", "pallas"]
+
+
+@functools.lru_cache(maxsize=None)
+def _oracle(name):
+    """The recurrence's values and five gradients (the cotangent of seed 9)
+    for one case of ``KDA_CASES``: made once, whatever the group and the
+    lowering it is held against."""
+    t, _chunk, strongest, beta, repeat = KDA_CASES[name]
+    args = _kda_inputs(t, strongest, beta, repeat)
+    with jax.default_matmul_precision("highest"):
+        want, pull = jax.vjp(
+            lambda q, k, v, g, be: _recurrent(*_normed(q, k), v, g, be), *args)
+        return want, pull(_rand(*args[2].shape, seed=9))
+
+
+@pytest.fixture
+def lowered_as(monkeypatch):
+    """``lowered_as("pallas")``: the scan's two kernels under Pallas's
+    interpreter, whatever the widths (the module itself chooses them on a
+    TPU alone); ``"xla"``: the ``jax.numpy`` form, as the CPU takes it."""
+    def choose(lowering):
+        if lowering == "pallas":
+            monkeypatch.setattr(delta_rule, "_lowering_for",
+                                lambda *_: "interpret")
+    return choose
+
+
+@pytest.mark.parametrize("lowering", LOWERINGS)
 @pytest.mark.parametrize("group", [1024, 128], ids=["one_group", "groups"])
 @pytest.mark.parametrize("name", sorted(KDA_CASES))
-def test_chunked_scan_matches_the_recurrence(name, group):
+def test_chunked_scan_matches_the_recurrence(name, group, lowering, lowered_as):
     """Values and all five gradients, float32, to 1e-4 of the largest
     gradient: chunks that divide the sequence and do not, decays from mild to
     the assumed initialisation's strongest (1.6 a position on some channels:
     102 inside a chunk, past what ``exp`` holds in float32 when factored round
     the chunk's start) and 5 times beyond, ``beta`` at 0 and 1, a head whose
-    keys repeat; one group of chunks and several (the backward's kept states)."""
+    keys repeat; one group of chunks and several (the backward's kept states);
+    as the ``jax.numpy`` form and as the two kernels."""
+    lowered_as(lowering)
     t, chunk, strongest, beta, repeat = KDA_CASES[name]
     args = _kda_inputs(t, strongest, beta, repeat)
     cot = _rand(*args[2].shape, seed=9)
@@ -117,13 +149,10 @@ def test_chunked_scan_matches_the_recurrence(name, group):
             q, k, v, g, be, chunk=chunk, group=group, qk_l2norm=True,
             scale=q.shape[-1] ** -0.5)
 
-    def recurrent(q, k, v, g, be):
-        return _recurrent(*_normed(q, k), v, g, be)
-
     with jax.default_matmul_precision("highest"):
-        got, want = chunked(*args), recurrent(*args)
-        g_got = jax.grad(lambda *a: jnp.sum(chunked(*a) * cot), range(5))(*args)
-        g_want = jax.grad(lambda *a: jnp.sum(recurrent(*a) * cot), range(5))(*args)
+        got, pull = jax.vjp(chunked, *args)     # one trace: values and gradients
+        g_got = pull(cot)
+    want, g_want = _oracle(name)
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     for a, b in zip(g_got, g_want):
@@ -134,10 +163,12 @@ def test_chunked_scan_matches_the_recurrence(name, group):
         assert float(jnp.abs(got).max()) == 0.0
 
 
-def test_chunked_scan_in_bfloat16_with_a_float32_state():
+@pytest.mark.parametrize("lowering", LOWERINGS)
+def test_chunked_scan_in_bfloat16_with_a_float32_state(lowering, lowered_as):
     """bfloat16 ``q, k, v`` with float32 decay and state against the float32
     recurrence on the same (rounded) inputs: the products' operands are
     rounded to 8 bits, so 2% of the result's scale."""
+    lowered_as(lowering)
     q, k, v, g, be = _kda_inputs(256, 1.6, "noise")
     lo = [x.astype(jnp.bfloat16) for x in (q, k, v)]
     got = delta_rule.gated_delta_rule(*lo, g, be.astype(jnp.bfloat16),
@@ -148,6 +179,91 @@ def test_chunked_scan_in_bfloat16_with_a_float32_state():
                       be.astype(jnp.bfloat16).astype(jnp.float32))
     err = float(jnp.abs(got.astype(jnp.float32) - want).max())
     assert err <= 0.02 * float(jnp.abs(want).max()), err
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("l2norm", [True, False], ids=["l2norm", "raw"])
+def test_kernels_match_the_jax_numpy_form(l2norm, dtype, lowered_as):
+    """The two lowerings of one algebra against each other, values and the
+    five gradients, ``q`` and ``k`` normalised inside the op and raw:
+    float32 to float noise; bfloat16 operands (the state float32 in both) to
+    the operands' rounding, and every gradient in its input's dtype."""
+    q, k, v, g, be = _kda_inputs(200, 1.6, "noise")
+    if not l2norm:
+        q, k = _normed(q, k)
+    args = [x.astype(dtype) for x in (q, k, v)] + [g, be.astype(dtype)]
+    cot = _rand(*v.shape, seed=9).astype(dtype)
+
+    def both():
+        f = lambda *a: delta_rule.gated_delta_rule(      # noqa: E731
+            *a, group=128, qk_l2norm=l2norm, scale=0.5)
+        out, pull = jax.vjp(f, *args)
+        return (out,) + pull(cot)
+
+    want = both()
+    lowered_as("pallas")
+    got = both()
+    tol = 1e-5 if dtype == "float32" else 3e-2
+    for a, b, like in zip(got, want, [args[2]] + args):
+        assert a.dtype == b.dtype == like.dtype
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert bool(jnp.isfinite(a).all())
+        assert float(jnp.abs(a - b).max()) <= tol * float(jnp.abs(b).max()) + 1e-7
+
+
+@pytest.mark.parametrize("why,kwargs,patched", [
+    ("the_cpu", dict(dk=128, dv=128), False),
+    ("a_width_that_is_no_lane_tile", dict(dk=96, dv=128), True),
+    ("a_value_width_that_is_no_lane_tile", dict(dk=128, dv=64), True),
+    ("a_chunk_of_32", dict(dk=128, dv=128, chunk=32), True)])
+def test_what_the_kernels_do_not_take_runs_the_jax_numpy_form(
+        why, kwargs, patched, monkeypatch):
+    """The choice is made from the backend and the shapes alone, and the
+    plan says what was chosen."""
+    from mxnet_tpu import context
+    if patched:
+        monkeypatch.setattr(context, "on_tpu", lambda: True)
+    chunk = kwargs.pop("chunk", 64)
+    args = _kda_inputs(64, 0.5, "noise", heads=1, **kwargs)
+    with delta_rule.plan_recording():
+        text = str(jax.make_jaxpr(lambda *a: delta_rule.gated_delta_rule(
+            *a, chunk=chunk))(*args))
+    assert "pallas_call" not in text
+    plan = delta_rule.last_plan_summary()
+    assert plan["kernel_layers"] == 0 and plan["chunked_layers"] == 1
+    assert plan["layers"][0]["lowering"] == "xla"
+    # the same shapes at whole lane tiles, a chunk of 64 and a TPU: kernels
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    assert delta_rule._lowering_for(64, 128, 256) == "pallas"
+
+
+@pytest.mark.parametrize("shape", [(4, 1), (2, 2)], ids=["dp4", "dp2_tp2"])
+def test_kernels_under_a_mesh_equal_the_unsharded_call(shape, lowered_as):
+    """Four virtual CPU devices: the ``shard_map``-wrapped kernels (batch
+    over the data axis, heads over ``model``) against one device's."""
+    from mxnet_tpu.parallel import build_mesh
+    from mxnet_tpu.parallel.mesh import kernel_mesh
+    lowered_as("pallas")
+    q, k, v, g, be = _kda_inputs(128, 0.5, "noise", heads=2)
+    args = [jnp.concatenate([x, x[::-1]]) for x in (q, k, v, g, be)]  # batch 4
+    cot = _rand(*args[2].shape, seed=9)
+
+    def both():
+        # a function of its own a trace: the mesh is no argument jax sees
+        def run(*a):
+            out, pull = jax.vjp(lambda *b: delta_rule.gated_delta_rule(
+                *b, group=64, qk_l2norm=True, scale=0.5), *a)
+            return (out,) + pull(cot)
+        return run
+
+    want = jax.jit(both())(*args)
+    mesh = build_mesh(devices=jax.devices()[:4], tp=shape[1])
+    with kernel_mesh(mesh):
+        text = str(jax.make_jaxpr(both())(*args))
+        got = jax.jit(both())(*args)
+    assert text.count("shard_map") == text.count("pallas_call") == 2
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
 
 def test_scan_keeps_a_state_a_group_not_a_token():
@@ -165,8 +281,10 @@ def test_scan_keeps_a_state_a_group_not_a_token():
     assert plan["chunked_layers"] == 1
     assert plan["layers"][0] == {
         "heads": 3, "dk": 32, "dv": 16, "positions": 256, "chunk": 64,
-        "group": 128, "form": "chunked", "state_bytes": states[0].size * 4}
+        "group": 128, "form": "chunked", "lowering": "xla",
+        "state_bytes": states[0].size * 4}
     assert plan["state_bytes"] == states[0].size * 4
+    assert plan["kernel_layers"] == 0
 
 
 # ------------------------------------------- the small ops beside it
@@ -637,8 +755,9 @@ def test_model_through_sharded_trainer_follows_the_reference(both_sides, number,
 def test_trainer_records_the_three_plans(both_sides):
     kda, experts, causal = both_sides[2]
     assert kda["chunked_layers"] == 4 and len(kda["layers"]) == 4
-    assert {(x["heads"], x["dk"], x["dv"], x["chunk"], x["form"])
-            for x in kda["layers"]} == {(4, 16, 16, 64, "chunked")}
+    assert {(x["heads"], x["dk"], x["dv"], x["chunk"], x["form"], x["lowering"])
+            for x in kda["layers"]} == {(4, 16, 16, 64, "chunked", "xla")}
+    assert kda["kernel_layers"] == 0
     assert kda["state_bytes"] == 4 * 4 * 4 * 16 * 16
     assert experts["expert_layers"] == 4
     assert {(x["buffer_rows"], x["even_rows"]) for x in experts["layers"]} \
@@ -646,6 +765,47 @@ def test_trainer_records_the_three_plans(both_sides):
     # no causal flash kernel on the CPU: the plain formula ran
     assert causal is None or all(k["dk"] != k["dv"] or k["dk"] != 24
                                  for k in causal["kernels"])
+
+
+def test_toy_step_lowered_for_the_tpu_holds_the_two_kernels_a_layer(
+        toy_cell, monkeypatch):
+    """The toy model's two leading layers (both KDA) at one head of 128, the
+    platform probe patched true, the step lowered for the TPU from here: two
+    ``tpu_custom_call``s a layer, named for the trace, forward under
+    ``mxtpu.fwd`` and backward under ``mxtpu.bwd``, and the plan says
+    ``pallas``."""
+    from mxnet_tpu import context
+    from mxnet_tpu.parallel import ShardedTrainer, build_mesh
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    cfg = dict(toy_cell.cfg, num_hidden_layers=2, linear_attn_config=dict(
+        toy_cell.cfg["linear_attn_config"], head_dim=128, num_heads=1))
+    net, data, label = toy_cell.cfgmod.build(cfg, toy_cell.mix, 1)
+    opt = dict(cfg["optimizer"])
+    t = ShardedTrainer(
+        net, build_mesh(devices=jax.devices()[:1], tp=1), data_shapes=data,
+        label_shapes=label, optimizer=opt.pop("optimizer"), seed=1, **opt,
+        **cfg["trainer"])
+    spec = lambda tree: jax.tree.map(                       # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    args = (spec(t.params), spec(t.opt_state), spec(t.aux),
+            {k: jax.ShapeDtypeStruct(v, jnp.float32)
+             for k, v in {**data, **label}.items()},
+            jax.ShapeDtypeStruct((2,), jnp.uint32),
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.float32))
+    text = jax.jit(t._py_step).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert (delta_rule.KDA_FWD, delta_rule.KDA_BWD) == \
+        ("mxtpu_kda_fwd", "mxtpu_kda_bwd")
+    assert text.count("tpu_custom_call") == 4
+    assert text.count('kernel_name = "mxtpu_kda_fwd"') == 2
+    assert text.count('kernel_name = "mxtpu_kda_bwd"') == 2
+    assert "mxtpu.fwd/jvp(mxtpu.block.kda)/mxtpu_kda_fwd/pallas_call" in text
+    assert "jvp(mxtpu.block.kda)/mxtpu_kda_bwd/pallas_call" in text
+    assert "mxtpu.bwd/" in text
+    plan = delta_rule.last_plan_summary()
+    assert plan["kernel_layers"] == plan["chunked_layers"] == 2
+    assert {x["lowering"] for x in plan["layers"]} == {"pallas"}
 
 
 def test_every_leaf_of_the_model_is_drawn_on_the_device(both_sides):
@@ -666,12 +826,14 @@ def test_new_readers_read_the_plans_and_none_without_them(monkeypatch, toy_cell)
         return run.load_module("layer_metrics", name).read({"cell": toy_cell})
 
     monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", {
-        "layers": [{}] * 4, "chunked_layers": 4, "state_bytes": 67108864})
+        "layers": [{}] * 4, "chunked_layers": 4, "kernel_layers": 3,
+        "state_bytes": 67108864})
     layer = {"buffer_rows": 8192, "even_rows": 2048.0, "experts_held": 8,
              "num_experts": 256}
     monkeypatch.setattr(moe, "_LAST_SUMMARY", {
         "layers": [layer, dict(layer, buffer_rows=4096)]})
     assert read("kda_chunked_layers") == 4
+    assert read("kda_kernel_layers") == 3
     assert read("kda_state_saved_gb") == 67108864 / 1e9
     assert read("moe_buffer_rows_pct") == 12.5
     # LFM2's quarter: every assignment has a row
@@ -681,11 +843,15 @@ def test_new_readers_read_the_plans_and_none_without_them(monkeypatch, toy_cell)
     # a plan of the parent's (no even_rows): nothing to read
     monkeypatch.setattr(moe, "_LAST_SUMMARY", {"layers": [{"buffer_rows": 64}]})
     assert read("moe_buffer_rows_pct") is None
+    # the parent's plan (no lowering recorded): the new reader has nothing
+    monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", {
+        "layers": [{}] * 4, "chunked_layers": 4, "state_bytes": 67108864})
+    assert read("kda_kernel_layers") is None
     # a program without the records (the parent of this change): None, no raise
     monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", None)
     monkeypatch.delattr(moe, "last_plan_summary")
-    for name in ("kda_chunked_layers", "kda_state_saved_gb",
-                 "moe_buffer_rows_pct"):
+    for name in ("kda_chunked_layers", "kda_kernel_layers",
+                 "kda_state_saved_gb", "moe_buffer_rows_pct"):
         assert read(name) is None
 
 
