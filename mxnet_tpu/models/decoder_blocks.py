@@ -1,6 +1,7 @@
 """Symbol-level pieces the decoder language models share
-(``lfm2_moe``, ``kimi_linear``): a linear map without bias, the gated
-MLP and the top-k expert layer over the experts held here."""
+(``lfm2_moe``, ``kimi_linear``, ``afmoe``): a linear map without bias, the
+gated MLP, grouped-query attention, and the top-k expert layer over the
+experts held here with the shared expert beside it."""
 from __future__ import annotations
 
 from .. import symbol as sym
@@ -18,6 +19,44 @@ def gated_mlp(x, width, d, prefix):
     """``w2(silu(w1 x) * w3 x)``."""
     gate = sym.Activation(linear(x, width, prefix + "w1"), act_type="silu")
     return linear(gate * linear(x, width, prefix + "w3"), d, prefix + "w2")
+
+
+def grouped_query_attention(x, prefix, d, hq, hk, hd, eps, rope_theta=None,
+                            window=0, gated=False):
+    """``W_o(softmax(q k^T * hd ** -0.5) v)`` of ``hq`` query heads over
+    ``hk`` key/value heads of ``hd`` (``_contrib_FlashAttention``, causal):
+    an RMSNorm of its own over each head of ``q`` and of ``k``; rotary
+    embedding over the whole head (rotate-half, base ``rope_theta``) on
+    both, none with ``rope_theta`` None; with ``window``, position ``t``
+    sees the keys ``t - window < j <= t`` only; ``gated``: the concatenated
+    head outputs times ``sigmoid(W_g x)``, elementwise, before ``W_o``."""
+    def heads(name, n, normed):
+        y = sym.Reshape(linear(x, n * hd, prefix + name), shape=(0, 0, n, hd))
+        if normed:
+            y = sym.RMSNorm(y, eps=eps, name=prefix + name + "_norm")
+            if rope_theta is not None:
+                y = sym._contrib_RotaryEmbedding(y, base=float(rope_theta))
+        return y
+
+    att = sym._contrib_FlashAttention(
+        heads("q", hq, True), heads("k", hk, True), heads("v", hk, False),
+        causal=True, window=int(window), name=prefix + "attn")
+    att = sym.Reshape(att, shape=(0, 0, -3))
+    if gated:
+        att = att * sym.Activation(linear(x, hq * hd, prefix + "g"),
+                                   act_type="sigmoid")
+    return linear(att, d, prefix + "o")
+
+
+def add_shared_expert(y, x, cfg, prefix):
+    """``y`` plus the shared expert's part: one gated MLP as wide as
+    ``num_shared_experts`` experts, on every token; every chip that shares
+    the layer computes it alike."""
+    shared = int(cfg.get("num_shared_experts", 0))
+    if shared:
+        y = y + gated_mlp(x, shared * cfg["moe_intermediate_size"],
+                          cfg["hidden_size"], prefix + "shared_")
+    return y
 
 
 def topk_experts(x, cfg, name, top_k, renormalize, use_bias):

@@ -81,7 +81,8 @@ from .. import initializer
 from .. import symbol as sym
 from ..base import MXNetError
 from ..telemetry.spans import span
-from .decoder_blocks import gated_mlp, linear, topk_experts
+from .decoder_blocks import add_shared_expert, gated_mlp, linear, \
+    topk_experts
 
 
 def _kda(x, cfg, prefix):
@@ -149,11 +150,7 @@ def _experts(x, cfg, prefix):
                          "group is built")
     y = topk_experts(x, cfg, prefix + "moe", cfg["num_experts_per_token"],
                      cfg["moe_renormalize"], True)
-    shared = int(cfg.get("num_shared_experts", 0))
-    if shared:      # one MLP as wide as that many experts, on every token
-        y = y + gated_mlp(x, shared * cfg["moe_intermediate_size"],
-                          cfg["hidden_size"], prefix + "shared_")
-    return y
+    return add_shared_expert(y, x, cfg, prefix)
 
 
 def get_symbol(cfg, seq_len):

@@ -64,8 +64,8 @@ from __future__ import annotations
 from .. import symbol as sym
 from ..base import MXNetError
 from ..telemetry.spans import span
-from .decoder_blocks import gated_mlp as _gated_mlp, linear as _linear, \
-    topk_experts
+from .decoder_blocks import gated_mlp as _gated_mlp, \
+    grouped_query_attention, linear as _linear, topk_experts
 
 CONV, ATTENTION = "conv", "full_attention"
 
@@ -83,23 +83,11 @@ def _short_conv(x, cfg, prefix):
 
 
 def _attention(x, cfg, prefix):
-    d, hq, hk = (cfg["hidden_size"], cfg["num_attention_heads"],
-                 cfg["num_key_value_heads"])
-    hd = cfg.get("head_dim") or d // hq
-    eps, theta = float(cfg["norm_eps"]), float(cfg["rope_theta"])
-
-    def heads(name, n, normed):
-        y = sym.Reshape(_linear(x, n * hd, prefix + name),
-                        shape=(0, 0, n, hd))
-        if normed:
-            y = sym.RMSNorm(y, eps=eps, name=prefix + name + "_norm")
-            y = sym._contrib_RotaryEmbedding(y, base=theta)
-        return y
-
-    att = sym._contrib_FlashAttention(
-        heads("q", hq, True), heads("k", hk, True), heads("v", hk, False),
-        causal=True, name=prefix + "attn")
-    return _linear(sym.Reshape(att, shape=(0, 0, -3)), d, prefix + "o")
+    d, hq = cfg["hidden_size"], cfg["num_attention_heads"]
+    return grouped_query_attention(
+        x, prefix, d, hq, cfg["num_key_value_heads"],
+        cfg.get("head_dim") or d // hq, float(cfg["norm_eps"]),
+        rope_theta=cfg["rope_theta"])
 
 
 def _experts(x, cfg, prefix):

@@ -98,6 +98,31 @@ def test_grouped_query_flash_compiles_for_v5e(one_chip, shape):
         if " broadcast(" in line and "=" in line]
 
 
+# (batch, seq, query heads, key/value heads, head_dim, window): Trinity-Mini's
+# attention at the cell's 8192 positions: 8 query heads of 128 a key/value
+# head (the backward asks for the 32 MiB of its dQ accumulator), a sliding
+# layer (the windowed kernels), the full layer (window 0: the streamed
+# ones), and a sliding layer at one K/V panel (the panel kernels and a mask)
+WINDOW_SHAPES = [((1, 8192, 32, 4, 128, 2048), "_window"),
+                 ((1, 8192, 32, 4, 128, 0), "_stream"),
+                 ((1, 2048, 32, 4, 128, 512), "_panel")]
+
+
+@pytest.mark.parametrize("shape,route", WINDOW_SHAPES, ids=str)
+def test_windowed_grouped_flash_compiles_for_v5e(one_chip, shape, route):
+    b, t, hq, hk, d, window = shape
+
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, True, False, window).astype(
+            jnp.float32).sum()
+
+    _compile_for_chip(
+        jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+        ((b, t, hq, d), jnp.bfloat16), ((b, t, hk, d), jnp.bfloat16),
+        ((b, t, hk, d), jnp.bfloat16),
+        names=["mxtpu_flash_fwd" + route, "mxtpu_flash_bwd" + route])
+
+
 # (batch, seq, heads, query/key width, value width): Kimi Linear's latent
 # attention at the cell's 8192 positions (streaming kernels; a 192-wide
 # head takes two lane tiles a row, so the backward asks for the VMEM of its

@@ -139,7 +139,8 @@ def test_flash_op_partitions_itself_under_a_mesh(monkeypatch):
     real = pk.flash_attention
     monkeypatch.setattr(
         pk, "flash_attention",
-        lambda q, k, v, causal: real(q, k, v, causal, True))
+        lambda q, k, v, causal, _interpret=False, window=0: real(
+            q, k, v, causal, True, window))
     q, k, v = (jax.random.normal(kk, (4, 128, 2, 32), jnp.float32)
                for kk in jax.random.split(jax.random.PRNGKey(0), 3))
 

@@ -14,7 +14,13 @@ the algorithm needs (the causal half of the square, no recomputation:
 Usage (on the TPU host; prints one JSON line a kernel and count):
     python tools/flash_causal_bench.py \
         --shapes 4x2048x32x32x64,1x8192x32x8x64 --ranges 1,2,4,8,16
-Shapes are ``B x T x query heads x key/value heads x head_dim``.
+    python tools/flash_causal_bench.py --shapes 1x8192x32x4x128 \
+        --window 2048 --ranges 4 --blocks 128x2048,128x1024,128x512
+Shapes are ``B x T x query heads x key/value heads x head_dim``: the
+head grouping is the two head counts.  ``--window``: a sliding window
+(the kernels ``mxtpu_flash_{fwd,bwd}_window`` past one K/V panel); the
+needed operations are then the band's, ``0 <= t - j < window``.
+``--blocks`` takes several pairs, each measured in turn.
 """
 from __future__ import annotations
 
@@ -48,9 +54,10 @@ def kernel_events(trace_dir):
     return sorted(out)
 
 
-def bench(pk, shape, ranges, reps, dtype, blocks=None):
+def bench(pk, shape, ranges, reps, dtype, blocks=None, window=0):
     """``[record]`` for one shape: forward and backward at each count
-    (``blocks``: a (block_q, block_k) other than the heuristic's)."""
+    (``blocks``: a (block_q, block_k) other than the heuristic's;
+    ``window``: a sliding window, 0 for none)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -60,6 +67,8 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None):
     mk = lambda h: jnp.asarray(rng.normal(0, 1, (b, t, h, d)), dtype)
     q, k, v, g = mk(hq), mk(hk), mk(hk), mk(hq)
     kw = {} if blocks is None else {"blocks": tuple(blocks)}
+    if window:
+        kw["window"] = window
     blocks = blocks or pk._blocks(t)
     variants = []
     for s in ranges:
@@ -86,7 +95,12 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None):
     f32 = lambda x: np.asarray(x, np.float32)
     for i, (s, _f, _b, outs, grads) in enumerate(variants):
         plan = pk._causal_plan(blocks[0], blocks[1], s)
-        pct = pk._scores_computed_pct(t, blocks[0], blocks[1], plan)
+        pct = pk._scores_computed_pct(
+            t, blocks[0], blocks[1], plan,
+            window if 0 < window < t and t > blocks[1] else 0)
+        # multiply-adds a head needs: the causal half, or the band
+        pairs = t * t / 2 if not 0 < window < t else \
+            window * (window + 1) / 2 + (t - window) * window
         for j, (which, products, got, ref) in enumerate((
                 ("fwd", 2, outs[:1], first[3][:1]),
                 ("bwd", 4, grads, first[4]))):
@@ -94,9 +108,10 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None):
             names = {n.rstrip(".0123456789") for _t0, _d, n in chunk}
             assert len(names) == 1 and which in min(names), names
             ms = statistics.median(dur for _t0, dur, _n in chunk) / 1e6
-            needed = products * b * hq * t * t * d      # causal half
+            needed = products * b * hq * 2 * pairs * d
             records.append({
                 "kernel": min(names).lstrip("%"), "shape": list(shape),
+                "window": window,
                 "blocks": list(blocks), "ranges": len(plan[1]),
                 "scores_computed_pct": round(pct, 2), "ms": round(ms, 4),
                 "needed_gflop": round(needed / 1e9, 1),
@@ -113,8 +128,11 @@ def main(argv=None):
     ap.add_argument("--shapes", default="4x2048x32x32x64,1x8192x32x8x64")
     ap.add_argument("--ranges", default="1,2,4,8,16")
     ap.add_argument("--blocks", default=None,
-                    help="block_q x block_k, e.g. 256x2048 (default: the "
-                         "heuristic's for each length)")
+                    help="block_q x block_k, e.g. 256x2048, or several "
+                         "(128x2048,128x512), each measured in turn "
+                         "(default: the heuristic's for each length)")
+    ap.add_argument("--window", type=int, default=0,
+                    help="sliding window in keys (default 0: none)")
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--dtype", default="bfloat16")
     args = ap.parse_args(argv)
@@ -124,12 +142,15 @@ def main(argv=None):
         sys.exit("flash_causal_bench: no TPU attached (backend %s); a time "
                  "from another device is not these kernels' time"
                  % jax.default_backend())
-    blocks = args.blocks and tuple(int(n) for n in args.blocks.split("x"))
+    pairs = [tuple(int(n) for n in pair.split("x"))
+             for pair in args.blocks.split(",")] if args.blocks else [None]
     for spec in args.shapes.split(","):
         shape = tuple(int(n) for n in spec.split("x"))
-        for rec in bench(pk, shape, [int(s) for s in args.ranges.split(",")],
-                         args.reps, args.dtype, blocks):
-            print(json.dumps(rec), flush=True)
+        for blocks in pairs:
+            for rec in bench(pk, shape,
+                             [int(s) for s in args.ranges.split(",")],
+                             args.reps, args.dtype, blocks, args.window):
+                print(json.dumps(rec), flush=True)
 
 
 if __name__ == "__main__":

@@ -56,7 +56,8 @@ MODULES = [
 # overwritten here, only indexed): title -> filename
 HAND_WRITTEN = [
     ("language-model ops (RMSNorm, rotary, short convolution, "
-     "grouped-query and latent flash attention, gated delta rule, "
+     "grouped-query, latent and sliding-window flash attention, gated "
+     "delta rule, "
      "top-k expert layer)",
      "language_model_ops.md"),
     ("resilience", "resilience.md"),
