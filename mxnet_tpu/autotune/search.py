@@ -102,14 +102,16 @@ def divisors(n, lo=1, hi=None):
     return [d for d in out if lo <= d <= hi]
 
 
-def candidate_flash_configs(t, limit=8):
+def candidate_flash_configs(t, limit=8, heur=None):
     """Block configs for a flash kernel at sequence length ``t``:
     ``block_q`` from the MXU-friendly divisors of t, ``block_k`` from
-    the divisor lattice up to the VMEM-scale bound — the heuristic
-    (``ops.pallas_kernels._blocks``) always leads the list, so a tuned
-    winner can never measure worse than it."""
+    the divisor lattice up to the VMEM-scale bound — ``heur``, the pair
+    the kernels fall back on for the call being tuned
+    (``ops.pallas_kernels._flash_blocks`` of its shape; ``_blocks(t)``,
+    a call's that is not causal, where none is given), always leads the
+    list, so a tuned winner can never measure worse than it."""
     from ..ops.pallas_kernels import _BLOCK_K, _blocks
-    heur = _blocks(t)
+    heur = tuple(heur or _blocks(t))
     bq_cands = [b for b in (64, 128, 256) if t % b == 0] or [heur[0]]
     if heur[0] not in bq_cands:
         bq_cands.insert(0, heur[0])
@@ -212,7 +214,10 @@ def tune_flash(shape, dtype="float32", causal=False, which="fwd",
     rng = np.random.RandomState(seed)
     mk = lambda: rng.normal(0, 1, (b, t, h, d)).astype(dtype)
     q, k, v = mk(), mk(), mk()
-    heur_cfg = dict(zip(("block_q", "block_k"), pk._blocks(t)))
+    # q, k and v share the key's shape: values as wide as the keys, one
+    # query head a key/value head
+    heur = pk._flash_blocks(t, d, d, 1, causal)
+    heur_cfg = dict(zip(("block_q", "block_k"), heur))
     heur_cfg["n_k"] = t // heur_cfg["block_k"]
     op = "flash_attention_%s" % which
     key_shapes = [tuple(key_shape or shape)]
@@ -231,7 +236,7 @@ def tune_flash(shape, dtype="float32", causal=False, which="fwd",
         g = rng.normal(0, 1, (b, t, h, d)).astype(dtype)
 
     results = []
-    for cfg in candidate_flash_configs(t, limit=max_candidates):
+    for cfg in candidate_flash_configs(t, limit=max_candidates, heur=heur):
         blocks = (cfg["block_q"], cfg["block_k"])
         if which == "fwd":
             fn = lambda q_, k_, v_: pk._flash_attention_fwd_pallas(

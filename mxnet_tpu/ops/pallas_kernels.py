@@ -61,12 +61,32 @@ prefix is the causal call itself, and the panel route masks only.
 has the table): a windowed call runs Q blocks of 512 rows against K/V
 tiles of 1024.
 
-Block selection (ISSUE 9): both kernels consult the persistent tuning
-cache first (:mod:`mxnet_tpu.autotune`, ``MXNET_TPU_TUNE_CACHE``) and
-fall back to the :func:`_blocks` heuristic on miss — a tuned
-(block_q, block_k) measured by ``tools/autotune.py`` wins over the
-hand-written rule, and the dispatched choice stays queryable through
-the cost database's kernel records.
+Block selection (ISSUE 9, PR 35): both kernels consult the persistent
+tuning cache first (:mod:`mxnet_tpu.autotune`, ``MXNET_TPU_TUNE_CACHE``)
+and fall back to :func:`_flash_blocks` on miss — a tuned (block_q,
+block_k) measured by ``tools/autotune.py`` wins over the hand-written
+rule, and the dispatched choice stays queryable through the cost
+database's kernel records.  The rule reads the call's shape: the K/V
+tile is :func:`_blocks`' (2048 columns, or the largest divisor of the
+length: the long product wins), and so is the 128-row Q block of a call
+that is not causal; a causal call takes the largest Q block of 512, 256,
+128 rows that divides the length and the K/V tile, leaves the tile's
+diagonal four places (so the prefix ranges and the share of the square
+computed stay those of 128 rows) and whose backward kernel needs no more
+VMEM than a kernel may ask for (:func:`_vmem_need`: two score tiles and,
+on the streamed route, the dQ accumulator of a whole group's rows).  A
+Q block's fixed work is a third of its time at 128 rows and is paid once
+a block whatever its rows (PERF.md section 6, PR 27 and PR 35).  A
+backward kernel whose reckoned need passes Mosaic's scoped default asks
+for its VMEM (:func:`_vmem_params`), and none that the default holds: a
+call that asks costs the ops round it 0.15 ms.  At 512 x 2048 in
+bfloat16 the panel backward is reckoned at 15 MiB of the default's 16
+(the compiler's least limit is 15) and asks for nothing; the streamed
+backward asks where its accumulator makes it (grouped queries, two lane
+tiles a row, or 512 rows at 8192 positions); no forward asks (the
+compiler's least is 4-13 MiB).  A windowed call
+keeps :data:`_WINDOW_BLOCKS`; ``last_causal_plan()["q_block_rows"]`` is
+the smallest Q block over a traced step's causal and windowed kernels.
 """
 from __future__ import annotations
 
@@ -538,16 +558,55 @@ def _blocks(t):
     return block_q, block_k
 
 
-def _select_blocks(op, q, causal):
+#: Rows a causal call's Q block may have, largest first.  A Q block's
+#: fixed work (the grid step, the Q / dO / dQ blocks: 0.36 us forward and
+#: 0.72 us backward of 1.13 / 2.12 us at 128 rows, PR 27) is paid once a
+#: block whatever its rows; 1024 rows are out (the backward spills: 17.7
+#: ms where 512 rows take 5.5, PR 33).  PERF.md section 6 (PR 35) has the
+#: table that decided it, four shapes by three blocks.
+_Q_BLOCKS = (512, 256, 128)
+
+
+def _flash_blocks(t, dk, dv=None, group=1, causal=False):
+    """(block_q, block_k) of a call from its shape: the heuristic the
+    tuning cache falls back on.  ``block_k`` is :func:`_blocks`' (the
+    long product wins), and so is the Q block of a call that is not
+    causal.  A causal call takes the largest Q block of
+    :data:`_Q_BLOCKS` that divides ``t``, leaves a K/V tile's diagonal
+    at least :data:`_CAUSAL_RANGES` places (so :func:`_causal_plan`
+    keeps its ranges and the share of the square computed is that of
+    128 rows) and whose backward kernel, the larger of the two, needs
+    no more VMEM than it may ask for (:func:`_vmem_need`; its dQ
+    accumulator holds ``group * t`` rows on the streamed route); a
+    length none of them suits keeps :func:`_blocks`' own.  Forward and
+    backward take the same pair.  A windowed call: :func:`_window_blocks`."""
+    block_q, block_k = _blocks(t)
+    if not causal:
+        return block_q, block_k
+    d = max(dk, dv or dk)
+    dq_rows = group * t if t > block_k else 0
+    for rows in _Q_BLOCKS:
+        if rows > block_q and t % rows == 0 and block_k % rows == 0 \
+                and block_k // rows >= _CAUSAL_RANGES \
+                and _vmem_request(_vmem_need(d, rows, block_k,
+                                             dq_rows)) <= _VMEM_MAX:
+            return rows, block_k
+    return block_q, block_k
+
+
+def _select_blocks(op, q, causal, v=None, group=1):
     """Block selection for one flash kernel instantiation: the
     persistent tuning cache first (``mxnet_tpu.autotune``, keyed by
     (op, q shape, dtype, backend, causal) — emits the cache hit/miss
-    metrics and a ``tune_lookup`` flight event), the :func:`_blocks`
-    heuristic on miss/off/invalid.  A cached config only wins when it
-    tiles this sequence exactly — a corrupt or stale entry degrades to
-    the heuristic, never to a compile error."""
+    metrics and a ``tune_lookup`` flight event), the
+    :func:`_flash_blocks` rule on miss/off/invalid (``v``: values of a
+    width of their own; ``group``: query heads a key/value head).  A
+    cached config only wins when it tiles this sequence exactly — a
+    corrupt or stale entry degrades to the heuristic, never to a
+    compile error."""
     t = q.shape[1]
-    block_q, block_k = _blocks(t)
+    block_q, block_k = _flash_blocks(
+        t, q.shape[-1], None if v is None else v.shape[-1], group, causal)
     try:
         from .. import autotune
         cfg = autotune.kernel_config(
@@ -652,6 +711,7 @@ class causal_plan_recording:
                 "causal_ranges": max(k["causal_ranges"] for k in kernels),
                 "scores_computed_pct": max(k["scores_computed_pct"]
                                            for k in kernels),
+                "q_block_rows": min(k["block_q"] for k in kernels),
                 "window_layers": sum(
                     k["kernel"] == "flash_attention_fwd_window"
                     for k in windowed),
@@ -668,7 +728,10 @@ def last_causal_plan():
     ``tiles_per_q_block`` as its cost database record has them
     (:func:`_note_kernel_cost`), and the largest of the first two over
     the step's kernels.  50 plus half a Q block's share is what the
-    mask leaves; 100 is the whole square.  ``window_layers``: the
+    mask leaves; 100 is the whole square.  ``q_block_rows``: the
+    smallest Q block over the step's causal and windowed kernels (512
+    where :func:`_flash_blocks` engaged on every one of them).
+    ``window_layers``: the
     forward kernels among them that skip tiles by a sliding window
     (``flash_attention_fwd_window``; a windowed layer on the panel
     route, which masks only, is not one), and
@@ -729,7 +792,8 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
     window = _window_of(window, causal, q.shape[1])
     block_q, block_k = blocks if blocks is not None else \
         _window_blocks(q.shape[1]) if window else \
-        _select_blocks("flash_attention_fwd", q, causal)
+        _select_blocks("flash_attention_fwd", q, causal, v,
+                       _kv_group(q, k, v))
     assert q.shape[1] % block_q == 0, \
         "seq length must be a multiple of the Q block"
     plan = _causal_plan(block_q, block_k, ranges) if causal else None
@@ -906,30 +970,60 @@ FLASH_BWD_STREAM = "mxtpu_flash_bwd_stream"
 
 #: VMEM a kernel may use without asking (Mosaic's scoped default on v5e)
 _VMEM_DEFAULT = 16 * 2 ** 20
+#: the most a kernel asks for (a v5e core has 128 MiB)
+_VMEM_MAX = 100 * 2 ** 20
 
 
-def _grouped_stream_params(group, t, d, block_q, block_k):
-    """Extra ``pallas_call`` arguments of the streaming backward under
-    grouped queries: its dQ accumulator holds the whole group's rows
-    (``group * t`` rows of float32, lanes padded to 128), 16 MiB at 4
-    query heads a key/value head and 8192 positions, so the kernel asks
-    for the VMEM it needs; so does a head wider than the 128 lanes
-    (latent attention's 192 takes two lane tiles a row).  Nothing for
-    one query head a key/value head of at most 128: that call stays as
-    it always was."""
+def _vmem_need(d, block_q, block_k, dq_rows=0, itemsize=2):
+    """Bytes of VMEM one step of a backward flash kernel holds, reckoned
+    from its blocks: the larger of a call's two kernels (every forward
+    the rule can pick compiles under the scoped default, 4-13 MiB by the
+    compiler's least, so no forward asks).  ``d``: the wider of the two
+    head widths; a block's row takes whole tiles of 128 lanes, so latent
+    attention's 192 takes two.  ``itemsize``: of the operands,
+    bfloat16's by default.  ``dq_rows``: ``group * t`` on the streamed
+    route, whose dQ accumulator holds that many float32 rows at whole
+    lane tiles (32 MiB at 8 query heads of 128 a key/value head and 8192
+    positions, 16 at 4 of 64); 0 on the panel route.  Counted: two
+    float32 score tiles of ``block_q x block_k`` (``s`` / ``p`` and
+    ``dp`` / ``ds`` share), K / V double-buffered, the float32 dK / dV
+    output blocks double-buffered and, on the streamed route, their
+    scratch, the accumulator, and the Q / dO blocks and the float32 dQ
+    block double-buffered.  On every bfloat16 row of PERF.md section 6's
+    table (PR 35) this reads within 3 MiB of the least limit the
+    compiler accepts: the panel at 512 x 2048 15 MiB (least 15), the
+    streamed kernel at 4 query heads of 64 a key/value head 33 (31), at
+    8 of 128 49 (47), at 192 / 128 wide 34 (31), under a window at
+    512 x 1024 41 (40), one query head a key/value head at 512 x 2048
+    21 (21)."""
     lanes = -(-d // 128) * 128
-    if group == 1 and lanes == 128:
+    need = 2 * 4 * block_q * block_k                     # s / p, dp / ds
+    need += 2 * 2 * itemsize * lanes * block_k           # k, v
+    need += (2 + bool(dq_rows)) * 2 * 4 * lanes * block_k    # dk, dv
+    need += 4 * lanes * dq_rows                          # dq accumulator
+    return need + 2 * (2 * itemsize + 4) * lanes * block_q   # q, dO; dq
+
+
+def _vmem_request(need):
+    """What a kernel that needs ``need`` bytes asks for: half as much
+    again, the reckoning being rough off the rows it was checked on."""
+    return need * 3 // 2
+
+
+def _vmem_params(need):
+    """Extra ``pallas_call`` arguments of a backward kernel that needs
+    ``need`` bytes of VMEM (:func:`_vmem_need`): nothing while the
+    scoped default holds it, where the call stays as it always was (a
+    call that asks costs the ops round it 0.15 ms whatever it asks for,
+    1% of OPT-1.3B's step over its sixteen panel calls); past the
+    default the kernel asks for what it needs.  In the cells that is the
+    streamed backward under grouped queries or at two lane tiles a row,
+    for its accumulator."""
+    if need <= _VMEM_DEFAULT:
         return {}
     from jax.experimental.pallas import tpu as pltpu
-    need = 4 * lanes * (group * t                 # dq accumulator
-                        + 6 * block_k             # dk/dv scratch + outputs
-                        + 8 * block_q)            # q, dO, dq blocks
-    need += 4 * 4 * block_q * block_k             # s, p, dp, ds tiles
-    need += 2 * 2 * 2 * lanes * block_k           # k, v double-buffered
-    if need <= _VMEM_DEFAULT * 3 // 4:
-        return {}
     return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=min(int(need * 1.5), 100 * 2 ** 20))}
+        vmem_limit_bytes=min(_vmem_request(need), _VMEM_MAX))}
 
 
 def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc,
@@ -1095,7 +1189,8 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
     window = _window_of(window, causal, q.shape[1])
     block_q, block_k = blocks if blocks is not None else \
         _window_blocks(q.shape[1]) if window else \
-        _select_blocks("flash_attention_bwd", q, causal)
+        _select_blocks("flash_attention_bwd", q, causal, v,
+                       _kv_group(q, k, v))
     plan = _causal_plan(block_q, block_k, ranges) if causal else None
     # 5 matmuls at 2*t*t*width each: dQ, dK and the recomputed S over
     # the query/key width, dV and dP over the value width; traffic:
@@ -1163,6 +1258,8 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal, interpret, block_q,
             out_shape=[dq_shape, dk_shape, dv_shape],
             interpret=interpret,
             name=FLASH_BWD_PANEL,
+            **_vmem_params(_vmem_need(max(d, dv), block_q, block_k,
+                                      itemsize=q.dtype.itemsize)),
         )(qt, kt, vt, dot, lse, delta)
     else:
         kernel = functools.partial(_flash_bwd_kernel, scale=scale,
@@ -1195,8 +1292,8 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal, interpret, block_q,
                             pltpu.VMEM((block_k, dv), jnp.float32)],
             interpret=interpret,
             name=FLASH_BWD_WINDOW if window else FLASH_BWD_STREAM,
-            **_grouped_stream_params(group, t, max(d, dv), block_q,
-                                     block_k),
+            **_vmem_params(_vmem_need(max(d, dv), block_q, block_k,
+                                      group * t, q.dtype.itemsize)),
         )(qt, kt, vt, dot, lse, delta)
     return (_unfold_heads(dq.reshape(b * h, t, d), b, h).astype(q.dtype),
             _unfold_heads(dk_, b, hk).astype(k.dtype),

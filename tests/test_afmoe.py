@@ -642,6 +642,25 @@ def test_new_readers_read_the_plan_and_none_without_it(monkeypatch, toy_cell):
     assert [read(n) for n in names[:2]] == [None, None]
 
 
+@pytest.mark.parametrize("plan,want", [
+    (None, None),
+    # the parent's plan: no summary key, each kernel says its block_q
+    ({"kernels": [{"block_q": 512}, {"block_q": 128}, {"block_q": 512}],
+      "causal_ranges": 4, "scores_computed_pct": 53.125}, 128),
+    ({"kernels": [{"block_q": 512}], "causal_ranges": 4}, 512),
+    ({"kernels": [], "causal_ranges": 4}, None),
+    # the summary key where there is one
+    ({"kernels": [{"block_q": 128}], "q_block_rows": 512}, 512)],
+    ids=["no_plan", "parent_plan", "parent_plan_512", "no_kernel", "summary"])
+def test_q_block_rows_reader(monkeypatch, toy_cell, plan, want):
+    reader = run.load_module("layer_metrics", "flash_q_block_rows")
+    monkeypatch.setattr(pk, "_LAST_CAUSAL_PLAN", plan)
+    assert reader.read({"cell": toy_cell}) == want
+    # a program without the function at all
+    monkeypatch.delattr(pk, "last_causal_plan")
+    assert reader.read({"cell": toy_cell}) is None
+
+
 def test_cell_configuration_keeps_every_published_width():
     """``benchmark/configs/trinity-mini.json`` against the catalog's ``config``
     (``model-configs``' ``architectures.jsonl``, quoted here): only the four

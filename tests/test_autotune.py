@@ -52,7 +52,8 @@ def test_blocks_full_divisor_lattice():
     """Satellite: the heuristic across the full lattice — _BLOCK_K
     multiples, the ADVICE cliff shapes (2176, 3200), prime-ish T, and
     T below one Q block."""
-    from mxnet_tpu.ops.pallas_kernels import _BLOCK_K, _BLOCK_Q, _blocks
+    from mxnet_tpu.ops.pallas_kernels import (_BLOCK_K, _BLOCK_Q, _blocks,
+                                              _flash_blocks)
 
     # panel / streaming regulars
     assert _blocks(2048) == (128, 2048)
@@ -74,6 +75,17 @@ def test_blocks_full_divisor_lattice():
         assert bq == min(_BLOCK_Q, t)
         assert t % bk == 0 and bk % bq == 0
         assert bk <= max(_BLOCK_K, bq)
+    # the rule the tuning cache falls back on: a call that is not causal
+    # keeps that pair, a causal one the K block and the largest Q block of
+    # 512, 256, 128 rows that leaves its diagonal four places
+    assert _flash_blocks(2048, 64) == (128, 2048)
+    assert _flash_blocks(2048, 64, causal=True) == (512, 2048)
+    assert _flash_blocks(4096, 64, causal=True) == (512, 2048)
+    assert _flash_blocks(1024, 64, causal=True) == (256, 1024)
+    assert _flash_blocks(512, 64, causal=True) == (128, 512)
+    assert _flash_blocks(3200, 64, causal=True) == (128, 640)
+    assert _flash_blocks(2176, 64, causal=True) == (128, 128)
+    assert _flash_blocks(100, 64, causal=True) == (100, 100)
 
 
 def test_select_blocks_tuned_cache_override_wins(monkeypatch, tmp_path):
@@ -98,6 +110,12 @@ def test_select_blocks_tuned_cache_override_wins(monkeypatch, tmp_path):
     assert pk._select_blocks("flash_attention_fwd", q2, False) \
         == (128, 2048)
     assert autotune.summary()["misses"] == 1
+    # the causal rule is a fall-back too: a cached pair still wins
+    assert pk._select_blocks("flash_attention_fwd", q2, True) == (512, 2048)
+    autotune.put("flash_attention_fwd", [(2, 2048, 8, 64)], ["float32"],
+                 {"block_q": 128, "block_k": 1024}, wall_s=1e-3,
+                 extra={"causal": True})
+    assert pk._select_blocks("flash_attention_fwd", q2, True) == (128, 1024)
 
 
 def test_select_blocks_invalid_cached_config_degrades(monkeypatch,
@@ -278,6 +296,10 @@ def test_candidate_spaces_contain_heuristic():
                    and c["block_k"] == heur["block_k"] for c in cands)
         for c in cands:
             assert t % c["block_q"] == 0 and t % c["block_k"] == 0
+        # a causal call's own pair leads the list it is tuned from
+        heur = pk._flash_blocks(t, 64, 64, 1, True)
+        assert tuple(autotune.candidate_flash_configs(t, heur=heur)[0][k]
+                     for k in ("block_q", "block_k")) == heur
 
 
 def test_tune_flash_fwd_and_bwd_interpret(monkeypatch, tmp_path):

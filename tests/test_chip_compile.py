@@ -74,6 +74,26 @@ def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
                       names=["mxtpu_flash_fwd_", "mxtpu_flash_bwd_"])
 
 
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_factor_one_streamed_flash_compiles_for_v5e(one_chip, d, causal):
+    """One query head a key/value head at 8192 positions: without a mask
+    the call keeps its 128-row Q block and asks for no VMEM, as it always
+    did, and compiles under the scoped default; the causal rule's 512 rows
+    hold 21 MiB, ask for them and compile."""
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, causal).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2))
+    shapes = [((1, 8192, 8, d), jnp.bfloat16)] * 3
+    _compile_for_chip(fn, one_chip, *shapes,
+                      names=["mxtpu_flash_fwd_stream", "mxtpu_flash_bwd_stream"])
+    lowered = jax.jit(fn).lower(*[
+        jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes])
+    # a request is the custom call's scoped memory configuration
+    assert ("scoped_memory_configs" in lowered.as_text()) == causal
+
+
 # (batch, seq, query heads, key/value heads, head_dim): LFM2-8B-A1B's
 # attention at the cell's 8192 positions (streaming kernels; the backward
 # asks for the VMEM of its 4-head dQ accumulator) and at one K/V panel

@@ -460,8 +460,11 @@ def test_equal_widths_are_the_kernels_of_before(route):
     o = both(q, k, v, g)[0]
     o2, _ = pk._flash_attention_fwd_pallas(q, k, wide, True, True, blocks=blocks)
     np.testing.assert_array_equal(o, o2[..., :32])
-    assert pk._grouped_stream_params(1, 8192, 128, 128, 2048) == {}
-    assert "compiler_params" in pk._grouped_stream_params(1, 8192, 192, 128, 2048)
+    # a 128-wide head at 8192 positions asks for nothing, as it always did;
+    # a 192-wide one takes two lane tiles a row, and that call asks
+    assert pk._vmem_params(pk._vmem_need(128, 128, 2048, 8192)) == {}
+    assert "compiler_params" in pk._vmem_params(
+        pk._vmem_need(192, 128, 2048, 8192))
 
 
 def test_op_takes_the_latent_widths_and_carries_the_scope():

@@ -210,8 +210,11 @@ def test_factor_one_flash_calls_are_unchanged():
     # one remainder each, and the grouped kernels one more
     assert text((64, 128)).count(" rem ") == 2
     assert text((64, 128), kv_heads=1).count(" rem ") == 4
-    assert pk._grouped_stream_params(1, 8192, 64, 128, 2048) == {}
-    assert "compiler_params" in pk._grouped_stream_params(4, 8192, 64, 128, 2048)
+    # one query head a key/value head at 8192 positions asks for nothing, as
+    # it always did; four hold a dQ accumulator of 8 MiB, and that call asks
+    assert pk._vmem_params(pk._vmem_need(64, 128, 2048, 8192)) == {}
+    assert "compiler_params" in pk._vmem_params(
+        pk._vmem_need(64, 128, 2048, 4 * 8192))
 
 
 # ------------------------------------------------------------ expert layer

@@ -90,7 +90,8 @@ def test_block_choice_cliff_shapes():
     multiple used to collapse straight to 128-wide K blocks (3200 ->
     25 tiny streams).  _blocks must now pick the largest block_q-
     multiple divisor of t that still fits the VMEM budget."""
-    from mxnet_tpu.ops.pallas_kernels import _BLOCK_K, _BLOCK_Q, _blocks
+    from mxnet_tpu.ops.pallas_kernels import (_BLOCK_K, _BLOCK_Q, _blocks,
+                                              _flash_blocks)
 
     # multiples of _BLOCK_K stream the full panel
     assert _blocks(2048) == (128, 2048)
@@ -106,13 +107,20 @@ def test_block_choice_cliff_shapes():
 
     # invariants across every Q-tileable length: the K block always
     # divides t (the grid is exact), is a block_q multiple (MXU
-    # tileable), and never exceeds the VMEM budget
+    # tileable), and never exceeds the VMEM budget; a causal call's Q
+    # block (_flash_blocks) is 128 rows or more, divides t and the K
+    # block, and leaves the K block what it was
     for t in range(128, 8193, 128):
         bq, bk = _blocks(t)
         assert bq == min(_BLOCK_Q, t)
         assert t % bk == 0, t
         assert bk % bq == 0, t
         assert bk <= max(_BLOCK_K, bq), t
+        assert _flash_blocks(t, 64) == (bq, bk)
+        cq, ck = _flash_blocks(t, 64, causal=True)
+        assert ck == bk and cq in (128, 256, 512), t
+        assert t % cq == 0 and bk % cq == 0, t
+        assert cq == 128 or bk // cq >= 4, t
 
 
 def test_attention_step_names_its_kernels(monkeypatch):
@@ -309,12 +317,16 @@ def test_last_causal_plan_counts_the_scores_computed(t, block_k, ranges,
     q = jax.ShapeDtypeStruct((1, t, 2, 64), jnp.bfloat16)
     lse = jax.ShapeDtypeStruct((2, t, 1), jnp.float32)
 
+    # sixteen places on a tile's diagonal, for up to sixteen ranges: Q
+    # blocks of 128 rows (the rule's 512 leave four)
+    kw = dict(ranges=ranges, blocks=(128, block_k))
+
     def f(q, k, v, o, lse, g):
-        pk._flash_attention_fwd_pallas(q, k, v, True, True, ranges=ranges)
+        pk._flash_attention_fwd_pallas(q, k, v, True, True, **kw)
         # a kernel that is not causal joins no plan
-        pk._flash_attention_fwd_pallas(q, k, v, False, True, ranges=ranges)
+        pk._flash_attention_fwd_pallas(q, k, v, False, True, **kw)
         return pk._flash_attention_bwd_pallas(q, k, v, o, lse, g, True, True,
-                                              ranges=ranges)
+                                              **kw)
 
     with pk.causal_plan_recording():
         jax.make_jaxpr(f)(q, q, q, q, lse, q)
@@ -355,3 +367,163 @@ def test_default_causal_ranges_follow_the_blocks():
             assert plan[-1][1] == m
             assert all(cols >= hi * block_q and cols <= block_k
                        for _lo, hi, cols in plan)
+
+
+# ------------------------------------------- the Q block from the shape
+def _shape_chosen_case(block_q, route, group, widths):
+    """(``_BLOCK_K``, t, q heads, k/v heads, dk, dv) at which
+    ``_flash_blocks`` gives ``block_q`` rows on ``route``: a diagonal of
+    four places, one K/V tile (panel) or two (stream)."""
+    block_k = 4 * block_q
+    return (block_k, block_k * (2 if route == "stream" else 1), group, 1) \
+        + widths
+
+
+@pytest.mark.parametrize("widths", [(16, 16), (24, 16)],
+                         ids=["dk_eq_dv", "dk_192_dv_128_scaled"])
+@pytest.mark.parametrize("group", [1, 2], ids=["one_head", "grouped"])
+@pytest.mark.parametrize("route", ["panel", "stream"])
+@pytest.mark.parametrize("block_q", [256, 512])
+def test_causal_call_at_the_shape_chosen_q_block(monkeypatch, block_q, route,
+                                                 group, widths):
+    """Forward and backward in interpret mode at Q blocks of 256 and 512
+    rows, chosen by the rule itself (a smaller ``_BLOCK_K`` where 256 is
+    the largest that leaves four places), on both routes, one query head a
+    key/value head and two, equal widths and latent attention's 192 / 128
+    scaled down, against the plain formula."""
+    import jax
+    from mxnet_tpu.ops import pallas_kernels as pk
+    block_k, t, hq, hk, dk, dv = _shape_chosen_case(block_q, route, group,
+                                                    widths)
+    monkeypatch.setattr(pk, "_BLOCK_K", block_k)
+    assert pk._flash_blocks(t, dk, dv, hq // hk, True) == (block_q, block_k)
+    rng = np.random.RandomState(block_q + t + hq + dk)
+    mk = lambda h, d: rng.normal(0, 1, (1, t, h, d)).astype(np.float32)
+    q, k, v, g = mk(hq, dk), mk(hk, dk), mk(hk, dv), mk(hq, dv)
+    with pk.causal_plan_recording():
+        out, vjp = jax.vjp(lambda q, k, v:
+                           pk.flash_attention(q, k, v, True, True), q, k, v)
+        grads = vjp(g)
+    plan = pk.last_causal_plan()
+    assert plan["q_block_rows"] == block_q and plan["causal_ranges"] == 4
+    assert [(e["kernel"], e["block_q"], e["block_k"])
+            for e in plan["kernels"]] == [
+        ("flash_attention_fwd", block_q, block_k),
+        ("flash_attention_bwd", block_q, block_k)]
+    ref, ref_vjp = jax.vjp(lambda q, k, v:
+                           pk._attention_jnp(q, k, v, True), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    for got, want in zip(grads, ref_vjp(g)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=3e-5)
+
+
+@pytest.mark.parametrize("t,pct", [(2048, 62.5), (8192, 53.125)])
+def test_rule_keeps_four_ranges_and_the_share_computed(t, pct):
+    """OPT's panel and the 8192-token cells' streamed tiles: Q blocks of
+    512 rows leave a tile of 2048 columns four places on its diagonal, so
+    the plan keeps four ranges and computes what 128 rows computed."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    for dk, dv, group in ((64, 64, 1), (64, 64, 4), (192, 128, 1),
+                          (128, 128, 8)):
+        blocks = pk._flash_blocks(t, dk, dv, group, True)
+        assert blocks == (512, 2048)
+        m, ranges = plan = pk._causal_plan(*blocks)
+        assert (m, len(ranges)) == (4, pk._CAUSAL_RANGES)
+        assert pk._scores_computed_pct(t, *blocks, plan) == pct == \
+            pk._scores_computed_pct(t, 128, 2048, pk._causal_plan(128, 2048))
+
+
+@pytest.mark.parametrize("t,causal,group,d,blocks,why", [
+    (1024, True, 1, 64, (256, 1024), "512 rows leave a panel of 1024 two places"),
+    (1536, True, 1, 64, (256, 1536), "512 rows leave 1536 columns three places"),
+    (2560, True, 1, 64, (256, 1280), "512 does not divide the K/V tile of 1280"),
+    (3200, True, 1, 64, (128, 640), "256 does not divide the K/V tile of 640"),
+    (1152, True, 1, 64, (128, 1152), "256 does not divide t"),
+    (512, True, 1, 64, (128, 512), "a panel of 512 has two places at 256"),
+    (8192, False, 8, 128, (128, 2048), "not causal"),
+    (16384, True, 8, 128, (128, 2048), "the group's dQ rows leave no VMEM"),
+    (16384, True, 2, 128, (512, 2048), "two heads' dQ rows do")])
+def test_rule_falls_back_where_512_rows_do_not_suit(t, causal, group, d,
+                                                    blocks, why):
+    from mxnet_tpu.ops import pallas_kernels as pk
+    assert pk._flash_blocks(t, d, d, group, causal) == blocks, why
+    assert t % blocks[0] == 0 and t % blocks[1] == 0
+    # the op's own test of a length keeps its granularity of 128 rows
+    assert blocks[0] >= min(pk._BLOCK_Q, t)
+
+
+def test_windowed_call_keeps_its_measured_blocks(monkeypatch):
+    """A sliding window's blocks are ``_WINDOW_BLOCKS`` still, and the plan
+    says the smallest Q block over causal and windowed kernels."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+    q = jax.ShapeDtypeStruct((1, 4096, 2, 32), jnp.float32)
+
+    def both(q, k, v):
+        return (pk._flash_attention_fwd_pallas(q, k, v, True, True,
+                                               window=1024)[0],
+                pk._flash_attention_fwd_pallas(q, k, v, True, True)[0])
+
+    with pk.causal_plan_recording():
+        jax.make_jaxpr(both)(q, q, q)
+    plan = pk.last_causal_plan()
+    assert [(e["kernel"], e["block_q"], e["block_k"])
+            for e in plan["kernels"]] == [
+        ("flash_attention_fwd_window", 512, 1024),
+        ("flash_attention_fwd", 512, 2048)]
+    assert plan["q_block_rows"] == 512
+    assert pk._window_blocks(4096) == pk._WINDOW_BLOCKS == (512, 1024)
+    # one kernel on the old constant and the summary says so
+    with pk.causal_plan_recording():
+        jax.make_jaxpr(lambda q, k, v: (
+            both(q, k, v), pk._flash_attention_fwd_pallas(
+                q, k, v, True, True, blocks=(128, 2048))))(q, q, q)
+    assert pk.last_causal_plan()["q_block_rows"] == 128
+
+
+@pytest.mark.parametrize("shape,blocks", [
+    ((1, 2048, 2, 2, 64), (128, 2048)), ((1, 2048, 2, 2, 64), (256, 2048)),
+    ((1, 2048, 2, 2, 64), (512, 2048)), ((1, 8192, 4, 1, 64), (128, 2048)),
+    ((1, 8192, 4, 1, 64), (512, 2048)), ((1, 4096, 1, 1, 192), (512, 2048)),
+    ((1, 512, 2, 2, 64), (128, 512)), ((1, 4096, 1, 1, 64), (128, 128))],
+    ids=str)
+def test_vmem_is_asked_for_exactly_when_the_need_passes(shape, blocks):
+    """A backward kernel asks for ``vmem_limit_bytes`` when, and only when,
+    the need reckoned from its blocks passes the scoped default: the
+    streamed backward's dQ accumulator under grouped queries does, the
+    panel kernel at 512 x 2048 in bfloat16 does not (reckoned 15 MiB, the
+    compiler's least limit there); no forward asks."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+    b, t, hq, hk, d = shape
+    q = jax.ShapeDtypeStruct((b, t, hq, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, t, hk, d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((b * hq, t, 1), jnp.float32)
+    dq_rows = hq // hk * t if t > blocks[1] else 0
+    fwd = str(jax.make_jaxpr(lambda q, k, v: pk._flash_attention_fwd_pallas(
+        q, k, v, True, True, blocks=blocks))(q, kv, kv))
+    bwd = str(jax.make_jaxpr(
+        lambda q, k, v, o, lse, g: pk._flash_attention_bwd_pallas(
+            q, k, v, o, lse, g, True, True, blocks=blocks))(
+                q, kv, kv, q, lse, q))
+    assert "vmem_limit" not in fwd
+    need = pk._vmem_need(d, *blocks, dq_rows)
+    asked = need > pk._VMEM_DEFAULT
+    assert ("vmem_limit_bytes=%d" % min(need * 3 // 2, pk._VMEM_MAX)
+            in bwd) == asked, need
+    assert ("vmem_limit" in bwd) == asked
+    assert (pk._vmem_params(need) != {}) == asked
+    # OPT's sixteen panel calls ask for nothing: 15 of the default's 16 MiB
+    assert pk._vmem_need(64, 512, 2048) == 15 * 2 ** 20
+    # what the rule's Q block adds to a call of one query head a key/value
+    # head at 8192 positions is a request; at 128 rows, without a mask or
+    # under a window (512 x 1024), that call asks for nothing, as always
+    assert "compiler_params" in pk._vmem_params(
+        pk._vmem_need(128, 512, 2048, 8192))
+    for d_, blocks_ in ((64, (128, 2048)), (128, (128, 2048)),
+                        (128, pk._WINDOW_BLOCKS)):
+        assert pk._vmem_params(pk._vmem_need(d_, *blocks_, 8192)) == {}

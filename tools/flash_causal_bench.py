@@ -17,7 +17,13 @@ Usage (on the TPU host; prints one JSON line a kernel and count):
     python tools/flash_causal_bench.py --shapes 1x8192x32x4x128 \
         --window 2048 --ranges 4 --blocks 128x2048,128x1024,128x512
 Shapes are ``B x T x query heads x key/value heads x head_dim``: the
-head grouping is the two head counts.  ``--window``: a sliding window
+head grouping is the two head counts; a sixth number is the values'
+width where it is not the queries' (``1x8192x32x32x192x128``).  Every
+record says beside the blocks it timed the pair the kernels' own rule
+takes for the shape (``rule_blocks``: ``_flash_blocks``, under a window
+``_window_blocks``), so that
+``--ranges 4 --blocks 128x2048,256x2048,512x2048`` makes a shape's rows
+of the table the rule was decided by.  ``--window``: a sliding window
 (the kernels ``mxtpu_flash_{fwd,bwd}_window`` past one K/V panel); the
 needed operations are then the band's, ``0 <= t - j < window``.
 ``--blocks`` takes several pairs, each measured in turn.
@@ -62,14 +68,17 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None, window=0):
     import jax.numpy as jnp
     import numpy as np
 
-    b, t, hq, hk, d = shape
+    b, t, hq, hk, d = shape[:5]
+    dv = shape[5] if len(shape) > 5 else d
     rng = np.random.RandomState(0)
-    mk = lambda h: jnp.asarray(rng.normal(0, 1, (b, t, h, d)), dtype)
-    q, k, v, g = mk(hq), mk(hk), mk(hk), mk(hq)
+    mk = lambda h, d: jnp.asarray(rng.normal(0, 1, (b, t, h, d)), dtype)
+    q, k, v, g = mk(hq, d), mk(hk, d), mk(hk, dv), mk(hq, dv)
+    rule = pk._window_blocks(t) if 0 < window < t else \
+        pk._flash_blocks(t, d, dv, hq // hk, True)
     kw = {} if blocks is None else {"blocks": tuple(blocks)}
     if window:
         kw["window"] = window
-    blocks = blocks or pk._blocks(t)
+    blocks = blocks or rule
     variants = []
     for s in ranges:
         fwd = jax.jit(lambda q, k, v, s=s: pk._flash_attention_fwd_pallas(
@@ -101,18 +110,22 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None, window=0):
         # multiply-adds a head needs: the causal half, or the band
         pairs = t * t / 2 if not 0 < window < t else \
             window * (window + 1) / 2 + (t - window) * window
-        for j, (which, products, got, ref) in enumerate((
-                ("fwd", 2, outs[:1], first[3][:1]),
-                ("bwd", 4, grads, first[4]))):
+        # products needed, as (over the query/key width, over the values'):
+        # forward QK^T and PV; backward dQ, dK and dV, dP (the recomputed
+        # scores are not needed work), as the configurations' kernel_costs
+        for j, (which, (over_d, over_dv), got, ref) in enumerate((
+                ("fwd", (1, 1), outs[:1], first[3][:1]),
+                ("bwd", (2, 2), grads, first[4]))):
             chunk = events[(2 * i + j) * reps:(2 * i + j + 1) * reps]
             names = {n.rstrip(".0123456789") for _t0, _d, n in chunk}
             assert len(names) == 1 and which in min(names), names
             ms = statistics.median(dur for _t0, dur, _n in chunk) / 1e6
-            needed = products * b * hq * 2 * pairs * d
+            needed = 2 * b * hq * pairs * (over_d * d + over_dv * dv)
             records.append({
                 "kernel": min(names).lstrip("%"), "shape": list(shape),
                 "window": window,
-                "blocks": list(blocks), "ranges": len(plan[1]),
+                "blocks": list(blocks), "rule_blocks": list(rule),
+                "ranges": len(plan[1]),
                 "scores_computed_pct": round(pct, 2), "ms": round(ms, 4),
                 "needed_gflop": round(needed / 1e9, 1),
                 "peak_pct_needed": round(
