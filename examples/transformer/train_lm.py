@@ -66,24 +66,25 @@ def transformer_block(x, d_model, n_heads, prefix,
 
 def gpt_symbol(vocab_size, seq_len, d_model=128, n_heads=4, n_layers=2,
                dropout=0.1, attention="flash"):
-    data = mx.sym.Variable("data")              # (batch, seq)
-    label = mx.sym.Variable("softmax_label")
-    tok = mx.sym.Embedding(data, input_dim=vocab_size,
-                           output_dim=d_model, name="tok_embed")
-    # learned positional embedding, looked up with a constant iota
-    pos_ids = mx.sym.arange(start=0, stop=seq_len, name="pos_ids")
-    pos = mx.sym.Embedding(pos_ids, input_dim=seq_len,
-                           output_dim=d_model, name="pos_embed")
-    x = mx.sym.broadcast_add(tok, mx.sym.expand_dims(pos, axis=0))
-    for i in range(n_layers):
-        x = transformer_block(x, d_model, n_heads, "block%d" % i,
-                              dropout=dropout, attention=attention)
-    x = mx.sym.LayerNorm(x, name="ln_f")
-    x = mx.sym.Reshape(x, shape=(-1, d_model))
-    logits = mx.sym.FullyConnected(x, num_hidden=vocab_size,
-                                   name="lm_head")
-    label = mx.sym.Reshape(label, shape=(-1,))
-    return mx.sym.SoftmaxOutput(logits, label=label, name="softmax")
+    with mx.telemetry.span("model.build", category="model", model="gpt"):
+        data = mx.sym.Variable("data")              # (batch, seq)
+        label = mx.sym.Variable("softmax_label")
+        tok = mx.sym.Embedding(data, input_dim=vocab_size,
+                               output_dim=d_model, name="tok_embed")
+        # learned positional embedding, looked up with a constant iota
+        pos_ids = mx.sym.arange(start=0, stop=seq_len, name="pos_ids")
+        pos = mx.sym.Embedding(pos_ids, input_dim=seq_len,
+                               output_dim=d_model, name="pos_embed")
+        x = mx.sym.broadcast_add(tok, mx.sym.expand_dims(pos, axis=0))
+        for i in range(n_layers):
+            x = transformer_block(x, d_model, n_heads, "block%d" % i,
+                                  dropout=dropout, attention=attention)
+        x = mx.sym.LayerNorm(x, name="ln_f")
+        x = mx.sym.Reshape(x, shape=(-1, d_model))
+        logits = mx.sym.FullyConnected(x, num_hidden=vocab_size,
+                                       name="lm_head")
+        label = mx.sym.Reshape(label, shape=(-1,))
+        return mx.sym.SoftmaxOutput(logits, label=label, name="softmax")
 
 
 def build_bench_trainer(vocab=16384, seq=1024, d_model=1024, heads=16,

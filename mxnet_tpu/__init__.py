@@ -9,9 +9,29 @@ Import convention mirrors the reference's ``import mxnet as mx``::
 """
 from __future__ import annotations
 
+import sys as _sys
 import time as _time
 
 _IMPORT_T0 = _time.perf_counter()   # the ``mxnet_tpu.import`` span's start
+# what the process had done before this line, for ``process.before_import``
+_JAX_IMPORTED = "jax" in _sys.modules
+_BACKEND_UP = _JAX_IMPORTED and \
+    _sys.modules["jax"]._src.xla_bridge.backends_are_initialized()
+
+
+def _process_start():
+    """The process's start on the ``perf_counter`` clock: its start time
+    in ``/proc/self/stat`` (clock ticks after boot) against
+    ``CLOCK_BOOTTIME``.  None where that cannot be read."""
+    import os
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = _time.clock_gettime(_time.CLOCK_BOOTTIME) \
+            - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, AttributeError, IndexError, ValueError):
+        return None
+    return _time.perf_counter() - age
 
 __version__ = "0.1.0"
 
@@ -90,5 +110,11 @@ except ImportError:  # pragma: no cover
     th = None
 
 # first to last line of this file, as a span record (telemetry.spans):
-# a benchmark's ``import_s``
+# a benchmark's ``import_s``; before it the process up to the first line
+# (the interpreter, the script's own imports, ``import jax`` and the
+# runtime's bring-up where they came first)
+_PROCESS_T0 = _process_start()
+if _PROCESS_T0 is not None and _PROCESS_T0 <= _IMPORT_T0:
+    telemetry.spans.record("process.before_import", _PROCESS_T0, _IMPORT_T0,
+                           jax_imported=_JAX_IMPORTED, backend_up=_BACKEND_UP)
 telemetry.spans.record("mxnet_tpu.import", _IMPORT_T0, _time.perf_counter())

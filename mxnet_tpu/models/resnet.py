@@ -8,6 +8,7 @@ ReLU fuse into the surrounding HLO.
 from __future__ import annotations
 
 from .. import symbol as sym
+from ..telemetry.spans import span
 
 
 def residual_unit(data, num_filter, stride, dim_match, name,
@@ -132,6 +133,13 @@ def residual_unit_v1(data, num_filter, stride, dim_match, name,
 def resnet(units, num_stages, filter_list, num_classes, image_shape,
            bottle_neck=True, bn_mom=0.9, workspace=256, version=2):
     """Build the full network (reference resnet.py resnet())."""
+    with span("model.build", category="model", model="resnet"):
+        return _resnet(units, num_stages, filter_list, num_classes,
+                       image_shape, bottle_neck, bn_mom, workspace, version)
+
+
+def _resnet(units, num_stages, filter_list, num_classes, image_shape,
+            bottle_neck, bn_mom, workspace, version):
     num_unit = len(units)
     assert num_unit == num_stages
     data = sym.Variable(name="data")

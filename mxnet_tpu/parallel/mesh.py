@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..telemetry.spans import span
+
 __all__ = ["build_mesh", "build_mesh_from_axes", "data_parallel_spec",
            "largest_tp_factor"]
 
@@ -34,6 +36,16 @@ def build_mesh(n_devices=None, tp=1, pp=1, axis_names=None,
     pp > 1 -> ('data', 'pipe') axes (pipeline stages inner; tp must be
     1 — packed pipeline stage params cannot also be tensor-sharded).
     """
+    # where ``devices`` is None the span holds ``jax.devices()``: in a
+    # process that has touched no device yet, the runtime's bring-up
+    with span("mesh.build", category="mesh") as sp:
+        mesh = _build_mesh(n_devices, tp, pp, axis_names, devices)
+        sp.attrs = {"devices": int(mesh.devices.size),
+                    "axes": dict(mesh.shape)}
+    return mesh
+
+
+def _build_mesh(n_devices, tp, pp, axis_names, devices):
     import jax
     from jax.sharding import Mesh
     if devices is None:

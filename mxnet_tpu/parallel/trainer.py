@@ -1635,13 +1635,24 @@ class ShardedTrainer:
         On a process-spanning mesh each process passes its OWN
         contiguous shard of the global batch (dim 0 split across the
         processes of the 'data' axis, reference num_parts/part_index
-        slicing); the staged result is one global array."""
-        import jax
+        slicing); the staged result is one global array.
+
+        Telemetry: a ``trainer.put_batch`` span with the attributes
+        ``host_bytes`` (what leaves the host, after the cast) and
+        ``inputs``."""
+        with _span("trainer.put_batch", category="trainer") as sp:
+            host = self._cast_batch(batch)
+            sp.attrs = {"host_bytes": sum(int(v.nbytes)
+                                          for v in host.values()),
+                        "inputs": len(host)}
+            return self._put_cast_batch(host)
+
+    def _put_cast_batch(self, host):
         import numpy as _np
         out = {}
         normalize = (self._input_mean is not None
                      or self._input_std is not None)
-        for k, v in self._cast_batch(batch).items():
+        for k, v in host.items():
             # batch dim may differ (partial tail batches): compare the
             # feature dims only to detect a host-NCHW image batch.  A
             # batch whose dims also match the NCHW reading (C==H==W) is
@@ -1942,7 +1953,9 @@ class ShardedTrainer:
                 # what the expert layers' products became in the
                 # program just compiled, read from its text
                 from . import moe as _moe
-                _moe.note_compiled(self._aot_exes.get((program, id(fn))))
+                with _span("program.plan", program=program):
+                    _moe.note_compiled(
+                        self._aot_exes.get((program, id(fn))))
             return out
         except BaseException:  # mxlint: allow-broad-except(re-raised unchanged — the handler only closes the costdb observation bind-only, so the compile's traced signatures cannot dangle and attach to the next program dispatched)
             _costdb.end_dispatch(obs, failed=True)

@@ -73,8 +73,9 @@ Three engines:
   evaluated over the run timeline by ``launch.py``;
   ``tools/health_top.py`` renders live and postmortem views.
 
-Compile events come from ``jax.monitoring`` listeners where available
-(:mod:`.compile`), else a first-call-vs-steady-state heuristic.
+Compile events come from one ``jax.monitoring`` listener
+(:mod:`.compile`), which also leaves every trace, lowering and compile
+of the process in the span ring as a ``jax.*`` record.
 
 See ``docs/api/telemetry.md`` for the full metric catalog, env knobs,
 and exporter formats.
@@ -113,7 +114,7 @@ __all__ = [
     "slo", "tracing",
 ]
 
-# best-effort process-wide init: compile listener (jax.monitoring) and
+# process-wide init: compile listener (jax.monitoring) and
 # env-derived gauges.  Both are cheap and dependency-light; the http
 # endpoint starts only when MXNET_TPU_TELEMETRY_PORT is set.
 compile_events.install()

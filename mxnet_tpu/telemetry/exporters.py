@@ -25,7 +25,6 @@ from collections import deque
 
 from .catalog import COUNTER, GAUGE, HISTOGRAM
 from .registry import REGISTRY, counter, gauge, histogram
-from . import compile as compile_mod
 from . import distview as distview_mod
 from . import flight
 from . import ioview as ioview_mod
@@ -44,9 +43,6 @@ _MAX_DURS = 500_000
 _lock = threading.Lock()
 _step_durs = deque(maxlen=_MAX_DURS)
 _jsonl = {"path": None, "fh": None}
-# compile count/time already attributed by the first-call heuristic in
-# windows discarded by reset_steps() (no jax.monitoring listener only)
-_heur_carry = {"count": 0, "time": 0.0}
 # counter snapshot at the previous step boundary (flight-event deltas)
 _last_counters = {}
 
@@ -360,19 +356,6 @@ def _percentile(sorted_vals, q):
     return sorted_vals[idx]
 
 
-def _heuristic_compiles(durs):
-    """First-call-vs-steady-state estimate: steps whose wall time dwarfs
-    the median are counted as compile-inflated, the excess over the
-    median as compile time.  Used only when jax.monitoring is absent."""
-    if len(durs) < 2:
-        return 0, 0.0
-    s = sorted(durs)
-    p50 = _percentile(s, 0.50)
-    thresh = max(4.0 * p50, p50 + 0.05)
-    hits = [d for d in durs if d > thresh]
-    return len(hits), sum(d - p50 for d in hits)
-
-
 def report():
     """End-of-run summary dict: step count + step-time percentiles,
     throughput (samples/sec and records/sec over summed step time),
@@ -385,17 +368,6 @@ def report():
     steps = int(counter("mxtpu_step_total").get())
     samples = counter("mxtpu_samples_total").get()
     records = sum(counter("mxtpu_io_records_total").samples().values())
-
-    if compile_mod.installed():
-        compile_count = int(counter("mxtpu_compile_total").get())
-        compile_time = counter("mxtpu_compile_seconds_total").get()
-        compile_source = "jax.monitoring"
-    else:
-        compile_count, compile_time = _heuristic_compiles(durs)
-        with _lock:
-            compile_count += _heur_carry["count"]
-            compile_time += _heur_carry["time"]
-        compile_source = "heuristic"
 
     phases = {}
     for key, val in histogram("mxtpu_span_seconds").samples().items():
@@ -423,9 +395,10 @@ def report():
             if total_time else 0.0,
         },
         "compile": {
-            "count": compile_count,
-            "total_s": round(float(compile_time), 6),
-            "source": compile_source,
+            "count": int(counter("mxtpu_compile_total").get()),
+            "total_s": round(float(
+                counter("mxtpu_compile_seconds_total").get()), 6),
+            "source": "jax.monitoring",
         },
         "phases": phases,
         "memory": {
@@ -443,21 +416,13 @@ def reset_steps():
     kvstore, resilience) intact.  ``bench.py`` calls this after its
     warmup/compile steps so the reported percentiles and throughput
     cover exactly the timed loop, while compile accounting still spans
-    the whole process (the heuristic fallback carries the discarded
-    window's compile attribution forward)."""
+    the whole process."""
     drain_step_spans()
     counter("mxtpu_step_total")._clear()
     counter("mxtpu_samples_total")._clear()
     histogram("mxtpu_step_seconds")._clear()
     histogram("mxtpu_span_seconds")._clear()
     with _lock:
-        if not compile_mod.installed() and _step_durs:
-            # without jax.monitoring the first-call heuristic is the
-            # only compile signal, and it lives in the durations being
-            # discarded — bank its estimate so report() keeps it
-            c, t = _heuristic_compiles(list(_step_durs))
-            _heur_carry["count"] += c
-            _heur_carry["time"] += t
         _step_durs.clear()
 
 
@@ -481,8 +446,6 @@ def reset():
     with _lock:
         _step_durs.clear()
         _last_counters.clear()
-        _heur_carry["count"] = 0
-        _heur_carry["time"] = 0.0
         if _jsonl["fh"] is not None:
             try:
                 _jsonl["fh"].close()

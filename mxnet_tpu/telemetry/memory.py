@@ -441,10 +441,13 @@ def planned_executable(program, fn, args):
                 "%s); dispatching via jit without a memory plan",
                 program, type(e).__name__, e)
             return fn
-    plan = plan_of(compiled, program)
-    if plan is not None:
-        register_plan(plan)
-        check_budget(plan)
+    # with the two spans above and the caller's .launch, a first
+    # dispatch has no stretch without a record
+    with span("program.plan", program=program):
+        plan = plan_of(compiled, program)
+        if plan is not None:
+            register_plan(plan)
+            check_budget(plan)
     return compiled
 
 

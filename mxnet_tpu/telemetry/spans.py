@@ -9,7 +9,9 @@ several sinks, all fed from the same ``__exit__``:
   of the span that was open on this thread at entry, thread id,
   attributes) appended to a bounded in-memory ring; :func:`records`
   reads it, :func:`self_time` is a span's duration less what its
-  children cover.  Always on: it costs a ``deque.append``.
+  children cover, :func:`uncovered` the stretches of an interval that
+  no record of a thread covers.  Always on: it costs a
+  ``deque.append``.
 * the ``mxtpu_span_seconds`` histogram (the per-phase breakdown
   ``report()`` prints) and the per-step sum the JSONL step-log drains;
 * the Chrome trace (:func:`mxnet_tpu.profiler.record_event`) while
@@ -40,8 +42,9 @@ from .. import profiler
 from . import tracing
 from .registry import histogram
 
-__all__ = ["span", "Record", "record", "records", "clear", "self_time",
-           "drain_step_spans", "step_span_totals", "RING_SIZE"]
+__all__ = ["span", "Record", "Gap", "record", "records", "clear",
+           "self_time", "uncovered", "drain_step_spans",
+           "step_span_totals", "RING_SIZE"]
 
 #: records the ring keeps (the newest): some ten a dispatch, so hours of
 #: a chained loop and minutes of a per-batch ``Module.fit``
@@ -172,10 +175,14 @@ class span:
 
 
 def record(name, start, end, **attrs):
-    """Append a root record for an interval its caller timed on
-    ``time.perf_counter()`` before this module could be imported
-    (``mxnet_tpu.import``).  Feeds the ring only."""
-    _ring.append(Record(name, start, end, next(_ids), None,
+    """Append a record for an interval its caller timed on
+    ``time.perf_counter()`` where no ``span`` could be open round it:
+    before this module was imported (``mxnet_tpu.import``), or inside
+    JAX (the ``jax.*`` records of ``telemetry.compile``).  Its parent is
+    the span open on the calling thread.  Feeds the ring only."""
+    stack = getattr(_tls, "open", None)
+    _ring.append(Record(name, start, end, next(_ids),
+                        stack[-1][_ID] if stack else None,
                         threading.get_ident(), attrs or None))
 
 
@@ -206,6 +213,36 @@ def self_time(rec, recs):
             covered += hi - lo
             upto = hi
     return (rec.end - rec.start) - covered
+
+
+class Gap(NamedTuple):
+    """A stretch no record covers, with the names of the record that
+    ends where it starts and of the one that starts where it ends
+    (None at the interval's own bounds)."""
+    start: float
+    end: float
+    before: str | None
+    after: str | None
+
+
+def uncovered(since, until, thread=None):
+    """The stretches of ``[since, until]`` that no record of ``thread``
+    (default: the calling thread) covers, longest first: "why did my
+    job take a minute to start" is ``uncovered(t0, now)[:3]`` beside
+    the longest records."""
+    if thread is None:
+        thread = threading.get_ident()
+    gaps, upto, before = [], since, None
+    for r in sorted((r for r in list(_ring) if r.thread == thread
+                     and r.end > since and r.start < until),
+                    key=lambda r: r.start):
+        if r.start > upto:
+            gaps.append(Gap(upto, r.start, before, r.name))
+        if r.end > upto:
+            upto, before = r.end, r.name
+    if until > upto:
+        gaps.append(Gap(upto, until, before, None))
+    return sorted(gaps, key=lambda g: g.start - g.end)
 
 
 def drain_step_spans():

@@ -379,8 +379,10 @@ def test_run_steps_span_children_say_where_a_dispatch_goes():
     assert whole.attrs == {"steps": 2}
     assert [k.name for k in kids] == [
         "trainer.run_steps.prepare", "program.lower", "program.compile",
-        "trainer.run_steps.launch", "trainer.run_steps.account"]
-    assert kids[1].attrs == kids[2].attrs == {"program": "trainer.run_steps"}
+        "program.plan", "trainer.run_steps.launch",
+        "trainer.run_steps.account"]
+    assert kids[1].attrs == kids[2].attrs == kids[3].attrs == \
+        {"program": "trainer.run_steps"}
     assert not [r for r in recs if r.name.endswith(".sync")]
 
     # the first dispatch after the compile: exactly the three phases,
