@@ -59,13 +59,22 @@ no row set in place) and walks the
 chunks through their products with the state; the backward takes the
 cotangents of the state-free part by ``jax.vjp`` of that function while the
 kernel body is traced, so that Mosaic sees dots, elementwise ops, ``iota``
-masks and row rolls.  Nothing of ``(chunks, heads, chunk, width)`` float32
+masks and row rolls.  One piece of it is a rule and not autodiff's
+transpose: the triangular inverse (:func:`_tiles_inverse`) is a
+``custom_vjp`` whose cotangent is the closed form ``-X^T X_bar X^T``, two
+products at the block formula's precision with the ``X`` the forward made,
+where the transpose of the substitution and of the block formula is eight
+such products and every row of the substitution walked back (a quarter of
+what the backward body issued).  The layer's plan says how many
+highest-precision products the traced backward body holds
+(``bwd_hi_products``).  Nothing of ``(chunks, heads, chunk, width)`` float32
 is written to HBM.  Anywhere else (the CPU, a toy width, another chunk) the
 same chunks run as ``jax.numpy`` ops under ``lax.scan`` (:func:`_forward`,
 :func:`_backward`): the tests' oracle beside the recurrence, as
-``_attention_jnp`` is for the flash kernels.  Under a mesh of more than one
-device the kernels' calls wrap themselves in a ``shard_map`` over (batch,
-heads).
+``_attention_jnp`` is for the flash kernels.  Its inverse
+(:func:`_unit_lower_inverse`) stays under autodiff on purpose: it is what
+the rule is checked against.  Under a mesh of more than one device the
+kernels' calls wrap themselves in a ``shard_map`` over (batch, heads).
 
 ``q`` and ``k`` may come raw: with ``qk_l2norm`` each head of both is
 normalised (``x * rsqrt(sum x^2 + 1e-6)``, float32) and ``q`` scaled inside
@@ -301,6 +310,7 @@ def _sibling_mask(c, b):
     return (bi == bj + 1) & ((bi & 1) == 1)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def _tiles_inverse(m, chunk, sub):
     """:func:`_unit_lower_inverse` for ``(n, chunk, chunk)`` tiles: forward
     substitution row by row inside the diagonal blocks of ``sub`` positions
@@ -331,6 +341,27 @@ def _tiles_inverse(m, chunk, sub):
         x = x - _bmm(_bmm(x, low, (2, 1), _HI), x, (2, 1), _HI)
         b *= 2
     return x
+
+
+def _tiles_inverse_fwd(m, chunk, sub):
+    x = _tiles_inverse.fun(m, chunk, sub)
+    return x, x
+
+
+def _tiles_inverse_bwd(chunk, sub, x, d_x):
+    """The cotangent of a matrix inverse in closed form: with ``X = (I +
+    L)^-1``, ``dX = -X dL X``, so ``L_bar = -X^T X_bar X^T``: two products
+    at the block formula's precision with the ``X`` the forward made, where
+    autodiff would walk the substitution's rows back and transpose each
+    level of the block formula (its four products become eight).  Kept where
+    ``L`` lives, under the diagonal."""
+    p = _bmm(jnp.swapaxes(x, 1, 2), d_x, (2, 1), _HI)
+    d_m = _bmm(p, x, (2, 2), _HI)
+    under = _iota((chunk, chunk), 0) > _iota((chunk, chunk), 1)
+    return (jnp.where(under, -d_m, 0.0),)
+
+
+_tiles_inverse.defvjp(_tiles_inverse_fwd, _tiles_inverse_bwd)
 
 
 def _tiles_state_free(q, k, v, g, beta, *, chunk, sub, l2norm, scale, roll):
@@ -684,6 +715,8 @@ def _scan(q, k, v, g, beta, how):
 
 def _scan_fwd(q, k, v, g, beta, how):
     o, starts = _lowerings(how)[0](q, k, v, g, beta, how=how)
+    if _RECORDING is not None and how[5] != "xla":
+        _note_backward_body((q, k, v, g, beta, starts, o), how)
     return o, (q, k, v, g, beta, starts)
 
 
@@ -747,17 +780,63 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, sub=SUB, group=GROUP,
                           beta.astype(q.dtype))]
         o = _on_the_mesh(args, how)
         o = jnp.moveaxis(o[:, :, :t], 1, 2).astype(v.dtype)
-    note_layer(heads=int(q.shape[2]), dk=int(q.shape[3]), dv=int(v.shape[3]),
-               positions=int(t), chunk=chunk, group=group, form="chunked",
-               lowering="xla" if lowering == "xla" else "pallas",
-               state_bytes=4 * int(q.shape[0]) * int(q.shape[2])
-               * int(q.shape[3]) * int(v.shape[3]) * (padded // group))
+    info = dict(heads=int(q.shape[2]), dk=int(q.shape[3]), dv=int(v.shape[3]),
+                positions=int(t), chunk=chunk, group=group, form="chunked",
+                lowering="xla" if lowering == "xla" else "pallas",
+                state_bytes=4 * int(q.shape[0]) * int(q.shape[2])
+                * int(q.shape[3]) * int(v.shape[3]) * (padded // group))
+    traced = _BWD_HI_PRODUCTS.get(_body_key(args[0], args[2], how))
+    if traced is not None:
+        info["bwd_hi_products"] = traced
+    note_layer(**info)
     return o
 
 
 # ---- what the last traced step's linear-attention layers are
 _RECORDING = None
 _LAST_SUMMARY = None
+#: highest-precision products in the backward kernel's body, by what the
+#: body's trace depends on (:func:`_body_key`)
+_BWD_HI_PRODUCTS = {}
+
+
+def _hi_products(jaxpr):
+    """``dot_general``s at ``Precision.HIGHEST`` in ``jaxpr`` and in the
+    jaxprs its equations hold (a ``pallas_call``'s body among them)."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            precision = eqn.params["precision"]
+            count += _HI in (precision if isinstance(precision, tuple)
+                             else (precision,))
+        count += sum(_hi_products(inner)
+                     for inner in jax.core.jaxprs_in_params(eqn.params))
+    return count
+
+
+def _body_key(q, v, how):
+    """What a kernel body's trace depends on: a group's rows, the widths,
+    the compute dtype and the statics; not the batch, the heads or the
+    number of groups, which are the grid's."""
+    return (q.dtype.name, int(q.shape[3]), int(v.shape[3]), how)
+
+
+def _note_backward_body(args, how):
+    """Counts the highest-precision products of the backward kernel's body
+    for the layer's plan.  The body is traced when the cotangents are
+    pulled, after the forward trace that a :class:`plan_recording` spans,
+    so it is traced here, from the forward rule, on the shapes the backward
+    will be handed: :func:`_backward_kernel` is traced once a signature, and
+    the pull finds this trace."""
+    key = _body_key(args[0], args[2], how)
+    if key not in _BWD_HI_PRODUCTS:
+        shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args]
+        # the pull traces under the mesh context spelled out, the forward
+        # rule under none: the same context, but another key of jit's cache
+        with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+            traced = jax.make_jaxpr(
+                functools.partial(_backward_kernel, how=how))(*shapes)
+        _BWD_HI_PRODUCTS[key] = _hi_products(traced.jaxpr)
 
 
 class plan_recording:
@@ -782,6 +861,10 @@ class plan_recording:
                 "kernel_layers": sum(1 for x in layers
                                      if x["lowering"] == "pallas"),
                 "state_bytes": sum(x["state_bytes"] for x in layers)}
+            traced = [x["bwd_hi_products"] for x in layers
+                      if "bwd_hi_products" in x]
+            if traced:
+                _LAST_SUMMARY["bwd_hi_products"] = max(traced)
         return False
 
 
@@ -797,7 +880,10 @@ def last_plan_summary():
     this process (None before any): per layer its heads, widths, positions,
     chunk and group lengths, the form it lowered to (``chunked``: this
     module's scan), its ``lowering`` (``pallas``: the two kernels; ``xla``:
-    ``jax.numpy`` ops) and the bytes of state its backward keeps (one state
-    a head and group); ``chunked_layers``, ``kernel_layers`` and
-    ``state_bytes`` over all of them.  As ``moe.last_plan_summary()``."""
+    ``jax.numpy`` ops), the bytes of state its backward keeps (one state
+    a head and group) and, on the kernels where the recording saw the
+    layer differentiated, ``bwd_hi_products`` (the ``dot_general``s at the
+    highest precision in the backward kernel's traced body);
+    ``chunked_layers``, ``kernel_layers`` and ``state_bytes`` over all of
+    them, ``bwd_hi_products`` the largest.  As ``moe.last_plan_summary()``."""
     return _LAST_SUMMARY

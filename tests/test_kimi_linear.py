@@ -211,6 +211,73 @@ def test_kernels_match_the_jax_numpy_form(l2norm, dtype, lowered_as):
         assert float(jnp.abs(a - b).max()) <= tol * float(jnp.abs(b).max()) + 1e-7
 
 
+@pytest.mark.parametrize("chunk,sub,largest", [
+    (64, 16, 0.9), (64, 64, 0.9), (32, 16, 0.9), (64, 16, 4.0)],
+    ids=["64_by_16", "64_by_64", "32_by_16", "a_strong_system"])
+def test_inverse_rule_is_autodiff_of_the_jax_numpy_inverse(chunk, sub, largest):
+    """The kernels' inverse carries its cotangent as a rule, ``-X^T X_bar
+    X^T``; the ``jax.numpy`` form's stays under autodiff and is what the rule
+    is held against: random strictly-lower tiles, float32, to 1e-5 of the
+    largest cotangent, also where entries of up to 4 make ``X`` grow past
+    1e20.  The rule's is zero on and above the diagonal, where ``L`` has no
+    entry (autodiff's is not: the substitution reads the zeros there)."""
+    rng = np.random.RandomState(chunk + sub)
+    under = np.tril(np.ones((chunk, chunk), bool), -1)
+    m = jnp.asarray(np.where(under, rng.uniform(-largest, largest,
+                                                 (8, chunk, chunk)), 0.0),
+                    jnp.float32)
+    cot = _rand(8, chunk, chunk, seed=9)
+    x, pull = jax.vjp(lambda a: delta_rule._tiles_inverse(a, chunk, sub), m)
+    want_x, want_pull = jax.vjp(
+        lambda a: delta_rule._unit_lower_inverse(a, sub), m)
+    np.testing.assert_allclose(x, want_x, rtol=1e-5,
+                               atol=1e-6 * float(jnp.abs(want_x).max()))
+    got, want = np.asarray(pull(cot)[0]), np.asarray(want_pull(cot)[0])
+    assert np.isfinite(got).all()
+    assert np.abs(np.where(under, 0.0, got)).max() == 0.0
+    size = np.abs(np.where(under, want, 0.0)).max()
+    assert size > (1e20 if largest > 1 else 10.0)
+    assert np.abs(np.where(under, got - want, 0.0)).max() <= 1e-5 * size
+
+
+def _backward_body_count(monkeypatch, rule):
+    """``bwd_hi_products`` of a layer's plan, the backward kernel's body
+    traced under the interpreter; ``rule`` False: the inverse's rule taken
+    off, so that autodiff transposes the substitution and the block formula
+    as it did before the rule."""
+    monkeypatch.setattr(delta_rule, "_lowering_for", lambda *_: "interpret")
+    monkeypatch.setattr(delta_rule, "_BWD_HI_PRODUCTS", {})
+    if not rule:
+        monkeypatch.setattr(delta_rule, "_tiles_inverse",
+                            delta_rule._tiles_inverse.fun)
+    # a width of its own a case: the kernel's call is traced once a signature
+    args = _kda_inputs(64, 0.5, "noise", heads=1, dk=32 if rule else 48)
+    with delta_rule.plan_recording():
+        jax.make_jaxpr(lambda *a: jax.vjp(
+            delta_rule.gated_delta_rule, *a)[1](a[2]))(*args)
+    return delta_rule.last_plan_summary()
+
+
+def test_backward_body_holds_fewer_highest_precision_products(monkeypatch):
+    """The plan's count is read from the traced body: 5 of the state-free
+    part's forward, 1 of the running sums' transpose, and 2 of the inverse's
+    rule where autodiff's transpose of it has 8; the reader returns it; a
+    forward-only trace and the ``jax.numpy`` form have no such body."""
+    plan = _backward_body_count(monkeypatch, rule=True)
+    assert plan["layers"][0]["bwd_hi_products"] == plan["bwd_hi_products"] == 8
+    read = run.load_module("layer_metrics", "kda_bwd_hi_products").read
+    assert read({}) == 8
+    without = _backward_body_count(monkeypatch, rule=False)
+    assert without["bwd_hi_products"] == 14 > plan["bwd_hi_products"]
+    monkeypatch.setattr(delta_rule, "_BWD_HI_PRODUCTS", {})
+    args = _kda_inputs(64, 0.5, "noise", heads=1)
+    with delta_rule.plan_recording():
+        jax.make_jaxpr(delta_rule.gated_delta_rule)(*args)
+    assert "bwd_hi_products" not in delta_rule.last_plan_summary()
+    assert "bwd_hi_products" not in delta_rule.last_plan_summary()["layers"][0]
+    assert read({}) is None
+
+
 @pytest.mark.parametrize("why,kwargs,patched", [
     ("the_cpu", dict(dk=128, dv=128), False),
     ("a_width_that_is_no_lane_tile", dict(dk=96, dv=128), True),
@@ -809,6 +876,10 @@ def test_toy_step_lowered_for_the_tpu_holds_the_two_kernels_a_layer(
     plan = delta_rule.last_plan_summary()
     assert plan["kernel_layers"] == plan["chunked_layers"] == 2
     assert {x["lowering"] for x in plan["layers"]} == {"pallas"}
+    # the trainer's recording spans the forward trace alone: the backward
+    # body's count is there all the same, one trace for both layers
+    assert [x["bwd_hi_products"] for x in plan["layers"]] == [8, 8]
+    assert plan["bwd_hi_products"] == 8
 
 
 def test_every_leaf_of_the_model_is_drawn_on_the_device(both_sides):
@@ -850,11 +921,18 @@ def test_new_readers_read_the_plans_and_none_without_them(monkeypatch, toy_cell)
     monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", {
         "layers": [{}] * 4, "chunked_layers": 4, "state_bytes": 67108864})
     assert read("kda_kernel_layers") is None
+    # a plan without the backward body's count (the parent's, the CPU's)
+    assert read("kda_bwd_hi_products") is None
+    monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", {
+        "layers": [{}] * 4, "chunked_layers": 4, "kernel_layers": 4,
+        "state_bytes": 67108864, "bwd_hi_products": 8})
+    assert read("kda_bwd_hi_products") == 8
     # a program without the records (the parent of this change): None, no raise
     monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", None)
     monkeypatch.delattr(moe, "last_plan_summary")
     for name in ("kda_chunked_layers", "kda_kernel_layers",
-                 "kda_state_saved_gb", "moe_buffer_rows_pct"):
+                 "kda_state_saved_gb", "moe_buffer_rows_pct",
+                 "kda_bwd_hi_products"):
         assert read(name) is None
 
 

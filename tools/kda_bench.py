@@ -8,7 +8,9 @@ it (``qk_l2norm``, the head-major transposes round the scan included), run
 ``--reps`` times under one profiler session and timed by its device events:
 the union of the op intervals of a call (the ``jax.numpy`` form is hundreds
 of small ops, some inside ``while`` loops), median over the calls; for the
-kernels also their own custom calls' time.  The share of the roofline
+kernels also their own custom calls' time and ``bwd_hi_products``, the
+highest-precision products in the backward kernel's body as the timed
+program traced it (the layer's plan entry).  The share of the roofline
 divides what the algorithm needs (``kernel_costs`` of
 ``benchmark/configs/kimi-linear-48b-a3b.py``: a layer's forward + backward;
 forward alone a third of its operations and the bytes of q, k, v, o, g, beta
@@ -140,12 +142,17 @@ def main(argv=None):
         fwd = jax.jit(op)
         both = jax.jit(lambda *a: jax.vjp(op, *a[:5])[1](a[5]))
         out = jax.block_until_ready(fwd(q, k, v, g, beta))      # compiles
-        grads = jax.block_until_ready(both(q, k, v, g, beta, cot))
-        variants.append((lowering, group, fwd, both, (out,) + tuple(grads)))
+        with delta_rule.plan_recording():
+            grads = jax.block_until_ready(both(q, k, v, g, beta, cot))
+        # of the backward kernel's body as this variant traced it (None:
+        # the jax.numpy form has no such body)
+        hi = delta_rule.last_plan_summary().get("bwd_hi_products")
+        variants.append((lowering, group, fwd, both, (out,) + tuple(grads),
+                         hi))
     delta_rule._lowering_for = chosen
     first = variants[-1][4]              # the jax.numpy form where asked for
     f32 = lambda x: np.asarray(x, np.float32)
-    for lowering, group, fwd, both, outs in variants:
+    for lowering, group, fwd, both, outs, hi in variants:
         for which, fn, extra in (("fwd", fwd, ()), ("both", both, (cot,))):
             with tempfile.TemporaryDirectory() as trace_dir:
                 jax.profiler.start_trace(trace_dir)
@@ -164,7 +171,7 @@ def main(argv=None):
                                  moved / (peaks["hbm_bytes_per_s"]))
             print(json.dumps({
                 "lowering": lowering, "group": group, "what": which,
-                "shape": list(shape),
+                "shape": list(shape), "bwd_hi_products": hi,
                 "ms": round(ms, 4), "kernels_ms": round(own, 4),
                 "device_ops": per, "needed_gflop": round(flops / 1e9, 1),
                 "needed_gb": round(moved / 1e9, 3),
