@@ -100,7 +100,9 @@ def _experts(x, cfg, prefix):
     y = topk_experts(
         x, dict(cfg, routed_scaling_factor=cfg["route_scale"]),
         prefix + "moe", cfg["num_experts_per_tok"], cfg["route_norm"], True)
-    return add_shared_expert(y, x, cfg, prefix)
+    return add_shared_expert(
+        y, x, int(cfg.get("num_shared_experts", 0))
+        * cfg["moe_intermediate_size"], cfg["hidden_size"], prefix)
 
 
 def get_symbol(cfg, seq_len):

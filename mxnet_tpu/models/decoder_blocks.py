@@ -1,7 +1,10 @@
 """Symbol-level pieces the decoder language models share
-(``lfm2_moe``, ``kimi_linear``, ``afmoe``): a linear map without bias, the
-gated MLP, grouped-query attention, and the top-k expert layer over the
-experts held here with the shared expert beside it."""
+(``lfm2_moe``, ``kimi_linear``, ``afmoe``, ``nemotron_h``): a linear map
+without bias, the gated and the plain MLP, grouped-query attention, and the
+top-k expert layer over the experts held here with the shared expert beside
+it.  An expert's kind is ``_contrib_TopKMoE``'s ``expert_act``:
+``"silu_gated"`` (``gated_mlp``) or ``"relu2"`` (``plain_mlp`` with
+``relu2``)."""
 from __future__ import annotations
 
 from .. import symbol as sym
@@ -21,11 +24,18 @@ def gated_mlp(x, width, d, prefix):
     return linear(gate * linear(x, width, prefix + "w3"), d, prefix + "w2")
 
 
+def plain_mlp(x, width, d, prefix, act):
+    """``w2(act(w1 x))``, no gate matrix; ``act`` an ``Activation`` type."""
+    return linear(sym.Activation(linear(x, width, prefix + "w1"),
+                                 act_type=act), d, prefix + "w2")
+
+
 def grouped_query_attention(x, prefix, d, hq, hk, hd, eps, rope_theta=None,
-                            window=0, gated=False):
+                            window=0, gated=False, qk_norm=True):
     """``W_o(softmax(q k^T * hd ** -0.5) v)`` of ``hq`` query heads over
     ``hk`` key/value heads of ``hd`` (``_contrib_FlashAttention``, causal):
-    an RMSNorm of its own over each head of ``q`` and of ``k``; rotary
+    an RMSNorm of its own over each head of ``q`` and of ``k`` (none
+    with ``qk_norm`` false, and then no rotary embedding either); rotary
     embedding over the whole head (rotate-half, base ``rope_theta``) on
     both, none with ``rope_theta`` None; with ``window``, position ``t``
     sees the keys ``t - window < j <= t`` only; ``gated``: the concatenated
@@ -39,7 +49,8 @@ def grouped_query_attention(x, prefix, d, hq, hk, hd, eps, rope_theta=None,
         return y
 
     att = sym._contrib_FlashAttention(
-        heads("q", hq, True), heads("k", hk, True), heads("v", hk, False),
+        heads("q", hq, qk_norm), heads("k", hk, qk_norm),
+        heads("v", hk, False),
         causal=True, window=int(window), name=prefix + "attn")
     att = sym.Reshape(att, shape=(0, 0, -3))
     if gated:
@@ -48,18 +59,19 @@ def grouped_query_attention(x, prefix, d, hq, hk, hd, eps, rope_theta=None,
     return linear(att, d, prefix + "o")
 
 
-def add_shared_expert(y, x, cfg, prefix):
-    """``y`` plus the shared expert's part: one gated MLP as wide as
-    ``num_shared_experts`` experts, on every token; every chip that shares
-    the layer computes it alike."""
-    shared = int(cfg.get("num_shared_experts", 0))
-    if shared:
-        y = y + gated_mlp(x, shared * cfg["moe_intermediate_size"],
-                          cfg["hidden_size"], prefix + "shared_")
-    return y
+def add_shared_expert(y, x, width, d, prefix, expert_act="silu_gated"):
+    """``y`` plus the shared expert's part: one MLP of ``width`` and of the
+    routed experts' kind on every token; every chip that shares the layer
+    computes it alike.  ``width`` 0: no shared expert."""
+    if not width:
+        return y
+    if expert_act == "relu2":
+        return y + plain_mlp(x, width, d, prefix + "shared_", "relu2")
+    return y + gated_mlp(x, width, d, prefix + "shared_")
 
 
-def topk_experts(x, cfg, name, top_k, renormalize, use_bias):
+def topk_experts(x, cfg, name, top_k, renormalize, use_bias,
+                 expert_act="silu_gated"):
     """``_contrib_TopKMoE`` from a configuration's keys.  The ones every
     such configuration has: ``num_experts`` (the experts HELD here),
     ``router_num_experts`` (the router's published width; default: all
@@ -76,4 +88,4 @@ def topk_experts(x, cfg, name, top_k, renormalize, use_bias):
         hidden_size=int(cfg["moe_intermediate_size"]),
         norm_topk_prob=bool(renormalize),
         routed_scaling_factor=float(cfg["routed_scaling_factor"]),
-        use_expert_bias=bool(use_bias), name=name)
+        use_expert_bias=bool(use_bias), expert_act=expert_act, name=name)

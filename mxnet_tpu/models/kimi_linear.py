@@ -150,7 +150,9 @@ def _experts(x, cfg, prefix):
                          "group is built")
     y = topk_experts(x, cfg, prefix + "moe", cfg["num_experts_per_token"],
                      cfg["moe_renormalize"], True)
-    return add_shared_expert(y, x, cfg, prefix)
+    return add_shared_expert(
+        y, x, int(cfg.get("num_shared_experts", 0))
+        * cfg["moe_intermediate_size"], cfg["hidden_size"], prefix)
 
 
 def get_symbol(cfg, seq_len):

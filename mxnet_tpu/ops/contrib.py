@@ -373,6 +373,8 @@ def _topk_moe_args(attrs):
     names = ("data", "router_weight")
     if attrs.get("use_expert_bias", True):
         names += ("expert_bias",)
+    if attrs.get("expert_act", "silu_gated") == "relu2":
+        return names + ("w1_weight", "w2_weight")
     return names + ("w1_weight", "w3_weight", "w2_weight")
 
 
@@ -381,7 +383,8 @@ def _topk_moe_args(attrs):
           params={"num_experts": 0, "experts_held": 0, "expert_offset": 0,
                   "num_experts_per_tok": 1, "hidden_size": 0,
                   "norm_topk_prob": True, "routed_scaling_factor": 1.0,
-                  "use_expert_bias": True, "router_trained": True},
+                  "use_expert_bias": True, "router_trained": True,
+                  "expert_act": "silu_gated"},
           aliases=("TopKMoE",))
 def topk_moe_op(attrs, ctx, data, router_weight, *rest):
     """Token-choice top-k mixture-of-experts feed-forward over
@@ -394,7 +397,12 @@ def topk_moe_op(attrs, ctx, data, router_weight, *rest):
     ``expert_bias`` ``(num_experts,)`` (selection only; absent with
     ``use_expert_bias=False``), ``w1_weight``/``w3_weight``
     ``(experts_held, d, hidden_size)`` and ``w2_weight``
-    ``(experts_held, hidden_size, d)``.  The partial results of shares
+    ``(experts_held, hidden_size, d)``: with ``expert_act``
+    ``"silu_gated"`` an expert is ``w2(silu(x w1) * (x w3))``, with
+    ``"relu2"`` the ungated ``w2(relu(x w1^T)^2)``: there is no
+    ``w3_weight`` input and ``w1_weight`` is ``(experts_held,
+    hidden_size, d)``, the model's width last in both of an expert's
+    matrices.  The partial results of shares
     that together hold all the experts add up to the whole layer's.
     So do their gradients for ``data`` and ``router_weight``: a share
     returns its true part of both.  ``router_trained=False`` makes the
@@ -410,7 +418,14 @@ def topk_moe_op(attrs, ctx, data, router_weight, *rest):
     from ..parallel import moe as _moe
     load = rest[-1]
     bias = rest[0] if attrs.get("use_expert_bias", True) else None
-    w1, w3, w2 = rest[-4:-1]
+    act = attrs.get("expert_act", "silu_gated")
+    if act not in ("silu_gated", "relu2"):
+        raise MXNetError("_contrib_TopKMoE: expert_act %r is neither "
+                         "silu_gated nor relu2" % (act,))
+    if act == "relu2":
+        (w1, w2), w3 = rest[-3:-1], None
+    else:
+        w1, w3, w2 = rest[-4:-1]
     e, k = int(attrs["num_experts"]), int(attrs["num_experts_per_tok"])
     held = int(attrs["experts_held"]) or e
     off, ff = int(attrs["expert_offset"]), int(attrs["hidden_size"])
@@ -422,10 +437,11 @@ def topk_moe_op(attrs, ctx, data, router_weight, *rest):
             "not describe a share of a layer" % (e, held, off, k, ff))
     d = data.shape[-1]
     for name, arr, shape in (("router_weight", router_weight, (e, d)),
-                             ("w1_weight", w1, (held, d, ff)),
+                             ("w1_weight", w1, (held, ff, d) if w3 is None
+                              else (held, d, ff)),
                              ("w3_weight", w3, (held, d, ff)),
                              ("w2_weight", w2, (held, ff, d))):
-        if tuple(arr.shape) != shape:
+        if arr is not None and tuple(arr.shape) != shape:
             raise MXNetError(
                 "_contrib_TopKMoE: %s is %s where the attributes ask for "
                 "%s" % (name, tuple(arr.shape), shape))
@@ -504,3 +520,45 @@ def gated_delta_rule_op(attrs, ctx, q, k, v, g, beta):
     return delta_rule.gated_delta_rule(
         q, k, v, g, beta, chunk=int(attrs["chunk_size"]),
         qk_l2norm=bool(attrs["qk_l2norm"]), scale=float(attrs["scale"]))
+
+
+@register("_contrib_SSDScan",
+          arg_names=("x", "dt", "B", "C", "A_log", "D", "dt_bias"),
+          params={"chunk_size": 128}, aliases=("SSDScan",))
+# mxlint: allow-dtype-widening(the step is float32 by the op's definition: its products with the decay are summed over a chunk)
+def ssd_scan_op(attrs, ctx, x, dt, b, c, a_log, d, dt_bias):
+    """Mamba-2's selective state-space recurrence (arXiv:2405.21060):
+    ``x`` ``(batch, seq, heads, head_dim)``, the raw steps ``dt`` ``(batch,
+    seq, heads)``, ``B`` and ``C`` ``(batch, seq, groups, state)`` (head
+    ``h`` reads group ``h // (heads / groups)``), ``A_log``, ``D`` and
+    ``dt_bias`` ``(heads,)``; returns ``(batch, seq, heads, head_dim)``.
+    The step is ``delta_t = softplus(dt_t + dt_bias)``, taken in float32
+    (``mamba_ssm``'s ``dt_bias`` under ``dt_softplus``).  A head carries a
+    ``head_dim x state`` float32 state ``S`` (``S_0 = 0``) along the
+    sequence, with ``A = -exp(A_log)``::
+
+        S_t = exp(delta_t A) S_{t-1} + delta_t x_t B_t^T
+        y_t = S_t C_t + D x_t
+
+    computed as a scan over chunks of ``chunk_size`` positions (four
+    batched products a chunk, one state a head carried between chunks and
+    one kept a group of chunks for the backward):
+    :mod:`mxnet_tpu.ops.ssd`, whose ``ssd_scan`` takes the step itself.
+    ``seq`` is a whole number of chunks."""
+    from . import ssd
+    shapes = tuple(tuple(a.shape) for a in (x, dt, b, c, a_log, d, dt_bias))
+    if x.ndim != 4 or dt.shape != x.shape[:3] or b.ndim != 4 \
+            or c.shape != b.shape or b.shape[:2] != x.shape[:2] \
+            or a_log.shape != x.shape[2:3] or d.shape != x.shape[2:3] \
+            or dt_bias.shape != x.shape[2:3] or x.shape[2] % b.shape[2]:
+        raise MXNetError(
+            "_contrib_SSDScan wants x (batch, seq, heads, head_dim), dt "
+            "(batch, seq, heads), B, C (batch, seq, groups, state) with "
+            "heads a multiple of groups, A_log, D and dt_bias (heads,); got "
+            "x %s, dt %s, B %s, C %s, A_log %s, D %s, dt_bias %s" % shapes)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
+    try:
+        return ssd.ssd_scan(x, dt, b, c, a_log, d,
+                            chunk=int(attrs["chunk_size"]))
+    except ValueError as e:
+        raise MXNetError("_contrib_SSDScan: %s" % e) from e

@@ -481,22 +481,37 @@ def rms_norm(attrs, ctx, data, gamma):
 
 
 @register("_contrib_GatedRMSNorm", arg_names=("data", "gate", "gamma"),
-          params={"eps": 1e-5}, aliases=("GatedRMSNorm",))
+          params={"eps": 1e-5, "gate_act": "sigmoid", "gate_first": False,
+                  "gamma_axes": 1},
+          aliases=("GatedRMSNorm",))
 # mxlint: allow-dtype-widening(normalization/softmax statistics accumulate in f32 by contract)
 def gated_rms_norm(attrs, ctx, data, gate, gamma):
-    """Sigmoid-gated RMS normalization over the last axis:
+    """Gated RMS normalization over the last axis:
     ``x * rsqrt(mean(x^2) + eps) * gamma * sigmoid(gate)`` with ``gate``
     of ``data``'s shape and ``gamma`` of the last axis' length: the
     output norm of a linear-attention layer, over each head of
     ``(batch, seq, heads, head_dim)`` with one gain a channel of the
-    head.  Statistics and the gate in float32, rounded once to the
-    input's dtype."""
-    if gate.shape != data.shape or gamma.shape != data.shape[-1:]:
+    head.  ``gate_act`` (``sigmoid`` | ``silu``) is the gate's function;
+    with ``gate_first`` the gate comes before the statistics,
+    ``norm(x * act(gate)) * gamma`` (Mamba-2's ``MambaRMSNormGated`` with
+    ``norm_before_gate`` false); ``gamma_axes=2``: ``gamma`` spans the
+    last two axes, a gain of its own for every channel of every group of
+    ``(batch, seq, groups, group_size)``.  Statistics and the gate in
+    float32, rounded once to the input's dtype."""
+    axes = int(attrs["gamma_axes"])
+    if gate.shape != data.shape or axes not in (1, 2) \
+            or gamma.shape != data.shape[-axes:]:
         raise MXNetError(
             "_contrib_GatedRMSNorm wants a gate of the data's shape and a "
-            "gamma of its last axis; got data %s, gate %s, gamma %s"
-            % (tuple(data.shape), tuple(gate.shape), tuple(gamma.shape)))
+            "gamma of its last %s; got data %s, gate %s, gamma %s"
+            % ("axis" if axes == 1 else "two axes", tuple(data.shape),
+               tuple(gate.shape), tuple(gamma.shape)))
     eps = float(attrs["eps"])
+    if attrs["gate_act"] not in ("sigmoid", "silu"):
+        raise MXNetError("_contrib_GatedRMSNorm: gate_act %r is neither "
+                         "sigmoid nor silu" % (attrs["gate_act"],))
+    act = _ACTIVATIONS[attrs["gate_act"]]
+    first = bool(attrs["gate_first"])
 
     # rematerialised: the backward keeps the op's inputs, not their
     # float32 copies
@@ -504,10 +519,12 @@ def gated_rms_norm(attrs, ctx, data, gate, gamma):
     # mxlint: allow-dtype-widening(normalization/softmax statistics accumulate in f32 by contract)
     def norm(data, gate, gamma):
         xf = data.astype(jnp.float32)
+        if first:
+            xf = xf * act(gate.astype(jnp.float32))
         inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
                         + eps)
-        return (xf * inv * gamma.astype(jnp.float32)
-                * jax.nn.sigmoid(gate.astype(jnp.float32))
+        y = xf * inv * gamma.astype(jnp.float32)
+        return (y if first else y * act(gate.astype(jnp.float32))
                 ).astype(data.dtype)
 
     return norm(data, gate, gamma)
@@ -546,15 +563,19 @@ def rotary_embedding(attrs, ctx, data):
 SCOPE_SHORTCONV = "mxtpu.block.shortconv"
 
 
-@register("_contrib_CausalConv1D", arg_names=("data", "weight"),
-          params={"kernel": 3, "act_type": ""}, aliases=("CausalConv1D",))
+@register("_contrib_CausalConv1D",
+          arg_names=lambda a: ("data", "weight") if a["no_bias"]
+          else ("data", "weight", "bias"),
+          params={"kernel": 3, "act_type": "", "no_bias": True},
+          aliases=("CausalConv1D",))
 # mxlint: allow-dtype-widening(the taps' sum accumulates in f32 and is rounded once)
-def causal_conv1d(attrs, ctx, data, weight):
+def causal_conv1d(attrs, ctx, data, weight, bias=None):
     """Depthwise causal convolution along the sequence of
     ``(batch, seq, channels)``: ``out[t] = sum_j w[:, j] * x[t - (K-1) + j]``
     with zeros before position 0, one ``K``-tap filter a channel
-    (``weight`` is ``(channels, K)``), no bias.  The sequence stays the
-    second axis and the channels the last: no relayout to the
+    (``weight`` is ``(channels, K)``); with ``no_bias=False`` a third
+    input ``bias`` ``(channels,)`` is added to the sum.  The sequence
+    stays the second axis and the channels the last: no relayout to the
     ``(batch, channels, width)`` that ``Convolution`` wants, and no
     symmetric padding to cut off again.  It is ``K`` shifted
     multiply-adds that XLA fuses into one pass over the activation.
@@ -562,26 +583,31 @@ def causal_conv1d(attrs, ctx, data, weight):
     activation of the sum is returned, rounded once, and the pair is
     rematerialised: the backward keeps ``data`` and not the sum."""
     k = int(attrs["kernel"])
-    if data.ndim != 3 or weight.shape != (data.shape[2], k):
+    if data.ndim != 3 or weight.shape != (data.shape[2], k) \
+            or (bias is not None and bias.shape != data.shape[2:]):
         raise MXNetError(
-            "_contrib_CausalConv1D wants (batch, seq, channels) data and a "
-            "(channels, %d) weight; got data %s, weight %s"
-            % (k, tuple(data.shape), tuple(weight.shape)))
+            "_contrib_CausalConv1D wants (batch, seq, channels) data, a "
+            "(channels, %d) weight and a (channels,) bias if any; got data "
+            "%s, weight %s%s"
+            % (k, tuple(data.shape), tuple(weight.shape),
+               "" if bias is None else ", bias %s" % (tuple(bias.shape),)))
     t = data.shape[1]
 
     # mxlint: allow-dtype-widening(the taps' sum accumulates in f32 and is rounded once)
-    def taps(data, weight):
+    def taps(data, weight, *bias):
         xp = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0)))
         w = weight.astype(jnp.float32)
-        return sum(xp[:, j:j + t, :].astype(jnp.float32) * w[:, j]
-                   for j in range(k))
+        out = sum(xp[:, j:j + t, :].astype(jnp.float32) * w[:, j]
+                  for j in range(k))
+        return out + bias[0].astype(jnp.float32) if bias else out
 
+    params = (weight,) if bias is None else (weight, bias)
     with jax.named_scope(SCOPE_SHORTCONV):
         if not attrs["act_type"]:
-            return taps(data, weight).astype(data.dtype)
+            return taps(data, *params).astype(data.dtype)
         act = _activation_fn(attrs["act_type"], data)
         return jax.checkpoint(
-            lambda d, w: act(taps(d, w)).astype(d.dtype))(data, weight)
+            lambda d, *p: act(taps(d, *p)).astype(d.dtype))(data, *params)
 
 
 @register("InstanceNorm", arg_names=("data", "gamma", "beta"),
@@ -642,6 +668,7 @@ _ACTIVATIONS = {
     "softrelu": jax.nn.softplus,
     "softsign": lambda x: x / (1 + jnp.abs(x)),
     "silu": jax.nn.silu,
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),
 }
 
 

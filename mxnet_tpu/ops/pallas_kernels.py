@@ -577,14 +577,15 @@ def _flash_blocks(t, dk, dv=None, group=1, causal=False):
     keeps its ranges and the share of the square computed is that of
     128 rows) and whose backward kernel, the larger of the two, needs
     no more VMEM than it may ask for (:func:`_vmem_need`; its dQ
-    accumulator holds ``group * t`` rows on the streamed route); a
-    length none of them suits keeps :func:`_blocks`' own.  Forward and
+    accumulator holds ``group * t`` rows on the streamed route, a part
+    of the group's where the call runs in parts: :func:`_group_parts`);
+    a length none of them suits keeps :func:`_blocks`' own.  Forward and
     backward take the same pair.  A windowed call: :func:`_window_blocks`."""
     block_q, block_k = _blocks(t)
     if not causal:
         return block_q, block_k
     d = max(dk, dv or dk)
-    dq_rows = group * t if t > block_k else 0
+    dq_rows = group // _group_parts(t, d, group) * t if t > block_k else 0
     for rows in _Q_BLOCKS:
         if rows > block_q and t % rows == 0 and block_k % rows == 0 \
                 and block_k // rows >= _CAUSAL_RANGES \
@@ -592,6 +593,25 @@ def _flash_blocks(t, dk, dv=None, group=1, causal=False):
                                              dq_rows)) <= _VMEM_MAX:
             return rows, block_k
     return block_q, block_k
+
+
+def _group_parts(t, d, group):
+    """In how many equal parts a streamed backward call runs the ``group``
+    query heads of a key/value head: the fewest whose dQ accumulator
+    (``group / parts * t`` float32 rows) the kernel may ask VMEM for
+    (:func:`_vmem_need` at :func:`_blocks`' own pair, the least a call can
+    take).  1 on the panel route, which has no accumulator, and for every
+    grouping up to 8 x 8192 x 128; 16 query heads a key/value head at
+    8192 x 128 would need 74 MiB and ask for 111, over :data:`_VMEM_MAX`,
+    and run as two calls of 8."""
+    block_q, block_k = _blocks(t)
+    if t <= block_k:
+        return 1
+    for parts in range(1, group + 1):
+        if group % parts == 0 and _vmem_request(_vmem_need(
+                d, block_q, block_k, group // parts * t)) <= _VMEM_MAX:
+            return parts
+    return group
 
 
 def _select_blocks(op, q, causal, v=None, group=1):
@@ -628,7 +648,7 @@ def _select_blocks(op, q, causal, v=None, group=1):
 
 
 def _note_kernel_cost(op, q, block_q, block_k, causal, n_matmuls,
-                      n_tensors, plan=None, v=None, window=0):
+                      n_tensors, plan=None, v=None, window=0, group_parts=1):
     """Label this kernel instantiation's chosen block shapes in the
     cost database (telemetry.costdb) so block-size cliffs — e.g. the
     2176-length 17-tiny-K-blocks fallback ADVICE flagged — become
@@ -648,6 +668,8 @@ def _note_kernel_cost(op, q, block_q, block_k, causal, n_matmuls,
     (``<op>_window``), says the window and the most K/V tiles a Q block
     runs (``tiles_per_q_block``), and counts its share and its ``flops``
     over the tiles of the band it executes, not over ``t * t``.
+    ``group_parts``: in how many calls a streamed backward runs the query
+    heads of a key/value head (:func:`_group_parts`; 1: one call).
     Host-side, once per compile; swallowed on failure (observability
     must not fail the trace)."""
     try:
@@ -666,7 +688,7 @@ def _note_kernel_cost(op, q, block_q, block_k, causal, n_matmuls,
                   "causal_ranges": len(plan[1]) if plan else 1,
                   "scores_computed_pct": _scores_computed_pct(
                       t, block_q, block_k, plan, window) if plan else 100.0,
-                  "window": int(window),
+                  "window": int(window), "group_parts": int(group_parts),
                   "tiles_per_q_block": _window_tiles_per_q_block(
                       t, block_q, block_k, window) if window
                   else int(t // block_k)}
@@ -815,10 +837,9 @@ def _flash_attention_fwd_pallas(q, k, v, causal, interpret,
 #: trace of the kernel's body.  A symbol's shape inference evaluates
 #: each node's ancestors again: 232 traces of the forward kernel for 8
 #: attention layers, before the step itself is traced.
-_traced_once = functools.partial(
-    jax.jit, inline=True,
-    static_argnames=("causal", "interpret", "block_q", "block_k", "plan",
-                     "window"))
+_STATIC = ("causal", "interpret", "block_q", "block_k", "plan", "window")
+_traced_once = functools.partial(jax.jit, inline=True,
+                                 static_argnames=_STATIC)
 
 
 @_traced_once
@@ -1187,10 +1208,11 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
     cache-then-heuristic, keyed independently of the forward.
     ``ranges``, ``window``: as the forward's."""
     window = _window_of(window, causal, q.shape[1])
+    group = _kv_group(q, k, v)
+    parts = _group_parts(q.shape[1], max(q.shape[-1], v.shape[-1]), group)
     block_q, block_k = blocks if blocks is not None else \
         _window_blocks(q.shape[1]) if window else \
-        _select_blocks("flash_attention_bwd", q, causal, v,
-                       _kv_group(q, k, v))
+        _select_blocks("flash_attention_bwd", q, causal, v, group)
     plan = _causal_plan(block_q, block_k, ranges) if causal else None
     # 5 matmuls at 2*t*t*width each: dQ, dK and the recomputed S over
     # the query/key width, dV and dP over the value width; traffic:
@@ -1198,18 +1220,43 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, interpret,
     # (dv wide; lse/delta rows are negligible)
     _note_kernel_cost("flash_attention_bwd", q, block_q, block_k, causal,
                       n_matmuls=(6, 4), n_tensors=(4, 4), plan=plan, v=v,
-                      window=_stream_window(window, q.shape[1], block_k))
-    return _flash_bwd_call(q, k, v, o, lse, g, causal=bool(causal),
-                           interpret=bool(interpret), block_q=int(block_q),
-                           block_k=int(block_k), plan=plan, window=window)
+                      window=_stream_window(window, q.shape[1], block_k),
+                      group_parts=parts)
+    call = functools.partial(
+        _flash_bwd_call, causal=bool(causal), interpret=bool(interpret),
+        block_q=int(block_q), block_k=int(block_k), plan=plan, window=window)
+    if parts == 1:
+        return call(q, k, v, o, lse, g)
+    # the query heads of a key/value head in ``parts`` calls, each the
+    # kernel of a group ``parts`` times smaller; dK and dV are the sum of
+    # the calls' float32 results
+    b, t, h, _d = q.shape
+    hk, sub = h // group, group // parts
+
+    def part(x, i):
+        x = x.reshape((b, t, hk, parts, sub) + x.shape[3:])[:, :, :, i]
+        return x.reshape((b, t, hk * sub) + x.shape[4:])
+
+    rows = lse.reshape(b, hk, parts, sub, t)
+    grads = [call(part(q, i), k, v, part(o, i),
+                  rows[:, :, i].reshape(b * hk * sub, t, 1), part(g, i),
+                  rounded=False)
+             for i in range(parts)]
+    dq = jnp.stack([x[0].reshape(b, t, hk, sub, -1) for x in grads], axis=3)
+    return (dq.reshape(q.shape).astype(q.dtype),
+            sum(x[1] for x in grads).astype(k.dtype),
+            sum(x[2] for x in grads).astype(v.dtype))
 
 
-@_traced_once
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=_STATIC + ("rounded",))
 def _flash_bwd_call(q, k, v, o, lse, g, *, causal, interpret, block_q,
-                    block_k, plan, window=0):
+                    block_k, plan, window=0, rounded=True):
     """The backward kernel's call for blocks and plan already chosen.
     ``q``, ``k`` and their gradients are ``d`` wide; ``v``, ``o``, ``g``
-    and ``dV`` ``dv``.  ``window``: as :func:`_flash_fwd_call`'s."""
+    and ``dV`` ``dv``.  ``window``: as :func:`_flash_fwd_call`'s.
+    ``rounded`` false: the gradients stay the kernel's float32 (a call
+    that is one part of a group's sums them before rounding)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1295,9 +1342,12 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal, interpret, block_q,
             **_vmem_params(_vmem_need(max(d, dv), block_q, block_k,
                                       group * t, q.dtype.itemsize)),
         )(qt, kt, vt, dot, lse, delta)
-    return (_unfold_heads(dq.reshape(b * h, t, d), b, h).astype(q.dtype),
-            _unfold_heads(dk_, b, hk).astype(k.dtype),
-            _unfold_heads(dv_, b, hk).astype(v.dtype))
+    grads = []
+    for x, heads, like in ((dq.reshape(b * h, t, d), h, q), (dk_, hk, k),
+                           (dv_, hk, v)):
+        x = _unfold_heads(x, b, heads)
+        grads.append(x.astype(like.dtype) if rounded else x)
+    return tuple(grads)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

@@ -131,7 +131,8 @@ def _gated_rms(attrs, known):
     data = known.get("data")
     if data is None:
         return {}
-    return {"gamma": (int(data[-1]),)}
+    axes = int(attrs.get("gamma_axes", 1))
+    return {"gamma": tuple(int(n) for n in data[-axes:])}
 
 
 @register_param_shapes("_contrib_KDAGate")
@@ -142,12 +143,22 @@ def _kda_gate(attrs, known):
     return {"a_log": (int(attrs["num_heads"]),), "dt_bias": (int(data[-1]),)}
 
 
+@register_param_shapes("_contrib_SSDScan")
+def _ssd_scan(attrs, known):
+    x = known.get("x")
+    if x is None:
+        return {}
+    heads = (int(x[2]),)
+    return {"A_log": heads, "D": heads, "dt_bias": heads}
+
+
 @register_param_shapes("_contrib_CausalConv1D")
 def _causal_conv1d(attrs, known):
     data = known.get("data")
     if data is None:
         return {}
-    return {"weight": (int(data[-1]), int(attrs["kernel"]))}
+    return {"weight": (int(data[-1]), int(attrs["kernel"])),
+            "bias": (int(data[-1]),)}
 
 
 @register_param_shapes("LeakyReLU")
@@ -264,6 +275,7 @@ def _topk_moe(attrs, known):
     e = int(attrs["num_experts"])
     held = int(attrs["experts_held"]) or e
     ff = int(attrs["hidden_size"])
+    up = (held, ff, d) if attrs.get("expert_act") == "relu2" else (held, d, ff)
     return {"router_weight": (e, d), "expert_bias": (e,),
-            "w1_weight": (held, d, ff), "w3_weight": (held, d, ff),
+            "w1_weight": up, "w3_weight": (held, d, ff),
             "w2_weight": (held, ff, d), "load": (held + 1,)}

@@ -110,8 +110,9 @@ SCOPE_MOE = "mxtpu.block.moe"
 _GROUPED_PRODUCT = re.compile(
     r"^\s*%?ragged-dot-(?!metadata)[\w-]*(?:\.\d+)? = .*\bcustom-call\(",
     re.M)
-#: grouped products of one trained expert layer: three forward, and
-#: each one's two backward products
+#: grouped products of one trained layer of gated experts: three forward,
+#: and each one's two backward products (ungated experts, two matrices an
+#: expert, have six: a layer's plan says its own, ``products_trained``)
 PRODUCTS_PER_TRAINED_LAYER = 9
 
 
@@ -155,11 +156,48 @@ def _sorted_dispatch(k):
     return dispatch, combine
 
 
+def _experts(xs, w1, w3, w2, sizes):
+    """The experts' products over rows sorted by expert, ``sizes`` rows
+    an expert: ``w2(silu(xs w1) * (xs w3))``, or with ``w3`` None the
+    ungated ``w2(relu(xs w1^T)^2)``, whose square is made again from the
+    up-projection's output in the backward.  The ungated ``w1`` comes
+    ``(experts, ff, d)``, both of an expert's matrices with ``d`` as
+    their last axis: at a width that is no whole number of 128 lanes
+    (Nemotron-H's 1856) XLA carries a float32 ``(experts, d, ff)`` master
+    and its optimizer state through a chain of steps transposed and
+    copies them in and out, 2.9 GB of the 8192-token step's plan
+    (PERF.md section 6, PR 38)."""
+    import jax
+    import jax.numpy as jnp
+
+    dtype = xs.dtype
+    if w3 is not None:
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, w1.astype(dtype), sizes)) \
+            * jax.lax.ragged_dot(xs, w3.astype(dtype), sizes)
+        return jax.lax.ragged_dot(h, w2.astype(dtype), sizes)
+    # an ungated width that is no whole number of 256-wide tiles is
+    # zero-padded to one for the products (hidden units that are 0 on every
+    # row): XLA:TPU's grouped matmul ran the 1856-wide experts' six products
+    # of a layer in 10.5 ms, a width of 1920 in 11.1 and this padding's 2048
+    # in 5.3 (PERF.md section 6, PR 38: three widths at one shape; whether
+    # seven tiles are as fast as eight was not measured); a width under one
+    # tile is left as it is
+    ff = w2.shape[1]
+    pad = -ff % GROUPED_WIDTH_TILE if ff > GROUPED_WIDTH_TILE else 0
+    up, down = jnp.swapaxes(w1, 1, 2).astype(dtype), w2.astype(dtype)
+    if pad:
+        up = jnp.pad(up, ((0, 0), (0, 0), (0, pad)))
+        down = jnp.pad(down, ((0, 0), (0, pad), (0, 0)))
+    u = jax.lax.ragged_dot(xs, up, sizes)
+    h = jax.checkpoint(lambda u: jnp.square(jax.nn.relu(u)))(u)
+    return jax.lax.ragged_dot(h, down, sizes)
+
+
 def _bounded_products(x, w1, w3, w2, gates, order, counts, n_rows):
     """The held experts' part of the result over a sorted buffer of
     ``n_rows < tokens * top_k`` rows (:func:`buffer_rows`): the buffer is
     the head of the sorted order, the groups end where it does, and the
-    gather into it, the three products and the sum back into token order
+    gather into it, the experts' products and the sum back into token order
     all run over its rows alone (autodiff's transposes too: a gather of
     ``n_rows`` rows for the scatter-add and the reverse).  An assignment
     past the buffer has no row and adds nothing."""
@@ -175,15 +213,16 @@ def _bounded_products(x, w1, w3, w2, gates, order, counts, n_rows):
     filled = jnp.arange(n_rows) < jnp.sum(sizes)
     weight = jnp.where(filled, gates.reshape(-1)[order], 0.0)
     xs = jnp.where(filled[:, None], x[token], 0)            # (n_rows, d)
-    h = jax.nn.silu(jax.lax.ragged_dot(xs, w1.astype(x.dtype), sizes)) \
-        * jax.lax.ragged_dot(xs, w3.astype(x.dtype), sizes)
-    rows = jax.lax.ragged_dot(h, w2.astype(x.dtype), sizes)
+    rows = _experts(xs, w1, w3, w2, sizes)
     rows = jnp.where(filled[:, None], rows.astype(jnp.float32), 0.0) \
         * weight[:, None]
     y = jnp.zeros((t, x.shape[1]), jnp.float32).at[token].add(rows)
     return y.astype(x.dtype)
 
 
+#: an ungated expert's width is padded to a whole number of these for the
+#: grouped products (:func:`_experts`)
+GROUPED_WIDTH_TILE = 256
 #: rows the sorted buffer is rounded up to (a sublane tile)
 ROW_TILE = 8
 #: how many times the even load of the held experts the buffer takes
@@ -212,7 +251,10 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
     width.  expert_bias: (E,) or None, added to the scores for the
     selection only.  w1, w3: (held, d, ff); w2: (held, ff, d): the
     gated experts ``w2(silu(x w1) * (x w3))`` of the ``held`` experts
-    ``expert_offset .. expert_offset + held - 1``.
+    ``expert_offset .. expert_offset + held - 1``; with ``w3`` None they
+    are the ungated ``w2(relu(x w1^T)^2)`` with ``w1`` ``(held, ff, d)``
+    (:func:`_experts` has why), two matrices an expert and two grouped
+    products forward.
 
     Every token is routed over all ``E`` experts: ``s = sigmoid(x
     router_w^T)`` in float32, its ``top_k`` experts are the largest of
@@ -294,10 +336,7 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
         else:
             dispatch, combine = _sorted_dispatch(k)
             xs = dispatch(x, order, inv, here)              # (t*k, d)
-            h = jax.nn.silu(
-                jax.lax.ragged_dot(xs, w1.astype(x.dtype), counts)) \
-                * jax.lax.ragged_dot(xs, w3.astype(x.dtype), counts)
-            rows = jax.lax.ragged_dot(h, w2.astype(x.dtype), counts)
+            rows = _experts(xs, w1, w3, w2, counts)
             back = combine(rows, order, inv).reshape(t, k, d)
             weight = jnp.where(here, gates, 0.0)[:, :, None]
             y = jnp.sum(jnp.where(here[:, :, None],
@@ -309,8 +348,10 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
             jnp.sum(~jnp.any(here, axis=1), dtype=jnp.float32)[None]])
     note_layer(num_experts=router_w.shape[0], experts_held=held,
                expert_offset=int(expert_offset), num_experts_per_tok=k,
-               hidden_size=w1.shape[2], buffer_rows=n_rows,
-               even_rows=t * k * held / router_w.shape[0])
+               hidden_size=w2.shape[1], buffer_rows=n_rows,
+               even_rows=t * k * held / router_w.shape[0],
+               products_trained=6 if w3 is None
+               else PRODUCTS_PER_TRAINED_LAYER)
     return y, load
 
 
@@ -354,8 +395,9 @@ def note_compiled(executable):
     """Read from the compiled program of the step traced last what its
     expert layers' products became: ``grouped_products`` counts the
     grouped-matmul custom calls in its text, ``grouped_layers`` the
-    expert layers they cover at :data:`PRODUCTS_PER_TRAINED_LAYER` each
-    (a backend that multiplies densely and masks reads 0).  Both stay
+    expert layers they cover, each at its own ``products_trained``
+    (:data:`PRODUCTS_PER_TRAINED_LAYER` for gated experts, 6 for ungated
+    ones; a backend that multiplies densely and masks reads 0).  Both stay
     None where the executable gives no text."""
     if _LAST_SUMMARY is None or not hasattr(executable, "as_text"):
         return
@@ -363,8 +405,13 @@ def note_compiled(executable):
     if text:
         n = len(_GROUPED_PRODUCT.findall(text))
         _LAST_SUMMARY["grouped_products"] = n
-        _LAST_SUMMARY["grouped_layers"] = min(
-            _LAST_SUMMARY["expert_layers"], n // PRODUCTS_PER_TRAINED_LAYER)
+        covered = 0
+        for layer in _LAST_SUMMARY["layers"]:
+            n -= layer.get("products_trained", PRODUCTS_PER_TRAINED_LAYER)
+            if n < 0:
+                break
+            covered += 1
+        _LAST_SUMMARY["grouped_layers"] = covered
 
 
 def last_plan_summary():
@@ -372,8 +419,10 @@ def last_plan_summary():
     process (None before any): ``expert_layers``; per layer the router
     width, experts held and offset, experts a token, the experts' width,
     ``buffer_rows`` (rows of the sorted buffer its products run over,
-    :func:`buffer_rows`) and ``even_rows`` (the assignments even routing
-    sends to the held experts); and, once that step's program is compiled,
+    :func:`buffer_rows`), ``even_rows`` (the assignments even routing
+    sends to the held experts) and ``products_trained`` (the grouped
+    products a trained step runs for it: 9 for gated experts, 6 for
+    ungated ones); and, once that step's program is compiled,
     ``grouped_products`` and ``grouped_layers`` as
     :func:`note_compiled` reads them from it.  As
     ``analysis.fusion.last_plan_summary()``."""

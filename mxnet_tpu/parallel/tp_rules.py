@@ -36,6 +36,7 @@ _PASS_OPS = frozenset({
     # per channel / per head: a column-sharded activation stays sharded
     "_contrib_CausalConv1D", "_contrib_RotaryEmbedding",
     "_contrib_GatedRMSNorm", "_contrib_GatedDeltaRule",
+    "_contrib_SSDScan",
 })
 
 

@@ -388,10 +388,15 @@ class ShardedTrainer:
             node.inputs[-1][0].name: node.name for node in self._topo
             if node.op is not None and node.op.name == "_contrib_TopKMoE"
             and node.inputs[-1][0].is_variable}
-        # linear-attention layers record their plan as the expert layers do
-        self._has_delta_rule = any(
-            node.op is not None and node.op.name == "_contrib_GatedDeltaRule"
-            for node in self._topo)
+        # linear-attention and state-space layers record their plan as
+        # the expert layers do: the modules of ``ops`` that hold one
+        ops_here = {node.op.name for node in self._topo
+                    if node.op is not None}
+        self._scan_modules = [
+            module for op, module in (("_contrib_GatedDeltaRule",
+                                       "delta_rule"),
+                                      ("_contrib_SSDScan", "ssd"))
+            if op in ops_here]
 
         # data inputs consumed as integer indices (Embedding/take/...):
         # these must NOT be cast to a narrow compute dtype — bf16 rounds
@@ -1348,7 +1353,7 @@ class ShardedTrainer:
                 from .moe import plan_recording
                 p = self._compute_view(p32, compute_dtype)
                 with image_layout(layout), kernel_mesh(self.mesh), \
-                        plan_recording(), self._delta_rule_plan(), \
+                        plan_recording(), self._scan_plans(), \
                         block_fusion(self._fuse_blocks), \
                         plan_decisions(self._plan_decisions), \
                         stem_s2d(self._stem_s2d), \
@@ -1972,15 +1977,18 @@ class ShardedTrainer:
                                  mesh=self._mesh_axis_sizes(),
                                  steps=steps)
 
-    def _delta_rule_plan(self):
-        """``ops.delta_rule.plan_recording()`` round a step's forward trace
-        where the graph has such a layer; nothing otherwise (the module
-        is not imported for a graph without one)."""
-        if not self._has_delta_rule:
-            import contextlib
-            return contextlib.nullcontext()
-        from ..ops import delta_rule
-        return delta_rule.plan_recording()
+    def _scan_plans(self):
+        """``plan_recording()`` of ``ops.delta_rule`` and of ``ops.ssd``
+        round a step's forward trace, each where the graph has such a
+        layer; nothing otherwise (a module is not imported for a graph
+        without its op)."""
+        import contextlib
+        import importlib
+        stack = contextlib.ExitStack()
+        for module in self._scan_modules:
+            stack.enter_context(importlib.import_module(
+                "..ops." + module, __package__).plan_recording())
+        return stack
 
     def _publish_moe_loads(self, obs):
         """The expert layers' loads of the last step as gauges

@@ -143,6 +143,31 @@ def test_windowed_grouped_flash_compiles_for_v5e(one_chip, shape, route):
         names=["mxtpu_flash_fwd" + route, "mxtpu_flash_bwd" + route])
 
 
+def test_sixteen_query_heads_a_key_value_head_compile_for_v5e(one_chip):
+    """Nemotron-H's attention at the cell's 8192 positions: 32 query heads
+    of 128 over 2 key/value heads.  The forward is one streamed call; the
+    backward's dQ accumulator for the whole group would be 64 MiB (74 MiB
+    reckoned, 111 asked for, past the 100 a kernel may ask), so it runs as
+    two calls of the kernel that 8 heads a key/value head compile, each
+    asking for the 73.5 MiB Trinity-Mini's full layer asks for."""
+    b, t, hq, hk, d = 1, 8192, 32, 2, 128
+    assert pk._group_parts(t, d, hq // hk) == 2
+    assert pk._flash_blocks(t, d, d, hq // hk, True) == (512, 2048)
+
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+    compiled = _compile_for_chip(
+        jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+        ((b, t, hq, d), jnp.bfloat16), ((b, t, hk, d), jnp.bfloat16),
+        ((b, t, hk, d), jnp.bfloat16),
+        names=["mxtpu_flash_fwd_stream", "mxtpu_flash_bwd_stream"])
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert sum("mxtpu_flash_fwd_stream" in c.split("=")[0] for c in calls) == 1
+    assert sum("mxtpu_flash_bwd_stream" in c.split("=")[0] for c in calls) == 2
+
+
 # (batch, seq, heads, query/key width, value width): Kimi Linear's latent
 # attention at the cell's 8192 positions (streaming kernels; a 192-wide
 # head takes two lane tiles a row, so the backward asks for the VMEM of its

@@ -498,7 +498,8 @@ def test_kernel_note_from_flash_attention():
                                    "n_k": 1, "causal": False,
                                    "causal_ranges": 1,
                                    "scores_computed_pct": 100.0,
-                                   "window": 0, "tiles_per_q_block": 1}
+                                   "window": 0, "group_parts": 1,
+                                   "tiles_per_q_block": 1}
     assert sig["flops"] == pytest.approx(4 * 1 * 2 * 256 * 256 * 8)
 
 

@@ -278,6 +278,9 @@ SKIP = {
     "_contrib_GatedDeltaRule": "a scan with a custom backward; values and all "
                                "five gradients against the token-by-token "
                                "recurrence in tests/test_kimi_linear.py",
+    "_contrib_SSDScan": "a scan with a custom backward; values and all six "
+                        "gradients against the token-by-token recurrence in "
+                        "tests/test_nemotron_h.py",
     "Custom": "user-defined python op",
     "BlockGrad": "gradient blocked by definition (backward is zero, "
                  "forward is identity)",

@@ -443,7 +443,8 @@ def test_rule_keeps_four_ranges_and_the_share_computed(t, pct):
     (1152, True, 1, 64, (128, 1152), "256 does not divide t"),
     (512, True, 1, 64, (128, 512), "a panel of 512 has two places at 256"),
     (8192, False, 8, 128, (128, 2048), "not causal"),
-    (16384, True, 8, 128, (128, 2048), "the group's dQ rows leave no VMEM"),
+    (16384, True, 8, 128, (512, 2048), "the group's dQ rows (64 MiB) leave no "
+     "VMEM: the backward runs as two calls of four heads, whose rows do"),
     (16384, True, 2, 128, (512, 2048), "two heads' dQ rows do")])
 def test_rule_falls_back_where_512_rows_do_not_suit(t, causal, group, d,
                                                     blocks, why):
