@@ -63,7 +63,10 @@ Departures from the published model, all of them:
   of a sum of eight sigmoids;
 * an expert layer that holds less than a quarter of its experts computes
   at most four times their even load (``parallel.moe.buffer_rows``); held
-  assignments past that are left out;
+  assignments past that are left out.  That is the bound only: where
+  a buffer of twice the even load saves more than half a row a token
+  (``parallel.moe.small_buffer_rows``), a step that holds no more runs
+  over that many rows and leaves out nothing;
 * positions start at 0 and there is no cache: this graph trains, it does
   not decode;
 * an expert's weights are stored ``(experts, in, out)``.

@@ -66,8 +66,11 @@ Departures from the published model, all of them:
   treats its scores as constants to the gradient (``lfm2_moe`` has why a
   lone share says so);
 * an expert layer that holds less than a quarter of its experts computes
-  at most four times their even load (``parallel.moe.buffer_rows``);
-  held assignments past that are left out;
+  at most four times their even load (``parallel.moe.buffer_rows``); held
+  assignments past that are left out.  That is the bound only: where
+  a buffer of twice the even load saves more than half a row a token
+  (``parallel.moe.small_buffer_rows``), a step that holds no more runs
+  over that many rows and leaves out nothing;
 * ``A_log`` and ``dt_bias`` reach the decay through the trainer's compute
   dtype like every parameter (bfloat16 under ``dtype="bfloat16"``); the
   decay itself, its running sums and the state are float32;

@@ -62,8 +62,11 @@ Departures from the published model, all of them:
   treats its scores as constants to the gradient (``lfm2_moe`` has why a
   lone share says so);
 * an expert layer that holds less than a quarter of its experts computes
-  at most four times their even load (``parallel.moe.buffer_rows``);
-  held assignments past that are left out;
+  at most four times their even load (``parallel.moe.buffer_rows``); held
+  assignments past that are left out.  That is the bound only: where
+  a buffer of twice the even load saves more than half a row a token
+  (``parallel.moe.small_buffer_rows``), a step that holds no more runs
+  over that many rows and leaves out nothing;
 * the gates' sum has 1e-6 added where the published code adds 1e-20 (3e-7
   of a gate: the sum of six sigmoids is about 3);
 * ``A_log``, ``dt_bias`` and ``D`` reach the scan through the trainer's

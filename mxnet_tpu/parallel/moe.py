@@ -9,7 +9,10 @@ computes the held experts' part of the result by sorting the
 assignments and multiplying group by group; it builds no tensor of
 tokens x experts x capacity, and its sorted buffer follows the share of
 the experts it holds (:func:`buffer_rows`): nothing can be dropped
-where a quarter or more is held.  Its docstring has the rest.
+where a quarter or more is held.  A layer that holds under half of them
+has a second, smaller buffer where that saves enough rows
+(:func:`small_buffer_rows`), and each step runs over the one that what it
+holds fits.  Its docstring has the rest.
 
 Switch routing, as it always was:
 
@@ -27,6 +30,7 @@ probability, and the standard load-balancing auxiliary loss.
 """
 from __future__ import annotations
 
+import functools
 import math
 import re
 
@@ -156,6 +160,20 @@ def _sorted_dispatch(k):
     return dispatch, combine
 
 
+def _keeps_products(prim, *_avals, **_params):
+    """``jax.checkpoint`` policy of the two branches of a layer with two
+    sizes: a branch keeps its grouped products' results for the backward
+    and makes the rest again from its inputs there (the gather into sorted
+    order, masks, the gate arithmetic: no product).  What autodiff keeps
+    of a ``lax.cond`` both branches allocate, and each writes zeros for
+    the other's; left to itself it keeps every elementwise value between
+    the products, and XLA cannot fuse them away across the ``cond``: one
+    layer at LFM2's size with its backward planned 2.66 GB of scratch so,
+    0.99 with this policy, 0.58 with one size (compiled for a described
+    v5e; PERF.md section 6, PR 39)."""
+    return prim.name == "ragged_dot_general"
+
+
 def _experts(xs, w1, w3, w2, sizes):
     """The experts' products over rows sorted by expert, ``sizes`` rows
     an expert: ``w2(silu(xs w1) * (xs w3))``, or with ``w3`` None the
@@ -220,6 +238,58 @@ def _bounded_products(x, w1, w3, w2, gates, order, counts, n_rows):
     return y.astype(x.dtype)
 
 
+def _at_the_bound(x, w1, w3, w2, gates, order, inv, here, counts, n_rows):
+    """The held experts' part of the result over a buffer of
+    :func:`buffer_rows` rows: with every assignment there can be in the
+    buffer the sorted order is a permutation and both directions are
+    gathers (:func:`_sorted_dispatch`); with fewer rows,
+    :func:`_bounded_products`."""
+    import jax.numpy as jnp
+
+    t, k = gates.shape
+    if n_rows < t * k:
+        return _bounded_products(x, w1, w3, w2, gates, order, counts, n_rows)
+    dispatch, combine = _sorted_dispatch(k)
+    xs = dispatch(x, order, inv, here)                      # (t*k, d)
+    rows = _experts(xs, w1, w3, w2, counts)
+    back = combine(rows, order, inv).reshape(t, k, x.shape[1])
+    weight = jnp.where(here, gates, 0.0)[:, :, None]
+    return jnp.sum(jnp.where(here[:, :, None],
+                             back.astype(jnp.float32), 0.0) * weight,
+                   axis=1).astype(x.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _two_sizes(n_rows, small_rows):
+    """The same over whichever of two buffers the step's held assignments
+    fit: a ``lax.cond`` on ``sum(counts) <= small_rows`` between
+    :func:`_bounded_products` over ``small_rows`` rows, where every held
+    assignment then has a row, and :func:`_at_the_bound`.  One jitted
+    function a pair of sizes, so that a model's expert layers, its step
+    and its chain, their derivatives and the graph's shape inference
+    trace the two branches once between them and not once each (the
+    four layers of the 8192-token LFM2 step lowered in 9.3 s instead of
+    5.4 while every call traced its own; PERF.md section 6, PR 39)."""
+    import jax
+    import jax.numpy as jnp
+
+    def two_sizes(x, w1, w3, w2, gates, order, inv, here, counts):
+        def small(x, w1, w3, w2, gates):
+            return _bounded_products(x, w1, w3, w2, gates, order, counts,
+                                     small_rows)
+
+        def bound(x, w1, w3, w2, gates):
+            return _at_the_bound(x, w1, w3, w2, gates, order, inv, here,
+                                 counts, n_rows)
+
+        return jax.lax.cond(
+            jnp.sum(counts) <= small_rows,
+            jax.checkpoint(small, policy=_keeps_products),
+            jax.checkpoint(bound, policy=_keeps_products),
+            x, w1, w3, w2, gates)
+    return jax.jit(two_sizes)
+
+
 #: an ungated expert's width is padded to a whole number of these for the
 #: grouped products (:func:`_experts`)
 GROUPED_WIDTH_TILE = 256
@@ -227,18 +297,47 @@ GROUPED_WIDTH_TILE = 256
 ROW_TILE = 8
 #: how many times the even load of the held experts the buffer takes
 BUFFER_OVER_EVEN = 4
+#: and how many times the second, smaller buffer, which a step runs over
+#: when what it holds fits
+SMALL_OVER_EVEN = 2
+#: rows a token that the smaller buffer must save for a layer to have it:
+#: the ``cond`` between the two sizes costs at its edge whatever it saves
+#: (its branches share no buffer and XLA fuses nothing across it), and at
+#: half a row a token, 8 of 256 experts held and 8 a token, the 8192-token
+#: Kimi Linear step lost 3.5% (PERF.md section 6, PR 39)
+SMALL_SAVES_ROWS_A_TOKEN = 0.5
+
+
+def _rows_over_even(over, tokens, top_k, held, num_experts):
+    every = int(tokens) * int(top_k)
+    share = -(-over * every * int(held) // int(num_experts))
+    return min(every, -(-share // ROW_TILE) * ROW_TILE)
 
 
 def buffer_rows(tokens, top_k, held, num_experts):
-    """Rows of :func:`topk_moe`'s sorted buffer: ``min(tokens * top_k,
-    BUFFER_OVER_EVEN * tokens * top_k * held / num_experts)``, the
-    second rounded up to :data:`ROW_TILE`.  With a quarter or more of
-    the experts held that is every assignment there can be; with 8 of
-    256 it is ``tokens * top_k / 8``, four times what even routing
-    sends here."""
-    every = int(tokens) * int(top_k)
-    share = -(-BUFFER_OVER_EVEN * every * int(held) // int(num_experts))
-    return min(every, -(-share // ROW_TILE) * ROW_TILE)
+    """Rows of :func:`topk_moe`'s sorted buffer, the bound on what a step
+    may hold: ``min(tokens * top_k, BUFFER_OVER_EVEN * tokens * top_k *
+    held / num_experts)``, the second rounded up to :data:`ROW_TILE`.
+    With a quarter or more of the experts held that is every assignment
+    there can be; with 8 of 256 it is ``tokens * top_k / 8``, four times
+    what even routing sends here.  A step that holds no more than
+    :func:`small_buffer_rows` runs over that many rows instead."""
+    return _rows_over_even(BUFFER_OVER_EVEN, tokens, top_k, held,
+                           num_experts)
+
+
+def small_buffer_rows(tokens, top_k, held, num_experts):
+    """Rows of the second, smaller sorted buffer: :data:`SMALL_OVER_EVEN`
+    times the even load of the held experts, rounded up to
+    :data:`ROW_TILE`; None, and the layer has one size, where that saves
+    no more than :data:`SMALL_SAVES_ROWS_A_TOKEN` rows a token on
+    :func:`buffer_rows`: half or more of the experts held (nothing
+    saved), or so few that the bound itself is small (``2 top_k held /
+    num_experts`` rows a token are saved below a quarter held)."""
+    small = _rows_over_even(SMALL_OVER_EVEN, tokens, top_k, held,
+                            num_experts)
+    saved = buffer_rows(tokens, top_k, held, num_experts) - small
+    return small if saved > SMALL_SAVES_ROWS_A_TOKEN * int(tokens) else None
 
 
 # mxlint: allow-dtype-widening(the router, its sigmoid and the gate normalisation run in float32 by the model's definition)
@@ -282,19 +381,34 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
     ``tokens * top_k`` keys, the others last), the tokens gathered into
     that order, and the three products done group by group
     (``jax.lax.ragged_dot``; the backward's two products are grouped
-    too).  The buffer has :func:`buffer_rows` rows, a rule from the
-    shapes alone.  Where ``held / E >= 1/4`` that is ``tokens * top_k``,
-    the most that can be held: no assignment is dropped whatever the
-    imbalance.  Below a quarter it is four times the even load of the
-    held experts (a layer that holds 8 of 256 would else gather and
-    multiply over 32 times its expected rows): the first
+    too).  The buffer has at most :func:`buffer_rows` rows, a rule from
+    the shapes alone.  Where ``held / E >= 1/4`` that is ``tokens *
+    top_k``, the most that can be held: no assignment is dropped
+    whatever the imbalance.  Below a quarter it is four times the even
+    load of the held experts (a layer that holds 8 of 256 would else
+    gather and multiply over 32 times its expected rows): the first
     ``buffer_rows`` held assignments in expert order are computed and
     the rest contribute nothing, which happens only when the held
     experts together draw more than four times their even share;
     ``load`` still counts them, so a reader sees ``sum(load[:-1]) -
     buffer_rows`` assignments left out.  Gathers, products and the
-    combine run over the buffer's rows only; rows past the held
-    assignments belong to no group and cost no product.
+    combine run over the buffer's rows, filled or not; only the
+    products skip the rows past the held assignments, which belong to
+    no group.
+
+    So the rows a step runs over follow what it holds.  Where twice the
+    even load (:func:`small_buffer_rows`) saves more than half a row a
+    token on the bound (under half of the experts held, and not so few
+    that the bound is small already), the layer is a
+    ``lax.cond`` on the step's own count: ``sum(counts) <=
+    small_rows`` runs :func:`_bounded_products` over ``small_rows``
+    rows, where every held assignment has a row, so it is exact; any
+    other step runs over ``buffer_rows`` rows as a layer with one size
+    does, the same drop past the bound included.  Both branches are
+    differentiated through the ``cond``; each keeps its grouped
+    products' results for the backward and makes its gathers and masks
+    again there (:func:`_keeps_products`).  Any other layer has one
+    size and no ``cond``.
 
     Returns ``(y (tokens, d) in x's dtype, load)`` where ``load`` is
     float32 ``(held + 1,)``: the held experts' assignment counts as the
@@ -330,18 +444,13 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
             axis=0, dtype=jnp.int32)                        # (held,)
 
         n_rows = buffer_rows(t, k, held, router_w.shape[0])
-        if n_rows < t * k:
-            y = _bounded_products(x, w1, w3, w2, gates, order, counts,
-                                  n_rows)
+        small_rows = small_buffer_rows(t, k, held, router_w.shape[0])
+        if small_rows is None:
+            y = _at_the_bound(x, w1, w3, w2, gates, order, inv, here,
+                              counts, n_rows)
         else:
-            dispatch, combine = _sorted_dispatch(k)
-            xs = dispatch(x, order, inv, here)              # (t*k, d)
-            rows = _experts(xs, w1, w3, w2, counts)
-            back = combine(rows, order, inv).reshape(t, k, d)
-            weight = jnp.where(here, gates, 0.0)[:, :, None]
-            y = jnp.sum(jnp.where(here[:, :, None],
-                                  back.astype(jnp.float32), 0.0) * weight,
-                        axis=1).astype(x.dtype)
+            y = _two_sizes(n_rows, small_rows)(
+                x, w1, w3, w2, gates, order, inv, here, counts)
 
         load = jnp.concatenate([
             counts.astype(jnp.float32),
@@ -349,7 +458,7 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
     note_layer(num_experts=router_w.shape[0], experts_held=held,
                expert_offset=int(expert_offset), num_experts_per_tok=k,
                hidden_size=w2.shape[1], buffer_rows=n_rows,
-               even_rows=t * k * held / router_w.shape[0],
+               small_rows=small_rows, even_rows=t * k * held / router_w.shape[0],
                products_trained=6 if w3 is None
                else PRODUCTS_PER_TRAINED_LAYER)
     return y, load
@@ -395,10 +504,12 @@ def note_compiled(executable):
     """Read from the compiled program of the step traced last what its
     expert layers' products became: ``grouped_products`` counts the
     grouped-matmul custom calls in its text, ``grouped_layers`` the
-    expert layers they cover, each at its own ``products_trained``
-    (:data:`PRODUCTS_PER_TRAINED_LAYER` for gated experts, 6 for ungated
-    ones; a backend that multiplies densely and masks reads 0).  Both stay
-    None where the executable gives no text."""
+    expert layers they cover, each at the products it compiled: its own
+    ``products_trained`` (:data:`PRODUCTS_PER_TRAINED_LAYER` for gated
+    experts, 6 for ungated ones), twice where the layer has two sizes
+    (``small_rows``), since the text holds both branches' calls and a
+    step runs one's (a backend that multiplies densely and masks reads
+    0).  Both stay None where the executable gives no text."""
     if _LAST_SUMMARY is None or not hasattr(executable, "as_text"):
         return
     text = executable.as_text()
@@ -407,7 +518,8 @@ def note_compiled(executable):
         _LAST_SUMMARY["grouped_products"] = n
         covered = 0
         for layer in _LAST_SUMMARY["layers"]:
-            n -= layer.get("products_trained", PRODUCTS_PER_TRAINED_LAYER)
+            n -= layer.get("products_trained", PRODUCTS_PER_TRAINED_LAYER) \
+                * (1 if layer.get("small_rows") is None else 2)
             if n < 0:
                 break
             covered += 1
@@ -418,11 +530,15 @@ def last_plan_summary():
     """Summary of the expert layers of the step traced last in this
     process (None before any): ``expert_layers``; per layer the router
     width, experts held and offset, experts a token, the experts' width,
-    ``buffer_rows`` (rows of the sorted buffer its products run over,
-    :func:`buffer_rows`), ``even_rows`` (the assignments even routing
-    sends to the held experts) and ``products_trained`` (the grouped
-    products a trained step runs for it: 9 for gated experts, 6 for
-    ungated ones); and, once that step's program is compiled,
+    ``buffer_rows`` (the most rows of the sorted buffer its products run
+    over, :func:`buffer_rows`: the bound on what a step may hold),
+    ``small_rows`` (the rows a step runs over instead when what it holds
+    fits them, :func:`small_buffer_rows`; None for a layer with one
+    size), ``even_rows`` (the assignments even routing sends to the held
+    experts) and ``products_trained`` (the grouped products a trained
+    step runs for it: 9 for gated experts, 6 for ungated ones; a layer
+    with two sizes compiles twice that); and, once that step's program is
+    compiled,
     ``grouped_products`` and ``grouped_layers`` as
     :func:`note_compiled` reads them from it.  As
     ``analysis.fusion.last_plan_summary()``."""
@@ -433,11 +549,19 @@ def publish_load(loads):
     """Publish the expert layers' loads of a step the host has already
     waited for.  ``loads``: ``{layer: host array (held + 1,)}`` as
     :func:`topk_moe` returns them.  Sets the ``mxtpu_moe_*`` gauges and
-    keeps the sample for :func:`load_samples`."""
+    keeps the sample for :func:`load_samples`.  For a layer whose plan
+    (:func:`last_plan_summary`) has ``small_rows``, the sample and the
+    gauge ``mxtpu_moe_small_buffer`` say whether the step's held
+    assignments fit them (1.0) or it ran at the bound (0.0): the layer's
+    own comparison, made again here from the same counts."""
     import time
     from ..telemetry.registry import gauge
     sample = {}
-    for layer, load in loads.items():
+    # the trainer's layers and the plan's are both in the graph's order
+    plans = (_LAST_SUMMARY or {}).get("layers", ())
+    if len(plans) != len(loads):
+        plans = [{}] * len(loads)
+    for (layer, load), plan in zip(loads.items(), plans):
         load = np.asarray(load, np.float64)
         counts = [float(c) for c in load[:-1]]
         for i, c in enumerate(counts):
@@ -447,14 +571,19 @@ def publish_load(loads):
             float(load[-1]))
         sample[layer] = {"assignments": counts,
                          "tokens_unrouted": float(load[-1])}
+        if plan.get("small_rows") is not None:
+            fits = float(sum(counts) <= plan["small_rows"])
+            gauge("mxtpu_moe_small_buffer").labels(layer=layer).set(fits)
+            sample[layer]["small_buffer"] = fits
     _LOAD_SAMPLES.append((time.perf_counter(), sample))
     del _LOAD_SAMPLES[:-LOAD_SAMPLES_KEPT]
 
 
 def load_samples():
     """``[(perf_counter time, {layer: {"assignments": [...],
-    "tokens_unrouted"}})]`` of the dispatches whose loads were
-    published, oldest first."""
+    "tokens_unrouted", and for a layer with two sizes
+    "small_buffer"}})]`` of the dispatches whose loads were published,
+    oldest first."""
     return list(_LOAD_SAMPLES)
 
 

@@ -211,6 +211,11 @@ CATALOG = {
         GAUGE, ("layer",),
         "tokens of that step none of whose chosen experts is held "
         "here (the layer gives them zero; the residual carries them)"),
+    "mxtpu_moe_small_buffer": (
+        GAUGE, ("layer",),
+        "1 where that step's held assignments fit the layer's smaller "
+        "sorted buffer (twice the even load), so the step ran over it; "
+        "0 where it ran over the bound's; unset for a layer with one size"),
     # ------------------------------- block fusion (analysis.fusion)
     "mxtpu_fusion_plans_total": (COUNTER, (),
                                  "block-fusion plans computed (one per "

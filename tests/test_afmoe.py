@@ -289,8 +289,12 @@ PARENT_DIGESTS = {
     # parent of PR 33 (``git archive`` of b8e6a7f, the same script)
     "smoke-lfm2": ("smoke-s64-b1-chain2",
                    "364a9ef0eb68e8ae50b5ce91fc4275e9e2b0a23f082ac769d7e265df9915b73f"),
+    # refreshed by PR 39, which means to move it: the toy holds 4 of 16
+    # experts, a layer with two sizes, so its step holds ``topk_moe``'s
+    # ``cond`` (7528ea78... on the parent of PR 33); the two others hold half
+    # or all of their experts and stay the parent's
     "smoke-kimi": ("smoke-s64-b1-chain2",
-                   "7528ea788e251c8b60fb0e995f1f3320d9a60697395bed50f6ad2776bd27e5be"),
+                   "2d4fa983b93f4193eb06a6276843ceb1669cf5e053760be84be1f5b43ba6ac63"),
     "smoke-opt": ("smoke-s32-b2-chain2",
                   "6c71f808a100828fbf2ad7368361a4237734a6cdd0e8fa7adb679093d03aa53a"),
 }

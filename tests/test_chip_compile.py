@@ -228,6 +228,36 @@ def test_grouped_matmul_of_the_expert_layer_compiles_for_v5e(one_chip):
         [c.split("=")[0] for c in calls]
 
 
+def test_two_size_expert_layer_compiles_for_v5e(one_chip):
+    """``topk_moe`` at LFM2's size (8192 tokens, 4 of 32 experts a token, 8
+    held): forward and backward are one ``conditional`` each, and each branch
+    holds its own Mosaic grouped products, over 16384 and over 32768 rows (the
+    sum of ``y`` needs no value of the third forward product: 8 a branch)."""
+    from mxnet_tpu.parallel import moe
+    t, k, e, held, d, ff = 8192, 4, 32, 8, 2048, 1792
+
+    def loss(x, router_w, bias, w1, w3, w2):
+        y, _load = moe.topk_moe(x, router_w, bias, w1, w3, w2, k,
+                                router_trained=False)
+        return y.astype(jnp.float32).sum()
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((t, d), jnp.bfloat16), ((e, d), jnp.float32), ((e,), jnp.float32),
+        ((held, d, ff), jnp.bfloat16), ((held, d, ff), jnp.bfloat16),
+        ((held, ff, d), jnp.bfloat16))]
+    text = jax.jit(jax.grad(loss, (0, 3, 4, 5))).lower(*args).compile().as_text()
+    lines = text.splitlines()
+    assert sum(" conditional(" in l for l in lines) == 2
+    products = [l.split("=")[1].split("{")[0].strip()
+                for l in moe._GROUPED_PRODUCT.findall(text)]
+    rows = sorted(int(p.split("[")[1].split(",")[0]) for p in products)
+    small, bound = moe.small_buffer_rows(t, k, held, e), moe.buffer_rows(t, k, held, e)
+    assert (small, bound) == (16384, 32768)
+    # a branch's five products with rows as their first axis; its three for
+    # the weights' gradients are (held, ., .)
+    assert rows == [held] * 6 + [small] * 5 + [bound] * 5, rows
+
+
 # stage 1 and stage 2 of ResNet-50 at batch 128 (the flatten round the
 # removed kernel moved the whole activation there), and the shape of
 # PR 25's A/B, whose flatten is a bitcast (the kernel lost there too)

@@ -131,3 +131,235 @@ def test_switch_moe_symbol_trains_through_module():
             eval_metric=_Acc())
     acc = mod.score(it, _Acc())[0][1]
     assert acc > 0.9, acc
+
+
+# ------------------------------------------- topk_moe's two buffer sizes
+T, K, D, FF, HELD = 64, 2, 32, 24, 4
+#: a quarter held: the bound is every assignment there can be (128 rows, the
+#: sorted gather path), the small buffer 64; a sixth held: the bound is four
+#: times the even load (88 rows, dropping past it), the small buffer 48
+SHARES = {"quarter": 16, "sixth": 24}
+EXPERTS = {"gated": True, "ungated": False}
+
+
+def _topk_params(num_experts, gated):
+    rng = np.random.RandomState(3)
+    f = lambda *s: jnp.asarray(rng.randn(*s) * 0.3, jnp.float32)
+    return {"x": f(T, D), "router_w": f(num_experts, D),
+            "w1": f(HELD, D, FF) if gated else f(HELD, FF, D),
+            "w3": f(HELD, D, FF) if gated else None, "w2": f(HELD, FF, D)}
+
+
+def _routed(p, first, second):
+    """``p`` with tokens and router made so that token ``i`` chooses the
+    experts ``first[i]`` and ``second[i]``, in that order, whatever else."""
+    e = p["router_w"].shape[0]
+    x = np.array(p["x"]) * 0.05
+    x[np.arange(T), first] += 6.0
+    x[np.arange(T), second] += 4.0
+    return dict(p, x=jnp.asarray(x), router_w=jnp.eye(e, D, dtype=jnp.float32))
+
+
+def _held_exactly(p, n):
+    """Routing that sends exactly ``n`` of the ``T * K`` assignments to the
+    held experts ``0 .. HELD - 1``: both choices of the first ``n // 2``
+    tokens, one of the next if ``n`` is odd, none of the others'."""
+    e = p["router_w"].shape[0]
+    i = np.arange(T)
+    first = np.where(i < (n + 1) // 2, i % HELD, HELD + i % (e - HELD))
+    second = np.where(i < n // 2, (i + 1) % HELD, HELD + (i + 1) % (e - HELD))
+    return _routed(p, first, second)
+
+
+def _layer_and_grads(p, bias, trained):
+    names = [n for n in ("x", "w1", "w3", "w2", "router_w")
+             if p[n] is not None and (n != "router_w" or trained)]
+    mix = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+
+    def loss(args):
+        q = dict(p, **args)
+        y, load = moe.topk_moe(q["x"], q["router_w"], bias, q["w1"], q["w3"],
+                               q["w2"], K, router_trained=trained)
+        return jnp.sum(y * mix), (y, load)
+
+    (_, (y, load)), grads = jax.value_and_grad(loss, has_aux=True)(
+        {n: p[n] for n in names})
+    return y, load, grads
+
+
+def _one_buffer(monkeypatch, p, bias, trained):
+    """The layer with the one size it had before it had two."""
+    with monkeypatch.context() as m:
+        m.setattr(moe, "small_buffer_rows", lambda *a: None)
+        return _layer_and_grads(p, bias, trained)
+
+
+def _assert_same(got, want):
+    (y, load, grads), (y0, load0, grads0) = got, want
+    np.testing.assert_array_equal(load, load0)
+    np.testing.assert_allclose(y, y0, rtol=1e-5, atol=1e-6)
+    assert sorted(grads) == sorted(grads0)
+    for n in grads:
+        assert float(jnp.abs(grads0[n]).max()) > 0, n
+        np.testing.assert_allclose(grads[n], grads0[n], rtol=1e-5, atol=1e-6,
+                                   err_msg=n)
+
+
+def _dense(p, bias):
+    """Token by token, every held assignment computed: no buffer at all."""
+    x = np.asarray(p["x"])
+    s = 1.0 / (1.0 + np.exp(-(x @ np.asarray(p["router_w"]).T)))
+    idx = np.argsort(-(s + (0 if bias is None else np.asarray(bias))),
+                     axis=1, kind="stable")[:, :K]
+    out = np.zeros_like(x)
+    for t in range(T):
+        gates = s[t, idx[t]] / (s[t, idx[t]].sum() + 1e-6)
+        for e, g in zip(idx[t], gates):
+            if e >= HELD:
+                continue
+            if p["w3"] is not None:
+                a = x[t] @ np.asarray(p["w1"][e])
+                h = a / (1.0 + np.exp(-a)) * (x[t] @ np.asarray(p["w3"][e]))
+            else:
+                h = np.maximum(np.asarray(p["w1"][e]) @ x[t], 0.0) ** 2
+            out[t] += g * (h @ np.asarray(p["w2"][e]))
+    return out
+
+
+@pytest.mark.parametrize("trained", [True, False])
+@pytest.mark.parametrize("experts", sorted(EXPERTS))
+@pytest.mark.parametrize("share", sorted(SHARES))
+def test_topk_moe_small_buffer_equals_the_one_buffer(monkeypatch, share,
+                                                     experts, trained):
+    """Even routing holds about its even share, under the small buffer: the
+    branch over ``small_rows`` gives the one-buffer layer's result, loads and
+    gradients, and the bufferless sum's result."""
+    e = SHARES[share]
+    p = _topk_params(e, EXPERTS[experts])
+    small = moe.small_buffer_rows(T, K, HELD, e)
+    assert small == {16: 64, 24: 48}[e] < moe.buffer_rows(T, K, HELD, e)
+    got = _layer_and_grads(p, None, trained)
+    assert 0 < float(got[1][:-1].sum()) <= small
+    _assert_same(got, _one_buffer(monkeypatch, p, None, trained))
+    np.testing.assert_allclose(got[0], _dense(p, None), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("trained", [True, False])
+@pytest.mark.parametrize("experts", sorted(EXPERTS))
+@pytest.mark.parametrize("share", sorted(SHARES))
+def test_topk_moe_past_the_small_buffer_runs_at_the_bound(monkeypatch, share,
+                                                          experts, trained):
+    """A bias that sends every assignment to the held experts: the branch at
+    ``buffer_rows`` runs and is the one-buffer layer.  With a quarter held
+    nothing is dropped; with a sixth the assignments past four times the
+    even load are, as before (the small buffer would have dropped more)."""
+    e = SHARES[share]
+    p = _topk_params(e, EXPERTS[experts])
+    bias = jnp.where(jnp.arange(e) < HELD, 10.0, 0.0)
+    got = _layer_and_grads(p, bias, trained)
+    assert float(got[1][:-1].sum()) == T * K > moe.small_buffer_rows(T, K, HELD, e)
+    _assert_same(got, _one_buffer(monkeypatch, p, bias, trained))
+    dense = _dense(p, bias)
+    if share == "quarter":
+        np.testing.assert_allclose(got[0], dense, rtol=1e-4, atol=1e-5)
+    else:
+        assert moe.buffer_rows(T, K, HELD, e) == 88 < T * K
+        assert float(np.abs(np.asarray(got[0]) - dense).max()) > 1e-3
+
+
+@pytest.mark.parametrize("trained", [True, False])
+@pytest.mark.parametrize("experts", sorted(EXPERTS))
+@pytest.mark.parametrize("over", [0, 1])
+def test_topk_moe_at_the_small_buffers_last_row_and_one_past(monkeypatch, over,
+                                                             experts, trained):
+    """Exactly ``small_rows`` held assignments fill the small buffer to its
+    last row and fit; one more goes to the bound's branch.  Either way the
+    result is the one-buffer layer's and nothing is dropped: the small buffer
+    would have left the one past it out."""
+    e = SHARES["quarter"]
+    small = moe.small_buffer_rows(T, K, HELD, e)
+    p = _held_exactly(_topk_params(e, EXPERTS[experts]), small + over)
+    got = _layer_and_grads(p, None, trained)
+    assert float(got[1][:-1].sum()) == small + over
+    _assert_same(got, _one_buffer(monkeypatch, p, None, trained))
+    np.testing.assert_allclose(got[0], _dense(p, None), rtol=1e-4, atol=1e-5)
+    # the host's gauge says which branch that step took
+    with moe.plan_recording():
+        _layer_and_grads(p, None, trained)
+    monkeypatch.setattr(moe, "_LOAD_SAMPLES", [])
+    moe.publish_load({"l": np.asarray(got[1])})
+    assert moe.load_samples()[-1][1]["l"]["small_buffer"] == 1.0 - over
+
+
+@pytest.mark.parametrize("tokens,top_k,held,experts,small", [
+    (8192, 4, 8, 32, 16384),     # LFM2's cell: 2 rows a token saved
+    (8192, 8, 8, 128, 8192),     # Trinity-Mini's: 1
+    (8192, 6, 8, 128, 6144),     # Nemotron's: 0.75
+    (8192, 8, 8, 256, None),     # Kimi Linear's: 0.5, and the cond cost more
+    (8192, 4, 16, 32, None), (8192, 4, 32, 32, None),   # half and all: nothing
+    (64, 2, 4, 32, None)])
+def test_small_buffer_rows_only_where_they_save_half_a_row_a_token(
+        tokens, top_k, held, experts, small):
+    assert moe.small_buffer_rows(tokens, top_k, held, experts) == small
+    if small is not None:
+        bound = moe.buffer_rows(tokens, top_k, held, experts)
+        assert bound - small > moe.SMALL_SAVES_ROWS_A_TOKEN * tokens
+
+
+@pytest.mark.parametrize("held,conditional", [(4, True), (8, False), (16, False)])
+def test_topk_moe_has_two_sizes_only_under_half_the_experts(held, conditional):
+    """Half or more of the experts held: twice the even load is every
+    assignment there can be, the layer has one size and lowers with no
+    conditional; its plan says ``small_rows`` None."""
+    p = _topk_params(16, True)
+    w = {n: jnp.concatenate([p[n]] * (held // HELD)) for n in ("w1", "w3", "w2")}
+    with moe.plan_recording():
+        text = jax.jit(lambda x: moe.topk_moe(
+            x, p["router_w"], None, w["w1"], w["w3"], w["w2"], K)[0]
+        ).lower(p["x"]).as_text()
+    assert ("stablehlo.case" in text or "stablehlo.if" in text) == conditional
+    plan = moe.last_plan_summary()["layers"][0]
+    assert plan["buffer_rows"] == T * K
+    assert plan["small_rows"] == (64 if conditional else None)
+    assert moe.small_buffer_rows(T, K, held, 16) == plan["small_rows"]
+
+
+def test_publish_load_says_small_buffer_only_where_the_plan_has_one(monkeypatch):
+    monkeypatch.setattr(moe, "_LOAD_SAMPLES", [])
+    monkeypatch.setattr(moe, "_LAST_SUMMARY", {"layers": [
+        {"small_rows": 10}, {"small_rows": None}, {"small_rows": 10}]})
+    loads = {"a": np.array([4.0, 6.0, 1.0]), "b": np.array([9.0, 9.0, 0.0]),
+             "c": np.array([5.0, 6.0, 0.0])}
+    moe.publish_load(loads)
+    sample = moe.load_samples()[-1][1]
+    assert sample["a"]["small_buffer"] == 1.0 and sample["c"]["small_buffer"] == 0.0
+    assert "small_buffer" not in sample["b"]
+    from mxnet_tpu import telemetry
+    flat = telemetry.REGISTRY.flat()
+    assert any(k.startswith("mxtpu_moe_small_buffer") for k in flat)
+    # a plan of another step's layers says nothing about these
+    monkeypatch.setattr(moe, "_LAST_SUMMARY", {"layers": [{"small_rows": 10}]})
+    moe.publish_load(loads)
+    assert all("small_buffer" not in v for v in moe.load_samples()[-1][1].values())
+
+
+@pytest.mark.parametrize("products,two_sizes,layers", [
+    (72, True, 4), (71, True, 3), (36, True, 2), (36, False, 4), (144, True, 4)])
+def test_grouped_layers_count_both_branches_products(products, two_sizes, layers):
+    """A layer with two sizes compiled both branches' grouped products: it is
+    covered when the text holds twice its ``products_trained``."""
+    with moe.plan_recording():
+        for _ in range(4):
+            moe.note_layer(buffer_rows=32768, products_trained=9,
+                           small_rows=16384 if two_sizes else None)
+
+    class Compiled:
+        def as_text(self):
+            return "\n".join(
+                "  %%ragged-dot-none.%d = bf16[16384,1792]{1,0} custom-call(%%a, "
+                "%%b), custom_call_target=\"tpu_custom_call\"" % i
+                for i in range(products))
+
+    moe.note_compiled(Compiled())
+    plan = moe.last_plan_summary()
+    assert (plan["grouped_products"], plan["grouped_layers"]) == (products, layers)

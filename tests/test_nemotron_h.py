@@ -433,6 +433,9 @@ def test_relu2_layer_says_six_products_and_gated_nine():
                      p["moe_w2_weight"], K)
     plan = moe.last_plan_summary()
     assert [x["products_trained"] for x in plan["layers"]] == [6, 9]
+    # the first holds 4 of 16: two sizes, and the compiled text holds both
+    # branches' products, twice what a step runs; the second holds them all
+    assert [x["small_rows"] is None for x in plan["layers"]] == [False, True]
 
     class Compiled:
         def __init__(self, n):
@@ -442,7 +445,7 @@ def test_relu2_layer_says_six_products_and_gated_nine():
             return "\n".join("  %%ragged-dot-none.%d = f32[8] custom-call(%%x)"
                              % i for i in range(self.n))
 
-    for products, layers in ((15, 2), (14, 1), (6, 1), (5, 0)):
+    for products, layers in ((21, 2), (20, 1), (12, 1), (11, 0)):
         moe.note_compiled(Compiled(products))
         assert (moe.last_plan_summary()["grouped_products"],
                 moe.last_plan_summary()["grouped_layers"]) == (products, layers)
