@@ -1,5 +1,5 @@
 """Symbol-level pieces the decoder language models share
-(``lfm2_moe``, ``kimi_linear``, ``afmoe``, ``nemotron_h``): a linear map
+(``lfm2_moe``, ``kimi_linear``, ``afmoe``, ``nemotron_h``, ``sdar_moe``): a linear map
 without bias, the gated and the plain MLP, grouped-query attention, and the
 top-k expert layer over the experts held here with the shared expert beside
 it.  An expert's kind is ``_contrib_TopKMoE``'s ``expert_act``:
@@ -31,7 +31,8 @@ def plain_mlp(x, width, d, prefix, act):
 
 
 def grouped_query_attention(x, prefix, d, hq, hk, hd, eps, rope_theta=None,
-                            window=0, gated=False, qk_norm=True):
+                            window=0, gated=False, qk_norm=True,
+                            diffusion_block=0, rope_period=None):
     """``W_o(softmax(q k^T * hd ** -0.5) v)`` of ``hq`` query heads over
     ``hk`` key/value heads of ``hd`` (``_contrib_FlashAttention``, causal):
     an RMSNorm of its own over each head of ``q`` and of ``k`` (none
@@ -39,19 +40,34 @@ def grouped_query_attention(x, prefix, d, hq, hk, hd, eps, rope_theta=None,
     embedding over the whole head (rotate-half, base ``rope_theta``) on
     both, none with ``rope_theta`` None; with ``window``, position ``t``
     sees the keys ``t - window < j <= t`` only; ``gated``: the concatenated
-    head outputs times ``sigmoid(W_g x)``, elementwise, before ``W_o``."""
+    head outputs times ``sigmoid(W_g x)``, elementwise, before ``W_o``.
+    ``diffusion_block``: not causal but under the block-diffusion mask
+    in blocks of that many positions, the rows a clean and then a noised
+    copy of a document (``_contrib_FlashAttention``'s ``diffusion_block``);
+    ``rope_period`` ``(period, copies)``: the rows are ``copies`` copies
+    of ``period`` positions and row ``r`` has position ``r mod period``
+    (the two copies share their positions)."""
     def heads(name, n, normed):
         y = sym.Reshape(linear(x, n * hd, prefix + name), shape=(0, 0, n, hd))
         if normed:
             y = sym.RMSNorm(y, eps=eps, name=prefix + name + "_norm")
-            if rope_theta is not None:
+            if rope_theta is not None and rope_period:
+                # the op counts positions from an iota over its rows: each
+                # copy as a sequence of its own (two free reshapes)
+                period, copies = rope_period
+                y = sym.Reshape(sym._contrib_RotaryEmbedding(
+                    sym.Reshape(y, shape=(-1, int(period), n, hd)),
+                    base=float(rope_theta)),
+                    shape=(-1, int(period) * int(copies), n, hd))
+            elif rope_theta is not None:
                 y = sym._contrib_RotaryEmbedding(y, base=float(rope_theta))
         return y
 
+    mask = dict(causal=False, diffusion_block=int(diffusion_block)) \
+        if diffusion_block else dict(causal=True, window=int(window))
     att = sym._contrib_FlashAttention(
         heads("q", hq, qk_norm), heads("k", hk, qk_norm),
-        heads("v", hk, False),
-        causal=True, window=int(window), name=prefix + "attn")
+        heads("v", hk, False), name=prefix + "attn", **mask)
     att = sym.Reshape(att, shape=(0, 0, -3))
     if gated:
         att = att * sym.Activation(linear(x, hq * hd, prefix + "g"),
@@ -71,14 +87,15 @@ def add_shared_expert(y, x, width, d, prefix, expert_act="silu_gated"):
 
 
 def topk_experts(x, cfg, name, top_k, renormalize, use_bias,
-                 expert_act="silu_gated"):
+                 expert_act="silu_gated", score_func="sigmoid"):
     """``_contrib_TopKMoE`` from a configuration's keys.  The ones every
     such configuration has: ``num_experts`` (the experts HELD here),
     ``router_num_experts`` (the router's published width; default: all
     held), ``expert_offset`` (the first held expert), ``router_trained``
     (whether this share moves its routers), ``moe_intermediate_size``
     and ``routed_scaling_factor``; the families name the rest
-    differently, so the caller reads them."""
+    differently, so the caller reads them.  ``score_func``: ``"sigmoid"``
+    or ``"softmax"`` over the router's whole width."""
     held = int(cfg["num_experts"])
     return sym._contrib_TopKMoE(
         x, num_experts=int(cfg.get("router_num_experts", held)),
@@ -88,4 +105,5 @@ def topk_experts(x, cfg, name, top_k, renormalize, use_bias,
         hidden_size=int(cfg["moe_intermediate_size"]),
         norm_topk_prob=bool(renormalize),
         routed_scaling_factor=float(cfg["routed_scaling_factor"]),
-        use_expert_bias=bool(use_bias), expert_act=expert_act, name=name)
+        use_expert_bias=bool(use_bias), expert_act=expert_act,
+        score_func=score_func, name=name)

@@ -384,7 +384,7 @@ def _topk_moe_args(attrs):
                   "num_experts_per_tok": 1, "hidden_size": 0,
                   "norm_topk_prob": True, "routed_scaling_factor": 1.0,
                   "use_expert_bias": True, "router_trained": True,
-                  "expert_act": "silu_gated"},
+                  "expert_act": "silu_gated", "score_func": "sigmoid"},
           aliases=("TopKMoE",))
 def topk_moe_op(attrs, ctx, data, router_weight, *rest):
     """Token-choice top-k mixture-of-experts feed-forward over
@@ -395,7 +395,9 @@ def topk_moe_op(attrs, ctx, data, router_weight, *rest):
     ``experts_held`` (0: all) and ``expert_offset`` say which of them
     this layer holds; ``router_weight`` is ``(num_experts, d)``,
     ``expert_bias`` ``(num_experts,)`` (selection only; absent with
-    ``use_expert_bias=False``), ``w1_weight``/``w3_weight``
+    ``use_expert_bias=False``; the scores are ``sigmoid`` of the router's
+    outputs or, with ``score_func="softmax"``, their softmax over all
+    ``num_experts`` in float32), ``w1_weight``/``w3_weight``
     ``(experts_held, d, hidden_size)`` and ``w2_weight``
     ``(experts_held, hidden_size, d)``: with ``expert_act``
     ``"silu_gated"`` an expert is ``w2(silu(x w1) * (x w3))``, with
@@ -422,6 +424,10 @@ def topk_moe_op(attrs, ctx, data, router_weight, *rest):
     if act not in ("silu_gated", "relu2"):
         raise MXNetError("_contrib_TopKMoE: expert_act %r is neither "
                          "silu_gated nor relu2" % (act,))
+    score = attrs.get("score_func", "sigmoid")
+    if score not in ("sigmoid", "softmax"):
+        raise MXNetError("_contrib_TopKMoE: score_func %r is neither "
+                         "sigmoid nor softmax" % (score,))
     if act == "relu2":
         (w1, w2), w3 = rest[-3:-1], None
     else:
@@ -450,7 +456,7 @@ def topk_moe_op(attrs, ctx, data, router_weight, *rest):
         x, router_weight, bias, w1, w3, w2, k, expert_offset=off,
         norm_topk_prob=bool(attrs["norm_topk_prob"]),
         routed_scaling_factor=float(attrs["routed_scaling_factor"]),
-        router_trained=bool(attrs["router_trained"]))
+        router_trained=bool(attrs["router_trained"]), score_func=score)
     return y.reshape(data.shape), new_load.astype(load.dtype)
 
 

@@ -103,12 +103,13 @@ from .registry import register
 _BLOCK_Q = 128
 
 
-def _attention_jnp(q, k, v, causal, window=0):
+def _attention_jnp(q, k, v, causal, window=0, diffusion_block=0):
     """Reference path (CPU / fallback / backward recompute).  Fewer
     key/value heads than query heads are repeated here, which only this
     path does: the kernels index them (:func:`_fold_queries`).  Under
     ``window`` (needs ``causal``) position ``t`` sees the keys ``t -
-    window < j <= t``."""
+    window < j <= t``; under ``diffusion_block`` (not ``causal``) a row
+    sees what :func:`flash_blockdiff.sees` says."""
     group = _kv_group(q, k, v)
     if group > 1:
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
@@ -121,6 +122,9 @@ def _attention_jnp(q, k, v, causal, window=0):
             mask = jnp.logical_and(
                 mask, jnp.logical_not(jnp.tril(mask, -int(window))))
         s = jnp.where(mask, s, -jnp.inf)
+    elif diffusion_block:
+        from .flash_blockdiff import sees
+        s = jnp.where(sees(s.shape[-1], diffusion_block), s, -jnp.inf)
     s = s - s.max(-1, keepdims=True)
     p = jnp.exp(s)
     p = p / p.sum(-1, keepdims=True)
@@ -296,7 +300,9 @@ def _causal_tiles(qpos, ki, block_q, block_k, plan, step):
 def _prefix(ref, cols, *lead):
     """Index of the first ``cols`` rows of ``ref`` under the leading
     indices ``lead``; ``cols`` None or all of them: the index the
-    whole-tile kernels use."""
+    whole-tile kernels use; a ``slice``: those rows."""
+    if isinstance(cols, slice):
+        return lead + (cols,)
     if cols is None or cols == ref.shape[len(lead)]:
         return lead or Ellipsis
     return lead + (slice(0, cols),)
@@ -728,6 +734,7 @@ class causal_plan_recording:
         kernels, _PLAN_RECORDING = _PLAN_RECORDING, self._prev
         if exc_type is None and kernels:
             windowed = [k for k in kernels if k["window"]]
+            diffusion = [k for k in kernels if k.get("diffusion_block")]
             _LAST_CAUSAL_PLAN = {
                 "kernels": kernels,
                 "causal_ranges": max(k["causal_ranges"] for k in kernels),
@@ -739,6 +746,12 @@ class causal_plan_recording:
                     for k in windowed),
                 "window_scores_computed_pct": max(
                     (k["scores_computed_pct"] for k in windowed),
+                    default=None),
+                "diffusion_layers": sum(
+                    k["kernel"] == "flash_attention_fwd_blockdiff"
+                    for k in diffusion),
+                "diffusion_scores_computed_pct": max(
+                    (k["scores_computed_pct"] for k in diffusion),
                     default=None)}
         return False
 
@@ -758,7 +771,13 @@ def last_causal_plan():
     (``flash_attention_fwd_window``; a windowed layer on the panel
     route, which masks only, is not one), and
     ``window_scores_computed_pct`` the largest share over the windowed
-    kernels (None without any).  As ``moe.last_plan_summary()``."""
+    kernels (None without any).  ``diffusion_layers``: the forward
+    kernels under the block-diffusion mask
+    (``flash_attention_fwd_blockdiff``, :mod:`.flash_blockdiff`; their
+    entries carry ``diffusion_block``, and a layer on the ``jnp`` path is
+    not one), and ``diffusion_scores_computed_pct`` the largest share of
+    the ``2L x 2L`` square over them (None without any; the mask needs
+    25.02 at L 4096, B 4).  As ``moe.last_plan_summary()``."""
     return _LAST_CAUSAL_PLAN
 
 
@@ -1381,9 +1400,24 @@ SCOPE_SWA = "mxtpu.block.swa"
 
 
 @register("_contrib_FlashAttention", arg_names=("q", "k", "v"),
-          params={"causal": False, "window": 0})
+          params={"causal": False, "window": 0, "diffusion_block": 0})
 def flash_attention_op(attrs, ctx, q, k, v):
     """Attention over (batch, seq, heads, head_dim) inputs.
+
+    ``diffusion_block`` (default 0: none; refuses ``causal`` and
+    ``window``): the block-diffusion training mask over ``seq = 2L``
+    rows, a clean copy of a document (rows ``0 .. L-1``) and then a
+    noised copy (rows ``L .. 2L-1``), both at positions ``0 .. L-1``, in
+    blocks of that many positions.  A clean row ``i`` sees the clean key
+    ``j`` iff ``j // B <= i // B``; a noised row ``i`` sees the noised
+    key ``j`` iff ``j // B == i // B`` and the clean key ``j`` iff ``j //
+    B < i // B``; no clean row sees a noised key.  One softmax runs over
+    everything a row sees.  Where ``L`` is a whole number of K/V tiles of
+    128 or more and ``B`` a power of two that divides them, the kernels
+    ``mxtpu_flash_fwd_blockdiff`` / ``mxtpu_flash_bwd_blockdiff``
+    (:mod:`mxnet_tpu.ops.flash_blockdiff`) multiply and fetch only the
+    tiles a block sees; any other shape takes the ``jnp`` path under the
+    same predicate.  Such a call carries the scope ``mxtpu.block.bda``.
 
     ``window`` (default 0: none; needs ``causal``): a sliding window,
     position ``t`` sees the keys ``t - window < j <= t``, its own among
@@ -1409,6 +1443,10 @@ def flash_attention_op(attrs, ctx, q, k, v):
     New TPU-native capability (the reference era has no attention ops);
     Pallas kernel on TPU, jnp fallback elsewhere.
     """
+    if int(attrs.get("diffusion_block", 0)):
+        from .flash_blockdiff import SCOPE_BDA
+        with jax.named_scope(SCOPE_BDA):
+            return _flash_attention_op(attrs, q, k, v)
     if q.ndim == 4 and v.ndim == 4 and q.shape[-1] != v.shape[-1]:
         with jax.named_scope(SCOPE_MLA):
             return _flash_attention_op(attrs, q, k, v)
@@ -1425,19 +1463,34 @@ def _flash_attention_op(attrs, q, k, v):
             "_contrib_FlashAttention wants (batch, seq, heads, head_dim) "
             "inputs; got q %s, k %s, v %s"
             % (tuple(q.shape), tuple(k.shape), tuple(v.shape)))
+    block = int(attrs.get("diffusion_block", 0))
     try:
         group = _kv_group(q, k, v)
         window = _window_of(attrs.get("window", 0), causal, q.shape[1])
+        if block:
+            from . import flash_blockdiff as _bd
+            if causal or window:
+                raise ValueError(
+                    "diffusion_block %d with causal or window; the "
+                    "block-diffusion mask is a mask of its own" % block)
+            block = _bd.check_shape(q.shape[1], block)
     except ValueError as e:
         raise MXNetError("_contrib_FlashAttention: %s" % e) from None
     t = q.shape[1]
     block_q = min(_BLOCK_Q, t)
+    if block and not _bd.kernels_take(q, k, v, block):
+        return _attention_jnp(q, k, v, False, 0, block)
+
+    def attend(q_, k_, v_):
+        if block:
+            return _bd.flash_attention_blockdiff(q_, k_, v_, block)
+        return flash_attention(q_, k_, v_, causal, False, window)
     if _context.on_tpu() and t > 0 and t % block_q == 0 \
             and k.shape[1] == t:
         from ..parallel import mesh as _mesh
         mesh = _mesh.active_kernel_mesh()
         if mesh is None:
-            return flash_attention(q, k, v, causal, False, window)
+            return attend(q, k, v)
         # each device runs the kernel on its (batch/data, heads/model)
         # tile; attention mixes neither dim
         from jax.sharding import PartitionSpec as P
@@ -1446,12 +1499,10 @@ def _flash_attention_op(attrs, q, k, v):
                                            q.shape[2] // group)
         spec = P(b_axis, None, h_axis, None)
         return _mesh.shard_map_nocheck(
-            lambda q_, k_, v_: flash_attention(q_, k_, v_, causal, False,
-                                               window),
-            mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
+            attend, mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
     # ragged tails (seq not a multiple of the Q block) and cross-attention
     # (tk != tq) take the jnp path rather than failing; XLA still fuses it
-    return _attention_jnp(q, k, v, causal, window)
+    return _attention_jnp(q, k, v, causal, window, block)
 
 
 @register("_contrib_RingAttention", arg_names=("q", "k", "v"),

@@ -340,10 +340,11 @@ def small_buffer_rows(tokens, top_k, held, num_experts):
     return small if saved > SMALL_SAVES_ROWS_A_TOKEN * int(tokens) else None
 
 
-# mxlint: allow-dtype-widening(the router, its sigmoid and the gate normalisation run in float32 by the model's definition)
+# mxlint: allow-dtype-widening(the router, its sigmoid or softmax and the gate normalisation run in float32 by the model's definition)
 def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
              expert_offset=0, norm_topk_prob=True,
-             routed_scaling_factor=1.0, router_trained=True):
+             routed_scaling_factor=1.0, router_trained=True,
+             score_func="sigmoid"):
     """Token-choice top-k expert layer over the experts held here.
 
     x: (tokens, d).  router_w: (E, d), the router at its published
@@ -356,7 +357,9 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
     products forward.
 
     Every token is routed over all ``E`` experts: ``s = sigmoid(x
-    router_w^T)`` in float32, its ``top_k`` experts are the largest of
+    router_w^T)`` in float32 (``score_func`` ``"softmax"``: the softmax
+    over the router's whole width, Qwen3-MoE's and SDAR's scores), its
+    ``top_k`` experts are the largest of
     ``s + expert_bias``, its gates ``s`` at those, divided by their sum
     (+1e-6) over all ``top_k`` if ``norm_topk_prob``, times
     ``routed_scaling_factor``.  The result is ``sum_e gate_e *
@@ -424,7 +427,11 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
     with jax.named_scope(SCOPE_MOE):
         logits = jnp.dot(x, router_w.T.astype(x.dtype),
                          preferred_element_type=jnp.float32)
-        s = jax.nn.sigmoid(logits)                          # (t, E) f32
+        if score_func not in ("sigmoid", "softmax"):
+            raise ValueError("topk_moe: score_func %r is neither sigmoid "
+                             "nor softmax" % (score_func,))
+        s = jax.nn.sigmoid(logits) if score_func == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)            # (t, E) f32
         if not router_trained:
             s = jax.lax.stop_gradient(s)
         sel = s if expert_bias is None else \
@@ -460,7 +467,7 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
                hidden_size=w2.shape[1], buffer_rows=n_rows,
                small_rows=small_rows, even_rows=t * k * held / router_w.shape[0],
                products_trained=6 if w3 is None
-               else PRODUCTS_PER_TRAINED_LAYER)
+               else PRODUCTS_PER_TRAINED_LAYER, score_func=score_func)
     return y, load
 
 
@@ -530,6 +537,7 @@ def last_plan_summary():
     """Summary of the expert layers of the step traced last in this
     process (None before any): ``expert_layers``; per layer the router
     width, experts held and offset, experts a token, the experts' width,
+    ``score_func`` (``"sigmoid"`` or ``"softmax"``),
     ``buffer_rows`` (the most rows of the sorted buffer its products run
     over, :func:`buffer_rows`: the bound on what a step may hold),
     ``small_rows`` (the rows a step runs over instead when what it holds

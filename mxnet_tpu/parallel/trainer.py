@@ -1332,6 +1332,8 @@ class ShardedTrainer:
         topo, entries = self._topo, self.symbol._entries
         head_is_loss = [bool(n.op is not None and n.op.is_loss)
                         for (n, _i) in entries]
+        head_is_own_loss = head_is_loss[0] \
+            and entries[0][0].op.name == "MakeLoss"
         rescale = self._rescale
         compute_dtype = jnp.dtype(self.dtype)
         layout, rule = self._layout, self._update_rule
@@ -1401,13 +1403,18 @@ class ShardedTrainer:
                 upd = aux_upd.get(id(n), aux[n.name])
                 new_aux[n.name] = upd.astype(jnp.float32)
 
-            # monitoring loss: mean -log p(label) from the softmax head
+            # monitoring loss: mean -log p(label) from the softmax head;
+            # a head that is its own loss (``MakeLoss``: the labels and
+            # the weights were made in the graph) is monitored by its
+            # value, the mean over its elements, in float32
             loss = jnp.float32(0)
             label = None
             for nm in self._input_names:
                 if "label" in nm:
                     label = batch[nm]
-            if label is not None and head_is_loss[0]:
+            if head_is_own_loss:
+                loss = jnp.mean(heads[0].astype(jnp.float32))
+            elif label is not None and head_is_loss[0]:
                 probs = heads[0]
                 if probs.ndim == 2 and label.ndim >= 2 and \
                         label.size == probs.shape[0]:

@@ -394,7 +394,8 @@ def test_lfm2_builds_its_attention_from_the_shared_builder():
         "x", "layer1_q_weight", "layer1_q_norm_gamma", "layer1_k_weight",
         "layer1_k_norm_gamma", "layer1_v_weight", "layer1_o_weight"]
     attn = next(n for n in nodes if n["op"] == "_contrib_FlashAttention")
-    assert attn["attrs"] == {"causal": "True", "window": "0"}
+    assert attn["attrs"] == {"causal": "True", "window": "0",
+                             "diffusion_block": "0"}
     assert decoder_blocks.grouped_query_attention.__module__ == \
         lfm2_moe.grouped_query_attention.__module__
 

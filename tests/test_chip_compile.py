@@ -275,3 +275,95 @@ def test_bottleneck_block_holds_no_kernel_for_v5e(one_chip, monkeypatch,
     moved = [line for line in compiled.as_text().splitlines()
              if " reshape(" in line and rows in line.split(" reshape(")[0]]
     assert not moved, moved[:2]
+
+
+def test_block_diffusion_flash_compiles_for_v5e(one_chip):
+    """SDAR's attention at the cell's size: a clean and a noised copy of a
+    4096-token document (8192 rows) in blocks of 4, 32 query heads over 4
+    key/value heads of 128.  Both new kernels compile at the rule's 512 x
+    2048 blocks; the backward asks for the VMEM of its 8-head dQ
+    accumulator."""
+    from mxnet_tpu.ops import flash_blockdiff as bd
+
+    def loss(q, k, v):
+        return bd.flash_attention_blockdiff(q, k, v, 4) \
+            .astype(jnp.float32).sum()
+
+    assert bd.blocks_for(8192, 4) == (512, 2048)
+    fn = jax.grad(loss, argnums=(0, 1, 2))
+    shapes = [((1, 8192, 32, 128), jnp.bfloat16)] \
+        + [((1, 8192, 4, 128), jnp.bfloat16)] * 2
+    _compile_for_chip(fn, one_chip, *shapes,
+                      names=["mxtpu_flash_fwd_blockdiff",
+                             "mxtpu_flash_bwd_blockdiff"])
+    lowered = jax.jit(fn).lower(*[
+        jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes])
+    assert lowered.as_text().count("scoped_memory_configs") == 1
+
+
+def test_block_diffusion_cells_chain_program_fits_a_v5e(one_chip, monkeypatch):
+    """The cell ``sdar-fused-s4096-bd4`` itself: its 2-step chain program,
+    built by the benchmark's own ``build`` at the published widths and
+    compiled for the described chip with the platform probe patched true,
+    holds the five layers' ten new kernels, and its memory plan (6.6 GB of
+    arguments, 7.3 GB of scratch: 13.9 GB, PR 40) fits the chip's 16.9 GB
+    with the room the ``cond``'s second branch needs."""
+    import importlib.util
+    import json
+    import os
+    import re
+    from mxnet_tpu.parallel import ShardedTrainer, build_mesh
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    with open(os.path.join(bench, "configs", "sdar-30b-a3b-chat.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(bench, "traffic", "bd-s4096-b1-chain2.json")) as f:
+        mix = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "bench_configs_sdar", os.path.join(bench, "configs",
+                                           "sdar-30b-a3b-chat.py"))
+    cfgmod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cfgmod)
+    net, data, label = cfgmod.build(cfg, mix, 1)
+    opt = dict(cfg["optimizer"])
+    t = ShardedTrainer(net, build_mesh(devices=jax.devices("cpu")[:1], tp=1),
+                       data_shapes=data, label_shapes=label,
+                       optimizer=opt.pop("optimizer"), seed=1, **opt,
+                       **cfg["trainer"])
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    on_chip = lambda tree: jax.tree.map(                    # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    steps = int(mix["chain"])
+    args = (on_chip(t.params), on_chip(t.opt_state), on_chip(t.aux),
+            {n: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+             for n, s in {**data, **label}.items()},
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+            jax.ShapeDtypeStruct((steps,), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((steps,), jnp.float32, sharding=one_chip))
+    step = t._py_step
+
+    def chain(params, opt_state, aux, batch, key, lrs, ts):
+        def body(carry, xs):
+            p, s, a, ky = carry
+            ky, sub = jax.random.split(ky)
+            p, s, a, loss = step(p, s, a, batch, sub, *xs)
+            return (p, s, a, ky), loss
+
+        (params, opt_state, aux, _), losses = jax.lax.scan(
+            body, (params, opt_state, aux, key), (lrs, ts), length=steps)
+        return params, opt_state, aux, losses
+
+    compiled = jax.jit(chain, donate_argnums=(0, 1, 2)).lower(*args).compile()
+    text = compiled.as_text()
+    assert set(re.findall(r"mxtpu_flash_\w+?_blockdiff", text)) == {
+        "mxtpu_flash_fwd_blockdiff", "mxtpu_flash_bwd_blockdiff"}
+    plan = pk.last_causal_plan()
+    assert (plan["diffusion_layers"], plan["diffusion_scores_computed_pct"],
+            plan["q_block_rows"]) == (5, 31.25, 512)
+    mem = compiled.memory_analysis()
+    state = 550984960 * 12
+    assert state <= mem.argument_size_in_bytes < state + 2 ** 20
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 13.0e9 < total < 15.0e9, total

@@ -503,6 +503,30 @@ def test_kernel_note_from_flash_attention():
     assert sig["flops"] == pytest.approx(4 * 1 * 2 * 256 * 256 * 8)
 
 
+def test_kernel_note_from_block_diffusion_flash_attention():
+    """The block-diffusion kernels' record has the causal kernels' fields and
+    ``diffusion_block``; its flops count the tiles it runs."""
+    telemetry.reset()
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import flash_blockdiff as bd
+    q = jax.ShapeDtypeStruct((1, 512, 2, 8), jnp.float32)
+    jax.make_jaxpr(lambda q: bd.fwd(q, q, q, 4, True, (64, 128))[0])(q)
+    with costdb.DB._lock:
+        pend = list(costdb.DB._pending)
+    assert len(pend) == 1 and pend[0]["kind"] == "kernel"
+    assert pend[0]["name"] == "flash_attention_fwd_blockdiff", pend[0]
+    # a half of 4 Q blocks over 2 K/V tiles: 64, 128, 128 + 64, 128 + 128
+    # clean columns a block of either copy, and 64 of its own a noised one
+    pct = 100.0 * 64 * (2 * (64 + 128 + 192 + 256) + 4 * 64) / (512 * 512)
+    assert pend[0]["block_config"] == {
+        "block_q": 64, "block_k": 128, "n_k": 4, "causal": False,
+        "causal_ranges": 2, "scores_computed_pct": pct, "window": 0,
+        "group_parts": 1, "tiles_per_q_block": 3, "diffusion_block": 4}
+    assert pend[0]["flops"] == pytest.approx(
+        4 * 8 * 1 * 2 * 512 * 512 * pct / 100.0)
+
+
 # ----------------------------------------------------------- perf_top
 
 def _seed_db(tmp_path, monkeypatch):

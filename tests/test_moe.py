@@ -363,3 +363,134 @@ def test_grouped_layers_count_both_branches_products(products, two_sizes, layers
     moe.note_compiled(Compiled())
     plan = moe.last_plan_summary()
     assert (plan["grouped_products"], plan["grouped_layers"]) == (products, layers)
+
+
+# --------------------------------------------- softmax scores (PR 40)
+def _softmax_dense(p, k, offset, held, trained):
+    """Every expert of the share on every token, the gates scattered over the
+    router's width: softmax over all of it, the ``k`` largest, renormalised
+    over the chosen (+1e-6, the layer's)."""
+    s = jax.nn.softmax(p["x"] @ p["router_w"].T, axis=-1)
+    if not trained:
+        s = jax.lax.stop_gradient(s)
+    top, idx = jax.lax.top_k(s, k)
+    gates = top / (jnp.sum(top, axis=1, keepdims=True) + 1e-6)
+    e = p["router_w"].shape[0]
+    scattered = jnp.sum(jax.nn.one_hot(idx, e) * gates[:, :, None], axis=1)
+    y = jnp.zeros_like(p["x"])
+    for j in range(held):
+        h = jax.nn.silu(p["x"] @ p["w1"][j]) * (p["x"] @ p["w3"][j])
+        y = y + scattered[:, offset + j, None] * (h @ p["w2"][j])
+    return y
+
+
+@pytest.mark.parametrize("trained", [True, False])
+@pytest.mark.parametrize("offset,held", [(0, 16), (0, 4), (8, 4)])
+def test_topk_moe_softmax_scores_match_a_dense_reference(offset, held, trained):
+    rng = np.random.RandomState(5)
+    f = lambda *s: jnp.asarray(rng.randn(*s) * 0.3, jnp.float32)   # noqa: E731
+    e, k = 16, 4
+    p = {"x": f(T, D), "router_w": f(e, D), "w1": f(held, D, FF),
+         "w3": f(held, D, FF), "w2": f(held, FF, D)}
+    mix = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+
+    def layer(p):
+        return moe.topk_moe(p["x"], p["router_w"], None, p["w1"], p["w3"],
+                            p["w2"], k, expert_offset=offset,
+                            router_trained=trained, score_func="softmax")[0]
+
+    np.testing.assert_allclose(layer(p), _softmax_dense(p, k, offset, held,
+                                                        trained),
+                               rtol=1e-5, atol=1e-6)
+    got = jax.grad(lambda p: jnp.sum(layer(p) * mix))(p)
+    want = jax.grad(lambda p: jnp.sum(
+        _softmax_dense(p, k, offset, held, trained) * mix))(p)
+    for n in p:
+        np.testing.assert_allclose(got[n], want[n], rtol=1e-4, atol=1e-6,
+                                   err_msg=n)
+    assert (float(jnp.abs(got["router_w"]).max()) > 0) == trained
+    # the scores are the softmax's: they differ from the sigmoid layer's
+    sig = moe.topk_moe(p["x"], p["router_w"], None, p["w1"], p["w3"], p["w2"],
+                       k, expert_offset=offset, router_trained=trained)[0]
+    assert float(jnp.abs(sig - layer(p)).max()) > 1e-3
+    with pytest.raises(ValueError, match="neither sigmoid nor softmax"):
+        moe.topk_moe(p["x"], p["router_w"], None, p["w1"], p["w3"], p["w2"],
+                     k, score_func="tanh")
+
+
+def test_the_eight_softmax_shares_add_up_to_the_uncut_layer():
+    """The share tied to the model (SDAR's cut: one of 8 chips): 8 shares of
+    2 of 16 experts, summed, give the uncut layer's result and its gradient
+    for the input."""
+    rng = np.random.RandomState(9)
+    f = lambda *s: jnp.asarray(rng.randn(*s) * 0.3, jnp.float32)   # noqa: E731
+    e, k, each = 16, 4, 2
+    x, rw = f(T, D), f(e, D)
+    w1, w3, w2 = f(e, D, FF), f(e, D, FF), f(e, FF, D)
+    mix = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+
+    def share(x, first, n):
+        part = slice(first, first + n)
+        return moe.topk_moe(x, rw, None, w1[part], w3[part], w2[part], k,
+                            expert_offset=first, score_func="softmax")[0]
+
+    whole, whole_grad = jax.value_and_grad(
+        lambda x: jnp.sum(share(x, 0, e) * mix))(x)
+    parts = [jax.value_and_grad(lambda x, i=i: jnp.sum(
+        share(x, i * each, each) * mix))(x) for i in range(e // each)]
+    np.testing.assert_allclose(sum(v for v, _g in parts), whole, rtol=1e-5)
+    np.testing.assert_allclose(sum(g for _v, g in parts), whole_grad,
+                               rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(
+        sum(share(x, i * each, each) for i in range(e // each)),
+        share(x, 0, e), rtol=1e-5, atol=1e-6)
+
+
+#: ``(held, experts, gated, trained)`` -> (sha256 of the gradient's jaxpr,
+#: its length, sums of |gradient| by argument and the load) of the sigmoid
+#: layer at PR 40's parent (a3ca9a0), on the fixed input of ``_pinned``
+SIGMOID_AT_PARENT = {
+    (4, 16, True, False): ("d567d47f71c55fbe", 55527, [
+        280.5479, 0.0, 304.4913, 338.0938, 12.0, 11.0, 6.0, 6.0, 36.0]),
+    (8, 16, True, True): ("7debaba37becd620", 16875, [
+        400.1437, 17.9823, 488.0611, 520.0387, 12.0, 11.0, 6.0, 6.0, 3.0, 5.0,
+        7.0, 9.0, 20.0]),
+    (4, 24, False, False): ("07f505dcd927c114", 56561, [
+        188.351, 0.0, 296.265, 290.355, 6.0, 0.0, 1.0, 7.0, 50.0])}
+
+
+def _pinned(held, experts, gated, trained, **kw):
+    import hashlib
+    import re
+    rng = np.random.RandomState(11)
+    f = lambda *s: jnp.asarray(rng.randn(*s) * 0.3, jnp.float32)   # noqa: E731
+    x, rw, b = f(T, D), f(experts, D), f(experts) * 0.1
+    w1 = f(held, D, FF) if gated else f(held, FF, D)
+    w3 = f(held, D, FF) if gated else None
+    w2 = f(held, FF, D)
+    mix = jnp.cos(jnp.arange(T * D, dtype=jnp.float32)).reshape(T, D)
+
+    def fn(x, rw, w1, w2):
+        y, load = moe.topk_moe(x, rw, b, w1, w3, w2, K,
+                               router_trained=trained, **kw)
+        return jnp.sum(y * mix), load
+
+    grad = jax.grad(lambda *a: fn(*a)[0], (0, 1, 2, 3))
+    text = re.sub(r"0x[0-9a-f]+", "0x",
+                  str(jax.make_jaxpr(grad)(x, rw, w1, w2)))
+    values = [float(jnp.sum(jnp.abs(o))) for o in grad(x, rw, w1, w2)] \
+        + [float(v) for v in fn(x, rw, w1, w2)[1]]
+    return hashlib.sha256(text.encode()).hexdigest()[:16], len(text), values
+
+
+@pytest.mark.parametrize("case", sorted(SIGMOID_AT_PARENT), ids=str)
+def test_sigmoid_layer_lowers_and_computes_as_its_parent_did(case):
+    """``score_func`` left the sigmoid path alone: the jaxpr of the layer's
+    gradient and its numbers are the parent commit's, whether the argument is
+    left out or says ``"sigmoid"``."""
+    sha, length, values = SIGMOID_AT_PARENT[case]
+    for kw in ({}, {"score_func": "sigmoid"}):
+        got = _pinned(*case, **kw)
+        assert got[:2] == (sha, length)
+        np.testing.assert_allclose(got[2], values, rtol=2e-5, atol=1e-4)
+    assert _pinned(*case, score_func="softmax")[0] != sha

@@ -27,6 +27,12 @@ of the table the rule was decided by.  ``--window``: a sliding window
 (the kernels ``mxtpu_flash_{fwd,bwd}_window`` past one K/V panel); the
 needed operations are then the band's, ``0 <= t - j < window``.
 ``--blocks`` takes several pairs, each measured in turn.
+``--diffusion-block B``: the block-diffusion mask in blocks of ``B`` over
+``T = 2L`` rows, clean copy first (``mxtpu_flash_{fwd,bwd}_blockdiff``,
+``mxnet_tpu.ops.flash_blockdiff``); the needed operations are the mask's
+``L^2 + L B`` pairs, and ``--ranges`` is not read (the kernels keep
+``_causal_plan``'s own).  Run the same shape without the switch for the
+causal kernels over the same rows.
 """
 from __future__ import annotations
 
@@ -136,6 +142,87 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None, window=0):
     return records
 
 
+def _blockdiff_diffs(bd, pk, arrays, group, block, blocks):
+    """Largest difference of the compiled kernels from the plain path,
+    forward and backward, on one key/value head's group over the first
+    rows of each half (at most 4096 rows: the plain path holds the whole
+    float32 score square); None where ``blocks`` do not tile that many."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    t = arrays[0].shape[1]
+    rows = min(t, 4096)
+    if not bd._tiles_the_mask(rows, block, *blocks):
+        return {"fwd": None, "bwd": None}
+    half = t // 2
+    q, k, v, g = (jnp.concatenate(
+        [x[:, :rows // 2, :n], x[:, half:half + rows // 2, :n]], axis=1)
+        for x, n in zip(arrays, (group, 1, 1, group)))
+    o, lse = bd.fwd(q, k, v, block, False, blocks)
+    grads = bd.bwd(q, k, v, o, lse, g, block, False, blocks)
+    ref, vjp = jax.vjp(lambda *a: pk._attention_jnp(*a, False, 0, block),
+                       *(x.astype(jnp.float32) for x in (q, k, v)))
+    f32 = lambda x: np.asarray(x, np.float32)
+    return {"fwd": float(np.abs(f32(o) - f32(ref)).max()),
+            "bwd": max(float(np.abs(f32(a) - f32(r)).max())
+                       for a, r in zip(grads, vjp(g.astype(jnp.float32))))}
+
+
+def bench_blockdiff(shape, block, reps, dtype, blocks=None):
+    """``[record]`` for one shape under the block-diffusion mask: the
+    forward and the backward kernel at ``blocks`` (default: the
+    kernels' own rule)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.ops import flash_blockdiff as bd, pallas_kernels as pk
+
+    b, t, hq, hk, d = shape[:5]
+    rng = np.random.RandomState(0)
+    mk = lambda h: jnp.asarray(rng.normal(0, 1, (b, t, h, d)), dtype)
+    q, k, v, g = mk(hq), mk(hk), mk(hk), mk(hq)
+    rule = bd.blocks_for(t, block)
+    blocks = tuple(blocks or rule)
+    fwd = jax.jit(lambda q, k, v: bd.fwd(q, k, v, block, False, blocks))
+    bwd = jax.jit(lambda q, k, v, o, lse, g: bd.bwd(q, k, v, o, lse, g, block,
+                                                   False, blocks))
+    o, lse = jax.block_until_ready(fwd(q, k, v))
+    grads = jax.block_until_ready(bwd(q, k, v, o, lse, g))
+    diffs = _blockdiff_diffs(bd, pk, (q, k, v, g), hq // hk, block, blocks)
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(reps):
+            jax.block_until_ready(fwd(q, k, v))
+        for _ in range(reps):
+            jax.block_until_ready(bwd(q, k, v, o, lse, g))
+        jax.profiler.stop_trace()
+        events = kernel_events(trace_dir)
+    assert len(events) == 2 * reps, len(events)
+    half = t // 2
+    pairs = half * half + half * block
+    pct = bd.scores_computed_pct(t, blocks[0], blocks[1], block,
+                                 pk._causal_plan(*blocks))
+    records = []
+    for j, (which, products) in enumerate((("fwd", 2), ("bwd", 4))):
+        chunk = events[j * reps:(j + 1) * reps]
+        names = {n.rstrip(".0123456789") for _t0, _d, n in chunk}
+        assert len(names) == 1 and which in min(names), names
+        ms = statistics.median(dur for _t0, dur, _n in chunk) / 1e6
+        needed = 2 * b * hq * pairs * products * d
+        records.append({
+            "kernel": min(names).lstrip("%"), "shape": list(shape),
+            "diffusion_block": block, "blocks": list(blocks),
+            "rule_blocks": list(rule) if rule else None,
+            "scores_computed_pct": round(pct, 2),
+            "scores_needed_pct": round(100.0 * pairs / (t * t), 2),
+            "ms": round(ms, 4), "needed_gflop": round(needed / 1e9, 1),
+            "peak_pct_needed": round(
+                100 * needed / (ms / 1e3) / PEAK_FLOPS, 2),
+            "max_diff_vs_jnp": diffs[which]})
+    return records
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--shapes", default="4x2048x32x32x64,1x8192x32x8x64")
@@ -146,6 +233,9 @@ def main(argv=None):
                          "(default: the heuristic's for each length)")
     ap.add_argument("--window", type=int, default=0,
                     help="sliding window in keys (default 0: none)")
+    ap.add_argument("--diffusion-block", type=int, default=0,
+                    help="the block-diffusion mask in blocks of this many "
+                         "positions over T = 2L rows (default 0: none)")
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--dtype", default="bfloat16")
     args = ap.parse_args(argv)
@@ -160,6 +250,11 @@ def main(argv=None):
     for spec in args.shapes.split(","):
         shape = tuple(int(n) for n in spec.split("x"))
         for blocks in pairs:
+            if args.diffusion_block:
+                for rec in bench_blockdiff(shape, args.diffusion_block,
+                                           args.reps, args.dtype, blocks):
+                    print(json.dumps(rec), flush=True)
+                continue
             for rec in bench(pk, shape,
                              [int(s) for s in args.ranges.split(",")],
                              args.reps, args.dtype, blocks, args.window):
