@@ -56,22 +56,30 @@ step makes the group's state-free part in VMEM (:func:`_tiles_state_free`:
 the same tree, the same substitution inside blocks of :data:`SUB` and the
 same block formula, on tiles: no reshape across the tiling, no ``stack``,
 no row set in place) and walks the
-chunks through their products with the state; the backward takes the
+chunks through their products with the state.  The forward kernel keeps one
+thing more than the states: each chunk's triangular inverse ``(I +
+Diag(beta) tril(A, -1))^-1``, float32 as :func:`_tiles_inverse` returned it,
+``chunk x chunk`` a chunk, a group's chunks side by side along the lanes
+(the bytes of a bfloat16 ``q`` at heads of 128).  The backward takes the
 cotangents of the state-free part by ``jax.vjp`` of that function while the
 kernel body is traced, so that Mosaic sees dots, elementwise ops, ``iota``
-masks and row rolls.  One piece of it is a rule and not autodiff's
-transpose: the triangular inverse (:func:`_tiles_inverse`) is a
-``custom_vjp`` whose cotangent is the closed form ``-X^T X_bar X^T``, two
-products at the block formula's precision with the ``X`` the forward made,
+masks and row rolls, and it makes that part again with the inverse handed
+in (:func:`_kept_inverse`): no row of the substitution and no product of
+the block formula is in its body.  The inverse's cotangent is a rule and not
+autodiff's transpose: the closed form ``-X^T X_bar X^T``, two
+products at the block formula's precision with the kept ``X``,
 where the transpose of the substitution and of the block formula is eight
-such products and every row of the substitution walked back (a quarter of
-what the backward body issued).  The layer's plan says how many
+such products and every row of the substitution walked back.  (``A`` itself
+is still made in the backward body: ``beta``'s cotangent is the system's
+times ``A``, row by row.)  The layer's plan says how many
 highest-precision products the traced backward body holds
-(``bwd_hi_products``).  Nothing of ``(chunks, heads, chunk, width)`` float32
-is written to HBM.  Anywhere else (the CPU, a toy width, another chunk) the
+(``bwd_hi_products``) and the bytes kept beyond the inputs, states and
+inverses (``state_bytes``).  Nothing of ``(chunks, heads, chunk, width)``
+float32 is written to HBM.  Anywhere else (the CPU, a toy width, another chunk) the
 same chunks run as ``jax.numpy`` ops under ``lax.scan`` (:func:`_forward`,
 :func:`_backward`): the tests' oracle beside the recurrence, as
-``_attention_jnp`` is for the flash kernels.  Its inverse
+``_attention_jnp`` is for the flash kernels; it keeps the states alone and
+makes its inverse again.  That inverse
 (:func:`_unit_lower_inverse`) stays under autodiff on purpose: it is what
 the rule is checked against.  Under a mesh of more than one device the
 kernels' calls wrap themselves in a ``shard_map`` over (batch, heads).
@@ -310,7 +318,6 @@ def _sibling_mask(c, b):
     return (bi == bj + 1) & ((bi & 1) == 1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def _tiles_inverse(m, chunk, sub):
     """:func:`_unit_lower_inverse` for ``(n, chunk, chunk)`` tiles: forward
     substitution row by row inside the diagonal blocks of ``sub`` positions
@@ -320,7 +327,9 @@ def _tiles_inverse(m, chunk, sub):
     inverse at ``2 b``.  No row is set in place and no block is cut out:
     row ``r`` of every block is made at once from the transposed blocks
     (their column ``r``, summed along the lanes, is what scales the rows
-    solved so far), summed over each block's rows and chosen by a mask."""
+    solved so far), summed over each block's rows and chosen by a mask.
+    The forward kernel's alone: the backward is handed what this returned
+    (:func:`_kept_inverse`)."""
     n = m.shape[0]
     row, lane = _iota((chunk, chunk), 0), _iota((chunk, chunk), 1)
     eye = jnp.where(row == lane, 1.0, 0.0).astype(_F32)
@@ -343,34 +352,45 @@ def _tiles_inverse(m, chunk, sub):
     return x
 
 
-def _tiles_inverse_fwd(m, chunk, sub):
-    x = _tiles_inverse.fun(m, chunk, sub)
-    return x, x
+@jax.custom_vjp
+def _kept_inverse(m, x):
+    """``(I + m)^-1`` for ``(n, chunk, chunk)`` tiles where it is at hand:
+    ``x``, what :func:`_tiles_inverse` made of the same ``m`` in the forward
+    kernel and kept.  Nothing is computed; ``m`` is taken for its
+    cotangent."""
+    del m
+    return x
 
 
-def _tiles_inverse_bwd(chunk, sub, x, d_x):
+def _kept_inverse_bwd(x, d_x):
     """The cotangent of a matrix inverse in closed form: with ``X = (I +
     L)^-1``, ``dX = -X dL X``, so ``L_bar = -X^T X_bar X^T``: two products
     at the block formula's precision with the ``X`` the forward made, where
     autodiff would walk the substitution's rows back and transpose each
     level of the block formula (its four products become eight).  Kept where
-    ``L`` lives, under the diagonal."""
+    ``L`` lives, under the diagonal; the kept ``X`` is a constant."""
+    chunk = x.shape[-1]
     p = _bmm(jnp.swapaxes(x, 1, 2), d_x, (2, 1), _HI)
     d_m = _bmm(p, x, (2, 2), _HI)
     under = _iota((chunk, chunk), 0) > _iota((chunk, chunk), 1)
-    return (jnp.where(under, -d_m, 0.0),)
+    return jnp.where(under, -d_m, 0.0), None
 
 
-_tiles_inverse.defvjp(_tiles_inverse_fwd, _tiles_inverse_bwd)
+_kept_inverse.defvjp(lambda m, x: (x, x), _kept_inverse_bwd)
 
 
-def _tiles_state_free(q, k, v, g, beta, *, chunk, sub, l2norm, scale, roll):
+def _tiles_state_free(q, k, v, g, beta, kept=None, *, chunk, sub, l2norm,
+                      scale, roll):
     """:func:`_state_free` for the ``n`` chunks of one group at once, on
     tiles: ``q, k, v`` ``(n * chunk, d)`` in the compute dtype, ``g``
     float32, ``beta`` ``(n, chunk)`` float32 (a chunk a row: lane-dense in
-    HBM; turned into a column here).  Returns ``(Q exp(G), W, U', K
+    HBM; turned into a column here).  Returns a pair: ``(Q exp(G), W, U', K
     exp(G_last - G))`` as ``(n * chunk, d)``, ``exp(G_last)`` ``(n, 1, dk)``
-    and ``tril(B)`` ``(n, chunk, chunk)``.
+    and ``tril(B)`` ``(n, chunk, chunk)``; and the chunks' ``(I + Diag(beta)
+    tril(A, -1))^-1`` in float32, ``(n, chunk, chunk)``: made here
+    (:func:`_tiles_inverse`) unless it is handed in as ``kept``
+    (:func:`_kept_inverse`: the backward kernel, which differentiates this
+    function).
 
     What differs from the ``jax.numpy`` form is where things live, not what
     is computed: the running sums are one product with a triangle of ones
@@ -430,7 +450,10 @@ def _tiles_state_free(q, k, v, g, beta, *, chunk, sub, l2norm, scale, roll):
         b *= 2
     own = jnp.sum(q32 * k32, axis=-1, keepdims=True)
     incl = strict_q + jnp.where(eye, own, 0.0)
-    inv = _tiles_inverse(beta * strict_k, chunk, sub).astype(dtype)
+    system = beta * strict_k
+    x = _tiles_inverse(system, chunk, sub) if kept is None \
+        else _kept_inverse(system, kept)
+    inv = x.astype(dtype)
     decay = jnp.exp(big_g)
     last = jnp.sum(jnp.where(pos == chunk - 1, big_g, 0.0),
                    axis=1, keepdims=True)                     # (n, 1, dk)
@@ -442,7 +465,7 @@ def _tiles_state_free(q, k, v, g, beta, *, chunk, sub, l2norm, scale, roll):
         return x.reshape(rows, x.shape[-1]).astype(dtype)
 
     return (flat(q32 * decay), flat(w), flat(u), flat(kd), jnp.exp(last),
-            incl.astype(dtype))
+            incl.astype(dtype)), x
 
 
 def _tile_fwd(state, free):
@@ -480,7 +503,7 @@ def _tile(free, c, chunk):
 
 
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, starts_ref,
-                    state, *, free_of, chunk):
+                    x_ref, state, *, free_of, chunk):
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(1) == 0)
@@ -489,16 +512,17 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, starts_ref,
 
     s = state[...]
     starts_ref[0, 0] = s
-    free = free_of(q_ref[0], k_ref[0], v_ref[0], g_ref[0], beta_ref[0, 0])
+    free, x = free_of(q_ref[0], k_ref[0], v_ref[0], g_ref[0], beta_ref[0, 0])
     for c in range(q_ref.shape[1] // chunk):
         s, o = _tile_fwd(s, _tile(free, c, chunk))
         o_ref[0, c * chunk:(c + 1) * chunk, :] = o.astype(o_ref.dtype)
+        x_ref[0, 0, :, c * chunk:(c + 1) * chunk] = x[c]
     state[...] = s
 
 
-def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref,
-                    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_state, *,
-                    free_of, chunk):
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, x_ref,
+                    do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_state,
+                    *, free_of, chunk):
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(1) == 0)
@@ -506,10 +530,14 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref,
         d_state[...] = jnp.zeros_like(d_state)
 
     n = q_ref.shape[1] // chunk
+    kept = jnp.stack([x_ref[0, 0, :, c * chunk:(c + 1) * chunk]
+                      for c in range(n)])
     # autodiff runs while this body is traced: what reaches Mosaic is the
-    # state-free part's own ops and their transposes
-    free, pull = jax.vjp(free_of, q_ref[0], k_ref[0], v_ref[0], g_ref[0],
-                         beta_ref[0, 0])
+    # state-free part's own ops, less the inverse it is handed, and their
+    # transposes
+    free, pull, _ = jax.vjp(
+        lambda *args: free_of(*args, kept), q_ref[0], k_ref[0], v_ref[0],
+        g_ref[0], beta_ref[0, 0], has_aux=True)
     s, handed = starts_ref[0, 0], []
     for c in range(n):
         handed.append(s)
@@ -550,10 +578,12 @@ def _kernel_parts(q, how, reverse=False):
     def rows(width):
         """A group's rows of a ``(heads, T, width)`` array; ``width`` None:
         of ``beta`` as ``(heads, groups, group / chunk, chunk)``, a chunk a
-        row; a ``(dv, dk)`` pair: the group's state of ``(groups, heads, dv,
-        dk)``."""
-        if width is None:
-            return pl.BlockSpec((1, 1, group // chunk, chunk),
+        row; ``"inverse"``: the group's kept inverses of ``(heads, groups,
+        chunk, group)``, a chunk beside a chunk along the lanes; a ``(dv,
+        dk)`` pair: the group's state of ``(groups, heads, dv, dk)``."""
+        tiles = {None: (group // chunk, chunk), "inverse": (chunk, group)}
+        if width in tiles:
+            return pl.BlockSpec((1, 1) + tiles[width],
                                 lambda bh, gi: (bh, group_of(gi), 0, 0))
         if isinstance(width, tuple):
             return pl.BlockSpec((1, 1) + width,
@@ -638,30 +668,37 @@ def _backward(q, k, v, g, beta, starts, d_o, *, how):
 def _forward_kernel(q, k, v, g, beta, *, how):
     """:func:`_forward` as one ``pallas_call``: grid (batch x heads, groups),
     a group a step, the head's float32 state in VMEM across the groups.  The
-    states come back transposed, ``(T / group, B, H, dv, dk)``: they are
-    :func:`_backward_kernel`'s alone."""
+    states come back transposed, ``(T / group, B, H, dv, dk)``, and with them
+    each chunk's float32 triangular inverse, ``(B, H, T / group, chunk,
+    group)``, a group's chunks side by side (whole lane tiles in HBM, where
+    ``(chunk, chunk)`` tiles of 64 would be padded to twice their bytes):
+    both are :func:`_backward_kernel`'s alone."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     free_of, rows, keywords, flat = _kernel_parts(q, how)
     (b, h, t, dk), dv = q.shape, v.shape[-1]
     n_groups = t // how[2]
-    o, starts = pl.pallas_call(
-        functools.partial(_kda_fwd_kernel, free_of=free_of, chunk=how[0]),
+    chunk, group = how[0], how[2]
+    o, starts, x = pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, free_of=free_of, chunk=chunk),
         grid=(b * h, n_groups),
         in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), rows(None)],
-        out_specs=[rows(dv), rows((dv, dk))],
+        out_specs=[rows(dv), rows((dv, dk)), rows("inverse")],
         out_shape=[jax.ShapeDtypeStruct((b * h, t, dv), v.dtype),
-                   jax.ShapeDtypeStruct((n_groups, b * h, dv, dk), _F32)],
+                   jax.ShapeDtypeStruct((n_groups, b * h, dv, dk), _F32),
+                   jax.ShapeDtypeStruct((b * h, n_groups, chunk, group), _F32)],
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
         name=KDA_FWD, **keywords(_VMEM_FWD),
     )(*flat(q, k, v, g, beta=beta))
-    return o.reshape(b, h, t, dv), starts.reshape(n_groups, b, h, dv, dk)
+    return (o.reshape(b, h, t, dv), starts.reshape(n_groups, b, h, dv, dk),
+            x.reshape(b, h, n_groups, chunk, group))
 
 
 @_traced_once
-def _backward_kernel(q, k, v, g, beta, starts, d_o, *, how):
+def _backward_kernel(q, k, v, g, beta, starts, x, d_o, *, how):
     """:func:`_backward` as one ``pallas_call`` that walks the groups in
-    reverse, the cotangent of the state in VMEM across them."""
+    reverse, the cotangent of the state in VMEM across them; ``x``: the
+    inverses :func:`_forward_kernel` kept."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     free_of, rows, keywords, flat = _kernel_parts(q, how, reverse=True)
@@ -671,12 +708,13 @@ def _backward_kernel(q, k, v, g, beta, starts, d_o, *, how):
     grads = pl.pallas_call(
         functools.partial(_kda_bwd_kernel, free_of=free_of, chunk=how[0]),
         grid=(b * h, t // how[2]),
-        in_specs=wide + [rows((dv, dk)), rows(dv)],
+        in_specs=wide + [rows((dv, dk)), rows("inverse"), rows(dv)],
         out_specs=wide,
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args],
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
         name=KDA_BWD, **keywords(_VMEM_BWD),
-    )(*args, starts.reshape((-1, b * h, dv, dk)), d_o.reshape(b * h, t, dv))
+    )(*args, starts.reshape((-1, b * h, dv, dk)),
+      x.reshape((b * h,) + x.shape[2:]), d_o.reshape(b * h, t, dv))
     return tuple(x.reshape(like.shape).astype(like.dtype)
                  for x, like in zip(grads, (q, k, v, g, beta)))
 
@@ -714,10 +752,12 @@ def _scan(q, k, v, g, beta, how):
 
 
 def _scan_fwd(q, k, v, g, beta, how):
-    o, starts = _lowerings(how)[0](q, k, v, g, beta, how=how)
+    # kept beside the inputs: the groups' states and, from the kernels, the
+    # chunks' inverses
+    o, *kept = _lowerings(how)[0](q, k, v, g, beta, how=how)
     if _RECORDING is not None and how[5] != "xla":
-        _note_backward_body((q, k, v, g, beta, starts, o), how)
-    return o, (q, k, v, g, beta, starts)
+        _note_backward_body((q, k, v, g, beta, *kept, o), how)
+    return o, (q, k, v, g, beta, *kept)
 
 
 def _scan_bwd(how, res, d_o):
@@ -780,11 +820,15 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, sub=SUB, group=GROUP,
                           beta.astype(q.dtype))]
         o = _on_the_mesh(args, how)
         o = jnp.moveaxis(o[:, :, :t], 1, 2).astype(v.dtype)
+    # float32 kept beyond the inputs: a state a head and group and, from
+    # the kernels, a (chunk, chunk) inverse a head and chunk
+    kept = int(q.shape[3]) * int(v.shape[3]) * (padded // group)
+    if lowering != "xla":
+        kept += chunk * padded
     info = dict(heads=int(q.shape[2]), dk=int(q.shape[3]), dv=int(v.shape[3]),
                 positions=int(t), chunk=chunk, group=group, form="chunked",
                 lowering="xla" if lowering == "xla" else "pallas",
-                state_bytes=4 * int(q.shape[0]) * int(q.shape[2])
-                * int(q.shape[3]) * int(v.shape[3]) * (padded // group))
+                state_bytes=4 * int(q.shape[0]) * int(q.shape[2]) * kept)
     traced = _BWD_HI_PRODUCTS.get(_body_key(args[0], args[2], how))
     if traced is not None:
         info["bwd_hi_products"] = traced
@@ -880,8 +924,10 @@ def last_plan_summary():
     this process (None before any): per layer its heads, widths, positions,
     chunk and group lengths, the form it lowered to (``chunked``: this
     module's scan), its ``lowering`` (``pallas``: the two kernels; ``xla``:
-    ``jax.numpy`` ops), the bytes of state its backward keeps (one state
-    a head and group) and, on the kernels where the recording saw the
+    ``jax.numpy`` ops), the float32 bytes its forward keeps beyond the inputs
+    (one state a head and group; on the kernels also one ``chunk x chunk``
+    triangular inverse a head and chunk) and, on the kernels where the
+    recording saw the
     layer differentiated, ``bwd_hi_products`` (the ``dot_general``s at the
     highest precision in the backward kernel's traced body);
     ``chunked_layers``, ``kernel_layers`` and ``state_bytes`` over all of

@@ -195,7 +195,9 @@ def test_gated_delta_rule_scan_compiles_for_v5e(one_chip, names, monkeypatch):
     """The chunked scan at Kimi Linear's heads and widths, forward and
     backward, two groups of chunks: as plain XLA ops (no custom call: what
     any backend but the TPU runs) and, the platform probe patched true, as
-    the two kernels; either plan fits a small share of the chip."""
+    the two kernels, the forward handing the backward each chunk's
+    triangular inverse, a group's eight side by side in whole lane tiles;
+    either plan fits a small share of the chip."""
     from mxnet_tpu.ops import delta_rule
     monkeypatch.setattr(context, "on_tpu", lambda: bool(names))
     b, t, h, d = 1, 1024, 32, 128
@@ -210,6 +212,20 @@ def test_gated_delta_rule_scan_compiles_for_v5e(one_chip, names, monkeypatch):
         jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip, wide, wide, wide,
         ((b, t, h, d), jnp.float32), ((b, t, h), jnp.bfloat16), names=names)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    if names:
+        lines = compiled.as_text().splitlines()
+        forward, backward = (
+            next(l for l in lines if "tpu_custom_call" in l
+                 and l.split("=")[0].strip().startswith("%" + name))
+            for name in names)
+        kept = "f32[%d,%d,64,512]{3,2,1,0:T(8,128)}" % (b * h, t // 512)
+        assert forward.split(" custom-call(")[0].count(kept) == 1
+        # the forward's third result is one of the backward's operands
+        part = next(l.split("=")[0].strip() for l in lines
+                    if " = " + kept + " get-tuple-element(" in l
+                    and "index=2" in l)
+        operands = backward.split(" custom-call(")[1].split(")")[0]
+        assert part in [x.split("*/")[-1] for x in operands.split(", ")]
 
 
 def test_grouped_matmul_of_the_expert_layer_compiles_for_v5e(one_chip):

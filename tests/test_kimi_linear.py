@@ -216,66 +216,169 @@ def test_kernels_match_the_jax_numpy_form(l2norm, dtype, lowered_as):
     ids=["64_by_16", "64_by_64", "32_by_16", "a_strong_system"])
 def test_inverse_rule_is_autodiff_of_the_jax_numpy_inverse(chunk, sub, largest):
     """The kernels' inverse carries its cotangent as a rule, ``-X^T X_bar
-    X^T``; the ``jax.numpy`` form's stays under autodiff and is what the rule
-    is held against: random strictly-lower tiles, float32, to 1e-5 of the
-    largest cotangent, also where entries of up to 4 make ``X`` grow past
-    1e20.  The rule's is zero on and above the diagonal, where ``L`` has no
-    entry (autodiff's is not: the substitution reads the zeros there)."""
+    X^T``, with the ``X`` the forward kernel made and kept; the ``jax.numpy``
+    form's stays under autodiff and is what the rule is held against: random
+    strictly-lower tiles, float32, to 1e-5 of the largest cotangent, also
+    where entries of up to 4 make ``X`` grow past 1e20.  The rule's is zero
+    on and above the diagonal, where ``L`` has no entry (autodiff's is not:
+    the substitution reads the zeros there), and the kept ``X`` takes none."""
     rng = np.random.RandomState(chunk + sub)
     under = np.tril(np.ones((chunk, chunk), bool), -1)
     m = jnp.asarray(np.where(under, rng.uniform(-largest, largest,
                                                  (8, chunk, chunk)), 0.0),
                     jnp.float32)
     cot = _rand(8, chunk, chunk, seed=9)
-    x, pull = jax.vjp(lambda a: delta_rule._tiles_inverse(a, chunk, sub), m)
+    kept = delta_rule._tiles_inverse(m, chunk, sub)
+    x, pull = jax.vjp(delta_rule._kept_inverse, m, kept)
+    assert x is kept
     want_x, want_pull = jax.vjp(
         lambda a: delta_rule._unit_lower_inverse(a, sub), m)
     np.testing.assert_allclose(x, want_x, rtol=1e-5,
                                atol=1e-6 * float(jnp.abs(want_x).max()))
-    got, want = np.asarray(pull(cot)[0]), np.asarray(want_pull(cot)[0])
-    assert np.isfinite(got).all()
+    got, to_kept = pull(cot)
+    got, want = np.asarray(got), np.asarray(want_pull(cot)[0])
+    assert np.isfinite(got).all() and not np.asarray(to_kept).any()
     assert np.abs(np.where(under, 0.0, got)).max() == 0.0
     size = np.abs(np.where(under, want, 0.0)).max()
     assert size > (1e20 if largest > 1 else 10.0)
     assert np.abs(np.where(under, got - want, 0.0)).max() <= 1e-5 * size
 
 
-def _backward_body_count(monkeypatch, rule):
-    """``bwd_hi_products`` of a layer's plan, the backward kernel's body
-    traced under the interpreter; ``rule`` False: the inverse's rule taken
-    off, so that autodiff transposes the substitution and the block formula
-    as it did before the rule."""
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(inner)
+
+
+def _made_again(rule):
+    """What stands in for ``_kept_inverse`` in a backward body that makes the
+    inverse again, as the bodies before the forward kept it did: under the
+    rule (PR 37's body) or under autodiff's transpose (the one before)."""
+    if not rule:
+        return lambda m, _kept: delta_rule._tiles_inverse(m, 64, 16)
+    made = jax.custom_vjp(lambda m: delta_rule._tiles_inverse(m, 64, 16))
+    made.defvjp(lambda m: (made(m),) * 2,
+                lambda x, d_x: delta_rule._kept_inverse_bwd(x, d_x)[:1])
+    return lambda m, _kept: made(m)
+
+
+#: the backward body's inverse -> (the head width that keeps the case's trace
+#: its own: the kernel's call is traced once a signature; the body's
+#: highest-precision products)
+BODIES = {"the_inverse_kept": (32, 4), "the_rule_alone": (48, 8),
+          "autodiffs_transpose": (80, 14)}
+
+
+def _backward_body(monkeypatch, body):
+    """``(the layer's plan, the jaxpr of the backward kernel's body)``, traced
+    under the interpreter."""
     monkeypatch.setattr(delta_rule, "_lowering_for", lambda *_: "interpret")
     monkeypatch.setattr(delta_rule, "_BWD_HI_PRODUCTS", {})
-    if not rule:
-        monkeypatch.setattr(delta_rule, "_tiles_inverse",
-                            delta_rule._tiles_inverse.fun)
-    # a width of its own a case: the kernel's call is traced once a signature
-    args = _kda_inputs(64, 0.5, "noise", heads=1, dk=32 if rule else 48)
+    if body != "the_inverse_kept":
+        monkeypatch.setattr(delta_rule, "_kept_inverse",
+                            _made_again(body == "the_rule_alone"))
+    args = _kda_inputs(64, 0.5, "noise", heads=1, dk=BODIES[body][0])
     with delta_rule.plan_recording():
-        jax.make_jaxpr(lambda *a: jax.vjp(
+        traced = jax.make_jaxpr(lambda *a: jax.vjp(
             delta_rule.gated_delta_rule, *a)[1](a[2]))(*args)
-    return delta_rule.last_plan_summary()
+    backward = [e for e in _eqns(traced.jaxpr)
+                if e.primitive.name == "pallas_call"
+                and e.params["name"] == delta_rule.KDA_BWD]
+    assert len(backward) == 1
+    return delta_rule.last_plan_summary(), backward[0].params["jaxpr"]
 
 
-def test_backward_body_holds_fewer_highest_precision_products(monkeypatch):
-    """The plan's count is read from the traced body: 5 of the state-free
-    part's forward, 1 of the running sums' transpose, and 2 of the inverse's
-    rule where autodiff's transpose of it has 8; the reader returns it; a
-    forward-only trace and the ``jax.numpy`` form have no such body."""
-    plan = _backward_body_count(monkeypatch, rule=True)
-    assert plan["layers"][0]["bwd_hi_products"] == plan["bwd_hi_products"] == 8
+@pytest.mark.parametrize("body", sorted(BODIES))
+def test_backward_body_holds_fewer_highest_precision_products(monkeypatch, body):
+    """The plan's count is read from the traced body.  With the forward's
+    inverse kept: the running sums' product and its transpose and the
+    inverse's rule's 2.  Made again under the rule, 4 more (the block
+    formula's); under autodiff's transpose, the rule's 2 are 8.  The reader
+    returns the count; a forward-only trace and the ``jax.numpy`` form have no
+    such body."""
+    plan, _ = _backward_body(monkeypatch, body)
+    want = BODIES[body][1]
+    assert plan["layers"][0]["bwd_hi_products"] == plan["bwd_hi_products"] == want
     read = run.load_module("layer_metrics", "kda_bwd_hi_products").read
-    assert read({}) == 8
-    without = _backward_body_count(monkeypatch, rule=False)
-    assert without["bwd_hi_products"] == 14 > plan["bwd_hi_products"]
+    assert read({}) == want
     monkeypatch.setattr(delta_rule, "_BWD_HI_PRODUCTS", {})
     args = _kda_inputs(64, 0.5, "noise", heads=1)
     with delta_rule.plan_recording():
-        jax.make_jaxpr(delta_rule.gated_delta_rule)(*args)
+        # a function of its own a case: a trace made before is not made again
+        jax.make_jaxpr(lambda *a: delta_rule.gated_delta_rule(*a))(*args)
     assert "bwd_hi_products" not in delta_rule.last_plan_summary()
     assert "bwd_hi_products" not in delta_rule.last_plan_summary()["layers"][0]
     assert read({}) is None
+
+
+def test_backward_body_holds_no_substitution(monkeypatch):
+    """Handed the inverse, the backward body makes none: against the body
+    that makes it again under the same rule, the block formula's four
+    highest-precision products are gone and every row of the substitution
+    (each cuts the tiles into ``(chunks, blocks, sub, chunk)`` once, and
+    nothing else does); the other products
+    are the same.  The six products of ``strict_k`` stay: ``beta``'s cotangent
+    is the inverse's times ``strict_k``, row by row."""
+    def census(jaxpr):
+        dots = [e for e in _eqns(jaxpr) if e.primitive.name == "dot_general"]
+        hi = delta_rule._hi_products(jaxpr)
+        rows = sum(1 for e in _eqns(jaxpr) for v in e.outvars
+                   if getattr(v.aval, "shape", ()) == (1, 4, 16, 64)
+                   and e.primitive.name == "reshape")
+        return hi, len(dots) - hi, rows
+
+    kept = census(_backward_body(monkeypatch, "the_inverse_kept")[1])
+    again = census(_backward_body(monkeypatch, "the_rule_alone")[1])
+    assert again[2] == 15 and kept[2] == 0
+    assert (again[0] - kept[0], again[1] - kept[1]) == (4, 0)
+    assert kept[0] == 4
+
+
+#: the inputs' kind -> what the largest entry of ``X`` reaches at least
+FORWARD_SYSTEMS = {
+    "noise": 1.0,
+    # keys of a few small whole numbers, no decay, a write strength of one:
+    # the system's entries are the same whole numbers in both forms, and its
+    # inverse grows past 1e20
+    "a_strong_system": 1e20,
+}
+
+
+@pytest.mark.parametrize("name", sorted(FORWARD_SYSTEMS))
+def test_forward_keeps_the_inverse_of_the_jax_numpy_form(name):
+    """What the forward kernel keeps a chunk, a group's chunks side by side
+    along the lanes, is ``(I + Diag(beta) tril(A, -1))^-1`` in float32:
+    against ``_unit_lower_inverse`` on the ``jax.numpy`` form's ``A`` of the
+    same inputs, to 1e-6 of its largest entry."""
+    t, chunk, sub, group = 256, 64, 16, 128
+    q, k, v, g, be = _kda_inputs(t, 0.5, "noise", heads=2)
+    l2norm = name == "noise"
+    if not l2norm:
+        rng = np.random.RandomState(3)
+        k = jnp.asarray(rng.randint(-2, 3, k.shape)
+                        * (rng.uniform(size=k.shape) < 0.5), jnp.float32)
+        g, be = jnp.zeros_like(g), jnp.ones_like(be)
+    args = [delta_rule._head_major(x, t) for x in (q, k, v, g, be)]
+    how = (chunk, sub, group, l2norm, 0.5, "interpret")
+    _o, starts, kept = delta_rule._forward_kernel(*args, how=how)
+    b, h = args[0].shape[:2]
+    assert starts.shape == (t // group, b, h, v.shape[-1], k.shape[-1])
+    assert kept.shape == (b, h, t // group, chunk, group)
+    assert kept.dtype == jnp.float32
+    # (B, H, groups, chunk, chunks of a group, chunk) -> a tile a chunk
+    got = jnp.moveaxis(kept.reshape(b, h, t // group, chunk, group // chunk,
+                                    chunk), 4, 3).reshape(b, h, -1, chunk, chunk)
+    kc, gc, bc = (jnp.moveaxis(delta_rule._chunks(x, chunk), 0, 2)
+                  for x in args[1:2] + args[3:])
+    k32 = delta_rule._l2(kc) if l2norm else kc
+    a = delta_rule._pairs(jnp.stack([k32, k32]), k32,
+                          jnp.cumsum(gc, axis=-2), jnp.float32)[0][0]
+    want = delta_rule._unit_lower_inverse(bc[..., :, None] * a, sub)
+    size = float(jnp.abs(want).max())
+    assert size >= FORWARD_SYSTEMS[name]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6 * size)
 
 
 @pytest.mark.parametrize("why,kwargs,patched", [
@@ -333,25 +436,35 @@ def test_kernels_under_a_mesh_equal_the_unsharded_call(shape, lowered_as):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
 
-def test_scan_keeps_a_state_a_group_not_a_token():
-    """What the backward is handed: the inputs and one float32 state a head
-    and group of chunks; and the plan says so."""
+@pytest.mark.parametrize("lowering", LOWERINGS)
+def test_scan_keeps_a_state_a_group_not_a_token(lowering, lowered_as):
+    """What the backward is handed: the inputs, one float32 state a head
+    and group of chunks and, from the kernels, one ``(chunk, chunk)`` float32
+    inverse a head and chunk; and the plan counts what is kept beyond the
+    inputs."""
+    lowered_as(lowering)
     q, k, v, g, be = _kda_inputs(256, 0.5, "noise")
     with delta_rule.plan_recording():
         _, res = jax.vjp(lambda *a: delta_rule.gated_delta_rule(
             *a, chunk=64, group=128), q, k, v, g, be)
     kept = [x for x in jax.tree_util.tree_leaves(res) if hasattr(x, "shape")]
-    states = [x for x in kept if x.ndim == 5]
-    assert [x.shape for x in states] == [(2, 2, 3, 32, 16)]
-    assert max(x.size for x in kept) <= max(q.size, states[0].size)
+    beyond = [x for x in kept if x.ndim == 5]
+    states = (2, 2, 3, 32, 16) if lowering == "xla" else (2, 2, 3, 16, 32)
+    inverses = [] if lowering == "xla" else [(2, 3, 2, 64, 128)]
+    assert [x.shape for x in beyond] == [states] + inverses
+    assert {x.dtype for x in beyond} == {jnp.dtype("float32")}
+    assert len(kept) == 5 + len(beyond)
+    assert max(x.size for x in kept) <= max(q.size, *(x.size for x in beyond))
     plan = delta_rule.last_plan_summary()
     assert plan["chunked_layers"] == 1
-    assert plan["layers"][0] == {
-        "heads": 3, "dk": 32, "dv": 16, "positions": 256, "chunk": 64,
-        "group": 128, "form": "chunked", "lowering": "xla",
-        "state_bytes": states[0].size * 4}
-    assert plan["state_bytes"] == states[0].size * 4
-    assert plan["kernel_layers"] == 0
+    held = sum(x.size * 4 for x in beyond)
+    assert plan["layers"][0] == dict(
+        {"heads": 3, "dk": 32, "dv": 16, "positions": 256, "chunk": 64,
+         "group": 128, "form": "chunked", "state_bytes": held},
+        **({"lowering": "xla"} if lowering == "xla"
+           else {"lowering": "pallas", "bwd_hi_products": 4}))
+    assert plan["state_bytes"] == held
+    assert plan["kernel_layers"] == (lowering != "xla")
 
 
 # ------------------------------------------- the small ops beside it
@@ -878,8 +991,8 @@ def test_toy_step_lowered_for_the_tpu_holds_the_two_kernels_a_layer(
     assert {x["lowering"] for x in plan["layers"]} == {"pallas"}
     # the trainer's recording spans the forward trace alone: the backward
     # body's count is there all the same, one trace for both layers
-    assert [x["bwd_hi_products"] for x in plan["layers"]] == [8, 8]
-    assert plan["bwd_hi_products"] == 8
+    assert [x["bwd_hi_products"] for x in plan["layers"]] == [4, 4]
+    assert plan["bwd_hi_products"] == 4
 
 
 def test_every_leaf_of_the_model_is_drawn_on_the_device(both_sides):

@@ -8,9 +8,11 @@ it (``qk_l2norm``, the head-major transposes round the scan included), run
 ``--reps`` times under one profiler session and timed by its device events:
 the union of the op intervals of a call (the ``jax.numpy`` form is hundreds
 of small ops, some inside ``while`` loops), median over the calls; for the
-kernels also their own custom calls' time and ``bwd_hi_products``, the
-highest-precision products in the backward kernel's body as the timed
-program traced it (the layer's plan entry).  The share of the roofline
+kernels also their own custom calls' time (``kernels_ms``; the two kernels'
+own beside it, ``fwd_kernel_ms`` / ``bwd_kernel_ms``), ``bwd_hi_products``,
+the highest-precision products in the backward kernel's body as the timed
+program traced it, and ``kept_mb``, what the forward keeps for the backward
+beyond its inputs (both from the layer's plan entry).  The share of the roofline
 divides what the algorithm needs (``kernel_costs`` of
 ``benchmark/configs/kimi-linear-48b-a3b.py``: a layer's forward + backward;
 forward alone a third of its operations and the bytes of q, k, v, o, g, beta
@@ -146,13 +148,13 @@ def main(argv=None):
             grads = jax.block_until_ready(both(q, k, v, g, beta, cot))
         # of the backward kernel's body as this variant traced it (None:
         # the jax.numpy form has no such body)
-        hi = delta_rule.last_plan_summary().get("bwd_hi_products")
+        plan = delta_rule.last_plan_summary()
         variants.append((lowering, group, fwd, both, (out,) + tuple(grads),
-                         hi))
+                         (plan.get("bwd_hi_products"), plan["state_bytes"])))
     delta_rule._lowering_for = chosen
     first = variants[-1][4]              # the jax.numpy form where asked for
     f32 = lambda x: np.asarray(x, np.float32)
-    for lowering, group, fwd, both, outs, hi in variants:
+    for lowering, group, fwd, both, outs, (hi, kept) in variants:
         for which, fn, extra in (("fwd", fwd, ()), ("both", both, (cot,))):
             with tempfile.TemporaryDirectory() as trace_dir:
                 jax.profiler.start_trace(trace_dir)
@@ -163,16 +165,21 @@ def main(argv=None):
             calls = split_calls(events, args.reps)
             per = statistics.median(len(c) for c in calls)
             ms = statistics.median(busy_ns(c) for c in calls) / 1e6
-            own = statistics.median(
-                sum(e - s for s, e, n in c if "mxtpu_kda_" in n)
-                for c in calls) / 1e6
+            own, own_fwd, own_bwd = (
+                statistics.median(sum(e - s for s, e, n in c if name in n)
+                                  for c in calls) / 1e6
+                for name in ("mxtpu_kda_", delta_rule.KDA_FWD,
+                             delta_rule.KDA_BWD))
             flops, moved = need[which]
             floor_ms = 1e3 * max(flops / (peaks["bf16_flops"]),
                                  moved / (peaks["hbm_bytes_per_s"]))
             print(json.dumps({
                 "lowering": lowering, "group": group, "what": which,
                 "shape": list(shape), "bwd_hi_products": hi,
+                "kept_mb": round(kept / 1e6, 3),
                 "ms": round(ms, 4), "kernels_ms": round(own, 4),
+                "fwd_kernel_ms": round(own_fwd, 4),
+                "bwd_kernel_ms": round(own_bwd, 4),
                 "device_ops": per, "needed_gflop": round(flops / 1e9, 1),
                 "needed_gb": round(moved / 1e9, 3),
                 "roofline_ms": round(floor_ms, 4),
