@@ -250,13 +250,6 @@ def _check_dead_entries(entries, nodes, report):
                        node=node.name)
 
 
-def _var_dtype(node, type_overrides):
-    import numpy as np
-    if node.name in type_overrides:
-        return np.dtype(type_overrides[node.name]).name
-    return node.raw_attr.get("__dtype__", "float32")
-
-
 def _auto_param_names(node):
     """The auto-created parameter/aux variable inputs of an op node:
     variables named ``<node>_<slot>`` (the Symbol._create convention)."""
@@ -269,129 +262,63 @@ def _auto_param_names(node):
 
 
 def _shape_pass(sym, topo, known_shapes, type_overrides, report):
-    """Per-node abstract interpretation.
-
-    Walks topo order keeping a ``jax.ShapeDtypeStruct`` tuple per node.
-    Param-shape hooks run just-in-time at each consumer op, exactly as
-    Symbol.infer_shape does, but a failure is localized to the node that
-    raised instead of aborting the whole inference.  Returns
+    """Per-node abstract interpretation: ``symbol._shape_walk``, the walk
+    ``Symbol.infer_shape`` makes, with each thing that stopped it
+    localized to its node as a diagnostic instead of raised.  Returns
     ``({var_name: shape}, {id(node): tuple(ShapeDtypeStruct)})`` — the
     resolved variable shapes feed the TP pass, the per-node structs
     feed MXG010 (:mod:`.perf`) and the autotuner's zoo mode.
     """
-    import jax
     import jax.numpy as jnp
     from ..ops import shapes as _shapes
-    from ..ops.registry import OpContext, apply_op
+    from ..symbol import _input_structs, _shape_walk
 
-    structs = {}          # id(node) -> tuple(ShapeDtypeStruct) | None
-    var_shapes = {}       # id(var-node) -> shape
+    var_shapes, structs, faults = _shape_walk(topo, known_shapes,
+                                              type_overrides)
     var_reported = set()  # variables already attributed to a diagnostic
-    resolved = {}         # var_name -> shape (the return value)
-
-    # seed variable shapes: explicit kwargs first, then __shape__ attrs
-    batch_size = None
-    for node in topo:
-        if not node.is_variable:
-            continue
-        shp = None
-        if node.name in known_shapes:
-            shp = tuple(known_shapes[node.name])
-        elif "__shape__" in node.raw_attr:
-            shp = tuple(json.loads(node.raw_attr["__shape__"]))
-        if shp is not None:
-            var_shapes[id(node)] = shp
-            if batch_size is None and len(shp) > 0:
-                batch_size = int(shp[0])
-
-    def var_struct(node):
-        shp = var_shapes.get(id(node))
-        if shp is None:
-            return None
-        return (jax.ShapeDtypeStruct(tuple(shp),
-                                     jnp.dtype(_var_dtype(node,
-                                                          type_overrides))),)
-
-    for node in topo:
-        if node.is_variable:
-            structs[id(node)] = var_struct(node)
-            if structs[id(node)] is not None:
-                resolved[node.name] = tuple(var_shapes[id(node)])
-            continue
-
-        slot_names = node.arg_names() + node.aux_names()
-
-        # just-in-time param-shape hook: fill variable inputs whose shape
-        # is still unknown from the shapes known so far
-        hook = _shapes.get_param_shapes(node.op.name)
-        unknown_vars = [(nm, src) for nm, (src, _i)
-                        in zip(slot_names, node.inputs)
-                        if src.is_variable and id(src) not in var_shapes]
-        if hook is not None and unknown_vars:
-            known_in = {}
-            for nm, (src, _i) in zip(slot_names, node.inputs):
-                st = structs.get(id(src))
-                if st is not None and len(st) > _i:
-                    known_in[nm] = tuple(st[_i].shape)
-                elif src.is_variable and id(src) in var_shapes:
-                    known_in[nm] = tuple(var_shapes[id(src)])
-            try:
-                inferred = hook(node.attrs, known_in)
-            except Exception as e:  # mxlint: allow-broad-except(a hook runs user code e.g. CustomOpProp.infer_shape; any failure becomes a diagnostic)
-                report.add("MXG005", "error",
-                           "param-shape rule for op %s raised: %s"
-                           % (node.op.name, e),
-                           node=node.name, op=node.op.name)
-                inferred = {}
-            for nm, src in unknown_vars:
-                if nm in inferred:
-                    var_shapes[id(src)] = tuple(inferred[nm])
-                    structs[id(src)] = var_struct(src)
-                    resolved[src.name] = tuple(inferred[nm])
-
-        # attribute still-unknown variable inputs
-        missing = [(nm, src) for nm, src in unknown_vars
-                   if id(src) not in var_shapes
-                   and id(src) not in var_reported]
-        auto_params = {nm for nm, _src in _auto_param_names(node)}
-        if missing:
-            for nm, src in missing:
-                var_reported.add(id(src))
-            auto_missing = [nm for nm, _s in missing if nm in auto_params]
-            if hook is None and auto_missing:
+    for node, stage, detail in faults:
+        op = node.op.name
+        if stage == "hook":
+            report.add("MXG005", "error",
+                       "param-shape rule for op %s raised: %s" % (op, detail),
+                       node=node.name, op=op)
+        elif stage == "op":
+            msg = str(detail).strip().splitlines()
+            report.add("MXG005", "error",
+                       "op %s rejects input shapes %s: %s"
+                       % (op, [tuple(st.shape) for st
+                               in _input_structs(node, structs)],
+                          msg[0] if msg else repr(detail)),
+                       node=node.name, op=op)
+        else:
+            missing = [nm for nm, src in detail
+                       if id(src) not in var_reported]
+            var_reported.update(id(src) for _nm, src in detail)
+            auto_params = {nm for nm, _src in _auto_param_names(node)}
+            auto_missing = [nm for nm in missing if nm in auto_params]
+            if _shapes.get_param_shapes(op) is None and auto_missing:
                 report.add(
                     "MXG004", "error",
                     "op %s auto-created parameter input(s) %s but has no "
                     "param-shape rule registered in ops.shapes and no "
                     "explicit __shape__; their shapes cannot be inferred"
-                    % (node.op.name, auto_missing),
-                    node=node.name, op=node.op.name)
-            else:
+                    % (op, auto_missing), node=node.name, op=op)
+            elif missing:
                 report.add(
                     "MXG009", "warning",
                     "shapes of input(s) %s of op %s are underdetermined "
                     "(provide them via infer kwargs or __shape__)"
-                    % ([nm for nm, _s in missing], node.op.name),
-                    node=node.name, op=node.op.name)
+                    % (missing, op), node=node.name, op=op)
 
-        # gather input structs; skip eval if anything upstream is unknown
-        in_structs = []
-        unknown_input = False
-        for (src, idx) in node.inputs:
-            st = structs.get(id(src))
-            if st is None or len(st) <= idx:
-                unknown_input = True
-                break
-            in_structs.append(st[idx])
-        if unknown_input:
-            structs[id(node)] = None
+    # dtype-promotion audit: mixed float widths feeding one op.
+    # issubdtype (not .kind == 'f') so bfloat16 — an ml_dtypes
+    # extension type with kind 'V', and THE TPU compute dtype —
+    # is covered.
+    for node in topo:
+        if node.is_variable:
             continue
-
-        # dtype-promotion audit: mixed float widths feeding one op.
-        # issubdtype (not .kind == 'f') so bfloat16 — an ml_dtypes
-        # extension type with kind 'V', and THE TPU compute dtype —
-        # is covered.
-        f_dtypes = sorted({jnp.dtype(st.dtype).name for st in in_structs
+        ins = _input_structs(node, structs) or ()
+        f_dtypes = sorted({jnp.dtype(st.dtype).name for st in ins
                            if jnp.issubdtype(st.dtype, jnp.floating)})
         if len(f_dtypes) > 1:
             report.add("MXG006", "warning",
@@ -399,45 +326,8 @@ def _shape_pass(sym, topo, known_shapes, type_overrides, report):
                        "promote implicitly (check intended precision)"
                        % (node.op.name, f_dtypes),
                        node=node.name, op=node.op.name)
-
-        # deferred batch dims in source-op shapes (RNN begin_state zeros)
-        node_attrs = node.attrs
-        shp = node_attrs.get("shape")
-        if (not node.inputs and isinstance(shp, (tuple, list))
-                and any(s == 0 for s in shp)):
-            if batch_size is None:
-                report.add("MXG005", "error",
-                           "source op %s has a deferred (0) dim in shape "
-                           "%s but no input shape fixes the batch size"
-                           % (node.op.name, tuple(shp)),
-                           node=node.name, op=node.op.name)
-                structs[id(node)] = None
-                continue
-            node_attrs = dict(node_attrs)
-            node_attrs["shape"] = tuple(batch_size if s == 0 else int(s)
-                                        for s in shp)
-
-        octx = OpContext(is_train=False, key=None)
-        op = node.op
-
-        def fn(*ins, _op=op, _attrs=node_attrs, _octx=octx):
-            return apply_op(_op, _attrs, _octx, *ins)
-
-        try:
-            outs = jax.eval_shape(fn, *in_structs)
-        except Exception as e:  # mxlint: allow-broad-except(fcompute tracing raises arbitrary exception types; each becomes a node diagnostic)
-            msg = str(e).strip().splitlines()
-            report.add("MXG005", "error",
-                       "op %s rejects input shapes %s: %s"
-                       % (node.op.name,
-                          [tuple(st.shape) for st in in_structs],
-                          msg[0] if msg else repr(e)),
-                       node=node.name, op=node.op.name)
-            structs[id(node)] = None
-            continue
-        if not isinstance(outs, (tuple, list)):
-            outs = (outs,)
-        structs[id(node)] = tuple(outs)
+    resolved = {n.name: var_shapes[id(n)] for n in topo
+                if n.is_variable and id(n) in var_shapes}
     return resolved, structs
 
 

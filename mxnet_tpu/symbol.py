@@ -5,9 +5,12 @@ Reference: ``python/mxnet/symbol.py`` (2092 L) over nnvm's C++ ``Symbol``/
 python DAG of ``_Node``s (op + parsed attrs + input edges).  There is no
 separate graph compiler — ``bind`` traces the DAG into one JAX function and
 ``jax.jit`` is the whole §3.4 pass pipeline (gradient, memory planning,
-fusion, placement all happen inside XLA).  Shape/type inference runs
-``jax.eval_shape`` over the same trace, with per-op parameter-shape hooks
-(:mod:`mxnet_tpu.ops.shapes`) standing in for the reference's FInferShape.
+fusion, placement all happen inside XLA).  Shape inference is one walk in
+topological order (:func:`_shape_walk`) that keeps each node's output
+``jax.ShapeDtypeStruct``s and runs ``jax.eval_shape`` once per op node over
+its own inputs' structs, with per-op parameter-shape hooks
+(:mod:`mxnet_tpu.ops.shapes`) standing in for the reference's FInferShape;
+:mod:`mxnet_tpu.analysis.verifier` reports from the same walk.
 
 JSON serialization keeps the reference's node/arg_nodes/heads layout
 (``nnvm::Symbol::Save``; ``src/c_api/c_api_symbolic.cc:400``) so checkpoints
@@ -100,6 +103,131 @@ def _classify_vars(topo):
         if node.is_variable:
             (aux if id(node) in aux_ids else args).append(node)
     return args, aux
+
+
+def _batch_attrs(node, batch_size):
+    """``node.attrs`` with a source op's deferred batch dim filled in.
+
+    A 0 in ``shape`` is deferred ONLY for source ops (zeros/ones/... with no
+    inputs, e.g. RNN begin_state) — ops WITH inputs (Reshape, ...) give 0
+    its own meaning ("copy this dim from the input") and resolve it
+    themselves."""
+    shp = node.attrs.get("shape")
+    if (node.inputs or not isinstance(shp, (tuple, list))
+            or all(s != 0 for s in shp)):
+        return node.attrs
+    if batch_size is None:
+        raise MXNetError(
+            "node %r has a deferred (0) dim in shape %s but no "
+            "batch size is known" % (node.name, shp))
+    return dict(node.attrs, shape=tuple(batch_size if s == 0 else int(s)
+                                        for s in shp))
+
+
+def _input_structs(node, structs):
+    """The structs of ``node``'s inputs, or None while one is unknown."""
+    ins = []
+    for (src, idx) in node.inputs:
+        st = structs.get(id(src))
+        if st is None or len(st) <= idx:
+            return None
+        ins.append(st[idx])
+    return ins
+
+
+def _shape_walk(topo, known, types=None):
+    """Shape inference: ONE walk in topological order.
+
+    Keeps a ``jax.ShapeDtypeStruct`` tuple per node and evaluates each op
+    node once, over its own inputs' structs.  A variable's shape comes from
+    ``known`` ({name: shape}), else its ``__shape__``, else from the
+    param-shape hook of the first op that consumes it, which reads its
+    op-output inputs' shapes off the structs already held.  ``types``
+    ({name: dtype}) overrides a variable's ``__dtype__``.
+
+    Returns ``(var_shapes, structs, faults)``: ``{id(var): shape}``,
+    ``{id(node): tuple(struct) | None}`` and, in walk order, what stopped a
+    node from being evaluated as ``(node, stage, detail)`` — stage
+    ``"hook"`` / ``"op"`` with the exception raised, or ``"unknown"`` with
+    the ``[(slot, var)]`` inputs no rule or caller gave a shape.
+    ``Symbol.infer_shape`` raises from these where ``analysis.verifier``
+    adds a report entry.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    types = types or {}
+    var_shapes, structs = {}, {}
+
+    def var_struct(node):
+        dtype = types.get(node.name,
+                          node.raw_attr.get("__dtype__", "float32"))
+        return (jax.ShapeDtypeStruct(var_shapes[id(node)],
+                                     jnp.dtype(dtype)),)
+
+    for node in topo:
+        if not node.is_variable:
+            continue
+        if node.name in known:
+            var_shapes[id(node)] = tuple(known[node.name])
+        elif "__shape__" in node.raw_attr:
+            var_shapes[id(node)] = tuple(
+                json.loads(node.raw_attr["__shape__"]))
+        structs[id(node)] = var_struct(node) \
+            if id(node) in var_shapes else None
+    # deferred batch dims (``_batch_attrs``) take the first sized argument's
+    batch_size = next((int(var_shapes[id(n)][0])
+                       for n in _classify_vars(topo)[0]
+                       if len(var_shapes.get(id(n), ())) > 0), None)
+    octx = OpContext(is_train=False, key=None)
+
+    while True:
+        learnt, faults = len(var_shapes), []
+        for node in topo:
+            if node.is_variable or structs.get(id(node)) is not None:
+                continue
+            slots = list(zip(node.arg_names() + node.aux_names(),
+                             node.inputs))
+            unknown = [(nm, src) for nm, (src, _i) in slots
+                       if src.is_variable and id(src) not in var_shapes]
+            hook = _shapes.get_param_shapes(node.op.name) if unknown \
+                else None
+            if hook is not None:
+                known_in = {}
+                for nm, (src, idx) in slots:
+                    st = structs.get(id(src))
+                    if st is not None and len(st) > idx:
+                        known_in[nm] = tuple(st[idx].shape)
+                try:
+                    inferred = hook(node.attrs, known_in)
+                except Exception as e:  # mxlint: allow-broad-except(a hook runs user code e.g. CustomOpProp.infer_shape; infer_shape raises it again, the verifier reports it)
+                    faults.append((node, "hook", e))
+                    inferred = {}
+                for nm, src in unknown:
+                    if nm in inferred and id(src) not in var_shapes:
+                        var_shapes[id(src)] = tuple(inferred[nm])
+                        structs[id(src)] = var_struct(src)
+                unknown = [(nm, src) for nm, src in unknown
+                           if id(src) not in var_shapes]
+            if unknown:
+                faults.append((node, "unknown", unknown))
+            ins = _input_structs(node, structs)
+            if ins is None:
+                continue
+            try:
+                attrs = _batch_attrs(node, batch_size)
+                outs = jax.eval_shape(
+                    lambda *xs: apply_op(node.op, attrs, octx, *xs), *ins)
+            except Exception as e:  # mxlint: allow-broad-except(fcompute tracing raises arbitrary exception types; infer_shape raises it again, the verifier reports it)
+                faults.append((node, "op", e))
+                continue
+            structs[id(node)] = tuple(outs) \
+                if isinstance(outs, (tuple, list)) else (outs,)
+        # a hook may size a variable that an earlier op of the walk also
+        # consumes (a tied weight): only then is there a second pass
+        if len(var_shapes) == learnt or \
+                all(stage != "unknown" for _n, stage, _d in faults):
+            return var_shapes, structs, faults
 
 
 def eval_graph(topo, entries, var_values, is_train=False, key=None,
@@ -207,21 +335,7 @@ def eval_graph(topo, entries, var_values, is_train=False, key=None,
         dev = device_map.get(id(node))
         if dev is not None:
             ins = [jax.device_put(x, dev) for x in ins]
-        node_attrs = node.attrs
-        shp = node_attrs.get("shape")
-        # deferred batch dim: ONLY for source ops (zeros/ones/... with no
-        # inputs, e.g. RNN begin_state) — ops WITH inputs (Reshape, ...)
-        # give 0 its own meaning ("copy this dim from the input") and
-        # resolve it themselves
-        if (not node.inputs and isinstance(shp, (tuple, list))
-                and any(s == 0 for s in shp)):
-            if batch_size is None:
-                raise MXNetError(
-                    "node %r has a deferred (0) dim in shape %s but no "
-                    "batch size is known" % (node.name, shp))
-            node_attrs = dict(node_attrs)
-            node_attrs["shape"] = tuple(batch_size if s == 0 else int(s)
-                                        for s in shp)
+        node_attrs = _batch_attrs(node, batch_size)
         stoch = node.op.stochastic
         if callable(stoch):
             stoch = stoch(node_attrs)
@@ -417,9 +531,6 @@ class Symbol:
         return self._infer_shape_impl(True, *args, **kwargs)
 
     def _infer_shape_impl(self, partial, *args, **kwargs):
-        import jax
-        import jax.numpy as jnp
-
         known = {}
         if args:
             arg_list = self.list_arguments()
@@ -432,76 +543,25 @@ class Symbol:
 
         topo = self._topo()
         arg_nodes, aux_nodes = _classify_vars(topo)
-        shapes = {}   # id(node) -> shape for variables
-        dtypes = {}
-        for node in arg_nodes + aux_nodes:
-            if node.name in known:
-                shapes[id(node)] = known[node.name]
-            elif "__shape__" in node.raw_attr:
-                shapes[id(node)] = tuple(
-                    json.loads(node.raw_attr["__shape__"]))
-            dtypes[id(node)] = node.raw_attr.get("__dtype__", "float32")
-
-        batch_size = None
-        for n in arg_nodes:
-            if id(n) in shapes and len(shapes[id(n)]) > 0:
-                batch_size = int(shapes[id(n)][0])
-                break
-
-        # propagate: per-op param-shape hooks fill parameter/aux variables
-        for node in topo:
-            if node.is_variable:
-                continue
-            hook = _shapes.get_param_shapes(node.op.name)
-            if hook is None:
-                continue
-            names = node.arg_names() + node.aux_names()
-            known_in = {}
-            for nm, (src, idx) in zip(names, node.inputs):
-                if src.is_variable and id(src) in shapes:
-                    known_in[nm] = shapes[id(src)]
-                elif not src.is_variable:
-                    pass  # outputs handled by eval_shape below; hooks only
-                          # need data shapes, resolved in the eval pass
-            # run a partial eval up to this node to learn non-var input shapes
-            inferred = hook(node.attrs, _resolve_input_shapes(
-                node, shapes, dtypes, topo, known_in, batch_size))
-            for nm, shp in inferred.items():
-                try:
-                    slot = names.index(nm)
-                except ValueError:
-                    continue
-                src, _ = node.inputs[slot]
-                if src.is_variable and id(src) not in shapes:
-                    shapes[id(src)] = tuple(shp)
-
+        shapes, structs, faults = _shape_walk(topo, known)
+        for _node, stage, err in faults:
+            if stage == "hook":
+                raise err
         missing = [n.name for n in arg_nodes + aux_nodes
                    if id(n) not in shapes]
         if missing and not partial:
             raise MXNetError(
                 "infer_shape: cannot infer shapes for %s; provide them "
                 "explicitly" % missing)
+        arg_shapes = [shapes.get(id(n)) for n in arg_nodes]
+        aux_shapes = [shapes.get(id(n)) for n in aux_nodes]
         if missing:
-            arg_shapes = [shapes.get(id(n)) for n in arg_nodes]
-            aux_shapes = [shapes.get(id(n)) for n in aux_nodes]
             return arg_shapes, None, aux_shapes
-
-        # full eval_shape for outputs
-        entries = self._entries
-
-        def fn(var_vals):
-            heads, _aux = eval_graph(topo, entries, var_vals,
-                                     is_train=False, key=None,
-                                     batch_size=batch_size)
-            return heads
-
-        var_vals = {id(n): jax.ShapeDtypeStruct(shapes[id(n)],
-                                                jnp.dtype(dtypes[id(n)]))
-                    for n in arg_nodes + aux_nodes}
-        out_structs = jax.eval_shape(fn, var_vals)
-        arg_shapes = [shapes[id(n)] for n in arg_nodes]
-        aux_shapes = [shapes[id(n)] for n in aux_nodes]
-        out_shapes = [tuple(s.shape) for s in out_structs]
+        for _node, stage, err in faults:
+            if stage == "op":
+                raise err
+        out_shapes = [tuple(structs[id(n)][i].shape)
+                      for (n, i) in self._entries]
         return arg_shapes, out_shapes, aux_shapes
 
     def infer_type(self, *args, **kwargs):
@@ -707,51 +767,6 @@ def _attr_str(v):
     if isinstance(v, (list, tuple)):
         return "(" + ", ".join(str(x) for x in v) + ")"
     return str(v)
-
-
-def _resolve_input_shapes(node, var_shapes, var_dtypes, topo, seed,
-                          batch_size=None):
-    """Best-effort shapes of ``node``'s inputs by name (for shape hooks).
-
-    Variable inputs read ``var_shapes``; op-output inputs are resolved by an
-    eval_shape over the sub-graph when all its variables are known.
-    """
-    import jax
-    import jax.numpy as jnp
-    names = node.arg_names() + node.aux_names()
-    out = dict(seed)
-    for nm, (src, idx) in zip(names, node.inputs):
-        if nm in out:
-            continue
-        if src.is_variable:
-            if id(src) in var_shapes:
-                out[nm] = var_shapes[id(src)]
-            continue
-        # op output: eval_shape the ancestor sub-graph
-        sub_topo = _topo_order([(src, idx)])
-        needed = [n for n in sub_topo if n.is_variable]
-        if any(id(n) not in var_shapes for n in needed):
-            continue
-        var_vals = {id(n): jax.ShapeDtypeStruct(
-            var_shapes[id(n)], jnp.dtype(var_dtypes.get(id(n), "float32")))
-            for n in needed}
-        bsz = batch_size
-        if bsz is None:
-            for n in needed:
-                if len(var_shapes[id(n)]) > 0:
-                    bsz = int(var_shapes[id(n)][0])
-                    break
-
-        def fn(vv, _sub_topo=sub_topo, _src=src, _idx=idx, _bsz=bsz):
-            heads, _ = eval_graph(_sub_topo, [(_src, _idx)], vv,
-                                  batch_size=_bsz)
-            return heads[0]
-        try:
-            st = jax.eval_shape(fn, var_vals)
-            out[nm] = tuple(st.shape)
-        except Exception:  # mxlint: allow-broad-except(sub-graph shape resolution is best-effort; Symbol.verify localizes the real error)
-            pass
-    return out
 
 
 # ---------------------------------------------------------------- creation
