@@ -187,7 +187,6 @@ def main():
         learning_rate=0.1, momentum=0.9, weight_decay=1e-4,
         dtype=os.environ.get("BENCH_DTYPE", "bfloat16"),
         layout=os.environ.get("BENCH_LAYOUT", "NHWC"),
-        auto_layouts=os.environ.get("BENCH_AUTO_LAYOUT", "1") == "1",
         # exact 4x4/s1 space-to-depth rewrite of the 7x7/s2 stem
         # (ops/fused.py; ~+1%, parity-tested)
         stem_space_to_depth=os.environ.get("BENCH_STEM_S2D", "1") == "1",

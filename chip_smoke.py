@@ -181,7 +181,15 @@ def bench_trainer(mesh, batch=128):
         label_shapes={"softmax_label": (batch,)},
         optimizer="sgd", learning_rate=0.1, momentum=0.9,
         weight_decay=1e-4, dtype="bfloat16", layout="NHWC",
-        auto_layouts=True, stem_space_to_depth=True, fuse_blocks=True)
+        stem_space_to_depth=True, fuse_blocks=True)
+
+
+def step_text(trainer):
+    """The text of ``trainer.step``'s compiled program."""
+    exes = [e for (prog, _), e in trainer._aot_exes.items()
+            if prog == "trainer.step"]
+    assert exes, "the step has no AOT executable"
+    return exes[0].as_text()
 
 
 def phase_fused_train(dev, batch=128, scan=10, steps=20):
@@ -215,7 +223,7 @@ def phase_fused_train(dev, batch=128, scan=10, steps=20):
         "%d compiles after warm-up" % (after[0] - warm_compiles[0])
     assert all(on_device(a, dev) for a in jax.tree.leaves(
         (trainer.params, trainer.opt_state, trainer.aux)))
-    text = trainer._step_fn.as_text()
+    text = step_text(trainer)
     summary = trainer.fusion_summary()
     say("fused_train", build_s=round(build_s, 2),
         warmup_s=round(warm_s, 2), steady_s=round(steady_s, 3),
@@ -271,13 +279,10 @@ def phase_flash(dev, layers=4, steps=3):
     losses = [float(trainer.step(staged)) for _ in range(steps)]
     lm_s = time.perf_counter() - t0
     assert np.isfinite(losses).all(), losses
-    exes = [e for (prog, _), e in trainer._aot_exes.items()
-            if prog == "trainer.step"]
-    assert exes, "the transformer step has no AOT executable"
-    n_calls = exes[0].as_text().count("tpu_custom_call")
+    n_calls = step_text(trainer).count("tpu_custom_call")
     # context.on_tpu() was true and the jnp attention was NOT taken
     assert n_calls, "no Pallas custom call in the transformer step"
-    del trainer, staged, exes
+    del trainer, staged
     gc.collect()
     say("flash", lm_s=round(lm_s, 2), layers=layers, steps=steps,
         losses=losses, step_tpu_custom_calls=n_calls,

@@ -150,7 +150,7 @@ def _fit_fused(args, sym, train, val, kv):
         label_shapes={label_name: tuple(label_shape)},
         optimizer=args.optimizer, optimizer_params=optimizer_params,
         learning_rate=lr, momentum=args.mom, weight_decay=args.wd,
-        dtype=args.dtype, auto_layouts=True,
+        dtype=args.dtype,
         # block-granularity fusion (analysis.fusion): on by default for
         # the fused path — conv+BN+ReLU blocks become single regions
         # with a pinned layout per boundary (docs/api/fusion.md)

@@ -88,8 +88,7 @@ def gpt_symbol(vocab_size, seq_len, d_model=128, n_heads=4, n_layers=2,
 
 
 def build_bench_trainer(vocab=16384, seq=1024, d_model=1024, heads=16,
-                        layers=12, batch=16, dtype="bfloat16",
-                        auto_layouts=False):
+                        layers=12, batch=16, dtype="bfloat16"):
     """(fused trainer, staged synthetic batch) at benchmark scale — ONE
     definition shared by tools/transformer_mfu.py and tools/xprof_top.py
     so the profiled program and the benchmarked program are identical
@@ -102,8 +101,7 @@ def build_bench_trainer(vocab=16384, seq=1024, d_model=1024, heads=16,
         net, build_mesh(n_devices=1),
         data_shapes={"data": (batch, seq)},
         label_shapes={"softmax_label": (batch, seq)},
-        optimizer="adam", learning_rate=1e-4, dtype=dtype,
-        auto_layouts=auto_layouts)
+        optimizer="adam", learning_rate=1e-4, dtype=dtype)
     rng = np.random.RandomState(0)
     x = rng.randint(0, vocab, (batch, seq)).astype("f")
     staged = trainer.put_batch({"data": x,

@@ -572,9 +572,7 @@ def _note_block_cost(blk, out, x, w):
             # conv and FC share one formula: every output element costs
             # (w.size / n_out) MACs — C*R*S for a conv, the input width
             # for an FC — plus the ~10 flops/element BN/act epilogue.
-            # n_out comes from the op attrs (num_filter / num_hidden),
-            # not from a weight axis, so a native HWIO weight layout
-            # cannot skew the estimate.
+            # n_out comes from the op attrs (num_filter / num_hidden).
             node = blk.conv if blk.conv is not None else blk.fc
             n_out = int(node.attrs.get("num_filter")
                         or node.attrs.get("num_hidden")

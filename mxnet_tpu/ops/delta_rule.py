@@ -97,6 +97,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import context as _context
+from ..telemetry import plan as _plan
 
 #: ``jax.named_scope`` of the op on the device
 SCOPE_KDA = "mxtpu.block.kda"
@@ -755,7 +756,7 @@ def _scan_fwd(q, k, v, g, beta, how):
     # kept beside the inputs: the groups' states and, from the kernels, the
     # chunks' inverses
     o, *kept = _lowerings(how)[0](q, k, v, g, beta, how=how)
-    if _RECORDING is not None and how[5] != "xla":
+    if _plan.active() and how[5] != "xla":
         _note_backward_body((q, k, v, g, beta, *kept, o), how)
     return o, (q, k, v, g, beta, *kept)
 
@@ -832,13 +833,12 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, sub=SUB, group=GROUP,
     traced = _BWD_HI_PRODUCTS.get(_body_key(args[0], args[2], how))
     if traced is not None:
         info["bwd_hi_products"] = traced
-    note_layer(**info)
+    _plan.note(SCOPE_KDA, **info)
     return o
 
 
 # ---- what the last traced step's linear-attention layers are
-_RECORDING = None
-_LAST_SUMMARY = None
+plan_recording = _plan.recording
 #: highest-precision products in the backward kernel's body, by what the
 #: body's trace depends on (:func:`_body_key`)
 _BWD_HI_PRODUCTS = {}
@@ -883,42 +883,6 @@ def _note_backward_body(args, how):
         _BWD_HI_PRODUCTS[key] = _hi_products(traced.jaxpr)
 
 
-class plan_recording:
-    """Collects what each linear-attention layer of one traced step is; on
-    a clean exit with at least one layer the collection becomes
-    :func:`last_plan_summary`.  ``ShardedTrainer`` opens one round the
-    step's forward trace, as it does ``moe.plan_recording``."""
-
-    def __enter__(self):
-        global _RECORDING
-        self._prev, _RECORDING = _RECORDING, []
-        return self
-
-    def __exit__(self, exc_type, *_exc):
-        global _RECORDING, _LAST_SUMMARY
-        layers, _RECORDING = _RECORDING, self._prev
-        if exc_type is None and layers:
-            _LAST_SUMMARY = {
-                "layers": layers,
-                "chunked_layers": sum(1 for x in layers
-                                      if x["form"] == "chunked"),
-                "kernel_layers": sum(1 for x in layers
-                                     if x["lowering"] == "pallas"),
-                "state_bytes": sum(x["state_bytes"] for x in layers)}
-            traced = [x["bwd_hi_products"] for x in layers
-                      if "bwd_hi_products" in x]
-            if traced:
-                _LAST_SUMMARY["bwd_hi_products"] = max(traced)
-        return False
-
-
-def note_layer(**info):
-    """One layer's plan, from :func:`gated_delta_rule` (no-op outside a
-    :class:`plan_recording`)."""
-    if _RECORDING is not None:
-        _RECORDING.append(info)
-
-
 def last_plan_summary():
     """Summary of the linear-attention layers of the step traced last in
     this process (None before any): per layer its heads, widths, positions,
@@ -932,4 +896,15 @@ def last_plan_summary():
     highest precision in the backward kernel's traced body);
     ``chunked_layers``, ``kernel_layers`` and ``state_bytes`` over all of
     them, ``bwd_hi_products`` the largest.  As ``moe.last_plan_summary()``."""
-    return _LAST_SUMMARY
+    layers = _plan.last(SCOPE_KDA)
+    if layers is None:
+        return None
+    summary = {
+        "layers": layers,
+        "chunked_layers": sum(1 for x in layers if x["form"] == "chunked"),
+        "kernel_layers": sum(1 for x in layers if x["lowering"] == "pallas"),
+        "state_bytes": sum(x["state_bytes"] for x in layers)}
+    traced = [x["bwd_hi_products"] for x in layers if "bwd_hi_products" in x]
+    if traced:
+        summary["bwd_hi_products"] = max(traced)
+    return summary

@@ -48,6 +48,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import plan as _plan
 from . import pallas_kernels as pk
 
 FLASH_FWD_BLOCKDIFF = "mxtpu_flash_fwd_blockdiff"
@@ -474,10 +475,9 @@ def _note(op, q, v, block_q, block_k, block, plan, n_matmuls, n_tensors):
                   "window": 0, "group_parts": 1,
                   "tiles_per_q_block": t // 2 // block_k + 1,
                   "diffusion_block": block}
-        if pk._PLAN_RECORDING is not None:
-            pk._PLAN_RECORDING.append(dict(
-                config, kernel=op, shape=tuple(int(n) for n in q.shape),
-                dk=int(dk), dv=int(dv)))
+        _plan.note(pk.PLAN_FLASH, **config, kernel=op,
+                   shape=tuple(int(n) for n in q.shape),
+                   dk=int(dk), dv=int(dv))
         itemsize = jnp.dtype(q.dtype).itemsize
         costdb.note_kernel(
             op, [tuple(q.shape)], [str(q.dtype)],

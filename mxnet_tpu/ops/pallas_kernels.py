@@ -98,6 +98,7 @@ import jax.numpy as jnp
 
 from .. import context as _context
 from ..base import MXNetError
+from ..telemetry import plan as _plan
 from .registry import register
 
 _BLOCK_Q = 128
@@ -701,10 +702,10 @@ def _note_kernel_cost(op, q, block_q, block_k, causal, n_matmuls,
         if window:
             op += "_window"
             flops *= config["scores_computed_pct"] / 100.0
-        if plan and _PLAN_RECORDING is not None:
-            _PLAN_RECORDING.append(dict(
-                config, kernel=op, shape=tuple(int(n) for n in q.shape),
-                dk=int(dk), dv=int(dv)))
+        if plan:
+            _plan.note(PLAN_FLASH, **config, kernel=op,
+                       shape=tuple(int(n) for n in q.shape),
+                       dk=int(dk), dv=int(dv))
         costdb.note_kernel(
             op, [tuple(q.shape)], [str(q.dtype)], flops=flops,
             bytes_accessed=bytes_, block_config=config)
@@ -714,46 +715,10 @@ def _note_kernel_cost(op, q, block_q, block_k, causal, n_matmuls,
         pass
 
 
-_PLAN_RECORDING = None    # causal flash kernels of the step being traced
-_LAST_CAUSAL_PLAN = None
-
-
-class causal_plan_recording:
-    """Collects the causal flash kernels of one traced step, forward and
-    backward; on a clean exit with at least one kernel the collection
-    becomes :func:`last_causal_plan`.  ``ShardedTrainer`` opens one
-    round the step's trace, as it does ``moe.plan_recording``."""
-
-    def __enter__(self):
-        global _PLAN_RECORDING
-        self._prev, _PLAN_RECORDING = _PLAN_RECORDING, []
-        return self
-
-    def __exit__(self, exc_type, *_exc):
-        global _PLAN_RECORDING, _LAST_CAUSAL_PLAN
-        kernels, _PLAN_RECORDING = _PLAN_RECORDING, self._prev
-        if exc_type is None and kernels:
-            windowed = [k for k in kernels if k["window"]]
-            diffusion = [k for k in kernels if k.get("diffusion_block")]
-            _LAST_CAUSAL_PLAN = {
-                "kernels": kernels,
-                "causal_ranges": max(k["causal_ranges"] for k in kernels),
-                "scores_computed_pct": max(k["scores_computed_pct"]
-                                           for k in kernels),
-                "q_block_rows": min(k["block_q"] for k in kernels),
-                "window_layers": sum(
-                    k["kernel"] == "flash_attention_fwd_window"
-                    for k in windowed),
-                "window_scores_computed_pct": max(
-                    (k["scores_computed_pct"] for k in windowed),
-                    default=None),
-                "diffusion_layers": sum(
-                    k["kernel"] == "flash_attention_fwd_blockdiff"
-                    for k in diffusion),
-                "diffusion_scores_computed_pct": max(
-                    (k["scores_computed_pct"] for k in diffusion),
-                    default=None)}
-        return False
+#: the recorder's scope of the causal, windowed and block-diffusion flash
+#: kernels of a traced step, forward and backward (``telemetry.plan``)
+PLAN_FLASH = "mxtpu.block.flash"
+causal_plan_recording = _plan.recording
 
 
 def last_causal_plan():
@@ -778,7 +743,26 @@ def last_causal_plan():
     not one), and ``diffusion_scores_computed_pct`` the largest share of
     the ``2L x 2L`` square over them (None without any; the mask needs
     25.02 at L 4096, B 4).  As ``moe.last_plan_summary()``."""
-    return _LAST_CAUSAL_PLAN
+    kernels = _plan.last(PLAN_FLASH)
+    if kernels is None:
+        return None
+    windowed = [k for k in kernels if k["window"]]
+    diffusion = [k for k in kernels if k.get("diffusion_block")]
+    return {
+        "kernels": kernels,
+        "causal_ranges": max(k["causal_ranges"] for k in kernels),
+        "scores_computed_pct": max(k["scores_computed_pct"]
+                                   for k in kernels),
+        "q_block_rows": min(k["block_q"] for k in kernels),
+        "window_layers": sum(
+            k["kernel"] == "flash_attention_fwd_window" for k in windowed),
+        "window_scores_computed_pct": max(
+            (k["scores_computed_pct"] for k in windowed), default=None),
+        "diffusion_layers": sum(
+            k["kernel"] == "flash_attention_fwd_blockdiff"
+            for k in diffusion),
+        "diffusion_scores_computed_pct": max(
+            (k["scores_computed_pct"] for k in diffusion), default=None)}
 
 
 def _window_of(window, causal, t):

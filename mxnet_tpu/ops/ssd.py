@@ -50,6 +50,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import plan as _plan
+
 #: ``jax.named_scope`` of the op on the device
 SCOPE_SSD = "mxtpu.block.ssd"
 #: positions of a chunk (the model's ``chunk_size``)
@@ -241,46 +243,16 @@ def ssd_scan(x, dt, b, c, a_log, d, chunk=CHUNK):
     with jax.named_scope(SCOPE_SSD):
         y = _on_the_mesh((x, dt.astype(_F32), b.astype(x.dtype),
                           c.astype(x.dtype), a_log, d), how)
-    note_layer(heads=h, head_dim=int(x.shape[3]), state=int(b.shape[3]),
-               groups=groups, positions=t, chunk=chunk,
-               group=chunk * per_group,
+    _plan.note(SCOPE_SSD, heads=h, head_dim=int(x.shape[3]),
+               state=int(b.shape[3]), groups=groups, positions=t,
+               chunk=chunk, group=chunk * per_group,
                state_bytes=4 * int(x.shape[0]) * h * int(x.shape[3])
                * int(b.shape[3]) * (n // per_group))
     return y
 
 
 # ---- what the last traced step's state-space layers are
-_RECORDING = None
-_LAST_SUMMARY = None
-
-
-class plan_recording:
-    """Collects what each state-space layer of one traced step is; on a
-    clean exit with at least one layer the collection becomes
-    :func:`last_plan_summary`.  ``ShardedTrainer`` opens one round the
-    step's forward trace, as it does ``delta_rule.plan_recording``."""
-
-    def __enter__(self):
-        global _RECORDING
-        self._prev, _RECORDING = _RECORDING, []
-        return self
-
-    def __exit__(self, exc_type, *_exc):
-        global _RECORDING, _LAST_SUMMARY
-        layers, _RECORDING = _RECORDING, self._prev
-        if exc_type is None and layers:
-            _LAST_SUMMARY = {
-                "layers": layers,
-                "chunked_layers": len(layers),
-                "state_bytes": sum(x["state_bytes"] for x in layers)}
-        return False
-
-
-def note_layer(**info):
-    """One layer's plan, from :func:`ssd_scan` (no-op outside a
-    :class:`plan_recording`)."""
-    if _RECORDING is not None:
-        _RECORDING.append(info)
+plan_recording = _plan.recording
 
 
 def last_plan_summary():
@@ -292,4 +264,8 @@ def last_plan_summary():
     scan: there is one lowering and no recurrence to fall to) and
     ``state_bytes`` over all of them.  As
     ``delta_rule.last_plan_summary()``."""
-    return _LAST_SUMMARY
+    layers = _plan.last(SCOPE_SSD)
+    if layers is None:
+        return None
+    return {"layers": layers, "chunked_layers": len(layers),
+            "state_bytes": sum(x["state_bytes"] for x in layers)}

@@ -21,6 +21,7 @@ consumer per key, kvstore_dist_server.h ``exec_``).
 """
 from __future__ import annotations
 
+import logging
 import os
 import pickle
 import socket
@@ -39,6 +40,10 @@ __all__ = ["AsyncKVStore", "ParameterServer"]
 # push/pull byte children come bound from KVStore.__init__
 # (store="dist_async"); only the in-flight gauge is module-level
 _PENDING = telemetry.gauge("mxtpu_kvstore_pending_async")
+
+# how long a closing server waits for peers that never say ``bye`` (a worker
+# that died); `_connect` gives a peer as long to appear
+_JOIN_S = 60.0
 
 
 def _send_msg(sock, obj):
@@ -87,19 +92,66 @@ class ParameterServer:
                 "set MXNET_TPU_ASYNC_PORT to a free port"
                 % (host, port, e)) from e
         self._listener.listen(num_workers + 1)
+        # accept() wakes to look at `_stopping`; an accepted socket blocks
+        self._listener.settimeout(0.2)
+        self._stopping = False
+        self._conns = []
         self._threads = []
         self._accept_thread = threading.Thread(target=self._accept_loop,
                                                daemon=True)
         self._accept_thread.start()
 
     def _accept_loop(self):
-        for _ in range(self.num_workers):
-            conn, _addr = self._listener.accept()
+        while len(self._threads) < self.num_workers and not self._stopping:
+            try:
+                conn, _addr = self._listener.accept()
+            except socket.timeout:
+                continue
             t = threading.Thread(target=self._serve, args=(conn,),
                                  daemon=True)
             t.start()
+            self._conns.append(conn)
             self._threads.append(t)
         self._listener.close()
+
+    def _alive(self, deadline):
+        """The server's threads still running at ``deadline``."""
+        self._accept_thread.join(max(0.0, deadline - time.monotonic()))
+        threads = [self._accept_thread] + self._threads
+        for t in threads[1:]:
+            t.join(max(0.0, deadline - time.monotonic()))
+        return [t for t in threads if t.is_alive()]
+
+    def join(self, timeout=None):
+        """Wait until every worker's connection has ended (its ``bye``,
+        or its socket closing) and drop the store, before the hosting
+        process exits: serving threads that outlived it held device
+        arrays while the interpreter finalised, which now and then
+        aborted a process whose work was done (exit code 134).  A worker
+        that died before it connected, or inside a barrier, never ends
+        its connection: after ``timeout`` seconds (`_JOIN_S`) the server
+        stops waiting for it, wakes its own threads and says what was
+        left."""
+        timeout = _JOIN_S if timeout is None else timeout
+        left = self._alive(time.monotonic() + timeout)
+        if left:
+            logging.warning(
+                "dist_async server: after %.0f s %d of %d workers had "
+                "connected and %d connections were still open; closing "
+                "without them", timeout, len(self._threads),
+                self.num_workers, len(left) - (self._accept_thread in left))
+            self._stopping = True
+            for conn in self._conns:
+                try:
+                    conn.shutdown(socket.SHUT_RDWR)   # wakes its recv()
+                except OSError:
+                    pass                              # ended meanwhile
+            with self._barrier_cv:
+                self._barrier_cv.notify_all()
+            self._alive(time.monotonic() + 5.0)
+        with self._lock:
+            self._store.clear()
+            self._updater = self._updater_obj = None
 
     def _serve(self, conn):
         msg = ("<recv>",)  # so the fault-report path below can never NameError
@@ -162,7 +214,8 @@ class ParameterServer:
                             self._barrier_gen += 1
                             self._barrier_cv.notify_all()
                         else:
-                            while self._barrier_gen == gen:
+                            while (self._barrier_gen == gen
+                                   and not self._stopping):
                                 self._barrier_cv.wait()
                     _send_msg(conn, ("ok",))
                 elif op == "stats":
@@ -526,6 +579,10 @@ class AsyncKVStore(KVStore):
                 # also deliver a corrupt (unpicklable) response
                 pass
         self._socks = []
+        if self._server is not None:
+            # the other workers may still be talking to the server here
+            server, self._server = self._server, None
+            server.join()
 
     def __del__(self):
         try:

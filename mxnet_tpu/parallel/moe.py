@@ -36,6 +36,8 @@ import re
 
 import numpy as np
 
+from ..telemetry import plan as _plan
+
 
 def switch_moe(x, router_w, w1, b1, w2, b2, capacity_factor=1.25,
                mesh=None, expert_axis="expert"):
@@ -472,39 +474,20 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
 
 
 # ---- what the last traced step's expert layers are, and what they carried
-_RECORDING = None
+plan_recording = _plan.recording
+#: returned by :func:`last_plan_summary` in place of the recorded plan.
+#: Nothing in the program sets it; ``benchmark/tests/test_moe_small_buffer.py``
+#: does, by this name, and only a ``benchmark`` PR may edit that file
 _LAST_SUMMARY = None
 _LOAD_SAMPLES = []
 #: sampled dispatches whose loads are kept (the oldest go first)
 LOAD_SAMPLES_KEPT = 256
 
 
-class plan_recording:
-    """Collects what each expert layer of one traced step is; on a clean
-    exit with at least one layer the collection becomes
-    :func:`last_plan_summary`.  ``ShardedTrainer`` opens one round the
-    step's forward trace."""
-
-    def __enter__(self):
-        global _RECORDING
-        self._prev, _RECORDING = _RECORDING, []
-        return self
-
-    def __exit__(self, exc_type, *_exc):
-        global _RECORDING, _LAST_SUMMARY
-        layers, _RECORDING = _RECORDING, self._prev
-        if exc_type is None and layers:
-            _LAST_SUMMARY = {"expert_layers": len(layers), "layers": layers,
-                             "grouped_products": None,
-                             "grouped_layers": None}
-        return False
-
-
 def note_layer(**info):
     """One expert layer's plan, from :func:`topk_moe` (no-op outside a
-    :class:`plan_recording`)."""
-    if _RECORDING is not None:
-        _RECORDING.append(info)
+    ``telemetry.plan.recording``)."""
+    _plan.note(SCOPE_MOE, **info)
 
 
 def note_compiled(executable):
@@ -517,20 +500,21 @@ def note_compiled(executable):
     (``small_rows``), since the text holds both branches' calls and a
     step runs one's (a backend that multiplies densely and masks reads
     0).  Both stay None where the executable gives no text."""
-    if _LAST_SUMMARY is None or not hasattr(executable, "as_text"):
+    layers = _plan.last(SCOPE_MOE)
+    if layers is None or not hasattr(executable, "as_text"):
         return
     text = executable.as_text()
     if text:
-        n = len(_GROUPED_PRODUCT.findall(text))
-        _LAST_SUMMARY["grouped_products"] = n
+        products = n = len(_GROUPED_PRODUCT.findall(text))
         covered = 0
-        for layer in _LAST_SUMMARY["layers"]:
+        for layer in layers:
             n -= layer.get("products_trained", PRODUCTS_PER_TRAINED_LAYER) \
                 * (1 if layer.get("small_rows") is None else 2)
             if n < 0:
                 break
             covered += 1
-        _LAST_SUMMARY["grouped_layers"] = covered
+        _plan.annotate(SCOPE_MOE, grouped_products=products,
+                       grouped_layers=covered)
 
 
 def last_plan_summary():
@@ -550,7 +534,14 @@ def last_plan_summary():
     ``grouped_products`` and ``grouped_layers`` as
     :func:`note_compiled` reads them from it.  As
     ``analysis.fusion.last_plan_summary()``."""
-    return _LAST_SUMMARY
+    if _LAST_SUMMARY is not None:
+        return _LAST_SUMMARY
+    layers = _plan.last(SCOPE_MOE)
+    if layers is None:
+        return None
+    return dict({"expert_layers": len(layers), "layers": layers,
+                 "grouped_products": None, "grouped_layers": None},
+                **_plan.annotations(SCOPE_MOE))
 
 
 def publish_load(loads):
@@ -566,7 +557,7 @@ def publish_load(loads):
     from ..telemetry.registry import gauge
     sample = {}
     # the trainer's layers and the plan's are both in the graph's order
-    plans = (_LAST_SUMMARY or {}).get("layers", ())
+    plans = (last_plan_summary() or {}).get("layers", ())
     if len(plans) != len(loads):
         plans = [{}] * len(loads)
     for (layer, load), plan in zip(loads.items(), plans):

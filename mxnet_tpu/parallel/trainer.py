@@ -36,8 +36,6 @@ training scripts use this.
 """
 from __future__ import annotations
 
-import contextlib
-import os
 import struct as _struct
 
 import numpy as np
@@ -47,6 +45,7 @@ from ..symbol import eval_graph, _classify_vars
 from ..initializer import Xavier, InitDesc, rule_for, draw
 from ..ops.nn import image_layout
 from .. import optimizer as _opt_mod
+from ..telemetry import plan as _plan
 from ..telemetry.spans import span as _span
 
 __all__ = ["ShardedTrainer"]
@@ -148,28 +147,6 @@ def _make_update_rule(opt):
         % name)
 
 
-@contextlib.contextmanager
-def _no_persistent_cache():
-    """Compile within, bypassing JAX's persistent compilation cache.
-
-    An executable compiled with ``Layout.AUTO`` must be compiled, never
-    loaded: read back from the cache (JAX 0.9.0 / libtpu 0.0.34, on the
-    chip) it takes other layouts than its ``input_formats`` report, and
-    the step fails with "compiled for input layouts that disagree with
-    the layouts of arguments passed to it".  The cache decides once per
-    process whether it is in use, so the switch needs its reset."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", enabled)
-        compilation_cache.reset_cache()
-
-
 class ShardedTrainer:
     @_span("trainer.build", category="trainer")
     def __init__(self, symbol, mesh, data_shapes, label_shapes=(),
@@ -180,7 +157,7 @@ class ShardedTrainer:
                  stem_space_to_depth=None, elide_input_bn_grad=True,
                  pipeline_stages=1, pipeline_microbatches=None,
                  sequence_parallel=False, input_mean=None, input_std=None,
-                 native_weight_layout=None, strict=None):
+                 strict=None):
         """
         symbol: loss-headed Symbol (e.g. SoftmaxOutput net).
         mesh: jax.sharding.Mesh with ('data', 'model') axes.
@@ -221,6 +198,8 @@ class ShardedTrainer:
             with NCHW checkpoints whenever Flatten only ever sees 1x1
             spatial maps (global-pool-then-FC nets like ResNet/Inception);
             an MLP-style Flatten of a WxH map permutes the FC input order.
+        auto_layouts: False, the one value left (the option went in
+            PR 43); state lives in jit's default layouts, masters OIHW.
         strict: run the distributed-correctness pass
             (``analysis.spmd``, MXG011-016) over this (graph, mesh,
             parallel config) triple before any compile and raise a
@@ -244,16 +223,15 @@ class ShardedTrainer:
         # jitted step runs on every process; host<->device staging goes
         # through parallel/multihost.py instead of device_put
         self._multiproc = multihost.spans_processes(mesh)
-        if self._multiproc and auto_layouts:
-            import logging
-            # AOT AUTO-layout lowering is a per-process choice; keep the
-            # multi-controller program deterministic across ranks
-            logging.warning(
-                "auto_layouts disabled on a process-spanning mesh: "
-                "XLA-chosen AOT layouts are a per-process decision and "
-                "could diverge across ranks of the multi-controller "
-                "program")
-            auto_layouts = False
+        # auto_layouts: kept only because every benchmark/configs/*.json
+        # passes ``"auto_layouts": false`` straight into this constructor
+        # and a PR may not edit those files; the option's code is gone
+        if auto_layouts:
+            raise MXNetError(
+                "ShardedTrainer(auto_layouts=True): XLA-chosen state "
+                "layouts were removed in PR 43 (+0.24% on ResNet-50 for "
+                "about 100 s of uncached compile); the keyword accepts "
+                "only False until the benchmark's configurations drop it")
         # input_mean/input_std: per-channel (or scalar) normalization
         # applied ON DEVICE to uint8 data inputs staged via put_batch —
         # the raw_uint8 ingest path (native reader ships bytes, the chip
@@ -261,10 +239,6 @@ class ShardedTrainer:
         # src/io/iter_normalize.h)
         self._input_mean = input_mean
         self._input_std = input_std
-        # auto_layouts: let XLA choose persistent param/state layouts
-        # (Layout.AUTO) instead of jit's default-pinned I/O layouts —
-        # kills the per-step relayout copies (docs/perf.md)
-        self._auto_layouts = bool(auto_layouts)
         if layout not in (None, "NCHW", "NHWC"):
             raise MXNetError("unsupported layout %r" % (layout,))
         self._layout = layout or "NCHW"
@@ -288,19 +262,6 @@ class ShardedTrainer:
         # an input-BN beta grad (ops/fused.py).  Always sound here: the
         # trainer's vjp differentiates params only, never batch inputs.
         self._elide_input_grads = bool(elide_input_bn_grad)
-        # native_weight_layout: store conv-weight MASTERS physically as
-        # HWIO (f32) so the default/canonical layout IS the layout the
-        # TPU conv wants.  jit's Layout.AUTO cannot reach lax.scan loop
-        # carries (run_steps), so OIHW masters pay per-step relayout
-        # copies (the xprof "copies" bucket, docs/perf.md); a physical
-        # shape change removes them everywhere.  Checkpoints and the
-        # graph itself still see reference OIHW (converted at the
-        # boundaries), so saved params stay interoperable.
-        if native_weight_layout is None:
-            native_weight_layout = \
-                os.environ.get("MXNET_NATIVE_WEIGHT_LAYOUT", "0") == "1"
-        self._native_weight_layout = bool(native_weight_layout) and \
-            self._layout == "NHWC"
         # pipeline_stages > 1: GPipe over the mesh's 'pipe' axis — the
         # graph is cut into stages at single-live-tensor positions and
         # the step streams microbatches stage-to-stage over ICI
@@ -318,10 +279,6 @@ class ShardedTrainer:
                                  "params cannot also be tensor-sharded)")
         self._pp_microbatches = int(pipeline_microbatches or
                                     (2 * self._pp if self._pp > 1 else 1))
-        if self._pp > 1:
-            # the pipelined step manages its own sharding; AUTO-layout
-            # AOT compilation is not composed with it
-            self._auto_layouts = False
         # sequence_parallel: shard data inputs' dim 1 (the sequence) over
         # the 'model' axis and activate the ring-attention context, so
         # _contrib_RingAttention nodes run the ICI ring schedule
@@ -366,8 +323,8 @@ class ShardedTrainer:
         """``trainer.build.graph``: everything the constructor works
         out from the symbol and the mesh before any array exists —
         variables and index inputs, shape inference, the optimizer's
-        rule, the native-layout weight set, tensor-parallel rules, the
-        SPMD verification pass, the shardings."""
+        rule, tensor-parallel rules, the SPMD verification pass, the
+        shardings."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         self._topo = symbol._topo()
@@ -388,15 +345,6 @@ class ShardedTrainer:
             node.inputs[-1][0].name: node.name for node in self._topo
             if node.op is not None and node.op.name == "_contrib_TopKMoE"
             and node.inputs[-1][0].is_variable}
-        # linear-attention and state-space layers record their plan as
-        # the expert layers do: the modules of ``ops`` that hold one
-        ops_here = {node.op.name for node in self._topo
-                    if node.op is not None}
-        self._scan_modules = [
-            module for op, module in (("_contrib_GatedDeltaRule",
-                                       "delta_rule"),
-                                      ("_contrib_SSDScan", "ssd"))
-            if op in ops_here]
 
         # data inputs consumed as integer indices (Embedding/take/...):
         # these must NOT be cast to a narrow compute dtype — bf16 rounds
@@ -490,15 +438,6 @@ class ShardedTrainer:
         self.optimizer = optimizer
         self._rescale = optimizer.rescale_grad
         self._n_slots, self._update_rule = _make_update_rule(optimizer)
-
-        # ---- native-layout weight set: conv masters stored HWIO
-        self._native_w = frozenset()
-        if self._native_weight_layout and self._pp == 1:
-            self._native_w = self._derive_native_weights()
-        self._store_shapes = dict(self._arg_shapes)
-        for n in self._native_w:
-            o, i, h, w = self._arg_shapes[n]
-            self._store_shapes[n] = (h, w, i, o)
 
         tp_size = mesh.shape.get("model", 1)
         if tp_rules is None:
@@ -616,14 +555,10 @@ class ShardedTrainer:
                 "ShardedTrainer strict bind (memory)")
 
         def param_spec(name):
-            shp = self._store_shapes.get(name, self._aux_shapes.get(name))
+            shp = self._arg_shapes.get(name, self._aux_shapes.get(name))
             spec = [None] * len(shp)
             if name in tp_rules:
-                d = tp_rules[name]
-                if name in self._native_w:
-                    # OIHW dim index -> its position in HWIO storage
-                    d = (3, 2, 0, 1)[d]
-                spec[d] = "model"
+                spec[tp_rules[name]] = "model"
             return P(*spec)
 
         self._param_sharding = {
@@ -670,10 +605,6 @@ class ShardedTrainer:
             rule(desc, arr)
             host_params[name] = arr.data
 
-        def to_store(name, value):   # rules see reference OIHW
-            return value.transpose(2, 3, 1, 0) \
-                if name in self._native_w else value
-
         # parameters of one rule and one shape share ONE traced and
         # lowered function, called once a parameter with its own key:
         # a model of many small layers costs a trace and a lowering per
@@ -686,10 +617,10 @@ class ShardedTrainer:
         def drawn(name, key):
             rule, desc, _ = traced[name]
             shape = self._arg_shapes[name]
-            at = (rule, shape, name in self._native_w)
+            at = (rule, shape)
             if at not in drawers:
                 drawers[at] = jax.jit(
-                    lambda k: to_store(name, draw(rule, desc, shape, k)))
+                    lambda k: draw(rule, desc, shape, k))
             return drawers[at](key)
 
         def program(key):
@@ -714,8 +645,7 @@ class ShardedTrainer:
 
         def nbytes(names):
             return sum(4 * int(np.prod(self._arg_shapes[n])) for n in names)
-        return ({n: np.ascontiguousarray(to_store(n, v))
-                 for n, v in host_params.items()},
+        return (host_params,
                 {"device_params": len(traced),
                  "device_bytes": nbytes(traced),
                  "host_params": len(host_params),
@@ -741,8 +671,8 @@ class ShardedTrainer:
 
     def _plan_step(self, strict):
         """``trainer.build.plan``: the fusion plan's decisions, the
-        step function that will trace under them (compiled here only
-        under ``auto_layouts``), and the dispatch bookkeeping."""
+        step function that will trace under them, and the dispatch
+        bookkeeping."""
         # plan-search decisions (analysis.plansearch): an ambient
         # plan_decisions context wins; otherwise consult the committed
         # graph_plan tuning-cache entry ONCE at construction — keyed by
@@ -829,7 +759,7 @@ class ShardedTrainer:
             return {n: [] for n in self._param_names}
 
         def make():
-            return {n: [jnp.zeros(self._store_shapes[n], jnp.float32)
+            return {n: [jnp.zeros(self._arg_shapes[n], jnp.float32)
                         for _ in range(self._n_slots)]
                     for n in self._param_names}
 
@@ -837,48 +767,9 @@ class ShardedTrainer:
                      for n in self._param_names}
         return jax.jit(make, out_shardings=shardings)()
 
-    def _derive_native_weights(self):
-        """Param names eligible for physical HWIO master storage: 4-d
-        weights whose EVERY graph use is the ``weight`` input of a 2-d
-        Convolution (shared/tied weights with any other consumer keep
-        reference layout)."""
-        uses = {}
-        for node in self._topo:
-            if node.is_variable or node.op is None:
-                continue
-            for pos, (src, _i) in enumerate(node.inputs):
-                if src.is_variable:
-                    uses.setdefault(src.name, []).append((node, pos))
-        out = set()
-        for name in self._param_names:
-            shp = self._arg_shapes.get(name)
-            if shp is None or len(shp) != 4:
-                continue
-            us = uses.get(name, ())
-            if us and all(n.op.name == "Convolution" and pos == 1
-                          for n, pos in us):
-                out.add(name)
-        return frozenset(out)
-
-    def _compute_view(self, params, compute_dtype):
-        """Compute-precision copies of the f32 masters, native-layout
-        weights rotated back to the reference OIHW view the graph
-        expects (the op-level OIHW->HWIO transpose then cancels, so the
-        conv consumes the HWIO master directly)."""
-        import jax.numpy as jnp
-        native = self._native_w
-        p = {}
-        for k, v in params.items():
-            v = v.astype(compute_dtype)
-            if k in native:
-                v = jnp.transpose(v, (3, 2, 0, 1))  # HWIO -> OIHW view
-            p[k] = v
-        return p
-
     def _put_state(self, value, target):
         """Stage a full host value (identical on every process) as a
-        device array.  ``target`` is a NamedSharding, or under
-        auto_layouts (single-process only) an XLA-chosen Format."""
+        device array under the NamedSharding ``target``."""
         import jax
         if self._multiproc:
             from . import multihost
@@ -1281,7 +1172,8 @@ class ShardedTrainer:
                     (P(None, None), P(None, "data", None),
                      x_side_specs), P(None))(stacked, xs, side)[0]
 
-            loss_sum, grads = jax.value_and_grad(loss_fn)(params)
+            with _plan.recording():
+                loss_sum, grads = jax.value_and_grad(loss_fn)(params)
             new_params, new_state = {}, {}
             for k, w in params.items():
                 lr_mult, wd_eff = hyper[k]
@@ -1303,18 +1195,22 @@ class ShardedTrainer:
             self._py_step_stats = step
         else:
             self._py_step = step
-        state_sharding = {n: [self._param_sharding[n]] * self._n_slots
-                          for n in self._param_names}
-        in_shardings = (self._param_sharding, state_sharding,
-                        self._aux_sharding, self._batch_sharding,
-                        None, None, None)
-        out_shardings = (self._param_sharding, state_sharding,
-                         self._aux_sharding, None)
-        if collect_stats:
-            out_shardings = out_shardings + (None,)
-        return jax.jit(step, in_shardings=in_shardings,
-                       out_shardings=out_shardings,
-                       donate_argnums=(0, 1, 2))
+        return self._jit_over_state(step, 2 if collect_stats else 1)
+
+    def _jit_over_state(self, fn, extra_outputs=1):
+        """The one way a step program is jitted: ``fn(params, opt_state,
+        aux, batch, key, lr, t)`` under the state's and the batch's
+        shardings, the state donated; it returns the new state and
+        ``extra_outputs`` more values (the loss; the stats)."""
+        import jax
+        state = (self._param_sharding,
+                 {n: [self._param_sharding[n]] * self._n_slots
+                  for n in self._param_names},
+                 self._aux_sharding)
+        return jax.jit(
+            fn, in_shardings=state + (self._batch_sharding, None, None, None),
+            out_shardings=state + (None,) * extra_outputs,
+            donate_argnums=(0, 1, 2))
 
     def _build_step(self, collect_stats=False):
         """Build the jitted train step.  ``collect_stats=True`` builds
@@ -1345,17 +1241,14 @@ class ShardedTrainer:
 
             def fwd(p32):
                 # compute-precision copies of the f32 masters (the astype
-                # vjp returns f32 grads automatically); native-layout
-                # weights arrive HWIO and grads flow back HWIO
+                # vjp returns f32 grads automatically)
                 from ..ops.fused import (stem_s2d, elide_input_grads,
                                          block_fusion)
                 from ..analysis.fusion import plan_decisions
                 from .sequence import sequence_parallel as seq_ctx
                 from .mesh import kernel_mesh
-                from .moe import plan_recording
-                p = self._compute_view(p32, compute_dtype)
+                p = {k: v.astype(compute_dtype) for k, v in p32.items()}
                 with image_layout(layout), kernel_mesh(self.mesh), \
-                        plan_recording(), self._scan_plans(), \
                         block_fusion(self._fuse_blocks), \
                         plan_decisions(self._plan_decisions), \
                         stem_s2d(self._stem_s2d), \
@@ -1378,10 +1271,11 @@ class ShardedTrainer:
                 return heads, (aux_upd, dict(sink) if sink else {})
 
             # stable names on the device: every op of the step carries
-            # one of these scopes in its ``op_name``, whatever the compile
+            # one of these scopes in its ``op_name``, whatever the compile.
+            # The recording spans the forward trace and the pull: the
+            # layers note their plans from either (telemetry.plan)
             from ..ops.nn import maybe_mirror
-            from ..ops.pallas_kernels import causal_plan_recording
-            with causal_plan_recording():
+            with _plan.recording():
                 with jax.named_scope(SCOPE_FWD):
                     heads, vjp, (aux_upd, blk_stats) = jax.vjp(
                         maybe_mirror(fwd), params, has_aux=True)
@@ -1443,22 +1337,7 @@ class ShardedTrainer:
         else:
             # the scan chain (_build_multi_step) composes the PLAIN step
             self._py_step = step
-        state_sharding = {n: [self._param_sharding[n]] * self._n_slots
-                          for n in self._param_names}
-        if self._auto_layouts:
-            return self._compile_auto_layout(
-                "trainer.step_stats" if collect_stats else "trainer.step",
-                step, state_sharding)
-        in_shardings = (self._param_sharding, state_sharding,
-                        self._aux_sharding, self._batch_sharding,
-                        None, None, None)
-        out_shardings = (self._param_sharding, state_sharding,
-                         self._aux_sharding, None)
-        if collect_stats:
-            out_shardings = out_shardings + (None,)
-        return jax.jit(step, in_shardings=in_shardings,
-                       out_shardings=out_shardings,
-                       donate_argnums=(0, 1, 2))
+        return self._jit_over_state(step, 2 if collect_stats else 1)
 
     def _build_multi_step(self, k):
         """k steps chained inside ONE compiled program via lax.scan.
@@ -1486,107 +1365,7 @@ class ShardedTrainer:
             return params, opt_state, aux, losses
 
         multi.__name__ = multi.__qualname__ = "mxtpu_train_chain"
-        state_sharding = {n: [self._param_sharding[n]] * self._n_slots
-                          for n in self._param_names}
-        if self._auto_layouts:
-            import jax.numpy as jnp
-            return self._compile_auto_layout(
-                "trainer.run_steps", multi, state_sharding,
-                lr_example=jnp.zeros((k,), jnp.float32),
-                t_example=jnp.ones((k,), jnp.float32),
-                migrate=False)
-        in_shardings = (self._param_sharding, state_sharding,
-                        self._aux_sharding, self._batch_sharding,
-                        None, None, None)
-        out_shardings = (self._param_sharding, state_sharding,
-                         self._aux_sharding, None)
-        return jax.jit(multi, in_shardings=in_shardings,
-                       out_shardings=out_shardings,
-                       donate_argnums=(0, 1, 2))
-
-    def _compile_auto_layout(self, program, step, state_sharding,
-                             lr_example=None, t_example=None,
-                             migrate=True):
-        """Compile the step with XLA-chosen parameter/state layouts.
-
-        jit pins donated I/O to default layouts, so every step pays
-        per-weight relayout copies between the conv-preferred tilings
-        and the I/O layout (docs/perf.md "copies" bucket).  With
-        Layout.AUTO on the persistent state, XLA keeps params/opt/aux
-        in its preferred tilings ACROSS steps (the state is donated, so
-        the layout round-trips for free); the one-time device_put below
-        migrates the live state into the chosen formats.
-
-        Each AOT compile may choose different layouts, so the chosen
-        formats are recorded on the compiled object (``_state_formats``)
-        and callers re-migrate via :meth:`_ensure_state_formats` when
-        switching between compiled entry points (step vs run_steps).
-        """
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental.layout import Format, Layout
-
-        def auto_of(sharding_tree):
-            return jax.tree.map(lambda s: Format(Layout.AUTO, s),
-                                sharding_tree,
-                                is_leaf=lambda x: hasattr(x, "spec"))
-
-        in_shardings = (auto_of(self._param_sharding),
-                        auto_of(state_sharding),
-                        auto_of(self._aux_sharding),
-                        self._batch_sharding, None, None, None)
-        out_shardings = (auto_of(self._param_sharding),
-                         auto_of(state_sharding),
-                         auto_of(self._aux_sharding), None)
-        jf = jax.jit(step, in_shardings=in_shardings,
-                     out_shardings=out_shardings, donate_argnums=(0, 1, 2))
-        # _input_shapes are already layout-converted; stage zeros directly
-        # (put_batch would transpose a host NCHW batch a second time)
-        zero_batch = {
-            n: jax.device_put(
-                jnp.zeros(s, jnp.float32
-                          if ("label" in n or n in self._int_inputs)
-                          else jnp.dtype(self.dtype)),
-                self._batch_sharding[n])
-            for n, s in self._input_shapes.items()}
-        def as_spec(tree):
-            # AUTO-layout args must be abstract at lower time
-            return jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-
-        if lr_example is None:
-            lr_example = jnp.float32(0.0)
-        if t_example is None:
-            t_example = jnp.float32(1.0)
-        example = (as_spec(self.params), as_spec(self.opt_state),
-                   as_spec(self.aux), zero_batch, jax.random.PRNGKey(0),
-                   lr_example, t_example)
-        with _no_persistent_cache():
-            with _span("program.lower", program=program):
-                lowered = jf.lower(*example)
-            with _span("program.compile", program=program):
-                compiled = lowered.compile()
-        fmts = compiled.input_formats[0]
-        compiled._state_formats = (fmts[0], fmts[1], fmts[2])
-        if migrate:
-            # migrate live state into the chosen layouts (one-time copies)
-            self._migrate_state(compiled._state_formats)
-        return compiled
-
-    def _migrate_state(self, fmts):
-        import jax
-        self.params = jax.device_put(self.params, fmts[0])
-        self.opt_state = jax.device_put(self.opt_state, fmts[1])
-        self.aux = jax.device_put(self.aux, fmts[2])
-        self._live_formats = fmts
-
-    def _ensure_state_formats(self, compiled):
-        """Under auto_layouts, move live state into the layouts the given
-        compiled entry point was lowered with (no-op when they match)."""
-        fmts = getattr(compiled, "_state_formats", None)
-        if fmts is not None and \
-                getattr(self, "_live_formats", None) is not fmts:
-            self._migrate_state(fmts)
+        return self._jit_over_state(multi)
 
     # ------------------------------------------------------------------ api
     def _maybe_rebuild(self):
@@ -1984,19 +1763,6 @@ class ShardedTrainer:
                                  mesh=self._mesh_axis_sizes(),
                                  steps=steps)
 
-    def _scan_plans(self):
-        """``plan_recording()`` of ``ops.delta_rule`` and of ``ops.ssd``
-        round a step's forward trace, each where the graph has such a
-        layer; nothing otherwise (a module is not imported for a graph
-        without its op)."""
-        import contextlib
-        import importlib
-        stack = contextlib.ExitStack()
-        for module in self._scan_modules:
-            stack.enter_context(importlib.import_module(
-                "..ops." + module, __package__).plan_recording())
-        return stack
-
     def _publish_moe_loads(self, obs):
         """The expert layers' loads of the last step as gauges
         (``parallel.moe.publish_load``), on the dispatches the cost
@@ -2075,13 +1841,12 @@ class ShardedTrainer:
         if self._numerics_sampled():
             # the numerics.nonfinite seam is evaluated ONLY on sampled
             # steps: an injected NaN must land where detection runs —
-            # poisoning an unsampled (or auto_layouts-gated) step would
-            # corrupt the run with zero anomaly signal
+            # poisoning an unsampled step would corrupt the run with
+            # zero anomaly signal
             dev_batch = self._maybe_poison_batch(dev_batch)
             if self._stats_step_fn is None:
                 self._stats_step_fn = self._build_step(collect_stats=True)
             program, fn = "trainer.step_stats", self._stats_step_fn
-        self._ensure_state_formats(fn)
         args = (self.params, self.opt_state, self.aux, dev_batch, sub,
                 jnp.float32(lr), jnp.float32(opt.num_update))
         self._measure_collective_entry("trainer.step")
@@ -2116,23 +1881,9 @@ class ShardedTrainer:
         The cadence is phased on the GLOBAL step (resume epoch + local
         count — the number the ledger records carry), so a resumed run
         samples the same step numbers as a from-scratch one and the
-        pre- vs post-resume ledgers stay numdiff-comparable.
-        auto_layouts is excluded: the stats variant would need its own
-        AOT layout choice and a state migration per sampled step."""
+        pre- vs post-resume ledgers stay numdiff-comparable."""
         from ..telemetry import numerics as _numerics
-        if not _numerics.sampled(self._resume_epoch + self._step_count):
-            return False
-        if self._auto_layouts:
-            if not getattr(self, "_numerics_warned", False):
-                self._numerics_warned = True
-                import logging
-                logging.warning(
-                    "MXNET_TPU_NUMERICS_EVERY is set but auto_layouts "
-                    "is active; numerics sampling is disabled for this "
-                    "trainer (the stats variant would re-migrate the "
-                    "state's XLA-chosen layouts on every sampled step)")
-            return False
-        return True
+        return _numerics.sampled(self._resume_epoch + self._step_count)
 
     def _maybe_poison_batch(self, dev_batch):
         """The ``numerics.nonfinite`` chaos seam: when armed
@@ -2196,7 +1947,7 @@ class ShardedTrainer:
                              position=order[0])
 
         compute_dtype = jnp.dtype(self.dtype)
-        p = self._compute_view(self.params, compute_dtype)
+        p = {k: v.astype(compute_dtype) for k, v in self.params.items()}
         bsz = next(iter(dev_batch.values())).shape[0]
         with image_layout(self._layout):
             var_values = self._node_value_map(p, dev_batch, self.aux)
@@ -2306,7 +2057,6 @@ class ShardedTrainer:
             lrs.append(opt.lr_scheduler(opt.num_update)
                        if opt.lr_scheduler is not None else opt.lr)
         self._key, sub = jax.random.split(self._key)
-        self._ensure_state_formats(fn)
         args = (self.params, self.opt_state, self.aux, dev_batch, sub,
                 jnp.asarray(_np.asarray(lrs, _np.float32)),
                 jnp.asarray(_np.asarray(ts, _np.float32)))
@@ -2327,7 +2077,8 @@ class ShardedTrainer:
                 from ..analysis.fusion import plan_decisions
                 from .sequence import sequence_parallel as seq_ctx
                 from .mesh import kernel_mesh
-                p = self._compute_view(params, compute_dtype)
+                p = {k: v.astype(compute_dtype)
+                     for k, v in params.items()}
                 bsz = next(iter(batch.values())).shape[0]
                 # loss heads still take label inputs at inference; their
                 # forward ignores the values, so zeros stand in
@@ -2384,10 +2135,8 @@ class ShardedTrainer:
     def mesh_descriptor(self):
         """JSON-able descriptor of this trainer's mesh + per-param
         partition layout (``parallel/reshard.py``): axis sizes, the
-        saving world size, and each param's spec in the REFERENCE
-        (OIHW) dim convention — native-layout HWIO storage is a device
-        detail the descriptor never sees, exactly like the checkpoint
-        files themselves.  Recorded in the checkpoint manifest's
+        saving world size, and each param's spec in the reference
+        (OIHW) dim convention.  Recorded in the checkpoint manifest's
         ``meta["mesh"]`` (schema v2) so a later load can detect a mesh
         reshape; see :meth:`load_checkpoint`."""
         from . import multihost, reshard as _reshard
@@ -2421,11 +2170,6 @@ class ShardedTrainer:
         from .. import ndarray as _nd
         from . import multihost
 
-        def to_ref(k, a):
-            # native-layout masters/slots live HWIO on device; files
-            # keep the reference OIHW so checkpoints stay interoperable
-            return a.transpose(3, 2, 0, 1) if k in self._native_w else a
-
         from .. import resilience
         # gather-on-save streams ONE array at a time off the mesh (the
         # host dict accumulates numpy copies; device memory never holds
@@ -2434,7 +2178,7 @@ class ShardedTrainer:
         host = {}
         for k, v in self.params.items():
             resilience.fault_point("reshard.gather")
-            host["arg:%s" % k] = to_ref(k, multihost.gather_to_host(v))
+            host["arg:%s" % k] = multihost.gather_to_host(v)
         for k, v in self.aux.items():
             resilience.fault_point("reshard.gather")
             host["aux:%s" % k] = multihost.gather_to_host(v)
@@ -2446,8 +2190,8 @@ class ShardedTrainer:
             for k, slots in self.opt_state.items():
                 for i, sl in enumerate(slots):
                     resilience.fault_point("reshard.gather")
-                    st["slot%d:%s" % (i, k)] = to_ref(
-                        k, multihost.gather_to_host(sl))
+                    st["slot%d:%s" % (i, k)] = \
+                        multihost.gather_to_host(sl)
         if not self._multiproc or jax.process_index() == 0:
             resilience.atomic_write("%s-symbol.json" % prefix,
                                     self.symbol.save)
@@ -2493,14 +2237,6 @@ class ShardedTrainer:
                                       meta=meta)
         if self._multiproc:
             multihost.process_barrier("sharded_trainer_ckpt_save")
-
-    def _state_target(self, live, sharding):
-        """device_put target preserving the live array's layout: under
-        auto_layouts the AOT-compiled step was lowered with XLA-chosen
-        formats, which a plain NamedSharding put would discard."""
-        if not self._auto_layouts:
-            return sharding
-        return live.format
 
     def load_checkpoint(self, prefix, epoch, load_optimizer_states=False):
         """Restore params/aux (and fused optimizer slots) saved by
@@ -2555,10 +2291,6 @@ class ShardedTrainer:
             raise MXNetError(
                 "checkpoint/model mismatch: missing %s, unexpected %s"
                 % (sorted(missing), sorted(unexpected)))
-        def to_store(name, a):
-            # files hold reference OIHW; native-layout state lives HWIO
-            return a.transpose(2, 3, 1, 0) if name in self._native_w else a
-
         # ---- elastic detection: the manifest's mesh descriptor vs the
         # mesh this trainer was built on.  The plan validates EVERY
         # array against the target layout before any state moves.
@@ -2594,17 +2326,14 @@ class ShardedTrainer:
                     if reshaping:
                         resilience.fault_point("reshard.scatter")
                     target_params[name] = self._put_state(
-                        to_store(name,
-                                 _np.asarray(v.asnumpy(), _np.float32)),
-                        self._state_target(self.params[name],
-                                           self._param_sharding[name]))
+                        _np.asarray(v.asnumpy(), _np.float32),
+                        self._param_sharding[name])
                 for name, v in file_aux.items():
                     if reshaping:
                         resilience.fault_point("reshard.scatter")
                     target_aux[name] = self._put_state(
                         _np.asarray(v.asnumpy(), _np.float32),
-                        self._state_target(self.aux[name],
-                                           self._aux_sharding[name]))
+                        self._aux_sharding[name])
                 if load_optimizer_states:
                     states_name = "%s-%04d.states" % (prefix, epoch)
                     try:
@@ -2647,12 +2376,8 @@ class ShardedTrainer:
                         if reshaping:
                             resilience.fault_point("reshard.scatter")
                         target_slots[name][i] = self._put_state(
-                            to_store(name,
-                                     _np.asarray(v.asnumpy(),
-                                                 _np.float32)),
-                            self._state_target(
-                                self.opt_state[name][i],
-                                self._param_sharding[name]))
+                            _np.asarray(v.asnumpy(), _np.float32),
+                            self._param_sharding[name])
         except (MXNetError, ValueError, RuntimeError, TypeError) as e:
             if reshaping:
                 # degrade to the old-mesh error path: the live state

@@ -413,34 +413,29 @@ def planned_executable(program, fn, args):
     ``lower().compile()`` and ordinary jit calls, so dispatching the
     returned object is what keeps this a single compile).
 
-    ``fn`` may already be an AOT ``Compiled`` (the trainer's
-    auto_layouts path): its analyses are read directly.  Anything that
-    prevents planning (no ``lower``, lowering failure, a backend
-    without analyses) degrades to returning ``fn`` unchanged — the
+    Anything that prevents planning (no ``lower``, lowering failure, a
+    backend without analyses) degrades to returning ``fn`` unchanged — the
     plan is observability, only the budget check is allowed to raise."""
-    if hasattr(fn, "memory_analysis"):
-        compiled = fn
-    else:
-        lower = getattr(fn, "lower", None)
-        if lower is None:
-            return fn
-        try:
-            # the one seam every program of the trainer and the executor
-            # compiles through: trace + lower (the graph passes run at
-            # trace time) apart from backend compile / cache load
-            with span("program.lower", program=program):
-                lowered = lower(*args)
-            with span("program.compile", program=program):
-                compiled = lowered.compile()
-        except MXNetError:
-            raise
-        except Exception as e:  # mxlint: allow-broad-except(AOT lowering is an optimization for plan capture; any backend/tracing failure falls back to the ordinary jit dispatch path)
-            import logging
-            logging.getLogger(__name__).debug(
-                "planned_executable(%s): AOT lowering unavailable (%s: "
-                "%s); dispatching via jit without a memory plan",
-                program, type(e).__name__, e)
-            return fn
+    lower = getattr(fn, "lower", None)
+    if lower is None:
+        return fn
+    try:
+        # the one seam every program of the trainer and the executor
+        # compiles through: trace + lower (the graph passes run at
+        # trace time) apart from backend compile / cache load
+        with span("program.lower", program=program):
+            lowered = lower(*args)
+        with span("program.compile", program=program):
+            compiled = lowered.compile()
+    except MXNetError:
+        raise
+    except Exception as e:  # mxlint: allow-broad-except(AOT lowering is an optimization for plan capture; any backend/tracing failure falls back to the ordinary jit dispatch path)
+        import logging
+        logging.getLogger(__name__).debug(
+            "planned_executable(%s): AOT lowering unavailable (%s: "
+            "%s); dispatching via jit without a memory plan",
+            program, type(e).__name__, e)
+        return fn
     # with the two spans above and the caller's .launch, a first
     # dispatch has no stretch without a record
     with span("program.plan", program=program):

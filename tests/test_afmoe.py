@@ -628,20 +628,20 @@ def test_new_readers_read_the_plan_and_none_without_it(monkeypatch, toy_cell):
 
     names = ("attn_window_layers", "flash_window_scores_computed_pct",
              "flash_scores_computed_pct")
-    monkeypatch.setattr(pk, "_LAST_CAUSAL_PLAN", {
+    monkeypatch.setattr(pk, "last_causal_plan", lambda: {
         "kernels": [], "causal_ranges": 4, "scores_computed_pct": 53.125,
         "window_layers": 4, "window_scores_computed_pct": 34.375})
     assert [read(n) for n in names] == [4, 34.375, 53.125]
     # a step without a windowed kernel (LFM2's): a count of 0, no share
-    monkeypatch.setattr(pk, "_LAST_CAUSAL_PLAN", {
+    monkeypatch.setattr(pk, "last_causal_plan", lambda: {
         "kernels": [], "causal_ranges": 4, "scores_computed_pct": 53.125,
         "window_layers": 0, "window_scores_computed_pct": None})
     assert [read(n) for n in names] == [0, None, 53.125]
     # the parent's plan (no such keys), no plan, and a program without one
-    monkeypatch.setattr(pk, "_LAST_CAUSAL_PLAN", {
+    monkeypatch.setattr(pk, "last_causal_plan", lambda: {
         "kernels": [], "causal_ranges": 4, "scores_computed_pct": 53.125})
     assert [read(n) for n in names] == [None, None, 53.125]
-    monkeypatch.setattr(pk, "_LAST_CAUSAL_PLAN", None)
+    monkeypatch.setattr(pk, "last_causal_plan", lambda: None)
     assert [read(n) for n in names] == [None, None, None]
     monkeypatch.delattr(pk, "last_causal_plan")
     assert [read(n) for n in names[:2]] == [None, None]
@@ -659,7 +659,7 @@ def test_new_readers_read_the_plan_and_none_without_it(monkeypatch, toy_cell):
     ids=["no_plan", "parent_plan", "parent_plan_512", "no_kernel", "summary"])
 def test_q_block_rows_reader(monkeypatch, toy_cell, plan, want):
     reader = run.load_module("layer_metrics", "flash_q_block_rows")
-    monkeypatch.setattr(pk, "_LAST_CAUSAL_PLAN", plan)
+    monkeypatch.setattr(pk, "last_causal_plan", lambda: plan)
     assert reader.read({"cell": toy_cell}) == want
     # a program without the function at all
     monkeypatch.delattr(pk, "last_causal_plan")

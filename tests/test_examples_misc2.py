@@ -9,7 +9,6 @@ import os
 import sys
 
 import numpy as np
-import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for sub in ("autoencoder", "dec", "cnn_text_classification", "nce_loss",
@@ -49,30 +48,29 @@ def test_toy_nce_auc():
     assert auc > 0.85, auc
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="chaotic trajectory under whole-suite in-process state: "
-           "passes in isolation, at file scope, AND with the full "
-           "alphabetically-preceding file set (bisected 2026-08), yet "
-           "deterministically lands below the bar inside the full "
-           "tier-1 process — the stochastic gates + momentum amplify "
-           "whatever XLA partition/rounding state 800+ prior tests "
-           "leave behind, and no smaller repro exists to tune against")
 def test_stochastic_depth_trains():
-    import mxnet_tpu as mx
-    import sd_mnist
-    # pin the RNGs, but note the pinned trajectory is still chaotic:
-    # the stochastic gates + momentum amplify reduction-order rounding
-    # differences, so at 10 epochs the SAME seed lands anywhere in
-    # 0.65-0.83 depending on the XLA host-device/thread partition
-    # (conftest forces an 8-device CPU platform; a plain 1-device run
-    # scores 0.82 where the suite scored 0.65).  By 20 epochs training
-    # has converged through that transition on every measured
-    # partition (>= 0.94), so assert there instead of tuning the bar
-    # to one environment's rounding.
-    mx.random.seed(42)
-    np.random.seed(42)
-    acc = sd_mnist.train(epochs=20, batch_size=100, num_blocks=2)
+    """In a process of its own.  The pinned trajectory is chaotic: the
+    stochastic gates and momentum amplify reduction-order rounding, so at
+    10 epochs the SAME seed lands anywhere in 0.65-0.83 depending on the
+    XLA host-device partition, and inside one process that had run the
+    whole suite before it (whatever partition and rounding state 800
+    tests leave behind; bisected 2026-08 to no smaller set) it landed
+    below the bar where alone, at file scope, and after any subset it
+    passed.  By 20 epochs training has converged through that transition
+    on every measured partition (>= 0.94).  A new process under the
+    suite's own environment (``conftest.py``'s eight CPU devices) takes
+    the rest of the suite out of the question."""
+    import subprocess
+    code = ("import sys; sys.path.insert(0, %r); sys.path.insert(0, %r)\n"
+            "import numpy as np, mxnet_tpu as mx, sd_mnist\n"
+            "mx.random.seed(42); np.random.seed(42)\n"
+            "print('acc=%%.4f' %% sd_mnist.train("
+            "epochs=20, batch_size=100, num_blocks=2))"
+            % (ROOT, os.path.join(ROOT, "examples", "stochastic_depth")))
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=600, cwd=ROOT)
+    assert res.returncode == 0, res.stdout + res.stderr
+    acc = float(res.stdout.strip().rsplit("acc=", 1)[1])
     assert acc > 0.75, acc
 
 

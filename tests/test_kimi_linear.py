@@ -1012,36 +1012,36 @@ def test_new_readers_read_the_plans_and_none_without_them(monkeypatch, toy_cell)
     def read(name):
         return run.load_module("layer_metrics", name).read({"cell": toy_cell})
 
-    monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", {
+    monkeypatch.setattr(delta_rule, "last_plan_summary", lambda: {
         "layers": [{}] * 4, "chunked_layers": 4, "kernel_layers": 3,
         "state_bytes": 67108864})
     layer = {"buffer_rows": 8192, "even_rows": 2048.0, "experts_held": 8,
              "num_experts": 256}
-    monkeypatch.setattr(moe, "_LAST_SUMMARY", {
+    monkeypatch.setattr(moe, "last_plan_summary", lambda: {
         "layers": [layer, dict(layer, buffer_rows=4096)]})
     assert read("kda_chunked_layers") == 4
     assert read("kda_kernel_layers") == 3
     assert read("kda_state_saved_gb") == 67108864 / 1e9
     assert read("moe_buffer_rows_pct") == 12.5
     # LFM2's quarter: every assignment has a row
-    monkeypatch.setattr(moe, "_LAST_SUMMARY", {"layers": [dict(
+    monkeypatch.setattr(moe, "last_plan_summary", lambda: {"layers": [dict(
         buffer_rows=32768, even_rows=8192.0, experts_held=8, num_experts=32)]})
     assert read("moe_buffer_rows_pct") == 100.0
     # a plan of the parent's (no even_rows): nothing to read
-    monkeypatch.setattr(moe, "_LAST_SUMMARY", {"layers": [{"buffer_rows": 64}]})
+    monkeypatch.setattr(moe, "last_plan_summary", lambda: {"layers": [{"buffer_rows": 64}]})
     assert read("moe_buffer_rows_pct") is None
     # the parent's plan (no lowering recorded): the new reader has nothing
-    monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", {
+    monkeypatch.setattr(delta_rule, "last_plan_summary", lambda: {
         "layers": [{}] * 4, "chunked_layers": 4, "state_bytes": 67108864})
     assert read("kda_kernel_layers") is None
     # a plan without the backward body's count (the parent's, the CPU's)
     assert read("kda_bwd_hi_products") is None
-    monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", {
+    monkeypatch.setattr(delta_rule, "last_plan_summary", lambda: {
         "layers": [{}] * 4, "chunked_layers": 4, "kernel_layers": 4,
         "state_bytes": 67108864, "bwd_hi_products": 8})
     assert read("kda_bwd_hi_products") == 8
     # a program without the records (the parent of this change): None, no raise
-    monkeypatch.setattr(delta_rule, "_LAST_SUMMARY", None)
+    monkeypatch.setattr(delta_rule, "last_plan_summary", lambda: None)
     monkeypatch.delattr(moe, "last_plan_summary")
     for name in ("kda_chunked_layers", "kda_kernel_layers",
                  "kda_state_saved_gb", "moe_buffer_rows_pct",

@@ -599,12 +599,12 @@ def test_moe_readers_on_hand_made_samples(monkeypatch, toy_cell):
             "b": {"assignments": [8.0] * 4, "tokens_unrouted": 0.0}}
     monkeypatch.setattr(moe, "_LOAD_SAMPLES", [(50.0, skew), (100.5, even),
                                                (101.0, even), (200.0, skew)])
-    monkeypatch.setattr(moe, "_LAST_SUMMARY", {
+    monkeypatch.setattr(moe, "last_plan_summary", lambda: {
         "grouped_layers": 2, "layers": [{"buffer_rows": 128}] * 2})
     assert read("moe_grouped_layers", ctx) == 2
     assert read("moe_dropped_tokens", ctx) == 0
     # a buffer with a capacity of 30 rows could not have held layer a's 32
-    monkeypatch.setattr(moe, "_LAST_SUMMARY", {
+    monkeypatch.setattr(moe, "last_plan_summary", lambda: {
         "grouped_layers": None, "layers": [{"buffer_rows": 30}] * 2})
     assert read("moe_dropped_tokens", ctx) == 2 * 2 * 2
     assert read("moe_grouped_layers", ctx) is None

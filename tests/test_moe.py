@@ -326,7 +326,7 @@ def test_topk_moe_has_two_sizes_only_under_half_the_experts(held, conditional):
 
 def test_publish_load_says_small_buffer_only_where_the_plan_has_one(monkeypatch):
     monkeypatch.setattr(moe, "_LOAD_SAMPLES", [])
-    monkeypatch.setattr(moe, "_LAST_SUMMARY", {"layers": [
+    monkeypatch.setattr(moe, "last_plan_summary", lambda: {"layers": [
         {"small_rows": 10}, {"small_rows": None}, {"small_rows": 10}]})
     loads = {"a": np.array([4.0, 6.0, 1.0]), "b": np.array([9.0, 9.0, 0.0]),
              "c": np.array([5.0, 6.0, 0.0])}
@@ -338,7 +338,7 @@ def test_publish_load_says_small_buffer_only_where_the_plan_has_one(monkeypatch)
     flat = telemetry.REGISTRY.flat()
     assert any(k.startswith("mxtpu_moe_small_buffer") for k in flat)
     # a plan of another step's layers says nothing about these
-    monkeypatch.setattr(moe, "_LAST_SUMMARY", {"layers": [{"small_rows": 10}]})
+    monkeypatch.setattr(moe, "last_plan_summary", lambda: {"layers": [{"small_rows": 10}]})
     moe.publish_load(loads)
     assert all("small_buffer" not in v for v in moe.load_samples()[-1][1].values())
 

@@ -768,9 +768,9 @@ def test_new_readers_read_the_plans_and_none_without_them(monkeypatch, toy_cell)
     readers = {name: run.load_module("layer_metrics", name) for name in (
         "ssd_chunked_layers", "ssd_state_saved_gb", "moe_products_per_layer",
         "moe_grouped_layers")}
-    monkeypatch.setattr(ssd, "_LAST_SUMMARY",
+    monkeypatch.setattr(ssd, "last_plan_summary", lambda:
                         {"chunked_layers": 4, "state_bytes": 134217728})
-    monkeypatch.setattr(moe, "_LAST_SUMMARY", {
+    monkeypatch.setattr(moe, "last_plan_summary", lambda: {
         "expert_layers": 2, "grouped_layers": 2,
         "layers": [{"products_trained": 6}, {"products_trained": 9}]})
     assert readers["ssd_chunked_layers"].read({}) == 4
@@ -779,8 +779,8 @@ def test_new_readers_read_the_plans_and_none_without_them(monkeypatch, toy_cell)
     assert readers["moe_grouped_layers"].read({}) == 2
     # a program that traced no such layer, and an older one whose plan has no
     # such field: nothing is reported, nothing raises
-    monkeypatch.setattr(ssd, "_LAST_SUMMARY", None)
-    monkeypatch.setattr(moe, "_LAST_SUMMARY",
+    monkeypatch.setattr(ssd, "last_plan_summary", lambda: None)
+    monkeypatch.setattr(moe, "last_plan_summary", lambda:
                         {"expert_layers": 1, "layers": [{"buffer_rows": 8}]})
     assert readers["ssd_chunked_layers"].read({}) is None
     assert readers["ssd_state_saved_gb"].read({}) is None

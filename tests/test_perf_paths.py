@@ -351,21 +351,3 @@ def test_fused_fit_cli(tmp_path):
     np.testing.assert_allclose(
         np.asarray(trainer2.params["fc1_weight"]),
         np.asarray(trainer.params["fc1_weight"]), rtol=1e-6)
-
-
-def test_run_steps_auto_layouts_roundtrip():
-    """run_steps under auto_layouts, interleaved with step(): the state
-    migrates between each compiled entry point's chosen formats."""
-    a = _trainer(elide=False)
-    b = _trainer(elide=False, auto_layouts=True)
-    batch = _batch(0)
-    for _ in range(2):
-        a.step(batch)
-    losses = b.run_steps(batch, 2)
-    assert np.all(np.isfinite(np.asarray(losses)))
-    a.step(batch)
-    b.step(batch)  # switch back to the single-step entry point
-    for name in a.params:
-        np.testing.assert_allclose(
-            np.asarray(a.params[name]), np.asarray(b.params[name]),
-            rtol=1e-5, atol=1e-6, err_msg=name)

@@ -316,7 +316,7 @@ def test_new_readers_read_the_plans_and_none_without_them(monkeypatch):
     readers = {name: run.load_module("layer_metrics", name) for name in (
         "blockdiff_attn_layers", "blockdiff_scores_computed_pct",
         "head_rows_pct", "flash_scores_computed_pct", "flash_q_block_rows")}
-    monkeypatch.setattr(pk, "_LAST_CAUSAL_PLAN", {
+    monkeypatch.setattr(pk, "last_causal_plan", lambda: {
         "kernels": [], "causal_ranges": 4, "scores_computed_pct": 31.25,
         "q_block_rows": 512, "window_layers": 0,
         "window_scores_computed_pct": None, "diffusion_layers": 5,
@@ -329,7 +329,7 @@ def test_new_readers_read_the_plans_and_none_without_them(monkeypatch):
         "head_rows_pct": 50.0, "flash_scores_computed_pct": 31.25,
         "flash_q_block_rows": 512}
     # the parent's plan (no such keys), no plan, no graph, no model
-    monkeypatch.setattr(pk, "_LAST_CAUSAL_PLAN", {
+    monkeypatch.setattr(pk, "last_causal_plan", lambda: {
         "kernels": [], "causal_ranges": 4, "scores_computed_pct": 53.125,
         "q_block_rows": 512, "window_layers": 0,
         "window_scores_computed_pct": None})
@@ -337,7 +337,7 @@ def test_new_readers_read_the_plans_and_none_without_them(monkeypatch):
     assert [readers[n].read({}) for n in (
         "blockdiff_attn_layers", "blockdiff_scores_computed_pct",
         "head_rows_pct")] == [None, None, None]
-    monkeypatch.setattr(pk, "_LAST_CAUSAL_PLAN", None)
+    monkeypatch.setattr(pk, "last_causal_plan", lambda: None)
     assert readers["blockdiff_attn_layers"].read({}) is None
     assert readers["blockdiff_scores_computed_pct"].read({}) is None
     monkeypatch.setitem(sys.modules, "mxnet_tpu.models.sdar_moe", None)

@@ -234,36 +234,6 @@ def test_bench_script_cpu_smoke(monkeypatch, capsys):
     assert rec["value"] > 0
 
 
-def test_auto_layouts_matches_default():
-    """auto_layouts=True (XLA-chosen persistent param layouts) trains
-    identically to the default-layout step."""
-    def build(auto):   # one seed: identical initial weights in both
-        data = mx.sym.Variable("data")
-        net = mx.sym.Convolution(data, kernel=(3, 3), pad=(1, 1),
-                                 num_filter=4, name="c1")
-        net = mx.sym.Activation(net, act_type="relu")
-        net = mx.sym.Pooling(net, global_pool=True, pool_type="avg")
-        net = mx.sym.FullyConnected(mx.sym.Flatten(net), num_hidden=3,
-                                    name="fc")
-        net = mx.sym.SoftmaxOutput(net, name="softmax")
-        mesh = build_mesh(tp=1)
-        return ShardedTrainer(net, mesh, data_shapes={"data": (8, 3, 8, 8)},
-                              label_shapes={"softmax_label": (8,)},
-                              learning_rate=0.1, seed=3,
-                              auto_layouts=auto)
-
-    batch = _batch(classes=3)
-    t0, t1 = build(False), build(True)
-    for _ in range(3):
-        l0 = float(t0.step(batch))
-        l1 = float(t1.step(batch))
-    np.testing.assert_allclose(l1, l0, rtol=1e-5)
-    for k in t0.params:
-        np.testing.assert_allclose(np.asarray(t1.params[k]),
-                                   np.asarray(t0.params[k]),
-                                   rtol=1e-5, atol=1e-6, err_msg=k)
-
-
 def test_trainer_checkpoint_roundtrip_and_module_interop(tmp_path):
     """save_checkpoint/load_checkpoint on the fused path: params,
     optimizer slots, and step counter resume identically; the files are
@@ -291,24 +261,6 @@ def test_trainer_checkpoint_roundtrip_and_module_interop(tmp_path):
     # Module can read the same files (reference checkpoint interop)
     sym, args, auxs = mx.model.load_checkpoint(prefix, 3)
     assert set(args) == set(t1.params)
-
-
-def test_trainer_checkpoint_auto_layouts(tmp_path):
-    """load_checkpoint preserves XLA-chosen layouts (auto_layouts): the
-    loaded state must still feed the AOT-compiled step."""
-    import os
-    prefix = os.path.join(str(tmp_path), "al")
-    t1 = _make(optimizer="adam", auto_layouts=True)
-    b = t1.put_batch(_batch())
-    float(t1.step(b))
-    t1.save_checkpoint(prefix, 1, save_optimizer_states=True)
-    t2 = _make(optimizer="adam", auto_layouts=True)
-    b2 = t2.put_batch(_batch())
-    float(t2.step(b2))  # compile the AOT step before loading
-    t2.load_checkpoint(prefix, 1, load_optimizer_states=True)
-    l1 = float(t1.step(b))
-    l2 = float(t2.step(b2))
-    np.testing.assert_allclose(l1, l2, rtol=1e-5)
 
 
 def test_trainer_checkpoint_optimizer_mismatch_raises(tmp_path):

@@ -4,8 +4,8 @@ A parameter whose rule is ``traceable`` (``initializer.rule_for``) comes
 out of one jitted program keyed by the constructor's ``seed``; a rule
 that only fills a host array (a user's ``_init_weight`` over
 ``np.random``) is drawn on the host as it always was.  The distribution
-is the host rule's: same scale from the same fans, computed from the
-reference OIHW shape for convolution masters held HWIO.
+is the host rule's: same scale from the same fans of the reference OIHW
+shape, as convolution masters are held.
 """
 import functools
 import os
@@ -21,7 +21,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.parallel import ShardedTrainer, build_mesh
 from mxnet_tpu.telemetry import spans
 
-CONV = (48, 32, 3, 3)    # OIHW as the rules see it; held HWIO (native)
+CONV = (48, 32, 3, 3)    # OIHW, as the rules see it and the master is held
 FC = (10, 48)
 
 
@@ -41,7 +41,6 @@ def _net():
 
 def _trainer(initializer=None, mesh=None, seed=3, net=None, **kw):
     kw.setdefault("layout", "NHWC")
-    kw.setdefault("native_weight_layout", True)
     return ShardedTrainer(
         net or _net(), mesh or build_mesh(n_devices=1, tp=1),
         data_shapes={"data": (8, 3, 8, 8)},
@@ -98,10 +97,7 @@ def test_device_draw_has_the_host_rules_distribution(rule, name, shape):
     assert attrs["host_params"] == 0 and attrs["host_bytes"] == 0
     w = values[name]
     assert w.dtype == np.float32
-    if len(shape) == 4:     # the master is held HWIO
-        assert w.shape == (3, 3, shape[1], shape[0])
-    else:
-        assert w.shape == shape
+    assert w.shape == shape
     _, kind, param = RULES[rule]
     p, n = param(shape), w.size
     if kind == "constant":
@@ -207,12 +203,8 @@ def test_every_parameter_is_its_own_keys_draw(which):
     for name in names:
         got = np.asarray(t.params[name])
         shape = got.shape
-        if name in t._native_w:   # held HWIO, drawn OIHW
-            shape = tuple(shape[i] for i in (3, 2, 0, 1))
         want = np.asarray(jax.random.normal(_key_of(t, 21, name), shape)) \
             * _xavier_scale(2, "in", shape)
-        if name in t._native_w:
-            want = want.transpose(2, 3, 1, 0)
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-8,
                                    err_msg=name)
     assert not np.array_equal(np.asarray(t.params[names[1]]),
@@ -266,10 +258,7 @@ def test_users_numpy_rule_keeps_the_host_and_its_values():
             continue
         want = (np.random.uniform(-1, 1, t._arg_shapes[name]) * 0.1
                 ).astype(np.float32)
-        if name in t._native_w:
-            want = want.transpose(2, 3, 1, 0)
         np.testing.assert_array_equal(np.asarray(t.params[name]), want)
-    assert t._native_w == {"conv0_weight", "conv1_weight"}
     # the same trainer's other parameters took the device's rules
     assert attrs["host_names"] == ["conv0_weight", "conv1_weight",
                                    "fc_weight"]

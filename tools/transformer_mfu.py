@@ -64,8 +64,6 @@ def main():
     p.add_argument("--repeats", type=int, default=3,
                    help="timed run_steps launches (best is reported)")
     p.add_argument("--dtype", default="bfloat16")
-    p.add_argument("--auto-layouts", type=int, default=1,
-                   help="XLA-chosen persistent state layouts (1=on)")
     p.add_argument("--peak-tflops", type=float, default=197.0,
                    help="chip bf16 peak (v5e: 197)")
     p.add_argument("--json-only", action="store_true")
@@ -82,8 +80,7 @@ def main():
     note("building trainer (param upload rides the host link)...")
     trainer, batch = build_bench_trainer(
         vocab=a.vocab, seq=a.seq, d_model=a.d_model, heads=a.heads,
-        layers=a.layers, batch=a.batch, dtype=a.dtype,
-        auto_layouts=bool(a.auto_layouts))
+        layers=a.layers, batch=a.batch, dtype=a.dtype)
 
     # compile + warm
     note("compiling the %d-step scan + first run..." % a.steps)

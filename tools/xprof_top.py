@@ -258,14 +258,10 @@ def main():
     # fusion names differ from the single-step program
     kk = jax.random.PRNGKey(0)
     if args.scan:
-        fnj = trainer._scan_fns[args.scan]
-        if hasattr(fnj, "as_text"):   # AOT-compiled (auto_layouts)
-            hlo = fnj.as_text()
-        else:
-            hlo = fnj.lower(
-                trainer.params, trainer.opt_state, trainer.aux, staged,
-                kk, jnp.zeros(args.scan, jnp.float32),
-                jnp.zeros(args.scan, jnp.float32)).compile().as_text()
+        hlo = trainer._scan_fns[args.scan].lower(
+            trainer.params, trainer.opt_state, trainer.aux, staged,
+            kk, jnp.zeros(args.scan, jnp.float32),
+            jnp.zeros(args.scan, jnp.float32)).compile().as_text()
     else:
         lowered = trainer._step_fn.lower(
             trainer.params, trainer.opt_state, trainer.aux, staged, kk,
