@@ -84,8 +84,8 @@ from .. import initializer
 from .. import symbol as sym
 from ..base import MXNetError
 from ..telemetry.spans import span
-from .decoder_blocks import add_shared_expert, gated_mlp, linear, \
-    topk_experts
+from .decoder_blocks import add_shared_expert, gated_mlp, \
+    latent_attention, linear, topk_experts
 
 
 def _kda(x, cfg, prefix):
@@ -119,30 +119,11 @@ def _kda(x, cfg, prefix):
 
 
 def _mla(x, cfg, prefix):
-    d, h = cfg["hidden_size"], int(cfg["num_attention_heads"])
-    nope, rope = int(cfg["qk_nope_head_dim"]), int(cfg["qk_rope_head_dim"])
-    dv, rank = int(cfg["v_head_dim"]), int(cfg["kv_lora_rank"])
     if cfg.get("q_lora_rank") is not None or not cfg.get("mla_use_nope", True):
-        raise MXNetError("kimi_linear: a query latent (q_lora_rank) and "
-                         "rotary latent attention are not built")
-    q = sym.Reshape(linear(x, h * (nope + rope), prefix + "q"),
-                    shape=(0, 0, h, nope + rope))
-    kva = linear(x, rank + rope, prefix + "kv_a")
-    latent = sym.RMSNorm(sym.slice_axis(kva, axis=2, begin=0, end=rank),
-                         eps=float(cfg["rms_norm_eps"]),
-                         name=prefix + "kv_norm")
-    kvb = sym.Reshape(linear(latent, h * (nope + dv), prefix + "kv_b"),
-                      shape=(0, 0, h, nope + dv))
-    k_rope = sym.broadcast_axis(
-        sym.Reshape(sym.slice_axis(kva, axis=2, begin=rank,
-                                   end=rank + rope),
-                    shape=(0, 0, 1, rope)), axis=2, size=h)
-    k = sym.Concat(sym.slice_axis(kvb, axis=3, begin=0, end=nope), k_rope,
-                   dim=3)
-    att = sym._contrib_FlashAttention(
-        q, k, sym.slice_axis(kvb, axis=3, begin=nope, end=nope + dv),
-        causal=True, name=prefix + "attn")
-    return linear(sym.Reshape(att, shape=(0, 0, -3)), d, prefix + "o")
+        raise MXNetError("kimi_linear: its latent attention has no query "
+                         "latent (q_lora_rank) and turns no dims "
+                         "(mla_use_nope); models.glm4_moe_lite builds both")
+    return latent_attention(x, dict(cfg, mla_use_nope=True), prefix)
 
 
 def _experts(x, cfg, prefix):

@@ -1090,6 +1090,28 @@ def softmax_cross_entropy(attrs, ctx, data, label):
     return -jnp.sum(picked).reshape((1,)).astype(data.dtype)
 
 
+@register("_contrib_TokenCrossEntropy", arg_names=("data", "label"),
+          aliases=("TokenCrossEntropy",))
+# mxlint: allow-dtype-widening(normalization/softmax statistics accumulate in f32 by contract)
+def token_cross_entropy(attrs, ctx, data, label):
+    """``-log softmax(data)[label]`` of every row, in float32: ``data``
+    ``(rows, classes)`` in any float dtype, ``label`` ``(rows,)`` class ids
+    (clipped to the classes, as ``pick``).  The term of a loss that is made
+    in the graph (``MakeLoss`` over a weighted sum of several of these).
+    Rematerialised: the backward keeps ``data`` as it came, not the float32
+    log-softmax of it, so that two heads over one vocabulary hold one
+    float32 copy of their logits at a time."""
+    idx = jnp.clip(label.astype(jnp.int32), 0, data.shape[-1] - 1)
+
+    @jax.checkpoint
+    # mxlint: allow-dtype-widening(normalization/softmax statistics accumulate in f32 by contract)
+    def nll(data):
+        logp = jax.nn.log_softmax(data.astype(jnp.float32), axis=-1)
+        return -jnp.take_along_axis(logp, idx[:, None], axis=-1)[:, 0]
+
+    return nll(data)
+
+
 @register("IdentityAttachKLSparseReg", arg_names=("data",),
           aux_names=("moving_avg",),
           params={"sparseness_target": 0.1, "penalty": 0.001,

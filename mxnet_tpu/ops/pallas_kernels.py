@@ -572,9 +572,15 @@ def _blocks(t):
 #: ms where 512 rows take 5.5, PR 33).  PERF.md section 6 (PR 35) has the
 #: table that decided it, four shapes by three blocks.
 _Q_BLOCKS = (512, 256, 128)
+#: the most rows of a backward Q block where BOTH head widths take two lane
+#: tiles a row (GLM-4.7-Flash's 256 / 256): at 512 rows the kernel spills,
+#: 10.12 ms where 256 rows take 3.03 at (1, 4096, 20, 256 / 256), while the
+#: forward wants its 512 (1.69 ms against 2.19; PERF.md section 5, PR 44).
+#: 192 / 128 (Kimi Linear's) keeps 512 rows: 15.15 ms against 16.70 at 128
+_BWD_ROWS_TWO_TILES = 256
 
 
-def _flash_blocks(t, dk, dv=None, group=1, causal=False):
+def _flash_blocks(t, dk, dv=None, group=1, causal=False, backward=False):
     """(block_q, block_k) of a call from its shape: the heuristic the
     tuning cache falls back on.  ``block_k`` is :func:`_blocks`' (the
     long product wins), and so is the Q block of a call that is not
@@ -587,14 +593,19 @@ def _flash_blocks(t, dk, dv=None, group=1, causal=False):
     accumulator holds ``group * t`` rows on the streamed route, a part
     of the group's where the call runs in parts: :func:`_group_parts`);
     a length none of them suits keeps :func:`_blocks`' own.  Forward and
-    backward take the same pair.  A windowed call: :func:`_window_blocks`."""
+    backward (``backward``) take the same pair but where both widths
+    pass one lane tile: there the backward stops at
+    :data:`_BWD_ROWS_TWO_TILES` rows.  A windowed call:
+    :func:`_window_blocks`."""
     block_q, block_k = _blocks(t)
     if not causal:
         return block_q, block_k
     d = max(dk, dv or dk)
     dq_rows = group // _group_parts(t, d, group) * t if t > block_k else 0
+    most = _BWD_ROWS_TWO_TILES if backward and min(dk, dv or dk) > 128 \
+        else _Q_BLOCKS[0]
     for rows in _Q_BLOCKS:
-        if rows > block_q and t % rows == 0 and block_k % rows == 0 \
+        if block_q < rows <= most and t % rows == 0 and block_k % rows == 0 \
                 and block_k // rows >= _CAUSAL_RANGES \
                 and _vmem_request(_vmem_need(d, rows, block_k,
                                              dq_rows)) <= _VMEM_MAX:
@@ -633,7 +644,8 @@ def _select_blocks(op, q, causal, v=None, group=1):
     compile error."""
     t = q.shape[1]
     block_q, block_k = _flash_blocks(
-        t, q.shape[-1], None if v is None else v.shape[-1], group, causal)
+        t, q.shape[-1], None if v is None else v.shape[-1], group, causal,
+        backward=op.endswith("_bwd"))
     try:
         from .. import autotune
         cfg = autotune.kernel_config(

@@ -18,6 +18,7 @@ interop at the file level.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 
 from .base import MXNetError
@@ -26,6 +27,7 @@ from . import attribute, name as _name_mod
 from .ops import registry as _registry
 from .ops.registry import OpContext, apply_op, get_op
 from .ops import shapes as _shapes
+from .telemetry import plan as _plan
 
 __all__ = ["Symbol", "Variable", "var", "Group", "load", "load_json",
            "zeros", "ones", "arange"]
@@ -343,7 +345,15 @@ def eval_graph(topo, entries, var_values, is_train=False, key=None,
         if stoch and key is not None:
             k = jax.random.fold_in(key, i)
         octx = OpContext(is_train=is_train, key=k)
-        outs = apply_op(node.op, node_attrs, octx, *ins)
+        # a block's builder names its ops' scope on the device and notes
+        # what the block is (``models.decoder_blocks.block_scope`` and
+        # ``plan_note``)
+        scope = node.raw_attr.get("__scope__")
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            outs = apply_op(node.op, node_attrs, octx, *ins)
+        if "__plan_note__" in node.raw_attr:
+            scope, info = json.loads(node.raw_attr["__plan_note__"])
+            _plan.note(scope, **info)
         n_vis = node.num_outputs()
         n_aux = len(node.inputs) - node.num_args
         vals[id(node)] = outs[:n_vis]

@@ -171,8 +171,12 @@ def test_sixteen_query_heads_a_key_value_head_compile_for_v5e(one_chip):
 # (batch, seq, heads, query/key width, value width): Kimi Linear's latent
 # attention at the cell's 8192 positions (streaming kernels; a 192-wide
 # head takes two lane tiles a row, so the backward asks for the VMEM of its
-# dQ accumulator) and at one K/V panel
-LATENT_SHAPES = [(1, 8192, 32, 192, 128), (1, 2048, 32, 192, 128)]
+# dQ accumulator) and at one K/V panel; GLM-4.7-Flash's at its cell's 4096
+# positions (20 heads, two lane tiles a row on BOTH sides: the backward at
+# 256 x 2048 blocks holds 25 MiB by ``_vmem_need`` with a 4096-row
+# accumulator and asks for 37.5; the compiler's least is 23, PR 44)
+LATENT_SHAPES = [(1, 8192, 32, 192, 128), (1, 2048, 32, 192, 128),
+                 (1, 4096, 20, 256, 256)]
 
 
 @pytest.mark.parametrize("shape", LATENT_SHAPES, ids=str)
@@ -187,6 +191,46 @@ def test_latent_width_flash_compiles_for_v5e(one_chip, shape):
         ((b, t, h, dk), jnp.bfloat16), ((b, t, h, dk), jnp.bfloat16),
         ((b, t, h, dv), jnp.bfloat16),
         names=["mxtpu_flash_fwd_", "mxtpu_flash_bwd_"])
+
+
+def test_latent_attention_block_with_its_options_compiles_for_v5e(
+        one_chip, monkeypatch):
+    """One GLM-4.7-Flash latent-attention block as the shared builder makes
+    it (query latent, the rotary part on slices, 256 / 256 wide heads at 4096
+    positions), forward and backward through the graph's own lowering: the
+    streamed pair at the rule's blocks (512 x 2048 forward, 256 x 2048
+    backward), the backward asking for its 37.5 MiB."""
+    from mxnet_tpu import symbol as sym
+    from mxnet_tpu.models.decoder_blocks import latent_attention
+    from mxnet_tpu.symbol import eval_graph
+    cfg = dict(hidden_size=2048, num_attention_heads=20, qk_nope_head_dim=192,
+               qk_rope_head_dim=64, v_head_dim=256, kv_lora_rank=512,
+               q_lora_rank=768, rms_norm_eps=1e-5, rope_theta=1000000)
+    net = latent_attention(sym.Variable("x"), cfg, "l_")
+    shapes = dict(zip(net.list_arguments(),
+                      net.infer_shape(x=(1, 4096, 2048))[0]))
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    topo = net._topo()
+    nodes = {n.name: n for n in topo if n.is_variable}
+
+    def loss(values):
+        out, _aux = eval_graph(topo, net._entries,
+                               {id(nodes[k]): v for k, v in values.items()},
+                               is_train=True)
+        return out[0].astype(jnp.float32).sum()
+
+    args = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for k, s in shapes.items()}
+    lowered = jax.jit(jax.grad(loss)).lower(args)
+    assert pk._flash_blocks(4096, 256, 256, 1, True) == (512, 2048)
+    assert pk._flash_blocks(4096, 256, 256, 1, True, backward=True) == \
+        (256, 2048)
+    assert "scoped_memory_configs" in lowered.as_text()
+    calls = [line.split("=")[0] for line in
+             lowered.compile().as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 2 and any("mxtpu_flash_fwd_stream" in c for c in calls) \
+        and any("mxtpu_flash_bwd_stream" in c for c in calls), calls
 
 
 @pytest.mark.parametrize("names", [[], ["mxtpu_kda_fwd", "mxtpu_kda_bwd"]],

@@ -163,6 +163,8 @@ CASES = {
     "SoftmaxActivation": ([_x(2, 5)], {}),
     "softmax_cross_entropy": ([_x(3, 4), np.array([0., 2., 1.])], {},
                               {"wrt": (0,)}),
+    "_contrib_TokenCrossEntropy": ([_x(3, 4), np.array([0., 2., 1.])], {},
+                                   {"wrt": (0,)}),
     # neural layers
     "Activation": ([_x(2, 5)], {"act_type": "relu"}),
     "LeakyReLU": ([_x(2, 5)], {"act_type": "leaky", "slope": 0.1}),

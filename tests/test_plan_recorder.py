@@ -47,6 +47,10 @@ TOYS = {
                    {"document": 1024, "seq": 2 * 1024 + 1024 // 4}),
 }
 CASES = [(name, branch) for name in TOYS for branch in ("cpu", "chip")]
+#: toys younger than the recorded file: held to values written out below
+NEW_TOYS = {
+    "smoke-glm47": ("smoke-s64-b1-chain2", {}, {"seq": 1024}),
+}
 
 
 def _load(path):
@@ -58,7 +62,7 @@ def _toy_trainer(name, branch, root):
     import jax
     from mxnet_tpu.parallel import ShardedTrainer, build_mesh
     bench = os.path.join(root, "benchmark")
-    mix_name, cfg_wide, mix_wide = TOYS[name]
+    mix_name, cfg_wide, mix_wide = {**TOYS, **NEW_TOYS}[name]
     cfg = _load(os.path.join(bench, "configs", name + ".json"))
     mix = _load(os.path.join(bench, "traffic", mix_name + ".json"))
     if branch == "chip":
@@ -287,6 +291,36 @@ def test_a_traced_toy_step_publishes_the_parents_summaries(
     assert sorted(got) == sorted(want)
     for module in want:
         assert got[module] == want[module], module
+
+
+@pytest.mark.parametrize("branch", ["cpu", "chip"])
+def test_glm_toy_step_publishes_its_blocks_plans(branch, plan):
+    """GLM-4.7-Flash's toy (PR 44): two decoder layers and the
+    multi-token-prediction module note three latent-attention layers with
+    both options and one module, beside the expert layers' and (as the chip
+    traces it) the flash kernels' plans."""
+    from mxnet_tpu.models import glm4_moe_lite
+    got = summaries("smoke-glm47", branch)
+    assert got["kda"] is None and got["ssd"] is None
+    assert got["moe"]["expert_layers"] == 2
+    seq = 1024 if branch == "chip" else 64
+    assert [(la["buffer_rows"], la["small_rows"], la["even_rows"])
+            for la in got["moe"]["layers"]] == \
+        [(seq * 4, seq * 2, seq * 4 * 4 / 16)] * 2
+    if branch == "cpu":
+        assert got["flash"] is None
+    else:
+        kernels = got["flash"]["kernels"]
+        assert [(k["kernel"], k["shape"], k["dk"], k["dv"], k["block_q"],
+                 k["block_k"]) for k in kernels] == \
+            [("flash_attention_fwd", [1, 1024, 4, 24], 24, 24, 256, 1024)] * 3 \
+            + [("flash_attention_bwd", [1, 1024, 4, 24], 24, 24, 256, 1024)] * 3
+    assert glm4_moe_lite.last_plan_summary() == {
+        "mla_layers": [{"q_lora_rank": 24, "rope_dims": 8, "dk": 24, "dv": 24,
+                        "heads": 4}] * 3,
+        "mtp": {"depth": 1, "layer_rows": seq, "head_rows": 2 * seq,
+                "loss_weight": 0.3,
+                "shared": ["embed_weight", "lm_head_weight"]}}
 
 
 def test_recorded_summaries_cover_every_case_and_every_module():

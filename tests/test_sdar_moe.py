@@ -388,6 +388,10 @@ PARENT_GRAPHS = {"smoke-lfm2": "c37b93222543fad4",
                  "smoke-nemotron": "8613002c0208e9ed",
                  "smoke-opt": "730cb35b36a99072"}
 NEW_DEFAULTS = {"diffusion_block": "0", "score_func": "sigmoid"}
+#: what PR 44's shared latent-attention builder writes on Kimi Linear's one
+#: such block and on nothing else: the block's scope on its nodes, the
+#: layer's plan note on its attention call
+BLOCK_META = ("__scope__", "__plan_note__")
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_GRAPHS))
@@ -405,6 +409,13 @@ def test_the_neighbours_graphs_are_the_parents(name):
             if node.get("attrs", {}).get(key) == value:
                 del node["attrs"][key]
                 carried += 1
+        meta = [key for key in BLOCK_META if key in node.get("attrs", {})]
+        assert not meta or (name == "smoke-kimi"
+                            and node["attrs"]["__scope__"] == "mxtpu.block.mla")
+        for key in meta:
+            del node["attrs"][key]
+        if meta and not node["attrs"]:
+            del node["attrs"]
     assert carried > 0
     assert hashlib.sha256(json.dumps(graph, sort_keys=True).encode()) \
         .hexdigest()[:16] == PARENT_GRAPHS[name]

@@ -16,6 +16,9 @@ Usage (on the TPU host; prints one JSON line a kernel and count):
         --shapes 4x2048x32x32x64,1x8192x32x8x64 --ranges 1,2,4,8,16
     python tools/flash_causal_bench.py --shapes 1x8192x32x4x128 \
         --window 2048 --ranges 4 --blocks 128x2048,128x1024,128x512
+    python tools/flash_causal_bench.py --shapes 1x4096x20x20x256 \
+        --ranges 4 --blocks 512x2048,256x2048,512x1024
+(the last: GLM-4.7-Flash's latent attention, two lane tiles on both sides.)
 Shapes are ``B x T x query heads x key/value heads x head_dim``: the
 head grouping is the two head counts; a sixth number is the values'
 width where it is not the queries' (``1x8192x32x32x192x128``).  Every
@@ -79,12 +82,15 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None, window=0):
     rng = np.random.RandomState(0)
     mk = lambda h, d: jnp.asarray(rng.normal(0, 1, (b, t, h, d)), dtype)
     q, k, v, g = mk(hq, d), mk(hk, d), mk(hk, dv), mk(hq, dv)
-    rule = pk._window_blocks(t) if 0 < window < t else \
-        pk._flash_blocks(t, d, dv, hq // hk, True)
+    # the rule's pair by kernel: the backward's differs from the forward's
+    # where both widths take two lane tiles
+    rule = {which: pk._window_blocks(t) if 0 < window < t else
+            pk._flash_blocks(t, d, dv, hq // hk, True, backward=which == "bwd")
+            for which in ("fwd", "bwd")}
     kw = {} if blocks is None else {"blocks": tuple(blocks)}
     if window:
         kw["window"] = window
-    blocks = blocks or rule
+    timed = {which: blocks or rule[which] for which in rule}
     variants = []
     for s in ranges:
         fwd = jax.jit(lambda q, k, v, s=s: pk._flash_attention_fwd_pallas(
@@ -109,10 +115,6 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None, window=0):
     records = []
     f32 = lambda x: np.asarray(x, np.float32)
     for i, (s, _f, _b, outs, grads) in enumerate(variants):
-        plan = pk._causal_plan(blocks[0], blocks[1], s)
-        pct = pk._scores_computed_pct(
-            t, blocks[0], blocks[1], plan,
-            window if 0 < window < t and t > blocks[1] else 0)
         # multiply-adds a head needs: the causal half, or the band
         pairs = t * t / 2 if not 0 < window < t else \
             window * (window + 1) / 2 + (t - window) * window
@@ -122,6 +124,11 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None, window=0):
         for j, (which, (over_d, over_dv), got, ref) in enumerate((
                 ("fwd", (1, 1), outs[:1], first[3][:1]),
                 ("bwd", (2, 2), grads, first[4]))):
+            blocks = timed[which]
+            plan = pk._causal_plan(blocks[0], blocks[1], s)
+            pct = pk._scores_computed_pct(
+                t, blocks[0], blocks[1], plan,
+                window if 0 < window < t and t > blocks[1] else 0)
             chunk = events[(2 * i + j) * reps:(2 * i + j + 1) * reps]
             names = {n.rstrip(".0123456789") for _t0, _d, n in chunk}
             assert len(names) == 1 and which in min(names), names
@@ -130,7 +137,7 @@ def bench(pk, shape, ranges, reps, dtype, blocks=None, window=0):
             records.append({
                 "kernel": min(names).lstrip("%"), "shape": list(shape),
                 "window": window,
-                "blocks": list(blocks), "rule_blocks": list(rule),
+                "blocks": list(blocks), "rule_blocks": list(rule[which]),
                 "ranges": len(plan[1]),
                 "scores_computed_pct": round(pct, 2), "ms": round(ms, 4),
                 "needed_gflop": round(needed / 1e9, 1),
