@@ -126,7 +126,13 @@ def _sorted_dispatch(k):
     """``(dispatch, combine)`` for ``k`` assignments a token: the two
     row permutations between token order and expert-sorted order, each
     with a hand-written transpose so that both directions are gathers
-    (autodiff would scatter-add ``tokens * k`` rows)."""
+    (autodiff would scatter-add ``tokens * k`` rows).  That holds for a
+    buffer of every assignment there can be, where the sorted order is a
+    permutation and ``inv`` its inverse; a buffer of fewer rows sums them
+    into token order another way (:func:`_bounded_products`): gathering
+    ``tokens * k`` rows through ``inv`` for the ``n_rows`` that are there
+    costs more than it saves below half of them (PERF.md section 5's
+    table)."""
     import jax
     import jax.numpy as jnp
 
@@ -213,14 +219,119 @@ def _experts(xs, w1, w3, w2, sizes):
     return jax.lax.ragged_dot(h, down, sizes)
 
 
-def _bounded_products(x, w1, w3, w2, gates, order, counts, n_rows):
+def _token_sum_form(t, n_rows, d, dtype):
+    """``(form, tokens a block)`` of the two sums of a sorted buffer's rows
+    into token order (:func:`_bounded_products`), a rule in the shapes and
+    the platform as ``_flash_blocks`` is one: ``"kernel"``,
+    ``mxtpu_moe_token_sum`` (``ops/moe_token_sum.py``), over rows of whole
+    lane tiles, whole row chunks and whole token blocks, at the largest
+    block of :data:`TOKEN_SUM_BLOCKS` that fits the default of VMEM at this
+    width, where :func:`_token_sum_lowering` has the kernel; anywhere else
+    ``"scatter_add"``, the ``jax.numpy`` form."""
+    from ..ops import moe_token_sum as _kernel
+    if d % 128 == 0 and n_rows % _kernel.CHUNK == 0:
+        for block, widest in TOKEN_SUM_BLOCKS:
+            if t % block == 0 and d <= widest:
+                return _token_sum_lowering(dtype), block
+    return "scatter_add", None
+
+
+def _token_sum_lowering(dtype):
+    """``"kernel"`` on a TPU over bf16 rows (a 0/1 matrix times bf16 rows
+    is exact on the MXU; float32 rows would take six passes), else
+    ``"scatter_add"``: the ``jax.numpy`` form, every other backend's path
+    and the tests' oracle, as the delta rule's and the flash kernels'
+    ``jax.numpy`` forms are.  Under a mesh of more than one device it stays
+    too: GSPMD cannot partition the kernel.  (``"interpret"``, the kernel
+    under Pallas's interpreter at any dtype, is the tests' to ask for.)"""
+    import jax.numpy as jnp
+    from .. import context as _context
+    from . import mesh as _mesh
+    if _context.on_tpu() and _mesh.active_kernel_mesh() is None \
+            and dtype == jnp.bfloat16:
+        return "kernel"
+    return "scatter_add"
+
+
+def _kernel_sums(k, n_rows, block, interpret):
+    """``(dispatch, combine)`` of :func:`_bounded_products` on the kernel:
+    the gather into the buffer's order and the weighted sum back, each with
+    a hand-written transpose, as :func:`_sorted_dispatch`'s pair has.  The
+    gather's transpose is the kernel with unit weights; the sum's is a
+    gather (``g[token] * weight``), and the gates' cotangent comes from the
+    kept product rows through ``slot``, another gather."""
+    import jax
+    import jax.numpy as jnp
+    from ..ops.moe_token_sum import token_sum
+
+    @jax.custom_vjp
+    def dispatch(x, head, filled, pos):
+        return jnp.where(filled[:, None], x[head // k], 0)
+
+    def dispatch_fwd(x, head, filled, pos):
+        return dispatch(x, head, filled, pos), pos
+
+    def dispatch_bwd(pos, g):
+        return (token_sum(g, pos, block=block, interpret=interpret),
+                None, None, None)
+
+    dispatch.defvjp(dispatch_fwd, dispatch_bwd)
+
+    @jax.custom_vjp
+    def combine(rows, gates, head, filled, slot, hit, pos):
+        weight = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)
+        return token_sum(rows, pos, weight, block=block, interpret=interpret)
+
+    def combine_fwd(rows, gates, *where):
+        # the product rows are kept only for the gates' cotangent: a layer
+        # whose router is not trained (``gates`` unperturbed) keeps none,
+        # as autodiff kept none through the scatter-add
+        y = combine(rows.value, gates.value, *(w.value for w in where))
+        return y, (rows.value if gates.perturbed else None, gates.value,
+                   *(w.value for w in where[:3]))
+
+    def combine_bwd(res, g):
+        rows, gates, head, filled, slot = res
+        if isinstance(g, jax.custom_derivatives.SymbolicZero):
+            g = jnp.zeros(g.shape, g.dtype)
+        weight = jnp.where(filled, gates.reshape(-1)[head], 0.0)
+        by_row = g[head // k].astype(jnp.float32)           # (n_rows, d)
+        if rows is None:
+            d_gates = jnp.zeros_like(gates)
+        else:
+            kept = jnp.where(filled[:, None], rows.astype(jnp.float32), 0.0)
+            # an assignment has a row where it sorts before the groups' end
+            d_gates = jnp.where(
+                slot < jnp.sum(filled),
+                jnp.sum(by_row * kept, axis=1)[jnp.minimum(slot, n_rows - 1)],
+                0.0)
+        # y has the rows' dtype, and so has its cotangent
+        return ((by_row * weight[:, None]).astype(g.dtype), d_gates,
+                None, None, None, None, None)
+
+    combine.defvjp(combine_fwd, combine_bwd, symbolic_zeros=True)
+    return dispatch, combine
+
+
+def _bounded_products(x, w1, w3, w2, gates, order, inv, counts, n_rows):
     """The held experts' part of the result over a sorted buffer of
     ``n_rows < tokens * top_k`` rows (:func:`buffer_rows`): the buffer is
     the head of the sorted order, the groups end where it does, and the
     gather into it, the experts' products and the sum back into token order
-    all run over its rows alone (autodiff's transposes too: a gather of
-    ``n_rows`` rows for the scatter-add and the reverse).  An assignment
-    past the buffer has no row and adds nothing."""
+    all run over its rows alone.  An assignment past the buffer has no row
+    and adds nothing.
+
+    The sum back and the gather's transpose both add the buffer's rows into
+    token order.  ``order`` is a *stable* ``argsort`` by expert, so inside
+    an expert's group the tokens ascend strictly and none repeats: the rows
+    of a group that a block of tokens reads are one contiguous range, and
+    where :func:`_token_sum_form` says ``"kernel"`` both are
+    ``mxtpu_moe_token_sum`` calls over those ranges, per token the
+    experts added in ascending order in float32, and their transposes are
+    gathers (:func:`_kernel_sums`).  Elsewhere they are the ``jax.numpy``
+    scatter-add and autodiff's transposes (a gather of ``n_rows`` rows for
+    the scatter-add and the reverse), which must assume that any two rows
+    may hit one token."""
     import jax
     import jax.numpy as jnp
 
@@ -231,6 +342,21 @@ def _bounded_products(x, w1, w3, w2, gates, order, counts, n_rows):
     # rows past the held assignments belong to no group: what a grouped
     # product leaves there is neither a result nor a gradient
     filled = jnp.arange(n_rows) < jnp.sum(sizes)
+    form, block = _token_sum_form(t, n_rows, x.shape[1], x.dtype)
+    if form != "scatter_add":
+        # pos[t, e]: the buffer row of token t's assignment to held expert
+        # e, -1 where it has none there (or none within the buffer)
+        ends = jnp.cumsum(sizes)
+        slot = inv.reshape(t, k)
+        hit = (slot[:, :, None] >= (ends - sizes)[None, None, :]) \
+            & (slot[:, :, None] < ends[None, None, :])      # (t, k, held)
+        pos = jnp.sum(jnp.where(hit, slot[:, :, None], 0), axis=1) \
+            - (~jnp.any(hit, axis=1))
+        dispatch, combine = _kernel_sums(k, n_rows, block,
+                                         form == "interpret")
+        xs = dispatch(x, order, filled, pos)
+        rows = _experts(xs, w1, w3, w2, sizes)
+        return combine(rows, gates, order, filled, slot, hit, pos)
     weight = jnp.where(filled, gates.reshape(-1)[order], 0.0)
     xs = jnp.where(filled[:, None], x[token], 0)            # (n_rows, d)
     rows = _experts(xs, w1, w3, w2, sizes)
@@ -250,7 +376,8 @@ def _at_the_bound(x, w1, w3, w2, gates, order, inv, here, counts, n_rows):
 
     t, k = gates.shape
     if n_rows < t * k:
-        return _bounded_products(x, w1, w3, w2, gates, order, counts, n_rows)
+        return _bounded_products(x, w1, w3, w2, gates, order, inv, counts,
+                                 n_rows)
     dispatch, combine = _sorted_dispatch(k)
     xs = dispatch(x, order, inv, here)                      # (t*k, d)
     rows = _experts(xs, w1, w3, w2, counts)
@@ -277,7 +404,7 @@ def _two_sizes(n_rows, small_rows):
 
     def two_sizes(x, w1, w3, w2, gates, order, inv, here, counts):
         def small(x, w1, w3, w2, gates):
-            return _bounded_products(x, w1, w3, w2, gates, order, counts,
+            return _bounded_products(x, w1, w3, w2, gates, order, inv, counts,
                                      small_rows)
 
         def bound(x, w1, w3, w2, gates):
@@ -302,6 +429,13 @@ BUFFER_OVER_EVEN = 4
 #: and how many times the second, smaller buffer, which a step runs over
 #: when what it holds fits
 SMALL_OVER_EVEN = 2
+#: tokens a block of ``mxtpu_moe_token_sum``, the largest first, each with
+#: the widest rows at which its accumulator, two windows and output fit the
+#: default of VMEM (compiled for a described v5e: 512 x 2304 fits and 512 x
+#: 2432 does not, 256 x 4096 fits and 128 x 8192 does not).  The largest
+#: block is the fastest at every shape of PERF.md section 5's table (LFM2's
+#: sum: 0.485 ms at 128, 0.375 at 256, 0.330 at 512; my chip run, PR 46)
+TOKEN_SUM_BLOCKS = ((512, 2304), (256, 4096), (128, 4096))
 #: rows a token that the smaller buffer must save for a layer to have it:
 #: the ``cond`` between the two sizes costs at its edge whatever it saves
 #: (its branches share no buffer and XLA fuses nothing across it), and at
@@ -401,6 +535,19 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
     products skip the rows past the held assignments, which belong to
     no group.
 
+    Two of those steps add the buffer's rows into token order: the
+    combine, and the transpose of the gather into the buffer.  Because the
+    sort is stable, inside an expert's group the tokens ascend and none
+    repeats, so on a TPU (bf16 rows of whole lane tiles, whole blocks of
+    tokens: :func:`_token_sum_form`) both are the Pallas kernel
+    ``mxtpu_moe_token_sum``, which reads each block of tokens' rows of a
+    group as one contiguous range and adds a token's experts in ascending
+    order in float32; their own transposes are gathers.  Anywhere else
+    they are the ``jax.numpy`` scatter-add, which must walk the rows one
+    by one.  A buffer that holds every assignment is a permutation, and
+    gathers both ways (:func:`_sorted_dispatch`).  A layer's plan says
+    which (``token_sum``).
+
     So the rows a step runs over follow what it holds.  Where twice the
     even load (:func:`small_buffer_rows`) saves more than half a row a
     token on the bound (under half of the experts held, and not so few
@@ -464,10 +611,15 @@ def topk_moe(x, router_w, expert_bias, w1, w3, w2, top_k,
         load = jnp.concatenate([
             counts.astype(jnp.float32),
             jnp.sum(~jnp.any(here, axis=1), dtype=jnp.float32)[None]])
+    # what sums the rows of the buffer a fitting step runs over into token
+    # order: the full buffer's permutation is gathers both ways already
+    summed = n_rows if small_rows is None else small_rows
     note_layer(num_experts=router_w.shape[0], experts_held=held,
                expert_offset=int(expert_offset), num_experts_per_tok=k,
                hidden_size=w2.shape[1], buffer_rows=n_rows,
                small_rows=small_rows, even_rows=t * k * held / router_w.shape[0],
+               token_sum="gathers" if summed == t * k else
+               _token_sum_form(t, summed, d, x.dtype)[0],
                products_trained=6 if w3 is None
                else PRODUCTS_PER_TRAINED_LAYER, score_func=score_func)
     return y, load
@@ -519,7 +671,8 @@ def note_compiled(executable):
 
 def last_plan_summary():
     """Summary of the expert layers of the step traced last in this
-    process (None before any): ``expert_layers``; per layer the router
+    process (None before any): ``expert_layers``; ``token_sum_layers``, the
+    layers whose ``token_sum`` is the kernel; per layer the router
     width, experts held and offset, experts a token, the experts' width,
     ``score_func`` (``"sigmoid"`` or ``"softmax"``),
     ``buffer_rows`` (the most rows of the sorted buffer its products run
@@ -527,9 +680,13 @@ def last_plan_summary():
     ``small_rows`` (the rows a step runs over instead when what it holds
     fits them, :func:`small_buffer_rows`; None for a layer with one
     size), ``even_rows`` (the assignments even routing sends to the held
-    experts) and ``products_trained`` (the grouped products a trained
-    step runs for it: 9 for gated experts, 6 for ungated ones; a layer
-    with two sizes compiles twice that); and, once that step's program is
+    experts), ``token_sum`` (what sums the rows of the buffer a fitting step
+    runs over into token order, forward and in the dispatch gather's
+    transpose: ``"kernel"``, ``mxtpu_moe_token_sum``; ``"scatter_add"``, the
+    ``jax.numpy`` form; ``"gathers"``, the permutation of a buffer that
+    holds every assignment) and ``products_trained`` (the grouped products
+    a trained step runs for it: 9 for gated experts, 6 for ungated ones; a
+    layer with two sizes compiles twice that); and, once that step's program is
     compiled,
     ``grouped_products`` and ``grouped_layers`` as
     :func:`note_compiled` reads them from it.  As
@@ -540,6 +697,8 @@ def last_plan_summary():
     if layers is None:
         return None
     return dict({"expert_layers": len(layers), "layers": layers,
+                 "token_sum_layers": sum(
+                     layer.get("token_sum") == "kernel" for layer in layers),
                  "grouped_products": None, "grouped_layers": None},
                 **_plan.annotations(SCOPE_MOE))
 

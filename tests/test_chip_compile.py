@@ -318,6 +318,78 @@ def test_two_size_expert_layer_compiles_for_v5e(one_chip):
     assert rows == [held] * 6 + [small] * 5 + [bound] * 5, rows
 
 
+#: the six expert cells' sums into token order: (tokens, held experts, width,
+#: rows of the buffer a fitting step runs over)
+TOKEN_SUM_SHAPES = {
+    "lfm2moe": (8192, 8, 2048, 16384), "sdar": (8192, 16, 2048, 16384),
+    "trinitymini": (8192, 8, 2048, 8192), "nemotron3nano": (8192, 8, 2688, 6144),
+    "kimilinear": (8192, 8, 2304, 8192), "glm47flash": (4096, 8, 2048, 4096),
+    # the widest rows of the 256- and 128-token blocks (Kimi Linear's are the
+    # 512-token block's)
+    "wide-256": (8192, 8, 4096, 8192), "wide-128": (8320, 8, 4096, 8192)}
+
+
+@pytest.mark.parametrize("cell", sorted(TOKEN_SUM_SHAPES))
+def test_moe_token_sum_compiles_for_v5e(one_chip, monkeypatch, cell):
+    """``mxtpu_moe_token_sum`` at each expert cell's shape, and at each block's
+    widest rows, with the block the rule gives it, with the gates' weights (the forward combine) and without
+    (the dispatch gather's transpose): it fits the default of VMEM and asks
+    for none."""
+    from mxnet_tpu.ops import moe_token_sum
+    from mxnet_tpu.parallel import moe
+    t, held, d, n_rows = TOKEN_SUM_SHAPES[cell]
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    form, block = moe._token_sum_form(t, n_rows, d, jnp.bfloat16)
+    assert form == "kernel"
+    shapes = [((n_rows, d), jnp.bfloat16), ((t, held), jnp.int32),
+              ((t, held), jnp.float32)]
+    for operands in (shapes, shapes[:2]):
+        compiled = _compile_for_chip(
+            lambda *a: moe_token_sum.token_sum(*a, block=block), one_chip,
+            *operands, names=[moe_token_sum.MOE_TOKEN_SUM])
+        assert "vmem_limit" not in compiled.as_text()
+
+
+def test_expert_layer_on_the_kernel_holds_no_scatter_for_v5e(one_chip,
+                                                             monkeypatch):
+    """``topk_moe`` at LFM2's size as the chip traces it: the small branch's
+    two sums into token order are two ``mxtpu_moe_token_sum`` calls (the
+    ``checkpoint`` does not run the forward's again in the backward), the
+    branch at the bound is the permutation's gathers, and no ``scatter`` is
+    left under the layer's scope."""
+    from mxnet_tpu.ops import moe_token_sum
+    from mxnet_tpu.parallel import moe
+    t, k, e, held, d, ff = 8192, 4, 32, 8, 2048, 1792
+    monkeypatch.setattr(context, "on_tpu", lambda: True)
+    moe._two_sizes.cache_clear()         # nothing traced off the chip's path
+
+    def loss(x, router_w, bias, w1, w3, w2):
+        y, _load = moe.topk_moe(x, router_w, bias, w1, w3, w2, k,
+                                router_trained=False)
+        return y.astype(jnp.float32).sum()
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((t, d), jnp.bfloat16), ((e, d), jnp.float32), ((e,), jnp.float32),
+        ((held, d, ff), jnp.bfloat16), ((held, d, ff), jnp.bfloat16),
+        ((held, ff, d), jnp.bfloat16))]
+    try:
+        with moe.plan_recording():
+            text = jax.jit(jax.value_and_grad(loss, (0, 3, 4, 5))).lower(
+                *args).compile().as_text()
+    finally:
+        moe._two_sizes.cache_clear()
+    plan = moe.last_plan_summary()
+    assert plan["token_sum_layers"] == 1
+    assert plan["layers"][0]["token_sum"] == "kernel"
+    lines = text.splitlines()
+    sums = [l for l in lines if "tpu_custom_call" in l
+            and moe_token_sum.MOE_TOKEN_SUM in l.split("=")[0]]
+    assert len(sums) == 2, [l.split("=")[0] for l in sums]
+    assert not [l.split("=")[0] for l in lines
+                if " scatter(" in l and moe.SCOPE_MOE in l]
+    assert sum(" conditional(" in l for l in lines) == 2
+
+
 # stage 1 and stage 2 of ResNet-50 at batch 128 (the flatten round the
 # removed kernel moved the whole activation there), and the shape of
 # PR 25's A/B, whose flatten is a bitcast (the kernel lost there too)

@@ -391,7 +391,7 @@ def test_topk_moe_op_shapes_aux_and_plan():
         "num_experts": 8, "experts_held": 2, "expert_offset": 4,
         "num_experts_per_tok": 2, "hidden_size": 24, "buffer_rows": 32,
             "small_rows": 16, "even_rows": 8.0, "products_trained": 9,
-            "score_func": "sigmoid"}
+            "score_func": "sigmoid", "token_sum": "scatter_add"}
     # how the products were lowered is read from a compiled program only
     assert plan["grouped_products"] is None and plan["grouped_layers"] is None
 
